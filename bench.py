@@ -14,10 +14,13 @@ reference GPU doc's recommended 63-bin setting
 the same synthetic task, so throughput is never quoted without accuracy
 (docs/GPU-Performance.rst:134-158 reports AUC next to speed).
 
+Needs a TPU: with no chip it exits nonzero and prints no rate.  The JSON line
+names the device it ran on (``platform``, ``device_kind``, ``device_count``).
+
 Env overrides: BENCH_ROWS, BENCH_ITERS, BENCH_LEAVES, BENCH_BIN (set
 BENCH_BIN to run ONE bin setting instead of both), BENCH_TELEMETRY_OUT
 (base path for the self-recording telemetry JSONL + summary artifacts;
-defaults under the system tempdir).
+defaults to ``bench_out/bench`` in the checkout).
 """
 import json
 import os
@@ -25,7 +28,8 @@ import sys
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+_HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, _HERE)
 
 BASELINE_ROW_TREES_PER_S = 10_500_000 * 500 / 238.5
 
@@ -40,9 +44,6 @@ def measure(X, y, X_test, y_test, *, max_bin, leaves, iters):
     and the BENCH numbers printed below are read back from that summary —
     bench.py no longer does its own accounting (``BENCH_TELEMETRY_OUT``
     overrides the artifact location)."""
-    import tempfile
-
-    import jax
     from lightgbm_tpu import obs
     from lightgbm_tpu.boosting.gbdt import GBDT
     from lightgbm_tpu.config import Config
@@ -58,28 +59,17 @@ def measure(X, y, X_test, y_test, *, max_bin, leaves, iters):
                  max_bin=max_bin)
     booster = GBDT(cfg, ds, create_objective("binary", cfg))
 
-    out_base = os.environ.get("BENCH_TELEMETRY_OUT")
-    if out_base:
-        out_path = "%s_bin%d.jsonl" % (out_base, max_bin)
-    else:
-        # a per-run private directory: a fixed shared-tempdir name would
-        # collide across users/concurrent benches on one box
-        out_path = os.path.join(
-            tempfile.mkdtemp(prefix="bench_telemetry_"),
-            "bench_bin%d.jsonl" % max_bin)
-
-    def force_sync():
-        # a scalar device fetch is the only reliable completion barrier on
-        # remote/tunneled runtimes where block_until_ready returns early
-        booster.train_score.block_until_ready()
-        float(jax.device_get(booster.train_score[0, 0]))
+    out_base = os.environ.get("BENCH_TELEMETRY_OUT") or os.path.join(
+        _HERE, "bench_out", "bench")
+    out_path = "%s_bin%d.jsonl" % (out_base, max_bin)
+    os.makedirs(os.path.dirname(out_path), exist_ok=True)
 
     # warm up with the SAME k=iters fused program the timed run uses (a
     # second program size would double the multi-minute 10.5M-row compile).
     # Telemetry starts AFTER the warmup: the artifact's chunk/rows-per-s
     # histograms describe the steady state, not the compile-laden warmup
     booster.train_chunk(iters)
-    force_sync()
+    booster.train_score.block_until_ready()
     tele = obs.configure(out=out_path, freq=1, entry="bench",
                          rows=n, features=f, max_bin=max_bin,
                          leaves=leaves, iters=iters)
@@ -89,8 +79,10 @@ def measure(X, y, X_test, y_test, *, max_bin, leaves, iters):
 
     with tele.time_block("timed_window", iters=iters):
         booster.train_chunk(iters)
-        force_sync()
+        booster.train_score.block_until_ready()
     dt = tele.histogram("timed_window_s").sum
+    if booster._fuse_failed:
+        sys.exit("bench.py: training left the fused train_chunk path")
     # snapshot BEFORE the AUC predict below (whose first-ever dispatch is a
     # legitimate compile): the pinned claim is about the timed window
     tele.gauge("recompiles_timed_window").set(obs.recompile.total())
@@ -103,12 +95,6 @@ def measure(X, y, X_test, y_test, *, max_bin, leaves, iters):
     # promoted form of the accounting bench.py used to carry inline)
     trees = booster.models[-iters:]
     est = obs_mfu.training_utilization(trees, n, iters, f, max_bin, dt)
-    if est["mfu"] is None:
-        # no recognized accelerator attached: keep the historical BENCH
-        # convention of quoting utilization against the v5e peaks so
-        # proxy-box runs stay comparable with the trajectory
-        est["device_util"] = est["bytes"] / dt / obs_mfu.V5E_PEAK_BW
-        est["mfu"] = est["macs"] / dt / obs_mfu.V5E_PEAK_MACS
     tele.gauge("mfu").set(est["mfu"])
     tele.gauge("device_util").set(est["device_util"])
     tele.gauge("train_rows").set(n)
@@ -134,15 +120,21 @@ def measure(X, y, X_test, y_test, *, max_bin, leaves, iters):
 
 def main() -> None:
     import jax
+    from lightgbm_tpu.utils.compile_cache import enable_compilation_cache
     from lightgbm_tpu.utils.log import Log
     Log.reset_level(Log.level_from_verbosity(-1))  # stdout = the JSON line only
 
-    on_tpu = jax.default_backend() == "tpu"
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit("bench.py needs a TPU: jax found platform=%r (%s); a CPU "
+                 "run measures nothing its users pay for"
+                 % (dev.platform, dev.device_kind))
+    enable_compilation_cache()
     # the REAL Higgs shape is the headline (docs/Experiments.rst:103-117);
     # fixed per-split costs amortize with rows, so 10.5M outruns 1M
-    n = int(os.environ.get("BENCH_ROWS", 10_500_000 if on_tpu else 50_000))
-    iters = int(os.environ.get("BENCH_ITERS", 20 if on_tpu else 5))
-    leaves = int(os.environ.get("BENCH_LEAVES", 255 if on_tpu else 31))
+    n = int(os.environ.get("BENCH_ROWS", 10_500_000))
+    iters = int(os.environ.get("BENCH_ITERS", 20))
+    leaves = int(os.environ.get("BENCH_LEAVES", 255))
     only_bin = os.environ.get("BENCH_BIN")
     f = 28
 
@@ -182,24 +174,16 @@ def main() -> None:
                "value_63": r63["value"],
                "vs_baseline_63": r63["vs_baseline"],
                "auc_63": r63["auc"]}
-    if os.environ.get("BENCH_WIDEF", "0") == "1":
-        # opt-in: the F=968 grid-over-groups measurement (PERF.md "Wide-F")
-        # in a subprocess so a pathological compile cannot hang the bench
-        import subprocess
-        try:
-            p = subprocess.run(
-                [sys.executable,
-                 os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "tools", "bench_widef.py"), "--json"],
-                capture_output=True, text=True, timeout=1800)
-            if p.returncode == 0 and p.stdout.strip():
-                out["widef"] = json.loads(p.stdout.strip().splitlines()[-1])
-            else:
-                out["widef_error"] = (p.stderr or "no output")[-500:]
-        except Exception as exc:  # timeout/JSON failure must not lose the
-            out["widef_error"] = repr(exc)[-500:]  # main bench results
-    from lightgbm_tpu import obs
+    out.update(platform=dev.platform, device_kind=dev.device_kind,
+               device_count=len(jax.devices()))
+    from lightgbm_tpu import obs, resilience
+    from lightgbm_tpu.plan import cache as plan_cache
     obs.disable()  # close the JSONL sink before the process exits
+    fallbacks = dict(resilience.fallback_counts(),
+                     plan_cache=plan_cache.fallback_count())
+    if any(fallbacks.values()):
+        sys.exit("bench.py: a degraded path served part of the run: %r"
+                 % fallbacks)
     print(json.dumps(out))
 
 

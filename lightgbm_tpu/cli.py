@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import os
 import sys
-import tempfile
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -21,6 +20,7 @@ from .config import Config, parse_config_file
 from .io.loader import DatasetLoader
 from .metric.metric import create_metrics
 from .objective import create_objective
+from .utils.compile_cache import enable_compilation_cache
 from .utils.log import Log
 from .utils.timer import global_timer
 
@@ -40,34 +40,6 @@ def parse_args(argv: List[str]) -> Dict[str, str]:
         for k, v in file_params.items():
             params.setdefault(k, v)
     return params
-
-
-def enable_compilation_cache() -> Optional[str]:
-    """Point jax at a persistent on-disk compilation cache BEFORE any jit.
-
-    The round-5 verdict flagged multi-minute XLA/Mosaic compiles hiding
-    inside the CLI's measured wall-clock (the 1M-row head-to-head charged
-    ~30 s of compilation to every run).  With the cache on, only the FIRST
-    run of a given program shape pays the compile; repeat invocations load
-    the serialized executable.  ``LIGHTGBM_TPU_CACHE_DIR`` overrides the
-    location (tools/head_to_head.py uses that to measure cold vs warm);
-    setting it to the empty string disables the cache."""
-    path = os.environ.get("LIGHTGBM_TPU_CACHE_DIR")
-    if path == "":
-        return None
-    if path is None:
-        path = os.path.join(tempfile.gettempdir(), "lightgbm_tpu_jax_cache")
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        # default min-compile-time gate (1 s) would skip the many small
-        # per-iteration programs whose compiles still add up on the CLI path
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as exc:  # cache is an optimization, never fatal
-        Log.warning("persistent compilation cache unavailable: %s", exc)
-        return None
-    return path
 
 
 class Application:

@@ -173,8 +173,7 @@ def _accum_onehot_tile_dyn(colf_dyn, v4, out_ref, t, *, num_features: int,
         preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.HIGHEST if exact else None)  # [4, 128]
     off = pl.multiple_of(t * _LANE, _LANE)
-    prev = pl.load(out_ref, (slice(None), pl.ds(off, _LANE)))
-    pl.store(out_ref, (slice(None), pl.ds(off, _LANE)), prev + acc)
+    out_ref[:, pl.ds(off, _LANE)] += acc
 
 
 def _colf_rows_dyn(w, *, bpc: int, packed: bool):
@@ -459,11 +458,15 @@ def _accum_factored_group(ti_bf, v4T, out_ref, g, *, num_features: int,
             colf = colsT[q:q + 1, :]
         # num_features is the histogrammed WINDOW's width (f_base is the
         # absolute byte offset of its first feature), so validity is local
-        valid = g * p + q < num_features       # traced bool scalar: the last
-        hi_oh = (colf >> sh) == iota_hi        # group's tail features mask
-        lo_oh = (colf & (nlo - 1)) == iota_lo  # to zero contribution
-        hi_oh = jnp.where(valid, hi_oh, False).astype(oh_t)   # [nhi, R]
-        lo_oh = jnp.where(valid, lo_oh, False).astype(oh_t)   # [nlo, R]
+        # traced bool scalar: the last group's tail features mask to zero
+        # contribution.  The mask rides the [1, R] i32 bin codes (-1 matches
+        # no iota row): Mosaic has no select on i1 vectors, so masking the
+        # boolean one-hots themselves does not legalize on the chip.
+        valid = g * p + q < num_features
+        hi_oh = (jnp.where(valid, colf >> sh, -1)
+                 == iota_hi).astype(oh_t)                     # [nhi, R]
+        lo_oh = (jnp.where(valid, colf & (nlo - 1), -1)
+                 == iota_lo).astype(oh_t)                     # [nlo, R]
         for c in range(nch):
             a_blocks.append(v4T[c:c + 1, :] * hi_oh)
         lo_blocks.append(lo_oh)
@@ -475,8 +478,7 @@ def _accum_factored_group(ti_bf, v4T, out_ref, g, *, num_features: int,
         precision=jax.lax.Precision.HIGHEST if exact else None)
     rows = a_big.shape[0]
     off = pl.multiple_of(g * rows, rows)
-    prev = pl.load(out_ref, (pl.ds(off, rows), slice(None)))
-    pl.store(out_ref, (pl.ds(off, rows), slice(None)), prev + acc)
+    out_ref[pl.ds(off, rows), :] += acc
 
 
 def _accum_factored_all(ti_bf, v4T, out_ref, *, num_features: int,
@@ -549,8 +551,12 @@ def _extract_values_T(ti_bf, *, voff: int, exact: bool, inwT=None,
     h_w = jax.lax.bitcast_convert_type(
         allTi[2:3, :] | (allTi[3:4, :] << 16), f32)
     if inwT is not None:
-        g_w = g_w * inwT
-        h_w = h_w * inwT
+        # select, never multiply: the fused kernel's right-block pass reads
+        # its last chunk past the rows it wrote into the scratch buffer, and
+        # what the chip left there can be NaN/Inf bit patterns (NaN * 0 is
+        # NaN, and one such row poisons every bin through the contraction)
+        g_w = jnp.where(inwT > 0, g_w, 0.0)
+        h_w = jnp.where(inwT > 0, h_w, 0.0)
     if quantized:
         # integer-valued f32 (core/quant.py, |v| <= 255): exact in bf16,
         # no lo rows — the 2-row operand of the halved MXU pass
@@ -822,7 +828,12 @@ def histogram_rows(rows: jax.Array, num_bins: int, start, count, *,
     both return the same exact integer sums."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    if use_pallas and rows.shape[0] % 2048 == 0:
+    if use_pallas:
+        if rows.shape[0] % 2048:
+            raise ValueError(
+                "the Pallas row-store histogram needs rows padded to a "
+                "multiple of 2048 (the learner pads them); got %d"
+                % rows.shape[0])
         return histogram_pallas_rows(rows, num_bins, start, count,
                                      num_features=num_features, voff=voff,
                                      bpc=bpc, packed=packed,
@@ -844,26 +855,6 @@ def histogram_rows(rows: jax.Array, num_bins: int, start, count, *,
         bins = jax.lax.dynamic_slice_in_dim(w, f_begin, num_features, axis=1)
     values = jnp.stack([_f32_col(w, voff), _f32_col(w, voff + 4)], axis=0)
     return histogram_xla_masked(bins, values, num_bins, start, count)
-
-
-def _pick_tile(n: int) -> int | None:
-    for tile in (4096, 2048, 1024):
-        if n % tile == 0:
-            return tile
-    return None
-
-
-def build_histogram(bins: jax.Array, values: jax.Array, num_bins: int,
-                    use_pallas: bool | None = None) -> jax.Array:
-    """Dispatch: Pallas on TPU, segment-sum elsewhere.  [F, 2, B] f32 output."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
-        tile = _pick_tile(bins.shape[0])
-        if tile is not None:
-            return histogram_pallas(bins, values, num_bins, row_tile=tile,
-                                    exact=_exact_hist())
-    return histogram_xla(bins, values, num_bins)
 
 
 def unpack_nibbles(packed: jax.Array, num_cols: int) -> jax.Array:

@@ -94,6 +94,7 @@ _MID_MAX = 16384     # bucket bound: windows <= this use the 1024-row chunk
 assert T == TS and T % _ALIGN == 0 and T == _LANE
 assert NIN >= 2
 assert CHUNK % SMALL_CHUNK == 0 and SMALL_CHUNK % T == 0
+assert 2 * CHUNK // T <= _LANE       # a chunk's subtile totals fit one lane row
 
 
 def _ring_depth(chunk: int) -> int:
@@ -251,6 +252,30 @@ def _subtile_prefixes(S_L, S_R, ltri, *, nsub):
     return pfxU, tot_col, incl_col, incl_col - tot_col
 
 
+def _subtile_totals_lanes(S_L, S_R, *, nsub):
+    """The subtile totals of :func:`_subtile_prefixes` once more, LANE-major
+    for the pipelined kernel's VMEM->SMEM totals bank: [2, 2*nsub] i32, row
+    0 = per-subtile counts (``tot_col``), row 1 = per-side inclusive running
+    counts (``incl_col``) — the same exact integers (0/1 operands, counts
+    <= chunk).  The bank's sliced dimension has to be a leading one with
+    whole-tile minor dims: Mosaic refuses the DMA of a lane-padded
+    [2*nsub, 2] column layout (slice not aligned to the 128 tiling)."""
+    S = jnp.concatenate([S_L, S_R], axis=0).astype(jnp.int8)  # [2*nsub, T]
+    tot_row = jax.lax.dot_general(
+        jnp.ones((1, T), jnp.int8), S, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32)                    # [1, 2*nsub]
+    iiB = jax.lax.broadcasted_iota(jnp.int32, (2 * nsub, 1), 0)
+    jjB = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * nsub), 1)
+    triBT = ((iiB <= jjB).astype(jnp.int32)
+             * ((iiB < nsub) == (jjB < nsub)).astype(jnp.int32)
+             ).astype(jnp.bfloat16)
+    incl_row = jax.lax.dot_general(
+        tot_row.astype(jnp.float32).astype(jnp.bfloat16), triBT,
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                  # [1, 2*nsub]
+    return jnp.concatenate([tot_row, incl_row.astype(jnp.int32)], axis=0)
+
+
 def _hist_tile(ti_c, hist_ref, scal_ref, start, cnt, *, num_features,
                num_bins, bpc, packed, exact, voff, f_shard,
                quantized=False):
@@ -299,7 +324,9 @@ def _hist_tile(ti_c, hist_ref, scal_ref, start, cnt, *, num_features,
     pos = jax.lax.broadcasted_iota(jnp.int32, (rows_n, 1), 0)
     inw = ((pos >= start).astype(jnp.float32)
            * (pos < start + cnt).astype(jnp.float32))
-    vals = jnp.concatenate([g * inw, h * inw], axis=1)
+    # select, never multiply (see histogram._extract_values_T)
+    vals = jnp.concatenate([jnp.where(inw > 0, g, 0.0),
+                            jnp.where(inw > 0, h, 0.0)], axis=1)
     v4 = _hilo_split(vals, axis=1, exact=exact, quantized=quantized)
     colf = _colf_rows_dyn(ti_c, bpc=bpc, packed=packed)
     _accum_onehot_all(colf, v4, hist_ref, num_features=num_features,
@@ -478,13 +505,11 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 excl_col = jnp.zeros((2 * nsub, 1), jnp.float32)
                 incl_col = jnp.zeros((2 * nsub, 1), jnp.float32)
             else:
-                pfxU, tot_col, incl_col, excl_col = _subtile_prefixes(
+                pfxU, _tot, incl_col, excl_col = _subtile_prefixes(
                     S_L, S_R, ltri, nsub=nsub)
                 if totals_on:
-                    totals_vm[bankt, 0:2 * nsub, 0:1] = tot_col.astype(
-                        jnp.int32)
-                    totals_vm[bankt, 0:2 * nsub, 1:2] = incl_col.astype(
-                        jnp.int32)
+                    totals_vm[bankt, :, 0:2 * nsub] = _subtile_totals_lanes(
+                        S_L, S_R, nsub=nsub)
 
                     @pl.when((kk == totk - 1) | (c == nchunks - 1))
                     def _start_totals():
@@ -551,8 +576,8 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                         totals_vm.at[pl.ds(base, totk)],
                         totals_sm.at[pl.ds(base, totk)],
                         sem_tot.at[gpar]).wait()
-                accL = fillL + totals_sm[bankt, nsub - 1, 1]
-                accR = fillR + totals_sm[bankt, 2 * nsub - 1, 1]
+                accL = fillL + totals_sm[bankt, 1, nsub - 1]
+                accR = fillR + totals_sm[bankt, 1, 2 * nsub - 1]
             else:                              # "prefix"/"totals" knockouts
                 accL, accR = fillL, fillR
             k1L = (headL + accL) // TS       # stream tiles complete after c
@@ -571,10 +596,10 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             for s in range(nsub) if "phaseC" not in dbg_skip else []:
                 compL = comp_buf[bankb, s * 2 * TS:s * 2 * TS + TS, :]
                 compR = comp_buf[bankb, s * 2 * TS + TS:(s + 1) * 2 * TS, :]
-                nls = totals_sm[bankt, s, 0]
-                nrs = totals_sm[bankt, nsub + s, 0]
-                baseL = fillL + totals_sm[bankt, s, 1] - nls
-                baseR = fillR + totals_sm[bankt, nsub + s, 1] - nrs
+                nls = totals_sm[bankt, 0, s]
+                nrs = totals_sm[bankt, 0, nsub + s]
+                baseL = fillL + totals_sm[bankt, 1, s] - nls
+                baseR = fillR + totals_sm[bankt, 1, nsub + s] - nrs
                 startL = jax.lax.rem(headL + baseL, TS)
                 startR = jax.lax.rem(baseR, TS)
                 curL = jax.lax.rem((headL + baseL) // TS, nb_ring)
@@ -650,7 +675,7 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             jnp.maximum(nchunks - totk, 0), nchunks, chunk_c, carry[2:])
         nl = fillL
         nr = fillR
-        stats_ref[0, 0] = nl
+        stats_ref[...] = jnp.full(stats_ref.shape, nl, jnp.int32)
 
         # drain the outstanding async flushes
         if "flush" not in dbg_skip:
@@ -970,9 +995,9 @@ def _make_small_partition_kernel(*, n_pad, W, num_features, num_bins, voff,
                     preferred_element_type=jnp.int32)            # [sc, W]
             outbuf[...] = (comp_i & 255).astype(jnp.uint8)
 
-            # left count out via a plain VMEM [1, 1] write — no SMEM totals
-            # DMA and no vector->scalar extraction anywhere in this variant
-            nl_ref[...] = nlv
+            # left count out via a plain VMEM write — no SMEM totals DMA
+            # and no vector->scalar extraction anywhere in this variant
+            nl_ref[0, 0:1, 0:1] = nlv
 
             # ---- smaller child's histogram from the SAME resident tile --
             if "hist" not in dbg_skip:
@@ -1048,6 +1073,13 @@ def partition_hist_pallas(rows: jax.Array, scal: jax.Array,
                            quantized=quantized)
 
 
+# Left-child count output: one whole (8, 128) i32 tile per window, the count
+# at [0, 0].  A (1, 1) block of an (nwin, 1) array is refused at lowering for
+# nwin > 1 (last two block dims must be tile multiples or the array's).
+_NL_TILE = (8, _LANE)
+_NL_BLOCK = pl.BlockSpec((1,) + _NL_TILE, lambda g, s: (g, 0, 0))
+
+
 def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
                     packed, exact, interpret, dbg_skip, chunk, small,
                     quantized=False):
@@ -1098,7 +1130,7 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
                 out_specs=[
                     pl.BlockSpec(memory_space=pl.ANY),       # rows (aliased)
                     pl.BlockSpec((h0, h1), lambda g, s: (g, 0)),  # hist
-                    pl.BlockSpec((1, 1), lambda g, s: (g, 0)),    # nl
+                    _NL_BLOCK,                                # nl
                 ],
                 scratch_shapes=[
                     pltpu.VMEM((chunk, W), jnp.uint8),       # window tile in
@@ -1110,14 +1142,14 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
             out_shape=[
                 jax.ShapeDtypeStruct((n_pad, W), jnp.uint8),
                 jax.ShapeDtypeStruct((nwin * h0, h1), jnp.float32),
-                jax.ShapeDtypeStruct((nwin, 1), jnp.int32),
+                jax.ShapeDtypeStruct((nwin,) + _NL_TILE, jnp.int32),
             ],
             input_output_aliases={1: 0},
             interpret=interpret,
         )(scal, rows)
         if multiwin:
             hist = hist.reshape(nwin, h0, h1)
-        return rows_new, hist, nl
+        return rows_new, hist, nl[:, 0, 0:1]
 
     nb_ring = _ring_depth(chunk)
     totk = _totk(chunk)
@@ -1139,8 +1171,7 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
                 pl.BlockSpec(memory_space=pl.ANY),       # rows out (aliased)
                 pl.BlockSpec(memory_space=pl.ANY),       # right-block scratch
                 pl.BlockSpec((h0, h1), lambda g, s: (g, 0)),  # hist
-                pl.BlockSpec((1, 1), lambda g, s: (g, 0),
-                             memory_space=pltpu.SMEM),        # nl
+                _NL_BLOCK,                               # nl
             ],
             scratch_shapes=[
                 pltpu.VMEM((NIN, chunk, W), jnp.uint8),  # streamed chunk ring
@@ -1150,8 +1181,8 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
                 pltpu.VMEM((2, TS, W), jnp.uint8),       # RMW/cb-read bounce
                 pltpu.VMEM((totk + 1, 2 * TS * nsub, W),
                            jnp.uint8),                   # placed, group banks
-                pltpu.VMEM((2 * totk, 2 * nsub, 2), jnp.int32),  # totals banks
-                pltpu.SMEM((2 * totk, 2 * nsub, 2), jnp.int32),  # totals land
+                pltpu.VMEM((2 * totk, 2, _LANE), jnp.int32),   # totals banks
+                pltpu.SMEM((2 * totk, 2, _LANE), jnp.int32),   # totals land
                 pltpu.SemaphoreType.DMA((NIN,)),         # chunk/cb reads
                 pltpu.SemaphoreType.DMA,                 # prefills + finals
                 pltpu.SemaphoreType.DMA((nb_ring,)),     # left flush ring
@@ -1164,14 +1195,14 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
             jax.ShapeDtypeStruct((n_pad, W), jnp.uint8),
             jax.ShapeDtypeStruct((n_pad, W), jnp.uint8),
             jax.ShapeDtypeStruct((nwin * h0, h1), jnp.float32),
-            jax.ShapeDtypeStruct((nwin, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nwin,) + _NL_TILE, jnp.int32),
         ],
         input_output_aliases={1: 0},
         interpret=interpret,
     )(scal, rows)
     if multiwin:
         hist = hist.reshape(nwin, h0, h1)
-    return rows_new, hist, nl
+    return rows_new, hist, nl[:, 0, 0:1]
 
 
 def level_plan(n: int) -> tuple:

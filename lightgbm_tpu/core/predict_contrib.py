@@ -25,7 +25,7 @@ recursion:
   (did the row follow the path direction at every node splitting on this
   feature?) — exactly representable;
 - pweight math runs in f64 on device (the kernel dispatches under
-  ``jax.experimental.enable_x64`` — its jit cache entries are keyed apart
+  ``jax.enable_x64`` — its jit cache entries are keyed apart
   from the f32 score programs);
 - phi accumulation order is CANONICAL (per tree: expected value, then
   leaves in index order, then path positions in order) on both sides:
@@ -65,7 +65,6 @@ from __future__ import annotations
 from typing import List, NamedTuple, Optional, Tuple
 
 import jax
-import jax.experimental  # noqa: F401  (enable_x64 context manager)
 import jax.numpy as jnp
 import numpy as np
 
@@ -295,7 +294,7 @@ def stack_contrib_blocked(trees: List[Tree], ncol: int, dataset=None,
     if g is None:
         g = contrib_tree_block(
             len(trees), contrib_bytes_per_tree(sched_host, dec_host))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dec = _block(dec_host, g)
         sched = _block(sched_host, g)
     return (dec, sched), int(g)
@@ -471,7 +470,7 @@ def contrib_scan(blocks, rows: jax.Array) -> jax.Array:
 predict_contrib_blocked = jax.jit(contrib_scan)
 """Jitted tree-blocked contrib dispatch: phi [N, C] f64 for a raw [N, F]
 f32 chunk or a binned [N, num_groups] u8/u16 chunk.  Call under
-``jax.experimental.enable_x64`` (the f64 schedule operands and phi)."""
+``jax.enable_x64`` (the f64 schedule operands and phi)."""
 
 # the degraded-mode contrib program: the same core over a g=1 re-blocking,
 # jitted into its OWN cache so a failure of the big blocked program cannot

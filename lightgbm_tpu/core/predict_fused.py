@@ -41,7 +41,6 @@ import time
 from typing import List, NamedTuple, Optional
 
 import jax
-import jax.experimental  # noqa: F401  (enable_x64 for the contrib path)
 import jax.numpy as jnp
 import numpy as np
 
@@ -52,6 +51,7 @@ from ..obs import compile as _compile
 from ..obs import recompile as _recompile
 from ..plan import device_specs as _device_specs
 from ..plan import state as _plan_state
+from ..resilience import PROGRAM_ERRORS
 from ..utils.timer import FunctionTimer
 from .predict import (EnsembleArrays, _path_matrix, decide_raw,
                       stack_ensemble_host)
@@ -490,6 +490,8 @@ class FusedPredictor:
                 misses = _recompile.note_dispatch(self._site, bucket,
                                                   predict_compile_count(),
                                                   watch="predict_blocked")
+            except PROGRAM_ERRORS:
+                raise
             except Exception as exc:  # degraded serving: never an exception
                 out = self._predict_degraded(
                     jnp.asarray(chunk), bucket, exc,
@@ -565,6 +567,8 @@ class FusedPredictor:
         X = self._prep_rows(X)
         try:
             return self._predict_contrib_device(X, ncol)
+        except PROGRAM_ERRORS:
+            raise
         except Exception as exc:  # harvest or double-failure: host net
             return self._contrib_host_scan(X, ncol, exc)
 
@@ -590,7 +594,7 @@ class FusedPredictor:
                                               predict_contrib_blocked)
                 with FunctionTimer("Predict::Contrib(dispatch)"), \
                         _annotate("contrib_fused"), \
-                        jax.experimental.enable_x64():
+                        jax.enable_x64(True):
                     # materialize INSIDE the x64 scope: slicing the f64
                     # result outside it would re-canonicalize avals to f32
                     res = np.asarray(predict_contrib_blocked(
@@ -598,6 +602,8 @@ class FusedPredictor:
                 misses = _recompile.note_dispatch(
                     "predict_contrib_blocked", bucket,
                     contrib_compile_count())
+            except PROGRAM_ERRORS:
+                raise
             except Exception as exc:  # degraded serving: never an exception
                 res = self._contrib_degraded(chunk, bucket, exc, ncol)
             if tele is not None:
@@ -640,14 +646,14 @@ class FusedPredictor:
             self.on_fallback(site)
         fb = self._fb_contrib.get(int(ncol))
         if fb is None:
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 fb = tuple(
                     type(part)(*[
                         jnp.reshape(a, (a.shape[0] * a.shape[1], 1)
                                     + a.shape[2:]) for a in part])
                     for part in self._contrib[int(ncol)])
             self._fb_contrib[int(ncol)] = fb
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             res = np.asarray(predict_contrib_scan_fallback(
                 fb, jnp.asarray(chunk)))
         _recompile.note_dispatch(

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .histogram import (build_histogram, histogram_rows, pack_nibbles,
+from .histogram import (histogram_rows, pack_nibbles,
                         partition_buckets, _exact_hist, _pad_bins,
                         _pad_bins_pow2, _use_factored)
 from .partition import (CHUNK as _PCHUNK, fold_hist, fused_bucket_plan,
@@ -282,6 +282,12 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     feature becomes used (:63-79 UpdateLeafBestSplits) — cached leaf bests
     here keep their original penalty until the leaf is re-evaluated.
     """
+    if pallas_interpret and jax.default_backend() == "tpu":
+        # with a chip attached the override would quietly swap the compiled
+        # kernels for the interpreter
+        raise RuntimeError(
+            "pallas_interpret (LIGHTGBM_TPU_PALLAS_INTERPRET=1) on a tpu "
+            "backend: interpret mode is for hosts without a chip")
     n, ncols = bins.shape
     f = feat.num_bin.shape[0]          # features may outnumber group columns
     L = num_leaves
@@ -321,7 +327,11 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # bucketed-switch partition on TPU: window contract requires a spare
     # CHUNK of rows past every window end, appended with valid unique
     # order bytes so the final row_leaf reconstruction scatter stays 1:1.
-    fused = use_pallas and not lazy_on and n % _PCHUNK == 0
+    if use_pallas and n % _PCHUNK:
+        raise ValueError(
+            "use_pallas needs the row count padded to a multiple of %d "
+            "(SerialTreeLearner pads it); got %d" % (_PCHUNK, n))
+    fused = use_pallas and not lazy_on
     # ---- round 22: quantized-gradient training (hist_precision) ----
     # Stochastically round grad/hess to small integers BEFORE the row-store
     # byte pack, so every histogram consumer — the standalone row kernels,
@@ -1548,9 +1558,10 @@ class SerialTreeLearner:
         self.bucket_plan = None
         self.pallas_interpret = False
         if os.environ.get("LIGHTGBM_TPU_PALLAS_INTERPRET", "0") == "1":
-            # force the fused Pallas path in interpret mode off-TPU — the
-            # hook CLI-driven child processes (fault injection, dryruns) use
-            # to exercise the fused/level dispatch without an accelerator
+            # force the fused Pallas path in interpret mode — the hook
+            # CLI-driven child processes (fault injection, dryruns) use to
+            # exercise the fused/level dispatch on a host WITHOUT a chip
+            # (build_tree_partitioned refuses interpret mode on a tpu)
             self.use_pallas = True
             self.pallas_interpret = True
         # round-12 level-batched dispatch (tree_grow_mode=level): BFS growth
@@ -1942,7 +1953,7 @@ class SerialTreeLearner:
         ncols = self.bins.shape[1]
         voff = -(-(ncols * bpc) // 4) * 4
         n = self.bins.shape[0]
-        fused = self.use_pallas and n % _PCHUNK == 0
+        fused = self.use_pallas
         return {"voff": voff, "aoff": voff + 12, "soff": voff + 16,
                 "n_arr": n + (_PCHUNK if fused else 0)}
 

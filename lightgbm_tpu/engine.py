@@ -17,6 +17,7 @@ import numpy as np
 from . import callback, obs
 from .basic import Booster, Dataset, LightGBMError
 from .config import alias_transform
+from .utils.compile_cache import enable_compilation_cache
 from .utils.log import Log
 from .utils.timer import global_timer
 
@@ -100,6 +101,7 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
     # constants; an unusable cache degrades to analytic with one warning
     # and the plan_cache_fallbacks counter.
     from .plan import state as _plan_state
+    enable_compilation_cache()
     _plan_state.configure(
         str(alias_transform(dict(params)).get("plan_cache", "") or "")
         or None)
@@ -373,6 +375,7 @@ def serve(models, params: Optional[Dict[str, Any]] = None, **server_kwargs):
     # tuned-plan cache (round 18): engaged before any predictor stacks so
     # the warmup compiles under the plan the run will serve with
     from .plan import state as _plan_state
+    enable_compilation_cache()
     _plan_state.configure_from_config(cfg)
     server = None
     try:
@@ -438,6 +441,7 @@ def serve_and_train(booster, train_set=None,
     cfg = Config(alias_transform(dict(params or {})))
     own_tele = _configure_owned_telemetry(cfg, "engine.serve_and_train")
     from .plan import state as _plan_state
+    enable_compilation_cache()
     _plan_state.configure_from_config(cfg)
     server = None
     try:

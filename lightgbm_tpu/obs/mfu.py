@@ -7,8 +7,8 @@ themselves (every row passes through one window per level, so
 visits = sum(leaf_count * depth)); bytes/MACs follow the fused split
 kernel's actual streaming scheme and the histogram layout the shape
 selects (factored hi/lo vs classic).  The device peak comes from the
-attached accelerator's ``device_kind``; on an unknown device (CPU hosts)
-the flop/byte totals are still reported and the utilization ratios are
+attached accelerator's ``device_kind``; on a host without one the
+flop/byte totals are still reported and the utilization ratios are
 ``None`` rather than a made-up number.
 """
 from __future__ import annotations
@@ -18,36 +18,22 @@ from typing import Dict, List, Optional
 import numpy as np
 
 # Device hardware tables live in plan/device_specs.py (round 18: ONE
-# source of truth per device_kind, shared with the kernel planner).  The
-# v5e peaks stay exported under their historical names — the BENCH
-# convention quotes proxy-box (no-accelerator) utilization against them
-# so the trajectory stays comparable, and bench.py references them
-# instead of re-hardcoding.
-from ..plan.device_specs import V5E_PEAK_BW, V5E_PEAK_MACS  # noqa: F401
-from ..plan.device_specs import device_peaks_table as _device_peaks_table
-
-# (peak HBM bytes/s, peak bf16 MACs/s) by device_kind substring, checked
-# in order.  MACs = FLOP/2 (the reference numbers quote FLOP/s).
-_DEVICE_PEAKS = _device_peaks_table()
+# source of truth per device_kind, shared with the kernel planner).
+from ..plan.device_specs import spec_for as _spec_for
 
 
 def device_peaks(device=None) -> Optional[Dict[str, float]]:
     """{"bw": bytes/s, "macs": MACs/s, "kind": str} for the attached
-    accelerator, or None when unknown (CPU hosts, new device kinds)."""
+    accelerator; None on a host without one.  A tpu whose kind has no
+    ``device_specs.SPECS`` row raises."""
     if device is None:
         import jax
-        devs = jax.devices()
-        if not devs:
-            return None
-        device = devs[0]
-    kind = str(getattr(device, "device_kind", "")).lower()
-    platform = str(getattr(device, "platform", "")).lower()
-    if platform not in ("tpu",):
+        device = jax.devices()[0]
+    if device.platform != "tpu":
         return None
-    for sub, (bw, macs) in _DEVICE_PEAKS:
-        if sub in kind:
-            return {"bw": bw, "macs": macs, "kind": kind}
-    return None
+    kind = str(device.device_kind).lower()
+    spec = _spec_for(kind)
+    return {"bw": spec.hbm_bw, "macs": spec.peak_macs, "kind": kind}
 
 
 def training_cost_model(trees: List, n_rows: int, iters: int,

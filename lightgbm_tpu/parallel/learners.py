@@ -36,15 +36,8 @@ from ..core.tree_learner import (Comm, SerialTreeLearner, TreeArrays,
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the public ``jax.shard_map`` alias
-    (with ``check_vma``) landed after 0.4.x; older jax exposes
-    ``jax.experimental.shard_map`` with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def default_mesh(num_devices: Optional[int] = None, axis: str = "data") -> Mesh:
@@ -117,6 +110,7 @@ def sharded_predict(ens, rows: np.ndarray, mesh: Optional[Mesh] = None, *,
     from ..obs import active as _telemetry_active
     from ..obs import annotate as _annotate
     from ..obs import recompile as _recompile
+    from ..resilience import PROGRAM_ERRORS as _PROGRAM_ERRORS
     from ..resilience import note_fallback as _note_fallback
     from ..resilience import watch as _watch
     mesh = mesh if mesh is not None else default_mesh()
@@ -145,6 +139,8 @@ def sharded_predict(ens, rows: np.ndarray, mesh: Optional[Mesh] = None, *,
                     _watch("sharded_predict", compile_key=int(bucket),
                            rows=int(nc), bucket=int(bucket), shards=int(d)):
                 out = fn(ens, jnp.asarray(chunk))
+        except _PROGRAM_ERRORS:
+            raise
         except Exception as exc:  # mesh unhealthy: serve single-device
             fell_back = True
             from ..core.predict_fused import predict_blocked
@@ -231,12 +227,11 @@ def sharded_predict_contrib(blocks, rows: np.ndarray, ncol: int,
     single-device blocked program as the degraded fallback (counted)."""
     import time as _time
 
-    import jax.experimental  # noqa: F401  (enable_x64)
-
     from ..core.predict_fused import PREDICT_BUCKETS, shape_bucket
     from ..obs import active as _telemetry_active
     from ..obs import annotate as _annotate
     from ..obs import recompile as _recompile
+    from ..resilience import PROGRAM_ERRORS as _PROGRAM_ERRORS
     from ..resilience import note_fallback as _note_fallback
     from ..resilience import watch as _watch
     mesh = mesh if mesh is not None else default_mesh()
@@ -265,10 +260,12 @@ def sharded_predict_contrib(blocks, rows: np.ndarray, ncol: int,
                     _watch("sharded_contrib", compile_key=int(bucket),
                            rows=int(nc), bucket=int(bucket),
                            shards=int(d)), \
-                    jax.experimental.enable_x64():
+                    jax.enable_x64(True):
                 # materialize INSIDE the x64 scope (slicing f64 results
                 # outside it re-canonicalizes avals to f32)
                 res = np.asarray(fn(blocks, jnp.asarray(chunk)))
+        except _PROGRAM_ERRORS:
+            raise
         except Exception as exc:  # mesh unhealthy: serve single-device
             fell_back = True
             from ..core.predict_contrib import predict_contrib_blocked
@@ -281,7 +278,7 @@ def sharded_predict_contrib(blocks, rows: np.ndarray, ncol: int,
                            bucket=int(bucket), shards=int(d))
             with _watch("sharded_contrib_fallback", compile_key=int(bucket),
                         rows=int(nc), bucket=int(bucket)), \
-                    jax.experimental.enable_x64():
+                    jax.enable_x64(True):
                 res = np.asarray(predict_contrib_blocked(
                     blocks, jnp.asarray(chunk)))
         if not fell_back:
@@ -387,6 +384,7 @@ class _ParallelTreeLearner(SerialTreeLearner):
             comm_mode=self.comm_mode, num_shards=self.num_shards,
             top_k=int(self.comm.top_k),
             hist_pool_slots=self.hist_pool_slots,
+            pallas_interpret=self.pallas_interpret,
             hist_precision=self.hist_precision,
             quant_seed=self.quant_seed)
 
@@ -471,6 +469,7 @@ class PartitionedDataParallelTreeLearner(_ParallelTreeLearner):
                 unpack_lanes=self.unpack_lanes,
                 packed_cols=self.packed_cols, axis_name=self.axis,
                 hist_pool_slots=self.hist_pool_slots,
+                pallas_interpret=self.pallas_interpret,
                 forced=forced,
                 cegb=(cegb_args if cegb_args != () else None),
                 paid_bits=(paid if lazy else None),
@@ -557,6 +556,9 @@ def create_tree_learner(dataset, config, mesh: Optional[Mesh] = None):
         n_dev = (int(np.prod(mesh.devices.shape)) if mesh is not None
                  else len(jax.devices()))
         if n_dev <= 1:
+            from ..utils.log import Log
+            Log.warning("tree_learner=%s with one device: training with the "
+                        "serial learner", kind)
             kind = "serial"
     if kind == "serial":
         return SerialTreeLearner(dataset, config)

@@ -2,8 +2,8 @@
 
 The autotuner (``plan/autotune.py``) microbenchmarks candidate tilings
 once per (shape-class, device_kind) and persists the winners here — a
-single JSON document living next to the XLA compilation cache the CLI
-already keeps (``cli.enable_compilation_cache``), written through the
+single JSON document living in the compilation cache directory
+(``utils/compile_cache.py``), written through the
 same retry/fsync/rename discipline as every other artifact
 (``utils.file_io.atomic_write``).
 
@@ -20,10 +20,10 @@ from __future__ import annotations
 
 import json
 import os
-import tempfile
 import threading
 from typing import Any, Dict, Optional
 
+from ..utils.compile_cache import cache_dir
 from . import planner
 
 CACHE_VERSION = 1
@@ -72,13 +72,10 @@ def reset_fallbacks() -> None:
 
 
 def default_cache_path() -> str:
-    """The plan cache's home: inside the XLA compilation cache directory
-    the CLI keeps (``LIGHTGBM_TPU_CACHE_DIR`` override honored, same as
-    ``cli.enable_compilation_cache``)."""
-    base = os.environ.get("LIGHTGBM_TPU_CACHE_DIR")
-    if not base:
-        base = os.path.join(tempfile.gettempdir(), "lightgbm_tpu_jax_cache")
-    return os.path.join(base, "plan_cache.json")
+    """The plan cache's home: inside the compilation cache directory
+    (``JAX_COMPILATION_CACHE_DIR`` if set, else the fixed in-checkout
+    one — same rule as ``utils.compile_cache``)."""
+    return os.path.join(cache_dir(), "plan_cache.json")
 
 
 class PlanCache:

@@ -15,9 +15,9 @@ Dependency-free by design: ``core/histogram.py`` and
 import jax, core, or obs.  ``lightgbm_tpu/plan/__init__.py`` is lazy
 (PEP 562) for the same reason.
 
-All VMEM budgets default to the v5e values every constant in the tree was
-hand-tuned for — the analytic planner must reproduce today's dispatch
-byte-for-byte on every device until the tuner measures otherwise.
+The CPU row carries the v5e VMEM budgets every constant in the tree was
+hand-tuned for, so a host without a chip plans byte-for-byte what the chip
+plans.  A TPU whose ``device_kind`` has no row is an error, never a default.
 """
 from __future__ import annotations
 
@@ -27,18 +27,16 @@ from typing import NamedTuple, Optional
 class DeviceSpec(NamedTuple):
     """Hardware envelope of one accelerator kind.
 
-    ``hbm_bw`` / ``peak_macs`` are ``None`` for kinds without published
-    peaks (CPU hosts, unknown devices): utilization ratios stay ``None``
-    rather than a made-up number (obs/mfu.py contract)."""
+    ``hbm_bw`` / ``peak_macs`` are ``None`` on the CPU row: utilization
+    ratios stay ``None`` rather than a made-up number (obs/mfu.py
+    contract)."""
     kind: str                     # canonical name (substring-matched)
     vmem_bytes: int               # per-core VMEM
     hbm_bw: Optional[float]       # HBM bytes/s
     peak_macs: Optional[float]    # bf16 MACs/s (FLOP/s / 2)
 
 
-# v5e peaks, exported under the historical names: the BENCH convention
-# quotes proxy-box (no-accelerator) utilization against these so the
-# trajectory stays comparable (obs/mfu.py re-exports them for bench.py)
+# v5e peaks (Google Cloud documentation, "TPU v5e")
 V5E_PEAK_BW = 819e9      # HBM bytes/s
 V5E_PEAK_MACS = 98.5e12  # bf16 MACs/s (197 TFLOP/s)
 
@@ -59,9 +57,9 @@ SPECS = (
     DeviceSpec("v6", 32 << 20, 1640e9, 459e12),    # v6e: 1.64 TB/s, 918 TF
 )
 
-# unknown device (CPU hosts, new backends): v5e-shaped VMEM budgets keep
-# the analytic planner byte-equal to the hand-tuned constants; no peaks
-DEFAULT_SPEC = DeviceSpec("unknown", V5E_VMEM_BYTES, None, None)
+# hosts whose platform is ``cpu`` (tests, rehearsals): v5e-shaped VMEM budgets
+# keep the analytic planner byte-equal to what the chip plans; no peaks
+CPU_SPEC = DeviceSpec("cpu", V5E_VMEM_BYTES, None, None)
 
 # path-matrix VMEM budget per predict scan block (f32 bytes) — the former
 # ``predict_fused.BLOCK_VMEM_BYTES`` literal; device-independent until the
@@ -69,17 +67,23 @@ DEFAULT_SPEC = DeviceSpec("unknown", V5E_VMEM_BYTES, None, None)
 PREDICT_BLOCK_VMEM_BYTES = 1 << 20
 
 
-def spec_for(device_kind: Optional[str]) -> DeviceSpec:
-    """The spec row of ``device_kind`` (substring match, first hit), or
-    :data:`DEFAULT_SPEC` — never ``None``, so every budget has a value."""
-    kind = str(device_kind or "").lower()
+def spec_for(device_kind: str) -> DeviceSpec:
+    """The spec row of ``device_kind`` (substring match, first hit);
+    ``"cpu"`` — what :func:`current_device_kind` answers on a host without
+    a chip — gets :data:`CPU_SPEC`.  A kind with no row raises: budgets and
+    peaks quoted for a device nobody looked up would be made-up numbers."""
+    kind = str(device_kind).lower()
+    if kind == "cpu":
+        return CPU_SPEC
     for spec in SPECS:
         if spec.kind in kind:
             return spec
-    return DEFAULT_SPEC
+    raise ValueError(
+        "unknown device_kind %r: add its row to plan/device_specs.SPECS"
+        % (device_kind,))
 
 
-def hist_accum_budget_bytes(device_kind: Optional[str] = None) -> int:
+def hist_accum_budget_bytes(device_kind: str) -> int:
     """VMEM budget of the factored-histogram accumulator — the round-6
     "4 MiB" gate in ``histogram._use_factored``, now derived as a quarter
     of the device VMEM (4 MiB at the 16 MiB v5e: the accumulator lives
@@ -100,40 +104,19 @@ _current_kind_cache = None
 
 def current_device_kind() -> str:
     """``device_kind`` of the attached accelerator, lowercased; ``"cpu"``
-    for non-TPU backends (matches the obs/mfu.py unknown-device
-    semantics).  jax is imported lazily and failures degrade to "cpu" —
-    the planner must resolve on any host.  Memoized after the first
-    successful probe: the device set is process-static and this is
-    called from trace-time layout choices (``histogram._use_factored``)."""
+    where jax's platform is not ``tpu``.  On a tpu the kind must have a
+    :data:`SPECS` row (raises otherwise).  jax is imported lazily (this
+    module stays dependency-free at import).  Memoized: the device set is
+    process-static and this is called from trace-time layout choices
+    (``histogram._use_factored``)."""
     global _current_kind_cache
-    if _current_kind_cache is not None:
-        return _current_kind_cache
-    kind = _probe_device_kind()
-    if kind is not None:
-        _current_kind_cache = kind
-        return kind
-    return "cpu"
-
-
-def _probe_device_kind():
-    """One device probe; ``None`` when jax isn't ready yet (the memo must
-    not freeze "cpu" before the backend is initialized)."""
-    try:
+    if _current_kind_cache is None:
         import jax
-        devs = jax.devices()
-        if not devs:
-            return "cpu"
-        dev = devs[0]
-        if str(getattr(dev, "platform", "")).lower() != "tpu":
-            return "cpu"
-        return str(getattr(dev, "device_kind", "")).lower() or "tpu"
-    except Exception:  # noqa: BLE001 - planning must never fail a run
-        return None
-
-
-def device_peaks_table():
-    """The (substring, (bw, macs)) rows obs/mfu.py's estimator matches
-    against — only kinds WITH published peaks (unknowns return None
-    ratios there)."""
-    return tuple((s.kind, (s.hbm_bw, s.peak_macs)) for s in SPECS
-                 if s.hbm_bw is not None and s.peak_macs is not None)
+        dev = jax.devices()[0]
+        if dev.platform != "tpu":
+            _current_kind_cache = "cpu"
+        else:
+            kind = str(dev.device_kind).lower()
+            spec_for(kind)          # a tpu without a row raises here
+            _current_kind_cache = kind
+    return _current_kind_cache

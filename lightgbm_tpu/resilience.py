@@ -467,6 +467,11 @@ def watch(name: str, compile_key: Any = None, **info: Any):
 _FB_LOCK = threading.Lock()
 _FALLBACKS: Dict[str, int] = {}
 
+# What a degraded-serving handler re-raises instead of serving around: these
+# come from a bug in the program (a removed API, a wrong call), not from an
+# unhealthy device or mesh — a fallback that answers anyway would hide it.
+PROGRAM_ERRORS = (AttributeError, TypeError, NameError)
+
 
 def note_fallback(site: str, reason: str = "", **fields: Any) -> None:
     """Count one degraded-path activation at ``site``; mirrored to the

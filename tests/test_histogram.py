@@ -107,7 +107,8 @@ def test_histogram_rows_classic_fallback(monkeypatch):
     """The classic packed-tile path stays correct (it serves accumulators
     past the factored path's 4 MiB VMEM bound, e.g. F > 1024 at B=64)."""
     import lightgbm_tpu.core.histogram as H
-    monkeypatch.setattr(H, "_use_factored", lambda f, b: False)
+    monkeypatch.setattr(H, "_use_factored",
+                        lambda f, b, quantized=False: False)
     for f, b, bpc, packed in ((9, 64, 1, False), (5, 512, 2, False),
                               (7, 32, 1, True)):
         n = 2048

@@ -141,7 +141,8 @@ def test_fused_kernel_classic_hist_fallback(monkeypatch):
     path wide-F x 256-bin datasets take past the 4 MiB accumulator gate —
     now a rolled fori_loop over lane tiles with dynamic extraction."""
     import lightgbm_tpu.core.partition as P
-    monkeypatch.setattr(P, "_use_factored", lambda f, b: False)
+    monkeypatch.setattr(P, "_use_factored",
+                        lambda f, b, quantized=False: False)
     # the jit cache key does not see the monkeypatch: force retraces both
     # entering (pick up the classic path) and leaving (restore factored)
     P.partition_hist_pallas.clear_cache()

@@ -112,17 +112,28 @@ def test_device_specs_single_source_of_truth():
     """obs/mfu.py's peaks table and the VMEM budgets all come from
     plan/device_specs.py — one row per device_kind."""
     from lightgbm_tpu.obs import mfu
-    assert mfu._DEVICE_PEAKS == device_specs.device_peaks_table()
-    assert mfu.V5E_PEAK_BW == device_specs.V5E_PEAK_BW
-    assert mfu.V5E_PEAK_MACS == device_specs.V5E_PEAK_MACS
+
+    class _Dev:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    assert mfu.device_peaks(_Dev()) == {
+        "bw": device_specs.V5E_PEAK_BW, "macs": device_specs.V5E_PEAK_MACS,
+        "kind": "tpu v5 lite"}
     v5e = device_specs.spec_for("tpu v5 lite")
     assert v5e.vmem_bytes == 16 << 20
     assert device_specs.hist_accum_budget_bytes("v5e") == 4 << 20
-    # unknown devices keep the v5e-shaped budgets (analytic byte-equality
-    # everywhere) but report no peaks
-    unk = device_specs.spec_for("warp-drive-9000")
-    assert unk.vmem_bytes == 16 << 20
-    assert unk.hbm_bw is None and unk.peak_macs is None
+    # a host without a chip keeps the v5e-shaped budgets (analytic
+    # byte-equality everywhere) but reports no peaks; a device nobody
+    # looked up is an error, not a default
+    cpu = device_specs.spec_for("cpu")
+    assert cpu.vmem_bytes == 16 << 20
+    assert cpu.hbm_bw is None and cpu.peak_macs is None
+    with pytest.raises(ValueError, match="warp-drive-9000"):
+        device_specs.spec_for("warp-drive-9000")
+    _Dev.device_kind = "warp-drive-9000"
+    with pytest.raises(ValueError):
+        mfu.device_peaks(_Dev())
     from lightgbm_tpu.core.predict_fused import BLOCK_VMEM_BYTES
     assert BLOCK_VMEM_BYTES == device_specs.PREDICT_BLOCK_VMEM_BYTES
 

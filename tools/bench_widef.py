@@ -53,7 +53,7 @@ def main():
         r = jnp.asarray(rows)
         orig = H._use_factored
         if force_classic:
-            H._use_factored = lambda f, bb: False
+            H._use_factored = lambda f, bb, quantized=False: False
         H.histogram_pallas_rows.clear_cache()
         try:
             t0 = time.perf_counter()

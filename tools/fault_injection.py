@@ -1358,7 +1358,7 @@ rng = np.random.RandomState(7)
 X = rng.normal(size=(n, 8))
 y = X[:, 0] * 1.5 + np.sin(X[:, 1]) + rng.normal(scale=0.1, size=n)
 # the cache is engaged through the DEFAULT discovery location
-# (LIGHTGBM_TPU_CACHE_DIR/plan_cache.json, set by the parent) — the
+# (JAX_COMPILATION_CACHE_DIR/plan_cache.json, set by the parent) — the
 # params stay byte-identical across runs, so the saved model files can
 # be compared whole
 params = dict(objective="regression", num_leaves=8, num_iterations=2,
@@ -1370,7 +1370,7 @@ gbdt = booster._booster
 print("BUCKET_PLAN=%r" % (gbdt.learner.bucket_plan,))
 print("PROVENANCE=%s" % (gbdt.learner.plan.provenance
                          if gbdt.learner.plan is not None else None))
-if os.environ.get("LIGHTGBM_TPU_CACHE_DIR"):
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     # a second engagement of the same bad cache must count again but
     # NEVER warn again (the ONE-warning contract is process-wide)
     plan_state.configure(None)
@@ -1397,7 +1397,7 @@ def scenario_plan_cache(workdir: str) -> None:
         out = os.path.join(workdir, "plan_model_%s.txt" % tag)
         env = {"MODEL_OUT": out}
         if cache_dir:
-            env["LIGHTGBM_TPU_CACHE_DIR"] = cache_dir
+            env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
         p = _run_child(_PLAN_CHILD_SRC, env)
         assert "TRAINED-TO-END" in p.stdout, p.stdout + p.stderr
         return out, p.stdout

@@ -138,7 +138,7 @@ def run_size(rows, iters, threads, skip_ref=False, skip_tpu=False):
             cache_dir = "%s/jax_cache" % WORK
             import shutil
             shutil.rmtree(cache_dir, ignore_errors=True)
-            env = {"LIGHTGBM_TPU_CACHE_DIR": cache_dir}
+            env = {"JAX_COMPILATION_CACHE_DIR": cache_dir}
             cold, aucs = run_cli(cli + ["config=" + conf_path],
                                  "%s_%d_cold" % (tag, rows), env)
             # the WARM run is self-recording: its telemetry artifact
@@ -215,7 +215,7 @@ def run_predict(rows, iters, threads, skip_ref=False, skip_tpu=False):
             cache_dir = "%s/jax_cache" % WORK
             import shutil
             shutil.rmtree(cache_dir, ignore_errors=True)
-            env = {"LIGHTGBM_TPU_CACHE_DIR": cache_dir}
+            env = {"JAX_COMPILATION_CACHE_DIR": cache_dir}
             cold, _ = run_cli(cli + ["config=" + conf_path],
                               "%s_pred_%d_cold" % (tag, rows), env)
             # warm predict run self-records per-bucket latencies and the
