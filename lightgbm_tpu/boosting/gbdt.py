@@ -36,7 +36,6 @@ from ..io.dataset import BinnedDataset
 from ..metric.metric import Metric, create_metrics
 from ..objective import ObjectiveFunction, create_objective
 from ..obs import active as _telemetry_active
-from ..obs import annotate as _annotate
 from ..obs import compile as _compile
 from ..obs import devmem as _devmem
 from ..obs import launches as _launches
@@ -235,7 +234,8 @@ class GBDT:
         self._score_fingerprint_out = None
         self._quality_baseline_cache = None
         if train_data is not None:
-            self.reset_training_data(train_data, objective)
+            with _spans.span("gbdt.construct"):
+                self.reset_training_data(train_data, objective)
 
     # ---- lazy tree materialization ----
 
@@ -324,7 +324,8 @@ class GBDT:
         self._last_poll = self.iter_
         if not self._nl_handles and not self._fin_handles:
             return False
-        with _watch("poll_stop", iteration=int(self.iter_)):
+        with _spans.span("gbdt.poll_stop"), \
+                _watch("poll_stop", iteration=int(self.iter_)):
             fetched = jax.device_get([h for _, _, h in self._nl_handles]
                                      + [f for _, f in self._fin_handles])
         nls = fetched[:len(self._nl_handles)]
@@ -670,7 +671,8 @@ class GBDT:
         if gradients is None or hessians is None:
             for k in range(K):
                 init_scores[k] = self._boost_from_average(k, True)
-            with FunctionTimer("GBDT::Boosting(dispatch)"):
+            with FunctionTimer("GBDT::Boosting(dispatch)"), \
+                    _spans.span("gbdt.gradients"):
                 grad, hess = self._get_gradients()
         else:
             grad = np.asarray(gradients, dtype=np.float32).reshape(
@@ -688,8 +690,9 @@ class GBDT:
             self._fin_handles.append(
                 (self.iter_,
                  jnp.isfinite(grad).all() & jnp.isfinite(hess).all()))
-        self._bagging(self.iter_)
-        grad, hess = self._adjust_gradients_for_bagging(grad, hess)
+        with _spans.span("gbdt.bagging"):
+            self._bagging(self.iter_)
+            grad, hess = self._adjust_gradients_for_bagging(grad, hess)
 
         feature_mask = self._feature_mask()
         self._last_iter_arrays = []
@@ -710,7 +713,8 @@ class GBDT:
                 scaled = arrays._replace(
                     leaf_value=arrays.leaf_value * rate,
                     internal_value=arrays.internal_value * rate)
-                with FunctionTimer("GBDT::UpdateScore(dispatch)"):
+                with FunctionTimer("GBDT::UpdateScore(dispatch)"), \
+                        _spans.span("gbdt.update_score"):
                     self.train_score = self.train_score.at[k].add(
                         self._gather_tree_output(scaled))
                     for vs in self.valid_sets:
@@ -858,29 +862,32 @@ class GBDT:
         def one_iter_of(bins):
             def one_iter(carry, it):
                 rows, vscores = carry
-                score = f32col(rows, soff)
-                auxv = f32col(rows, aoff)
-                order = jax.lax.bitcast_convert_type(
-                    rows[:, voff + 8:voff + 12], jnp.int32
-                ).reshape(rows.shape[0])
-                validf = (order < n).astype(jnp.float32)
-                g, h = objective.pointwise_gradients(score, auxv)
-                g = g * validf
-                h = h * validf
-                if bag is not None:
-                    # the store is PERMUTED, so the mask must be keyed by
-                    # each row's ORIGINAL id (the order bytes) — exactly
-                    # what the stateless hash provides
-                    frac, freq = bag
-                    mask, _ = _bag_mask_for(order, bag_seed, it, freq, frac)
-                    mask = mask * validf
-                    nd_it = jnp.maximum(
-                        jnp.sum(mask, dtype=jnp.float32), 1.0
-                    ).astype(jnp.int32)
-                    g = g * mask
-                    h = h * mask
-                else:
-                    nd_it = nd
+                # a named scope like the tree builder's (obs/scopes.py)
+                with jax.named_scope("gbdt.gradients"):
+                    score = f32col(rows, soff)
+                    auxv = f32col(rows, aoff)
+                    order = jax.lax.bitcast_convert_type(
+                        rows[:, voff + 8:voff + 12], jnp.int32
+                    ).reshape(rows.shape[0])
+                    validf = (order < n).astype(jnp.float32)
+                    g, h = objective.pointwise_gradients(score, auxv)
+                    g = g * validf
+                    h = h * validf
+                    if bag is not None:
+                        # the store is PERMUTED, so the mask must be keyed by
+                        # each row's ORIGINAL id (the order bytes) — exactly
+                        # what the stateless hash provides
+                        frac, freq = bag
+                        mask, _ = _bag_mask_for(order, bag_seed, it, freq,
+                                                frac)
+                        mask = mask * validf
+                        nd_it = jnp.maximum(
+                            jnp.sum(mask, dtype=jnp.float32), 1.0
+                        ).astype(jnp.int32)
+                        g = g * mask
+                        h = h * mask
+                    else:
+                        nd_it = nd
                 arr, rows = build_tree_partitioned(
                     bins, g[:ntot], h[:ntot], nd_it, fm, feat,
                     rows_carry=rows, score_rate=jnp.float32(rate),
@@ -960,19 +967,21 @@ class GBDT:
         def one_iter_of(bins):
             def one_iter(carry, it):
                 score, vscores = carry
-                live = score[:, :n]
-                g, h = objective.get_gradients(live[0] if K == 1 else live)
-                g = jnp.reshape(g, (K, n))
-                h = jnp.reshape(h, (K, n))
-                if bag is not None:
-                    frac, freq = bag
-                    mask, nd_it = _bag_mask_for(
-                        jnp.arange(n, dtype=jnp.int32), bag_seed, it, freq,
-                        frac)
-                    g = g * mask[None, :]
-                    h = h * mask[None, :]
-                else:
-                    nd_it = nd
+                with jax.named_scope("gbdt.gradients"):
+                    live = score[:, :n]
+                    g, h = objective.get_gradients(
+                        live[0] if K == 1 else live)
+                    g = jnp.reshape(g, (K, n))
+                    h = jnp.reshape(h, (K, n))
+                    if bag is not None:
+                        frac, freq = bag
+                        mask, nd_it = _bag_mask_for(
+                            jnp.arange(n, dtype=jnp.int32), bag_seed, it,
+                            freq, frac)
+                        g = g * mask[None, :]
+                        h = h * mask[None, :]
+                    else:
+                        nd_it = nd
                 outs = []
                 for kk in range(K):
                     gk = jnp.pad(g[kk], (0, pad))
@@ -999,6 +1008,22 @@ class GBDT:
         return _hoisted_jit(fused, self.train_score,
                             tuple(vs["score"] for vs in self.valid_sets),
                             jnp.int32(0))
+
+    def chunk_program_text(self, num_iters: int) -> Optional[str]:
+        """The compiled text of the fused ``num_iters``-tree chunk program
+        this booster has run, or None when it has run none.  Every
+        instruction's ``op_name`` carries the program's named scopes
+        (``obs.scopes.op_scopes`` reads them), and a profiler trace names its
+        device events by these instructions.  Lowers again and asks the
+        compiler, which the persistent cache answers."""
+        fn = self._fused_cache.get(
+            (num_iters, self.shrinkage_rate, self.num_tree_per_iteration,
+             len(self.valid_sets)))
+        if fn is None:
+            return None
+        return fn.lower(self.train_score,
+                        tuple(vs["score"] for vs in self.valid_sets),
+                        jnp.int32(0)).compile().as_text()
 
     def _objective_traceable(self) -> bool:
         """Whether the objective's gradients trace under jit (an objective
@@ -1056,7 +1081,8 @@ class GBDT:
                 return self.train_chunk(num_iters)
             # traces eagerly (_hoisted_jit runs make_jaxpr at construction):
             # a kernel's trace error surfaces here and propagates
-            fn = self._make_fused_train(num_iters)
+            with _spans.span("gbdt.fused.trace"):
+                fn = self._make_fused_train(num_iters)
             self._fused_cache[key] = fn
             # the fused k-iteration scan compiled a fresh XLA program; a
             # steady-state run reuses config-keyed chunk lengths, so this
@@ -1066,7 +1092,7 @@ class GBDT:
                        for kk in range(self.num_tree_per_iteration)]
         t0 = time.perf_counter()
         with FunctionTimer("GBDT::TrainChunk(dispatch)"), \
-                _annotate("fused_train_chunk"), \
+                _spans.span("fused_train_chunk"), \
                 _watch("fused_train_chunk", compile_key=int(num_iters),
                        first_iter=int(self.iter_), iters=int(num_iters)):
             new_score, new_vscores, stacked = fn(
@@ -1390,12 +1416,15 @@ class GBDT:
         the reduction rides the _poll_stop/_drain batch as a lazy handle —
         the async pipeline keeps its zero-sync property; only the resilient
         policies pay the per-chunk host sync their rollback needs."""
+        with _spans.span("gbdt.guard_chunk_scores"):
+            finite = jnp.isfinite(self.train_score).all()
+            if self._nan_policy != "raise":
+                finite = bool(finite)
         if self._nan_policy == "raise":
             self._prechunk = None
-            self._fin_handles.append(
-                (self.iter_, jnp.isfinite(self.train_score).all()))
+            self._fin_handles.append((self.iter_, finite))
             return False
-        if bool(jnp.isfinite(self.train_score).all()):
+        if finite:
             self._prechunk = None
             if self._nan_refused_fuse:
                 # the retried window completed clean: a TRANSIENT fault is

@@ -304,6 +304,7 @@ def histogram_pallas_masked(bins: jax.Array, values: jax.Array, num_bins: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((4, lanes), jnp.float32),
         interpret=interpret,
+        name="histogram_pallas_masked",
     )(win, bins, values)
     folded = raw[0:2] + raw[2:4]
     return folded.reshape(2, f_pad, num_bins).transpose(1, 0, 2)[:f]
@@ -751,6 +752,7 @@ def histogram_pallas_rows(rows: jax.Array, num_bins: int, start: jax.Array,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
             interpret=interpret,
+            name="histogram_pallas_rows_factored",
         )(win, rows)
         return _fold_factored(raw, num_features, num_bins, quantized)
 
@@ -782,6 +784,7 @@ def histogram_pallas_rows(rows: jax.Array, num_bins: int, start: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nch, lanes), jnp.float32),
         interpret=interpret,
+        name="histogram_pallas_rows_classic",
     )(win, rows)
     folded = raw[0:2] if quantized else raw[0:2] + raw[2:4]
     return folded.reshape(2, f_pad, num_bins).transpose(1, 0, 2)[:num_features]

@@ -139,6 +139,21 @@ def fused_bucket_plan(n: int) -> tuple:
     return tuple(plan)
 
 
+def bucket_name(small: bool, chunk: int) -> str:
+    """``small`` / ``c1024`` / ``c4096``: a plan entry's name, the suffix of
+    its kernel's name (``partition_hist_pallas_<name>``)."""
+    return "small" if small else "c%d" % chunk
+
+
+def bucket_of(window_rows, plan) -> np.ndarray:
+    """Index into ``plan`` of the bucket that serves a split window of
+    ``window_rows`` rows (host, NumPy): the bounds and the side the tree
+    builder's ``jnp.searchsorted`` uses.  A finished tree's
+    ``internal_count`` says which kernel variant served each split."""
+    bounds = np.asarray([b for (_, _, b) in plan[:-1]], np.int64)
+    return np.searchsorted(bounds, np.asarray(window_rows, np.int64))
+
+
 class _ScalRow:
     """One window's scalar-prefetch row: ``scal[i]`` reads ``scal_ref[i]``
     for the single-window kernels and ``scal_ref[g, i]`` for a grid step of
@@ -1112,6 +1127,10 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
         hist_shape = (2 if quantized else 4,
                       _padded_features(num_features, num_bins) * num_bins)
     h0, h1 = hist_shape
+    # the kernel's name is what a profiler trace prints: one per size bucket
+    # (``bucket_name``), the level-batched launches apart
+    name = "partition_hist%s_pallas_%s" % ("_level" if multiwin else "",
+                                           bucket_name(small, chunk))
 
     if small:
         kernel = _make_small_partition_kernel(
@@ -1146,6 +1165,7 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
             ],
             input_output_aliases={1: 0},
             interpret=interpret,
+            name=name,
         )(scal, rows)
         if multiwin:
             hist = hist.reshape(nwin, h0, h1)
@@ -1199,6 +1219,7 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
         ],
         input_output_aliases={1: 0},
         interpret=interpret,
+        name=name,
     )(scal, rows)
     if multiwin:
         hist = hist.reshape(nwin, h0, h1)

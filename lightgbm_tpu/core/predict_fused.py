@@ -46,7 +46,7 @@ import numpy as np
 
 from ..io.binning import BinType, MissingType
 from ..obs import active as _telemetry_active
-from ..obs import annotate as _annotate
+from ..obs.spans import span as _span
 from ..obs import compile as _compile
 from ..obs import recompile as _recompile
 from ..plan import device_specs as _device_specs
@@ -475,7 +475,7 @@ class FusedPredictor:
             misses = 0
             try:
                 with FunctionTimer("Predict::Fused(dispatch)"), \
-                        _annotate("tree_block_predict"):
+                        _span("tree_block_predict"):
                     out = predict_blocked(
                         self.ens, jnp.asarray(chunk),
                         early_stop_margin=float(early_stop_margin),
@@ -593,7 +593,7 @@ class FusedPredictor:
                 from .predict_contrib import (contrib_compile_count,
                                               predict_contrib_blocked)
                 with FunctionTimer("Predict::Contrib(dispatch)"), \
-                        _annotate("contrib_fused"), \
+                        _span("contrib_fused"), \
                         jax.enable_x64(True):
                     # materialize INSIDE the x64 scope: slicing the f64
                     # result outside it would re-canonicalize avals to f32

@@ -46,7 +46,7 @@ from .split import (BestSplit, FeatureInfo, SplitParams, best_split_numerical,
 from .tree import Tree
 from ..io.binning import BinType, MissingType
 from ..io.dataset import BinnedDataset
-from ..obs import annotate as _annotate
+from ..obs.spans import span as _span
 from ..utils.timer import FunctionTimer
 
 
@@ -364,52 +364,59 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         grad, hess, qscale = quantize_gradients(
             grad, hess, rid, it_q, quant_seed,
             axis_name=axis_name if comm_mode != "feature" else "")
-    if rows_carry is not None:
-        # boosting state already lives (permuted) in the store; refresh only
-        # the gradient/hessian bytes for this iteration
-        n_arr = n + (_PCHUNK if fused else 0)
-        assert rows_carry.shape == (n_arr, W), \
-            f"carried row store shape {rows_carry.shape} != {(n_arr, W)}"
-        gb = jax.lax.bitcast_convert_type(grad.astype(f32), jnp.uint8)
-        hb = jax.lax.bitcast_convert_type(hess.astype(f32), jnp.uint8)
-        ghb = jnp.concatenate([gb, hb], axis=1)
-        if n_arr > n:
-            ghb = jnp.pad(ghb, ((0, n_arr - n), (0, 0)))
-        rows0 = rows_carry.at[:, voff:voff + 8].set(ghb)
-    else:
-        if bpc == 2:
-            bins_u8 = jax.lax.bitcast_convert_type(
-                bins, jnp.uint8).reshape(n, nbytes_bins)
+    # The program's phases as named scopes: metadata only (the jaxpr's
+    # equations are the same with and without them), but they survive into
+    # the compiled HLO's op_name, which is how a profiler trace's
+    # compiler-made instruction names are read back as tree.store / root /
+    # pick_leaf / split / find_split / state_update / finish
+    # (obs/scopes.py).
+    with jax.named_scope("tree.store"):
+        if rows_carry is not None:
+            # boosting state already lives (permuted) in the store; refresh only
+            # the gradient/hessian bytes for this iteration
+            n_arr = n + (_PCHUNK if fused else 0)
+            assert rows_carry.shape == (n_arr, W), \
+                f"carried row store shape {rows_carry.shape} != {(n_arr, W)}"
+            gb = jax.lax.bitcast_convert_type(grad.astype(f32), jnp.uint8)
+            hb = jax.lax.bitcast_convert_type(hess.astype(f32), jnp.uint8)
+            ghb = jnp.concatenate([gb, hb], axis=1)
+            if n_arr > n:
+                ghb = jnp.pad(ghb, ((0, n_arr - n), (0, 0)))
+            rows0 = rows_carry.at[:, voff:voff + 8].set(ghb)
         else:
-            bins_u8 = bins.astype(jnp.uint8)
-        parts = [bins_u8]
-        if voff > nbytes_bins:
-            parts.append(jnp.zeros((n, voff - nbytes_bins), jnp.uint8))
-        parts.append(jax.lax.bitcast_convert_type(grad.astype(f32), jnp.uint8))
-        parts.append(jax.lax.bitcast_convert_type(hess.astype(f32), jnp.uint8))
-        parts.append(jax.lax.bitcast_convert_type(
-            jnp.arange(n, dtype=jnp.int32), jnp.uint8))
-        if carried:
-            aux0, score0 = extra
+            if bpc == 2:
+                bins_u8 = jax.lax.bitcast_convert_type(
+                    bins, jnp.uint8).reshape(n, nbytes_bins)
+            else:
+                bins_u8 = bins.astype(jnp.uint8)
+            parts = [bins_u8]
+            if voff > nbytes_bins:
+                parts.append(jnp.zeros((n, voff - nbytes_bins), jnp.uint8))
+            parts.append(jax.lax.bitcast_convert_type(grad.astype(f32), jnp.uint8))
+            parts.append(jax.lax.bitcast_convert_type(hess.astype(f32), jnp.uint8))
             parts.append(jax.lax.bitcast_convert_type(
-                aux0.astype(f32), jnp.uint8))
-            parts.append(jax.lax.bitcast_convert_type(
-                score0.astype(f32), jnp.uint8))
-        if lazy_on:
-            # rows that already paid lazy feature costs in EARLIER trees
-            # (feature_used_in_data_ lives for the whole training,
-            # cost_effective_gradient_boosting.hpp:47)
-            parts.append(paid_bits if paid_bits is not None
-                         else jnp.zeros((n, bitbytes), jnp.uint8))
-        if W > bitoff + bitbytes:
-            parts.append(jnp.zeros((n, W - bitoff - bitbytes), jnp.uint8))
-        rows0 = jnp.concatenate(parts, axis=1)
-        if fused:
-            pad_order = jax.lax.bitcast_convert_type(
-                jnp.arange(n, n + _PCHUNK, dtype=jnp.int32), jnp.uint8)
-            pad_block = jnp.zeros((_PCHUNK, W), jnp.uint8).at[
-                :, voff + 8:voff + 12].set(pad_order)
-            rows0 = jnp.concatenate([rows0, pad_block], axis=0)
+                jnp.arange(n, dtype=jnp.int32), jnp.uint8))
+            if carried:
+                aux0, score0 = extra
+                parts.append(jax.lax.bitcast_convert_type(
+                    aux0.astype(f32), jnp.uint8))
+                parts.append(jax.lax.bitcast_convert_type(
+                    score0.astype(f32), jnp.uint8))
+            if lazy_on:
+                # rows that already paid lazy feature costs in EARLIER trees
+                # (feature_used_in_data_ lives for the whole training,
+                # cost_effective_gradient_boosting.hpp:47)
+                parts.append(paid_bits if paid_bits is not None
+                             else jnp.zeros((n, bitbytes), jnp.uint8))
+            if W > bitoff + bitbytes:
+                parts.append(jnp.zeros((n, W - bitoff - bitbytes), jnp.uint8))
+            rows0 = jnp.concatenate(parts, axis=1)
+            if fused:
+                pad_order = jax.lax.bitcast_convert_type(
+                    jnp.arange(n, n + _PCHUNK, dtype=jnp.int32), jnp.uint8)
+                pad_block = jnp.zeros((_PCHUNK, W), jnp.uint8).at[
+                    :, voff + 8:voff + 12].set(pad_order)
+                rows0 = jnp.concatenate([rows0, pad_block], axis=0)
 
     def hist_rows(rows_mat, start, count):
         # hist_fc/hist_f0 are set below once the comm mode is known:
@@ -770,357 +777,365 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     branches = [] if fused else [make_branch(R) for R in buckets]
 
     # ---- root ----
-    hist0 = hist_rows(rows0, jnp.int32(0), jnp.int32(n))
-    sum_g = jnp.sum(grad)
-    sum_h = jnp.sum(hess)
-    # reduce_hist also DEQUANTIZES under hist_precision=quantized, so it
-    # runs unconditionally (identity for the serial exact path)
-    hist0 = reduce_hist(hist0)
-    if axis_name and not feat_mode:
-        # root aggregate Allreduce (data_parallel_tree_learner.cpp:99-146);
-        # feature mode replicates the rows, so local sums are already global
-        sum_g = jax.lax.psum(sum_g, axis_name)
-        sum_h = jax.lax.psum(sum_h, axis_name)
-    if quantized:
-        # root totals were summed over the INTEGER gradients: scale them
-        # back so leaf outputs / gains live in the real-valued domain
-        sum_g = sum_g * qscale[0]
-        sum_h = sum_h * qscale[1]
-    no_min = jnp.float32(-np.inf)
-    no_max = jnp.float32(np.inf)
-    used0 = (cegb[2] if cegb is not None else jnp.zeros((f,), bool))
-    if lazy_on:
-        # rows that pre-paid each feature's lazy cost in earlier trees
-        fi0 = np.arange(f)
-        pb0 = rows0[:, bitoff + fi0 // 8].astype(jnp.int32)
-        ucnt0 = jnp.sum(((pb0 >> jnp.asarray(fi0 % 8)) & 1).astype(f32),
-                        axis=0)
-        if axis_name:
-            ucnt0 = jax.lax.psum(ucnt0, axis_name)
-    else:
-        ucnt0 = jnp.zeros((f,), f32)
-    if cegb is not None:
-        best0, fb0 = best_of(hist0, sum_g, sum_h, num_data, no_min, no_max,
-                             used0, ucnt0)
-        fbc0 = type(fb0)(*[
-            jnp.full((L,) + x.shape,
-                     K_MIN_SCORE if name == "gain" else 0,
-                     dtype=x.dtype).at[0].set(x)
-            for name, x in zip(type(fb0)._fields, fb0)])
-    else:
-        best0 = best_of(hist0, sum_g, sum_h, num_data, no_min, no_max)
-        fbc0 = ()
+    with jax.named_scope("tree.root"):
+        hist0 = hist_rows(rows0, jnp.int32(0), jnp.int32(n))
+        sum_g = jnp.sum(grad)
+        sum_h = jnp.sum(hess)
+        # reduce_hist also DEQUANTIZES under hist_precision=quantized, so it
+        # runs unconditionally (identity for the serial exact path)
+        hist0 = reduce_hist(hist0)
+        if axis_name and not feat_mode:
+            # root aggregate Allreduce (data_parallel_tree_learner.cpp:99-146);
+            # feature mode replicates the rows, so local sums are already global
+            sum_g = jax.lax.psum(sum_g, axis_name)
+            sum_h = jax.lax.psum(sum_h, axis_name)
+        if quantized:
+            # root totals were summed over the INTEGER gradients: scale them
+            # back so leaf outputs / gains live in the real-valued domain
+            sum_g = sum_g * qscale[0]
+            sum_h = sum_h * qscale[1]
+        no_min = jnp.float32(-np.inf)
+        no_max = jnp.float32(np.inf)
+        used0 = (cegb[2] if cegb is not None else jnp.zeros((f,), bool))
+        if lazy_on:
+            # rows that pre-paid each feature's lazy cost in earlier trees
+            fi0 = np.arange(f)
+            pb0 = rows0[:, bitoff + fi0 // 8].astype(jnp.int32)
+            ucnt0 = jnp.sum(((pb0 >> jnp.asarray(fi0 % 8)) & 1).astype(f32),
+                            axis=0)
+            if axis_name:
+                ucnt0 = jax.lax.psum(ucnt0, axis_name)
+        else:
+            ucnt0 = jnp.zeros((f,), f32)
+        if cegb is not None:
+            best0, fb0 = best_of(hist0, sum_g, sum_h, num_data, no_min, no_max,
+                                 used0, ucnt0)
+            fbc0 = type(fb0)(*[
+                jnp.full((L,) + x.shape,
+                         K_MIN_SCORE if name == "gain" else 0,
+                         dtype=x.dtype).at[0].set(x)
+                for name, x in zip(type(fb0)._fields, fb0)])
+        else:
+            best0 = best_of(hist0, sum_g, sum_h, num_data, no_min, no_max)
+            fbc0 = ()
 
-    def zl(dtype=f32):
-        return jnp.zeros((L,), dtype=dtype)
+        def zl(dtype=f32):
+            return jnp.zeros((L,), dtype=dtype)
 
-    tree = TreeArrays(
-        split_feature=zl(jnp.int32), threshold_bin=zl(jnp.int32),
-        split_gain=zl(), default_left=zl(bool),
-        left_child=zl(jnp.int32), right_child=zl(jnp.int32),
-        internal_value=zl(), internal_weight=zl(), internal_count=zl(),
-        leaf_value=zl(), leaf_weight=zl().at[0].set(sum_h),
-        leaf_count=zl().at[0].set(num_data.astype(f32)),
-        leaf_parent=jnp.full((L,), -1, dtype=jnp.int32), leaf_depth=zl(jnp.int32),
-        cat_bitset=jnp.zeros((L, B // 32), dtype=jnp.uint32),
-        num_leaves=jnp.int32(1), row_leaf=jnp.zeros((n,), dtype=jnp.int32))
+        tree = TreeArrays(
+            split_feature=zl(jnp.int32), threshold_bin=zl(jnp.int32),
+            split_gain=zl(), default_left=zl(bool),
+            left_child=zl(jnp.int32), right_child=zl(jnp.int32),
+            internal_value=zl(), internal_weight=zl(), internal_count=zl(),
+            leaf_value=zl(), leaf_weight=zl().at[0].set(sum_h),
+            leaf_count=zl().at[0].set(num_data.astype(f32)),
+            leaf_parent=jnp.full((L,), -1, dtype=jnp.int32), leaf_depth=zl(jnp.int32),
+            cat_bitset=jnp.zeros((L, B // 32), dtype=jnp.uint32),
+            num_leaves=jnp.int32(1), row_leaf=jnp.zeros((n,), dtype=jnp.int32))
 
-    # Histogram state: unbounded keeps one slot per leaf ([L, F, 2, B], the
-    # round-3 behavior); histogram_pool_size > 0 bounds it to K LRU slots
-    # (the reference's HistogramPool, feature_histogram.hpp:687) — an evicted
-    # parent is REBUILT by streaming its window, which post-partition still
-    # holds exactly the parent's rows.
-    pooled = hist_pool_slots > 0
-    if pooled:
-        assert forced is None and cegb is None, \
-            "histogram_pool_size needs the full per-leaf cache for forced " \
-            "splits / CEGB candidate bookkeeping"
-        K_slots = max(2, min(hist_pool_slots, L))
-        hist = jnp.zeros((K_slots,) + hist0.shape, dtype=f32).at[0].set(hist0)
-        slot_of0 = jnp.full((L,), -1, jnp.int32).at[0].set(0)
-        stamps0 = jnp.full((K_slots,), -1, jnp.int32).at[0].set(0)
-    else:
-        hist = jnp.zeros((L,) + hist0.shape, dtype=f32).at[0].set(hist0)
-        slot_of0 = ()
-        stamps0 = ()
-    bests = BestSplit(*[jnp.broadcast_to(x, (L,) + x.shape).astype(x.dtype)
-                        for x in best0])
-    state = _PState(tree=tree, hist=hist, bests=bests, cont=jnp.bool_(True),
-                    cmin=jnp.full((L,), -np.inf, dtype=f32),
-                    cmax=jnp.full((L,), np.inf, dtype=f32),
-                    begin=zl(jnp.int32),
-                    wcount=zl(jnp.int32).at[0].set(n),
-                    rows=rows0,
-                    lsum_g=zl().at[0].set(sum_g),
-                    lsum_h=zl().at[0].set(sum_h),
-                    feat_used=used0,
-                    force_on=jnp.bool_(True),
-                    fbc=fbc0,
-                    slot_of=slot_of0,
-                    stamps=stamps0)
+        # Histogram state: unbounded keeps one slot per leaf ([L, F, 2, B], the
+        # round-3 behavior); histogram_pool_size > 0 bounds it to K LRU slots
+        # (the reference's HistogramPool, feature_histogram.hpp:687) — an evicted
+        # parent is REBUILT by streaming its window, which post-partition still
+        # holds exactly the parent's rows.
+        pooled = hist_pool_slots > 0
+        if pooled:
+            assert forced is None and cegb is None, \
+                "histogram_pool_size needs the full per-leaf cache for forced " \
+                "splits / CEGB candidate bookkeeping"
+            K_slots = max(2, min(hist_pool_slots, L))
+            hist = jnp.zeros((K_slots,) + hist0.shape, dtype=f32).at[0].set(hist0)
+            slot_of0 = jnp.full((L,), -1, jnp.int32).at[0].set(0)
+            stamps0 = jnp.full((K_slots,), -1, jnp.int32).at[0].set(0)
+        else:
+            hist = jnp.zeros((L,) + hist0.shape, dtype=f32).at[0].set(hist0)
+            slot_of0 = ()
+            stamps0 = ()
+        bests = BestSplit(*[jnp.broadcast_to(x, (L,) + x.shape).astype(x.dtype)
+                            for x in best0])
+        state = _PState(tree=tree, hist=hist, bests=bests, cont=jnp.bool_(True),
+                        cmin=jnp.full((L,), -np.inf, dtype=f32),
+                        cmax=jnp.full((L,), np.inf, dtype=f32),
+                        begin=zl(jnp.int32),
+                        wcount=zl(jnp.int32).at[0].set(n),
+                        rows=rows0,
+                        lsum_g=zl().at[0].set(sum_g),
+                        lsum_h=zl().at[0].set(sum_h),
+                        feat_used=used0,
+                        force_on=jnp.bool_(True),
+                        fbc=fbc0,
+                        slot_of=slot_of0,
+                        stamps=stamps0)
 
     def body(k, st: _PState) -> _PState:
-        node = k - 1
-        t = st.tree
-        gains = jnp.where(jnp.arange(L) < t.num_leaves, st.bests.gain, K_MIN_SCORE)
-        if max_depth > 0:
-            gains = jnp.where(t.leaf_depth < max_depth, gains, K_MIN_SCORE)
-        leaf = jnp.argmax(gains).astype(jnp.int32)
-        ok = (gains[leaf] > 0.0) & st.cont
-        force_now = None
-        if forced is not None:
-            fleaf, fbest, fvalid, in_sched = forced_best(st, k)
-            leaf = jnp.where(fvalid, fleaf, leaf)
-            ok = jnp.where(fvalid, st.cont, ok)
-            force_now = (fbest, fvalid)
-            # one failed entry invalidates the rest of the schedule's leaf ids
-            st = st._replace(force_on=st.force_on & (~in_sched | fvalid))
+        with jax.named_scope("tree.pick_leaf"):
+            node = k - 1
+            t = st.tree
+            gains = jnp.where(jnp.arange(L) < t.num_leaves, st.bests.gain, K_MIN_SCORE)
+            if max_depth > 0:
+                gains = jnp.where(t.leaf_depth < max_depth, gains, K_MIN_SCORE)
+            leaf = jnp.argmax(gains).astype(jnp.int32)
+            ok = (gains[leaf] > 0.0) & st.cont
+            force_now = None
+            if forced is not None:
+                fleaf, fbest, fvalid, in_sched = forced_best(st, k)
+                leaf = jnp.where(fvalid, fleaf, leaf)
+                ok = jnp.where(fvalid, st.cont, ok)
+                force_now = (fbest, fvalid)
+                # one failed entry invalidates the rest of the schedule's leaf ids
+                st = st._replace(force_on=st.force_on & (~in_sched | fvalid))
 
-        # The split always executes — a dead iteration (ok=False) partitions
-        # an EMPTY window of the smallest bucket (identity permutation, zero
-        # histogram) and every state write below is masked by ``ok``.  An
-        # actual lax.cond around the split forced XLA to materialize
-        # unification copies of the partitioned matrices every iteration.
-        t = st.tree
-        b = BestSplit(*[x[leaf] for x in st.bests])
-        if force_now is not None:
-            fbest, fvalid = force_now
-            b = BestSplit(*[jnp.where(fvalid, fx, x)
-                            for fx, x in zip(fbest, b)])
-        wb = jnp.where(ok, st.begin[leaf], 0)
-        wc = jnp.where(ok, st.wcount[leaf], 0)
-        left_smaller = b.left_count <= b.right_count
-        if fused:
-            # one fused Pallas pass: route + stable partition + smaller-child
-            # histogram, cost proportional to the window (core/partition.py)
-            fid = b.feature
-            if feat.offset is None:
-                unf = jnp.int32(0)
-                eoff = jnp.int32(0)
-            else:
-                unf = jnp.int32(1)
-                eoff = feat.offset[fid].astype(jnp.int32)
-            head = jnp.stack([
-                wb, wc, _feature_column(fid, feat).astype(jnp.int32),
-                b.threshold.astype(jnp.int32),
-                b.default_left.astype(jnp.int32),
-                feat.missing_type[fid].astype(jnp.int32),
-                feat.num_bin[fid].astype(jnp.int32),
-                feat.default_bin[fid].astype(jnp.int32),
-                feat.is_categorical[fid].astype(jnp.int32),
-                left_smaller.astype(jnp.int32), unf, eoff])
-            nw = num_bins // 32
-            bw = jax.lax.bitcast_convert_type(b.cat_bitset, jnp.int32)
-            if bw.shape[0] < nw:
-                bw = jnp.concatenate(
-                    [bw, jnp.zeros((nw - bw.shape[0],), jnp.int32)])
-            scal = jnp.concatenate([head, bw[:nw]])
-            if hist_fc != f_cols:
-                scal = jnp.concatenate(
-                    [scal, jnp.reshape(jnp.asarray(hist_f0, jnp.int32),
-                                       (1,))])
-            rows_new, hist4, nl_arr = _fused_split(st.rows, scal, wc)
-            hist_small = fold_hist(hist4, hist_fc, num_bins, quantized)
-            nl = nl_arr[0, 0]
-            used_l = used_r = jnp.zeros((f,), f32)
-        else:
-            which = jnp.searchsorted(bsizes, wc).astype(jnp.int32)
-            branch_out = jax.lax.switch(
-                which, branches, st.rows, wb, wc,
-                b.feature, b.threshold, b.default_left,
-                feat.is_categorical[b.feature], b.cat_bitset, left_smaller)
-            if lazy_on:
-                rows_new, hist_small, nl, used_l, used_r = branch_out
-            else:
-                rows_new, hist_small, nl = branch_out
+            # The split always executes — a dead iteration (ok=False) partitions
+            # an EMPTY window of the smallest bucket (identity permutation, zero
+            # histogram) and every state write below is masked by ``ok``.  An
+            # actual lax.cond around the split forced XLA to materialize
+            # unification copies of the partitioned matrices every iteration.
+            t = st.tree
+            b = BestSplit(*[x[leaf] for x in st.bests])
+            if force_now is not None:
+                fbest, fvalid = force_now
+                b = BestSplit(*[jnp.where(fvalid, fx, x)
+                                for fx, x in zip(fbest, b)])
+            wb = jnp.where(ok, st.begin[leaf], 0)
+            wc = jnp.where(ok, st.wcount[leaf], 0)
+            left_smaller = b.left_count <= b.right_count
+        with jax.named_scope("tree.split"):
+            if fused:
+                # one fused Pallas pass: route + stable partition + smaller-child
+                # histogram, cost proportional to the window (core/partition.py)
+                fid = b.feature
+                if feat.offset is None:
+                    unf = jnp.int32(0)
+                    eoff = jnp.int32(0)
+                else:
+                    unf = jnp.int32(1)
+                    eoff = feat.offset[fid].astype(jnp.int32)
+                head = jnp.stack([
+                    wb, wc, _feature_column(fid, feat).astype(jnp.int32),
+                    b.threshold.astype(jnp.int32),
+                    b.default_left.astype(jnp.int32),
+                    feat.missing_type[fid].astype(jnp.int32),
+                    feat.num_bin[fid].astype(jnp.int32),
+                    feat.default_bin[fid].astype(jnp.int32),
+                    feat.is_categorical[fid].astype(jnp.int32),
+                    left_smaller.astype(jnp.int32), unf, eoff])
+                nw = num_bins // 32
+                bw = jax.lax.bitcast_convert_type(b.cat_bitset, jnp.int32)
+                if bw.shape[0] < nw:
+                    bw = jnp.concatenate(
+                        [bw, jnp.zeros((nw - bw.shape[0],), jnp.int32)])
+                scal = jnp.concatenate([head, bw[:nw]])
+                if hist_fc != f_cols:
+                    scal = jnp.concatenate(
+                        [scal, jnp.reshape(jnp.asarray(hist_f0, jnp.int32),
+                                           (1,))])
+                rows_new, hist4, nl_arr = _fused_split(st.rows, scal, wc)
+                hist_small = fold_hist(hist4, hist_fc, num_bins, quantized)
+                nl = nl_arr[0, 0]
                 used_l = used_r = jnp.zeros((f,), f32)
-        # per-split Allreduce (psum) or ReduceScatter (rs) of the smaller
-        # child's histogram (data_parallel_tree_learner.cpp:161
-        # ReduceScatter); unconditional so the quantized path dequantizes
-        # on the serial learner too
-        hist_small = reduce_hist(hist_small)
-        if axis_name and lazy_on:
-            used_l = jax.lax.psum(used_l, axis_name)
-            used_r = jax.lax.psum(used_r, axis_name)
+            else:
+                which = jnp.searchsorted(bsizes, wc).astype(jnp.int32)
+                branch_out = jax.lax.switch(
+                    which, branches, st.rows, wb, wc,
+                    b.feature, b.threshold, b.default_left,
+                    feat.is_categorical[b.feature], b.cat_bitset, left_smaller)
+                if lazy_on:
+                    rows_new, hist_small, nl, used_l, used_r = branch_out
+                else:
+                    rows_new, hist_small, nl = branch_out
+                    used_l = used_r = jnp.zeros((f,), f32)
+        with jax.named_scope("tree.find_split"):
+            # per-split Allreduce (psum) or ReduceScatter (rs) of the smaller
+            # child's histogram (data_parallel_tree_learner.cpp:161
+            # ReduceScatter); unconditional so the quantized path dequantizes
+            # on the serial learner too
+            hist_small = reduce_hist(hist_small)
+            if axis_name and lazy_on:
+                used_l = jax.lax.psum(used_l, axis_name)
+                used_r = jax.lax.psum(used_r, axis_name)
 
         def sel(new, old):
             """Masked state write: keep ``old`` on dead iterations."""
             return jnp.where(ok, new, old)
 
-        if pooled:
-            # parent histogram from its LRU slot, or rebuilt by streaming the
-            # window (post-partition it still holds exactly the parent rows —
-            # HistogramPool::Get miss, feature_histogram.hpp:687).
-            # INVARIANT under comm_mode='rs': slot_of/stamps are REPLICATED
-            # across shards, so every shard takes the same cond branch and
-            # the psum_scatter inside _miss is executed collectively; a
-            # shard-local divergence of this state would deadlock the
-            # collective.  (Replication holds because slot bookkeeping is
-            # derived only from replicated best-split decisions.)
-            ps = st.slot_of[leaf]
+        with jax.named_scope("tree.find_split"):
+            if pooled:
+                # parent histogram from its LRU slot, or rebuilt by streaming the
+                # window (post-partition it still holds exactly the parent rows —
+                # HistogramPool::Get miss, feature_histogram.hpp:687).
+                # INVARIANT under comm_mode='rs': slot_of/stamps are REPLICATED
+                # across shards, so every shard takes the same cond branch and
+                # the psum_scatter inside _miss is executed collectively; a
+                # shard-local divergence of this state would deadlock the
+                # collective.  (Replication holds because slot bookkeeping is
+                # derived only from replicated best-split decisions.)
+                ps = st.slot_of[leaf]
 
-            def _hit(_):
-                return st.hist[jnp.maximum(ps, 0)]
+                def _hit(_):
+                    return st.hist[jnp.maximum(ps, 0)]
 
-            def _miss(_):
-                return reduce_hist(hist_rows(rows_new, wb, wc))
+                def _miss(_):
+                    return reduce_hist(hist_rows(rows_new, wb, wc))
 
-            parent_hist = jax.lax.cond(ps >= 0, _hit, _miss, 0)
-            hist_larger = parent_hist - hist_small
-            hist_left = jnp.where(left_smaller, hist_small, hist_larger)
-            hist_right = jnp.where(left_smaller, hist_larger, hist_small)
-            # left child inherits the parent's slot (or the LRU slot on a
-            # miss); right child evicts the next-least-recently-used slot
-            sL = jnp.where(ps >= 0, ps, jnp.argmin(st.stamps).astype(jnp.int32))
-            sR = jnp.argmin(st.stamps.at[sL].set(2 ** 30)).astype(jnp.int32)
-            hist_new = st.hist.at[sL].set(sel(hist_left, st.hist[sL])) \
-                              .at[sR].set(sel(hist_right, st.hist[sR]))
-            stamps_new = st.stamps.at[sL].set(k).at[sR].set(k)
-            slot_upd = jnp.where((st.slot_of == sL) | (st.slot_of == sR),
-                                 -1, st.slot_of)
-            slot_upd = slot_upd.at[leaf].set(sL).at[k].set(sR)
-        else:
-            hist_larger = st.hist[leaf] - hist_small
-            hist_left = jnp.where(left_smaller, hist_small, hist_larger)
-            hist_right = jnp.where(left_smaller, hist_larger, hist_small)
-            hist_new = st.hist.at[leaf].set(sel(hist_left, st.hist[leaf])) \
-                              .at[k].set(sel(hist_right, st.hist[k]))
-            stamps_new = st.stamps
-            slot_upd = st.slot_of
+                parent_hist = jax.lax.cond(ps >= 0, _hit, _miss, 0)
+                hist_larger = parent_hist - hist_small
+                hist_left = jnp.where(left_smaller, hist_small, hist_larger)
+                hist_right = jnp.where(left_smaller, hist_larger, hist_small)
+                # left child inherits the parent's slot (or the LRU slot on a
+                # miss); right child evicts the next-least-recently-used slot
+                sL = jnp.where(ps >= 0, ps, jnp.argmin(st.stamps).astype(jnp.int32))
+                sR = jnp.argmin(st.stamps.at[sL].set(2 ** 30)).astype(jnp.int32)
+                hist_new = st.hist.at[sL].set(sel(hist_left, st.hist[sL])) \
+                                  .at[sR].set(sel(hist_right, st.hist[sR]))
+                stamps_new = st.stamps.at[sL].set(k).at[sR].set(k)
+                slot_upd = jnp.where((st.slot_of == sL) | (st.slot_of == sR),
+                                     -1, st.slot_of)
+                slot_upd = slot_upd.at[leaf].set(sL).at[k].set(sR)
+            else:
+                hist_larger = st.hist[leaf] - hist_small
+                hist_left = jnp.where(left_smaller, hist_small, hist_larger)
+                hist_right = jnp.where(left_smaller, hist_larger, hist_small)
+                hist_new = st.hist.at[leaf].set(sel(hist_left, st.hist[leaf])) \
+                                  .at[k].set(sel(hist_right, st.hist[k]))
+                stamps_new = st.stamps
+                slot_upd = st.slot_of
 
-        begin = st.begin.at[k].set(wb + nl)
-        wcount = st.wcount.at[leaf].set(nl).at[k].set(wc - nl)
+        with jax.named_scope("tree.state_update"):
+            begin = st.begin.at[k].set(wb + nl)
+            wcount = st.wcount.at[leaf].set(nl).at[k].set(wc - nl)
 
-        # monotone constraint propagation
-        # (monotone_constraints.hpp UpdateConstraints)
-        pmin, pmax = st.cmin[leaf], st.cmax[leaf]
-        if has_monotone and feat.monotone is not None:
-            mono_f = feat.monotone[b.feature]
-        else:
-            mono_f = jnp.int32(0)
-        is_num = ~feat.is_categorical[b.feature]
-        mid = (b.left_output + b.right_output) * 0.5
-        lmin = jnp.where(is_num & (mono_f < 0), jnp.maximum(pmin, mid), pmin)
-        lmax = jnp.where(is_num & (mono_f > 0), jnp.minimum(pmax, mid), pmax)
-        rmin = jnp.where(is_num & (mono_f > 0), jnp.maximum(pmin, mid), pmin)
-        rmax = jnp.where(is_num & (mono_f < 0), jnp.minimum(pmax, mid), pmax)
-        cmin_new = st.cmin.at[leaf].set(lmin).at[k].set(rmin)
-        cmax_new = st.cmax.at[leaf].set(lmax).at[k].set(rmax)
+            # monotone constraint propagation
+            # (monotone_constraints.hpp UpdateConstraints)
+            pmin, pmax = st.cmin[leaf], st.cmax[leaf]
+            if has_monotone and feat.monotone is not None:
+                mono_f = feat.monotone[b.feature]
+            else:
+                mono_f = jnp.int32(0)
+            is_num = ~feat.is_categorical[b.feature]
+            mid = (b.left_output + b.right_output) * 0.5
+            lmin = jnp.where(is_num & (mono_f < 0), jnp.maximum(pmin, mid), pmin)
+            lmax = jnp.where(is_num & (mono_f > 0), jnp.minimum(pmax, mid), pmax)
+            rmin = jnp.where(is_num & (mono_f > 0), jnp.maximum(pmin, mid), pmin)
+            rmax = jnp.where(is_num & (mono_f < 0), jnp.minimum(pmax, mid), pmax)
+            cmin_new = st.cmin.at[leaf].set(lmin).at[k].set(rmin)
+            cmax_new = st.cmax.at[leaf].set(lmax).at[k].set(rmax)
 
-        feat_used = (st.feat_used | (jnp.arange(f) == b.feature)
-                     if cegb is not None else st.feat_used)
-        if cegb is not None:
-            # coupled-penalty refund (UpdateLeafBestSplits,
-            # cost_effective_gradient_boosting.hpp:63-79): the first split on
-            # a feature makes its coupled cost sunk, so every other leaf's
-            # cached candidate for that feature gets the penalty back and is
-            # promoted when it now beats the leaf's cached best.  (The
-            # reference adds the refund to the PRE-penalty cached gain — a
-            # quirk that inflates promoted gains; here the cache holds
-            # penalized gains so the refund yields the intended value.)
-            coupled_arr = cegb[1]
-            fnew = b.feature
-            newly = ok & ~st.feat_used[fnew]
-            refund = jnp.where(newly, coupled_arr[fnew], 0.0)
-            fbc = st.fbc._replace(gain=st.fbc.gain.at[:, fnew].add(refund))
-            cand_gain = jnp.take(fbc.gain, fnew, axis=1)          # [L]
-            promote = (newly & (st.bests.gain > K_MIN_SCORE)
-                       & (cand_gain > st.bests.gain))
+            feat_used = (st.feat_used | (jnp.arange(f) == b.feature)
+                         if cegb is not None else st.feat_used)
+        with jax.named_scope("tree.find_split"):
+            if cegb is not None:
+                # coupled-penalty refund (UpdateLeafBestSplits,
+                # cost_effective_gradient_boosting.hpp:63-79): the first split on
+                # a feature makes its coupled cost sunk, so every other leaf's
+                # cached candidate for that feature gets the penalty back and is
+                # promoted when it now beats the leaf's cached best.  (The
+                # reference adds the refund to the PRE-penalty cached gain — a
+                # quirk that inflates promoted gains; here the cache holds
+                # penalized gains so the refund yields the intended value.)
+                coupled_arr = cegb[1]
+                fnew = b.feature
+                newly = ok & ~st.feat_used[fnew]
+                refund = jnp.where(newly, coupled_arr[fnew], 0.0)
+                fbc = st.fbc._replace(gain=st.fbc.gain.at[:, fnew].add(refund))
+                cand_gain = jnp.take(fbc.gain, fnew, axis=1)          # [L]
+                promote = (newly & (st.bests.gain > K_MIN_SCORE)
+                           & (cand_gain > st.bests.gain))
 
-            def pick(cand_field, old_field):
-                cand_col = jnp.take(cand_field, fnew, axis=1)
-                shape_tail = (1,) * (old_field.ndim - 1)
-                return jnp.where(promote.reshape((-1,) + shape_tail),
-                                 cand_col, old_field)
+                def pick(cand_field, old_field):
+                    cand_col = jnp.take(cand_field, fnew, axis=1)
+                    shape_tail = (1,) * (old_field.ndim - 1)
+                    return jnp.where(promote.reshape((-1,) + shape_tail),
+                                     cand_col, old_field)
 
-            promoted = BestSplit(
-                gain=jnp.where(promote, cand_gain, st.bests.gain),
-                feature=jnp.where(promote, fnew, st.bests.feature),
-                threshold=pick(fbc.threshold, st.bests.threshold),
-                default_left=pick(fbc.default_left, st.bests.default_left),
-                left_sum_grad=pick(fbc.left_sum_grad,
-                                   st.bests.left_sum_grad),
-                left_sum_hess=pick(fbc.left_sum_hess,
-                                   st.bests.left_sum_hess),
-                left_count=pick(fbc.left_count, st.bests.left_count),
-                right_sum_grad=pick(fbc.right_sum_grad,
-                                    st.bests.right_sum_grad),
-                right_sum_hess=pick(fbc.right_sum_hess,
-                                    st.bests.right_sum_hess),
-                right_count=pick(fbc.right_count, st.bests.right_count),
-                left_output=pick(fbc.left_output, st.bests.left_output),
-                right_output=pick(fbc.right_output, st.bests.right_output),
-                cat_bitset=pick(fbc.cat_bitset, st.bests.cat_bitset))
-            child_best, child_fb = vmapped_best(
-                jnp.stack([hist_left, hist_right]),
-                jnp.stack([b.left_sum_grad, b.right_sum_grad]),
-                jnp.stack([b.left_sum_hess, b.right_sum_hess]),
-                jnp.stack([b.left_count, b.right_count]),
-                jnp.stack([lmin, rmin]), jnp.stack([lmax, rmax]),
-                feat_used, jnp.stack([used_l, used_r]))
-            fbc = type(fbc)(*[x.at[leaf].set(c[0]).at[k].set(c[1])
-                              for x, c in zip(fbc, child_fb)])
-            bests = _bests_update(promoted, leaf,
-                                  BestSplit(*[x[0] for x in child_best]))
-        else:
-            fbc = st.fbc
-            child_best = vmapped_best(
-                jnp.stack([hist_left, hist_right]),
-                jnp.stack([b.left_sum_grad, b.right_sum_grad]),
-                jnp.stack([b.left_sum_hess, b.right_sum_hess]),
-                jnp.stack([b.left_count, b.right_count]),
-                jnp.stack([lmin, rmin]), jnp.stack([lmax, rmax]),
-                feat_used)
-            bests = _bests_update(st.bests, leaf,
-                                  BestSplit(*[x[0] for x in child_best]))
-        bests = _bests_update(bests, k, BestSplit(*[x[1] for x in child_best]))
+                promoted = BestSplit(
+                    gain=jnp.where(promote, cand_gain, st.bests.gain),
+                    feature=jnp.where(promote, fnew, st.bests.feature),
+                    threshold=pick(fbc.threshold, st.bests.threshold),
+                    default_left=pick(fbc.default_left, st.bests.default_left),
+                    left_sum_grad=pick(fbc.left_sum_grad,
+                                       st.bests.left_sum_grad),
+                    left_sum_hess=pick(fbc.left_sum_hess,
+                                       st.bests.left_sum_hess),
+                    left_count=pick(fbc.left_count, st.bests.left_count),
+                    right_sum_grad=pick(fbc.right_sum_grad,
+                                        st.bests.right_sum_grad),
+                    right_sum_hess=pick(fbc.right_sum_hess,
+                                        st.bests.right_sum_hess),
+                    right_count=pick(fbc.right_count, st.bests.right_count),
+                    left_output=pick(fbc.left_output, st.bests.left_output),
+                    right_output=pick(fbc.right_output, st.bests.right_output),
+                    cat_bitset=pick(fbc.cat_bitset, st.bests.cat_bitset))
+                child_best, child_fb = vmapped_best(
+                    jnp.stack([hist_left, hist_right]),
+                    jnp.stack([b.left_sum_grad, b.right_sum_grad]),
+                    jnp.stack([b.left_sum_hess, b.right_sum_hess]),
+                    jnp.stack([b.left_count, b.right_count]),
+                    jnp.stack([lmin, rmin]), jnp.stack([lmax, rmax]),
+                    feat_used, jnp.stack([used_l, used_r]))
+                fbc = type(fbc)(*[x.at[leaf].set(c[0]).at[k].set(c[1])
+                                  for x, c in zip(fbc, child_fb)])
+                bests = _bests_update(promoted, leaf,
+                                      BestSplit(*[x[0] for x in child_best]))
+            else:
+                fbc = st.fbc
+                child_best = vmapped_best(
+                    jnp.stack([hist_left, hist_right]),
+                    jnp.stack([b.left_sum_grad, b.right_sum_grad]),
+                    jnp.stack([b.left_sum_hess, b.right_sum_hess]),
+                    jnp.stack([b.left_count, b.right_count]),
+                    jnp.stack([lmin, rmin]), jnp.stack([lmax, rmax]),
+                    feat_used)
+                bests = _bests_update(st.bests, leaf,
+                                      BestSplit(*[x[0] for x in child_best]))
+            bests = _bests_update(bests, k, BestSplit(*[x[1] for x in child_best]))
 
-        # parent child-pointer fixup (tree.h:338-346)
-        parent = t.leaf_parent[leaf]
-        pidx = jnp.maximum(parent, 0)
-        lc = t.left_child
-        rc = t.right_child
-        lc = lc.at[pidx].set(jnp.where((parent >= 0) & (lc[pidx] == ~leaf),
-                                       node, lc[pidx]))
-        rc = rc.at[pidx].set(jnp.where((parent >= 0) & (rc[pidx] == ~leaf),
-                                       node, rc[pidx]))
+        with jax.named_scope("tree.state_update"):
+            # parent child-pointer fixup (tree.h:338-346)
+            parent = t.leaf_parent[leaf]
+            pidx = jnp.maximum(parent, 0)
+            lc = t.left_child
+            rc = t.right_child
+            lc = lc.at[pidx].set(jnp.where((parent >= 0) & (lc[pidx] == ~leaf),
+                                           node, lc[pidx]))
+            rc = rc.at[pidx].set(jnp.where((parent >= 0) & (rc[pidx] == ~leaf),
+                                           node, rc[pidx]))
 
-        tree_new = TreeArrays(
-            split_feature=t.split_feature.at[node].set(b.feature),
-            threshold_bin=t.threshold_bin.at[node].set(b.threshold),
-            split_gain=t.split_gain.at[node].set(b.gain),
-            default_left=t.default_left.at[node].set(b.default_left),
-            left_child=lc.at[node].set(~leaf),
-            right_child=rc.at[node].set(~k),
-            internal_value=t.internal_value.at[node].set(t.leaf_value[leaf]),
-            internal_weight=t.internal_weight.at[node].set(t.leaf_weight[leaf]),
-            internal_count=t.internal_count.at[node].set(
-                b.left_count + b.right_count),
-            leaf_value=t.leaf_value.at[leaf].set(
-                jnp.nan_to_num(b.left_output)).at[k].set(
-                jnp.nan_to_num(b.right_output)),
-            leaf_weight=t.leaf_weight.at[leaf].set(
-                b.left_sum_hess).at[k].set(b.right_sum_hess),
-            leaf_count=t.leaf_count.at[leaf].set(
-                b.left_count).at[k].set(b.right_count),
-            leaf_parent=t.leaf_parent.at[leaf].set(node).at[k].set(node),
-            leaf_depth=t.leaf_depth.at[k].set(
-                t.leaf_depth[leaf] + 1).at[leaf].add(1),
-            cat_bitset=t.cat_bitset.at[node].set(b.cat_bitset),
-            num_leaves=t.num_leaves + 1,
-            row_leaf=t.row_leaf)
-        lsum_g = st.lsum_g.at[leaf].set(b.left_sum_grad).at[k].set(
-            b.right_sum_grad)
-        lsum_h = st.lsum_h.at[leaf].set(b.left_sum_hess).at[k].set(
-            b.right_sum_hess)
-        small_new = (tree_new, bests, cmin_new, cmax_new, begin, wcount,
-                     lsum_g, lsum_h, feat_used, fbc, slot_upd, stamps_new)
-        small_old = (t, st.bests, st.cmin, st.cmax, st.begin, st.wcount,
-                     st.lsum_g, st.lsum_h, st.feat_used, st.fbc,
-                     st.slot_of, st.stamps)
-        (tree_m, bests_m, cmin_m, cmax_m, begin_m, wcount_m, lsg_m, lsh_m,
-         fu_m, fbc_m, slot_m, stamps_m) = jax.tree_util.tree_map(
-            sel, small_new, small_old)
+            tree_new = TreeArrays(
+                split_feature=t.split_feature.at[node].set(b.feature),
+                threshold_bin=t.threshold_bin.at[node].set(b.threshold),
+                split_gain=t.split_gain.at[node].set(b.gain),
+                default_left=t.default_left.at[node].set(b.default_left),
+                left_child=lc.at[node].set(~leaf),
+                right_child=rc.at[node].set(~k),
+                internal_value=t.internal_value.at[node].set(t.leaf_value[leaf]),
+                internal_weight=t.internal_weight.at[node].set(t.leaf_weight[leaf]),
+                internal_count=t.internal_count.at[node].set(
+                    b.left_count + b.right_count),
+                leaf_value=t.leaf_value.at[leaf].set(
+                    jnp.nan_to_num(b.left_output)).at[k].set(
+                    jnp.nan_to_num(b.right_output)),
+                leaf_weight=t.leaf_weight.at[leaf].set(
+                    b.left_sum_hess).at[k].set(b.right_sum_hess),
+                leaf_count=t.leaf_count.at[leaf].set(
+                    b.left_count).at[k].set(b.right_count),
+                leaf_parent=t.leaf_parent.at[leaf].set(node).at[k].set(node),
+                leaf_depth=t.leaf_depth.at[k].set(
+                    t.leaf_depth[leaf] + 1).at[leaf].add(1),
+                cat_bitset=t.cat_bitset.at[node].set(b.cat_bitset),
+                num_leaves=t.num_leaves + 1,
+                row_leaf=t.row_leaf)
+            lsum_g = st.lsum_g.at[leaf].set(b.left_sum_grad).at[k].set(
+                b.right_sum_grad)
+            lsum_h = st.lsum_h.at[leaf].set(b.left_sum_hess).at[k].set(
+                b.right_sum_hess)
+            small_new = (tree_new, bests, cmin_new, cmax_new, begin, wcount,
+                         lsum_g, lsum_h, feat_used, fbc, slot_upd, stamps_new)
+            small_old = (t, st.bests, st.cmin, st.cmax, st.begin, st.wcount,
+                         st.lsum_g, st.lsum_h, st.feat_used, st.fbc,
+                         st.slot_of, st.stamps)
+            (tree_m, bests_m, cmin_m, cmax_m, begin_m, wcount_m, lsg_m, lsh_m,
+             fu_m, fbc_m, slot_m, stamps_m) = jax.tree_util.tree_map(
+                sel, small_new, small_old)
         return _PState(tree=tree_m, hist=hist_new, bests=bests_m,
                        cont=ok, cmin=cmin_m, cmax=cmax_m,
                        begin=begin_m, wcount=wcount_m,
@@ -1335,43 +1350,44 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     elif L > 1:
         state = jax.lax.fori_loop(1, L, body, state)
 
-    # reconstruct per-row leaf assignment from the windows + permutation
-    # (n_arr covers the fused path's spare CHUNK; those rows sit past every
-    # window, pick up a garbage leaf id, and are sliced away)
-    t = state.tree
-    n_arr = state.rows.shape[0]
-    valid = (jnp.arange(L) < t.num_leaves) & (state.wcount > 0)
-    mark_pos = jnp.where(valid, state.begin, n_arr)
-    marks = jnp.zeros((n_arr,), jnp.int32).at[mark_pos].set(
-        jnp.arange(L, dtype=jnp.int32) + 1, mode="drop")
-    if carried:
-        # The score column is updated in place by forward-filling each
-        # window's (shrinkage-scaled) leaf value — no per-row gather/scatter.
-        # row_leaf is returned EMPTY: the permuted-order assignment would
-        # corrupt original-order consumers (rollback, stall trim), which
-        # route the tree over the bins instead (gbdt._gather_tree_output).
-        lv = t.leaf_value * score_rate
-        vmarks = jnp.zeros((n_arr,), f32).at[mark_pos].set(lv, mode="drop")
-        _, leaf_val_pos = _ffill_pair(marks, vmarks)
-        score_old = jax.lax.bitcast_convert_type(
-            state.rows[:, soff:soff + 4], jnp.int32).reshape(n_arr)
-        score_new = (jax.lax.bitcast_convert_type(score_old, f32)
-                     + leaf_val_pos)
-        rows_out = state.rows.at[:, soff:soff + 4].set(
-            jax.lax.bitcast_convert_type(score_new, jnp.uint8))
-        return t._replace(row_leaf=jnp.zeros((0,), jnp.int32)), rows_out
-    leaf_of_pos = _ffill_nonzero(marks) - 1
-    order = jax.lax.bitcast_convert_type(
-        state.rows[:, voff + 8:voff + 12], jnp.int32).reshape(n_arr)
-    row_leaf = jnp.zeros((n_arr,), jnp.int32).at[order].set(
-        leaf_of_pos, unique_indices=True)[:n]
-    arrays = t._replace(row_leaf=row_leaf)
-    if lazy_on:
-        # paid-bit state back in ORIGINAL row order for the next tree
-        bits_out = jnp.zeros((n, bitbytes), jnp.uint8).at[order].set(
-            state.rows[:, bitoff:bitoff + bitbytes], unique_indices=True)
-        return arrays, bits_out
-    return arrays
+    with jax.named_scope("tree.finish"):
+        # reconstruct per-row leaf assignment from the windows + permutation
+        # (n_arr covers the fused path's spare CHUNK; those rows sit past every
+        # window, pick up a garbage leaf id, and are sliced away)
+        t = state.tree
+        n_arr = state.rows.shape[0]
+        valid = (jnp.arange(L) < t.num_leaves) & (state.wcount > 0)
+        mark_pos = jnp.where(valid, state.begin, n_arr)
+        marks = jnp.zeros((n_arr,), jnp.int32).at[mark_pos].set(
+            jnp.arange(L, dtype=jnp.int32) + 1, mode="drop")
+        if carried:
+            # The score column is updated in place by forward-filling each
+            # window's (shrinkage-scaled) leaf value — no per-row gather/scatter.
+            # row_leaf is returned EMPTY: the permuted-order assignment would
+            # corrupt original-order consumers (rollback, stall trim), which
+            # route the tree over the bins instead (gbdt._gather_tree_output).
+            lv = t.leaf_value * score_rate
+            vmarks = jnp.zeros((n_arr,), f32).at[mark_pos].set(lv, mode="drop")
+            _, leaf_val_pos = _ffill_pair(marks, vmarks)
+            score_old = jax.lax.bitcast_convert_type(
+                state.rows[:, soff:soff + 4], jnp.int32).reshape(n_arr)
+            score_new = (jax.lax.bitcast_convert_type(score_old, f32)
+                         + leaf_val_pos)
+            rows_out = state.rows.at[:, soff:soff + 4].set(
+                jax.lax.bitcast_convert_type(score_new, jnp.uint8))
+            return t._replace(row_leaf=jnp.zeros((0,), jnp.int32)), rows_out
+        leaf_of_pos = _ffill_nonzero(marks) - 1
+        order = jax.lax.bitcast_convert_type(
+            state.rows[:, voff + 8:voff + 12], jnp.int32).reshape(n_arr)
+        row_leaf = jnp.zeros((n_arr,), jnp.int32).at[order].set(
+            leaf_of_pos, unique_indices=True)[:n]
+        arrays = t._replace(row_leaf=row_leaf)
+        if lazy_on:
+            # paid-bit state back in ORIGINAL row order for the next tree
+            bits_out = jnp.zeros((n, bitbytes), jnp.uint8).at[order].set(
+                state.rows[:, bitoff:bitoff + bitbytes], unique_indices=True)
+            return arrays, bits_out
+        return arrays
 
 
 @functools.partial(jax.jit, static_argnames=("num_leaves",))
@@ -1749,7 +1765,11 @@ class SerialTreeLearner:
         return binned
 
     def _upload_bins(self, binned: np.ndarray) -> None:
-        self.bins = jnp.asarray(self._pad_host_rows(binned))
+        # host pad + transfer of the binned table, waited for so that the
+        # span holds the transfer: the ingest share of booster set-up
+        with _span("ingest.upload"):
+            self.bins = jnp.asarray(self._pad_host_rows(binned))
+            self.bins.block_until_ready()
 
     def pad_rows(self, arr: jax.Array, value=0.0) -> jax.Array:
         """Pad a per-row array up to num_data + padded_rows (idempotent)."""
@@ -1907,7 +1927,7 @@ class SerialTreeLearner:
                               key="n%d_b%d" % (self.num_data, self.num_bins),
                               mode=grow_mode)
         with span_ctx, FunctionTimer("Partition::BuildTree(dispatch)"), \
-                _annotate("partition_build_tree"):
+                _span("partition_build_tree"):
             out = build_tree_partitioned(
                 self.bins, grad, hess,
                 jnp.asarray(num_data_in_bag, dtype=jnp.int32),

@@ -32,6 +32,7 @@ import numpy as np
 from . import sample as _sample
 from .binning import BinMapper, BinType, MissingType
 from .metadata import Metadata
+from ..obs.spans import span as _span
 from ..utils.log import Log
 
 
@@ -72,7 +73,10 @@ class BinnedDataset:
                     enable_bundle: bool = True,
                     bin_mappers: Optional[List[BinMapper]] = None
                     ) -> "BinnedDataset":
-        data = np.ascontiguousarray(data, dtype=np.float64)
+        # ingest's three costs as spans (obs/spans.py): the f64 copy of the
+        # whole table, bin finding on the sample, binning column by column
+        with _span("ingest.to_f64"):
+            data = np.ascontiguousarray(data, dtype=np.float64)
         if data.ndim != 2:
             Log.fatal("Input data must be 2-dimensional")
         self = cls()
@@ -117,17 +121,18 @@ class BinnedDataset:
             # the round-21 shared schema path: the SAME deterministic sample
             # + freeze the streaming loader uses, so an in-memory load and a
             # chunked/sharded load of identical rows agree byte-for-byte
-            idx, keys = _sample.bottom_k_indices(
-                self.num_data, bin_construct_sample_cnt, data_random_seed)
-            self._adopt_schema(cls.schema_from_sample(
-                data[idx], keys, max_bin=max_bin,
-                min_data_in_bin=min_data_in_bin,
-                min_data_in_leaf=min_data_in_leaf,
-                categorical_feature=categorical_feature,
-                use_missing=use_missing, zero_as_missing=zero_as_missing,
-                feature_names=self.feature_names, forced_bins=forced_bins,
-                max_bin_by_feature=max_bin_by_feature,
-                enable_bundle=enable_bundle))
+            with _span("ingest.find_bins"):
+                idx, keys = _sample.bottom_k_indices(
+                    self.num_data, bin_construct_sample_cnt, data_random_seed)
+                self._adopt_schema(cls.schema_from_sample(
+                    data[idx], keys, max_bin=max_bin,
+                    min_data_in_bin=min_data_in_bin,
+                    min_data_in_leaf=min_data_in_leaf,
+                    categorical_feature=categorical_feature,
+                    use_missing=use_missing, zero_as_missing=zero_as_missing,
+                    feature_names=self.feature_names, forced_bins=forced_bins,
+                    max_bin_by_feature=max_bin_by_feature,
+                    enable_bundle=enable_bundle))
             schema_adopted = True
 
         if not schema_adopted:
@@ -139,19 +144,22 @@ class BinnedDataset:
                                         for i in self.used_feature_idx]
         col_dtype = (np.uint8 if max(self.num_bin_per_feature, default=2) <= 256
                      else np.uint16)
-        cols = [self.bin_mappers[i].values_to_bins(data[:, i]).astype(col_dtype)
-                for i in self.used_feature_idx]
-        if reference is not None:
-            self.feature_groups = [list(g) for g in reference.feature_groups]
-            self.group_idx = reference.group_idx
-            self.bin_offset = reference.bin_offset
-            self.num_bin_per_group = list(reference.num_bin_per_group)
-        elif not schema_adopted:
-            self.feature_groups = (self._find_groups_from_cols(cols)
-                                   if enable_bundle
-                                   else [[j] for j in range(len(cols))])
-            self._assign_group_layout()
-        self.binned = self._bundle_columns(cols)
+        with _span("ingest.bin_columns"):
+            cols = [self.bin_mappers[i].values_to_bins(
+                        data[:, i]).astype(col_dtype)
+                    for i in self.used_feature_idx]
+            if reference is not None:
+                self.feature_groups = [list(g)
+                                       for g in reference.feature_groups]
+                self.group_idx = reference.group_idx
+                self.bin_offset = reference.bin_offset
+                self.num_bin_per_group = list(reference.num_bin_per_group)
+            elif not schema_adopted:
+                self.feature_groups = (self._find_groups_from_cols(cols)
+                                       if enable_bundle
+                                       else [[j] for j in range(len(cols))])
+                self._assign_group_layout()
+            self.binned = self._bundle_columns(cols)
         if keep_raw:
             self.raw_data = data
         return self

@@ -1,6 +1,6 @@
 """Unified telemetry: metrics registry, JSONL events, recompile accounting,
-trace annotations, MFU estimation, end-of-run reports — and, since round
-14, the LIVE observability plane: an HTTP scrape surface
+spans on the profiler's clock, MFU estimation, end-of-run reports — and,
+since round 14, the LIVE observability plane: an HTTP scrape surface
 (:mod:`.exporter`: ``/metrics`` ``/healthz`` ``/summary.json``),
 request-scoped spans (:mod:`.spans`) and rank-aware pod shard sinks.
 
@@ -35,12 +35,11 @@ from . import recompile  # noqa: F401  (re-export)
 from .registry import (EVENT_SCHEMA_VERSION, Counter, Gauge, Histogram,
                        MetricsRegistry, Telemetry, iter_events, read_events,
                        shard_path, validate_event)
-from .trace import annotate
 
 __all__ = ["Telemetry", "MetricsRegistry", "Counter", "Gauge", "Histogram",
            "EVENT_SCHEMA_VERSION", "read_events", "iter_events",
            "validate_event", "shard_path", "configure", "active", "disable",
-           "annotate", "recompile", "spans", "quality",
+           "recompile", "spans", "quality",
            "devmem", "profiling", "alerts"]
 # NOTE: the compile-accounting submodule is reachable as obs.compile but
 # deliberately NOT in __all__ — a star-import must not shadow the

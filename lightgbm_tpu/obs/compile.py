@@ -27,6 +27,13 @@ median is the compile estimate.  Keys that never reach steady state (the
 run died, or the shape was dispatched once) resolve at snapshot time with
 the full dispatch wall as an upper bound and ``resolved: false``.
 
+What jax itself measured is kept beside that estimate, always on (the
+listener at the end of this file): every jit's trace, lowering, backend
+compile and persistent-cache retrieval become in-memory spans ``jax.trace``,
+``jax.lower``, ``jax.backend_compile`` and ``jax.cache_load``
+(``obs.spans.totals()``), so a warm start's cache loads and a cold start's
+compiles are read, not guessed from dispatch walls.
+
 Run-owned like the rest of the plane: the accountant lives on the active
 :class:`~.registry.Telemetry` (``tele.compile_acct``), every site gates on
 ``obs.active() is None`` first, and a telemetry-off run constructs nothing
@@ -39,6 +46,10 @@ from __future__ import annotations
 import threading
 from collections import deque
 from typing import Any, Dict, Optional
+
+import jax.monitoring
+
+from . import spans as _spans
 
 # a miss whose excess wall over the steady median is at or under this is a
 # persistent-cache warm load (executable deserialization), not a compile
@@ -204,3 +215,36 @@ def note_dispatch(tele, fn: str, bucket, dispatch_s: float,
     acct = accountant(tele, create=True)
     if acct is not None:
         acct.note(tele, fn, bucket, dispatch_s, misses)
+
+
+# ---- jax's own compile-phase durations as spans ----------------------------
+# jax times a persistent-cache retrieval INSIDE its backend-compile event, so
+# the retrieval is taken out again: ``jax.backend_compile`` is what the
+# compiler took, ``jax.cache_load`` what reading a compiled program back took.
+# The count of ``jax.cache_load`` is the cache's hits, the count of
+# ``jax.backend_compile`` every compile request; nested jits each report
+# their own ``jax.trace``, so its total counts an inner trace twice.
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_SPAN_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    _BACKEND_COMPILE: "jax.backend_compile",
+    _CACHE_LOAD: "jax.cache_load",
+}
+_loading = threading.local()
+
+
+def _on_jax_duration(event: str, duration: float, **_kw) -> None:
+    name = _SPAN_OF.get(event)
+    if name is None:
+        return
+    if event == _CACHE_LOAD:
+        _loading.s = getattr(_loading, "s", 0.0) + duration
+    elif event == _BACKEND_COMPILE:
+        duration = max(duration - getattr(_loading, "s", 0.0), 0.0)
+        _loading.s = 0.0
+    _spans.note(name, duration)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)

@@ -1,6 +1,6 @@
 """Triggered profiler capture: on-demand and flight-recorder traces.
 
-``obs/trace.py``'s annotations only light up when someone separately
+``obs/spans.py``'s profiler annotations only light up when someone separately
 starts ``jax.profiler`` — which nobody does at 3am when the p99 is
 burning.  This module makes capture a RUN capability:
 

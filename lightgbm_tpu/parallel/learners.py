@@ -108,7 +108,7 @@ def sharded_predict(ens, rows: np.ndarray, mesh: Optional[Mesh] = None, *,
 
     from ..core.predict_fused import PREDICT_BUCKETS, shape_bucket
     from ..obs import active as _telemetry_active
-    from ..obs import annotate as _annotate
+    from ..obs.spans import span as _span
     from ..obs import recompile as _recompile
     from ..resilience import PROGRAM_ERRORS as _PROGRAM_ERRORS
     from ..resilience import note_fallback as _note_fallback
@@ -135,7 +135,7 @@ def sharded_predict(ens, rows: np.ndarray, mesh: Optional[Mesh] = None, *,
         t0 = _time.perf_counter()
         fell_back = False
         try:
-            with _annotate("sharded_predict"), \
+            with _span("sharded_predict"), \
                     _watch("sharded_predict", compile_key=int(bucket),
                            rows=int(nc), bucket=int(bucket), shards=int(d)):
                 out = fn(ens, jnp.asarray(chunk))
@@ -229,7 +229,7 @@ def sharded_predict_contrib(blocks, rows: np.ndarray, ncol: int,
 
     from ..core.predict_fused import PREDICT_BUCKETS, shape_bucket
     from ..obs import active as _telemetry_active
-    from ..obs import annotate as _annotate
+    from ..obs.spans import span as _span
     from ..obs import recompile as _recompile
     from ..resilience import PROGRAM_ERRORS as _PROGRAM_ERRORS
     from ..resilience import note_fallback as _note_fallback
@@ -256,7 +256,7 @@ def sharded_predict_contrib(blocks, rows: np.ndarray, ncol: int,
         t0 = _time.perf_counter()
         fell_back = False
         try:
-            with _annotate("sharded_contrib"), \
+            with _span("sharded_contrib"), \
                     _watch("sharded_contrib", compile_key=int(bucket),
                            rows=int(nc), bucket=int(bucket),
                            shards=int(d)), \

@@ -64,9 +64,16 @@ def _on_chip(one_chip, shapes):
         shapes)
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, kernel):
+    """Compile, and see that the Pallas kernel is in the program under the
+    name a profiler trace will print: ``%<kernel>.<n>``."""
     text = jax.jit(fn).lower(*_on_chip(one_chip, shapes)).compile().as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    calls = [ln.split(" = ")[0].split()[-1] for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(c.rsplit(".", 1)[0] == "%" + kernel for c in calls), \
+        "%s names its kernel %r" % (kernel, calls)
+    return text
 
 
 @pytest.mark.parametrize("f,num_bins,quantized", [
@@ -83,7 +90,10 @@ def test_histogram_rows_compiles(one_chip, f, num_bins, quantized):
     _compile(lambda r, s, c: H.histogram_pallas_rows(
         r, num_bins, s, c, num_features=f, voff=voff, quantized=quantized),
         one_chip, _sds((n, width), jnp.uint8), _sds((), jnp.int32),
-        _sds((), jnp.int32))
+        _sds((), jnp.int32),
+        kernel="histogram_pallas_rows_" + (
+            "factored" if H._use_factored(f, num_bins, quantized)
+            else "classic"))
 
 
 @pytest.mark.parametrize("num_bins", [256, 64])
@@ -94,7 +104,8 @@ def test_partition_hist_compiles(one_chip, small, chunk, num_bins):
         r, s, num_features=F, num_bins=num_bins, voff=VOFF, chunk=chunk,
         small=small),
         one_chip, _sds((N_PAD, W), jnp.uint8),
-        _sds((12 + num_bins // 32,), jnp.int32))
+        _sds((12 + num_bins // 32,), jnp.int32),
+        kernel="partition_hist_pallas_" + P.bucket_name(small, chunk))
 
 
 @pytest.mark.parametrize("small,chunk", [
@@ -103,7 +114,8 @@ def test_partition_hist_quantized_compiles(one_chip, small, chunk):
     _compile(lambda r, s: P.partition_hist_pallas(
         r, s, num_features=F, num_bins=256, voff=VOFF, chunk=chunk,
         small=small, quantized=True),
-        one_chip, _sds((N_PAD, W), jnp.uint8), _sds((12 + 8,), jnp.int32))
+        one_chip, _sds((N_PAD, W), jnp.uint8), _sds((12 + 8,), jnp.int32),
+        kernel="partition_hist_pallas_" + P.bucket_name(small, chunk))
 
 
 @pytest.mark.parametrize("small,chunk", [
@@ -112,7 +124,114 @@ def test_partition_hist_level_compiles(one_chip, small, chunk):
     _compile(lambda r, s: P.partition_hist_level_pallas(
         r, s, num_features=F, num_bins=256, voff=VOFF, chunk=chunk,
         small=small),
-        one_chip, _sds((N_PAD, W), jnp.uint8), _sds((8, 12 + 8), jnp.int32))
+        one_chip, _sds((N_PAD, W), jnp.uint8), _sds((8, 12 + 8), jnp.int32),
+        kernel="partition_hist_level_pallas_" + P.bucket_name(small, chunk))
+
+
+def test_bucket_names_are_the_three_the_metrics_read():
+    assert [P.bucket_name(s, c) for s, c, _ in P.fused_bucket_plan(1 << 20)] \
+        == ["small", "c1024", "c4096"]
+
+
+# ---- the fused chunk program's phases, as the chip's compiler keeps them ----
+
+SCOPES = ["gbdt.gradients", "tree.store", "tree.root", "tree.pick_leaf",
+          "tree.split", "tree.find_split", "tree.state_update", "tree.finish"]
+# opcodes that take no time on the device's op line, or have a metric of
+# their own (the kernels, the loops)
+_NO_GLUE = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast",
+            "while", "conditional", "call", "custom-call", "copy-start",
+            "copy-done", "slice-start", "slice-done", "iota"}
+
+
+@pytest.fixture(scope="module")
+def chunk_text(one_chip, monkeypatch_module):
+    """Compiled text of a fused chunk of 2 trees x 255 leaves on 2^16 rows of
+    Higgs width, as ``GBDT.chunk_program_text`` gives it on the chip.  The
+    booster is built on the CPU; its fused step is lowered for the described
+    chip from shapes, the way ``_hoisted_jit`` lowers it from arrays."""
+    import numpy as np
+    from lightgbm_tpu.boosting import gbdt as G
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    from lightgbm_tpu.objective import create_objective
+    rng = np.random.default_rng(0)
+    X = rng.standard_normal((1 << 16, F)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] + rng.standard_normal(len(X)) > 0)
+    ds = BinnedDataset.from_matrix(X, label=y.astype(np.float32), max_bin=255)
+    cfg = Config(verbosity=-1, objective="binary", num_leaves=255,
+                 max_bin=255, min_data_in_leaf=0,
+                 min_sum_hessian_in_leaf=100.0)
+    g = G.GBDT(cfg, ds, create_objective("binary", cfg))
+    g.learner.use_pallas = True          # the chip's path, not the CPU's
+    taken = {}
+    monkeypatch_module.setattr(
+        G, "_hoisted_jit", lambda fused, *a: taken.update(fused=fused, args=a))
+    g._make_fused_train(2)
+
+    def spec(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(jnp.shape(a), jnp.result_type(a)), tree)
+    closed = jax.make_jaxpr(taken["fused"])(*spec(taken["args"]))
+    flat = jax.tree_util.tree_leaves(taken["args"])
+    return jax.jit(
+        lambda consts, *args: jax.core.eval_jaxpr(closed.jaxpr, consts, *args)
+    ).lower(*_on_chip(one_chip, (spec(closed.consts),) + tuple(spec(flat)))
+            ).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def monkeypatch_module():
+    with pytest.MonkeyPatch.context() as m:
+        yield m
+
+
+def _glue_instructions(text):
+    """{instruction: opcode} of the instructions that can show as glue on the
+    device's op line: those of the entry computation and of the bodies and
+    conditions of its loops, less what takes no time there."""
+    import re
+    loops = set(re.findall(r"(?:body|condition)=(%[\w.\-]+)", text))
+    found, holds = {}, False
+    for line in text.splitlines():
+        header = re.match(r"^(ENTRY\s+)?(%[^\s(]+)\s*\(.*\{\s*$", line)
+        if header:
+            holds = bool(header.group(1)) or header.group(2) in loops
+            continue
+        named = re.match(r"^\s*(?:ROOT\s+)?(%[^\s=]+)\s*=\s(.*)$", line)
+        if named and holds:
+            opcode = re.search(r"\s([a-z][a-z0-9\-]*)\(", " " + named.group(2))
+            if opcode and opcode.group(1) not in _NO_GLUE:
+                found[named.group(1)] = opcode.group(1)
+    return found
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_chunk_program_keeps_the_scope(chunk_text, scope):
+    from lightgbm_tpu.obs.scopes import op_scopes
+    glue = _glue_instructions(chunk_text)
+    scope_of = op_scopes(chunk_text, SCOPES)
+    mine = [op for op in glue if scope_of[op] == scope]
+    assert mine, "no instruction of the compiled chunk is under %s" % scope
+
+
+def test_chunk_program_scopes_cover_the_glue(chunk_text):
+    from lightgbm_tpu.obs.scopes import UNSCOPED, op_scopes
+    glue = _glue_instructions(chunk_text)
+    scope_of = op_scopes(chunk_text, SCOPES)
+    loose = [op for op in glue if scope_of[op] == UNSCOPED]
+    assert len(glue) > 300
+    assert len(loose) < 0.10 * len(glue), \
+        "%d of %d glue instructions unscoped: %r" % (
+            len(loose), len(glue), sorted(loose)[:40])
+    # the kernels are under the scope that launches them
+    kernels = {op: s for op, s in scope_of.items()
+               if op.startswith(("%partition_hist_pallas_",
+                                 "%histogram_pallas_rows_"))}
+    assert set(kernels.values()) <= {"tree.split", "tree.root"}
+    assert {k.rsplit(".", 1)[0] for k in kernels} >= {
+        "%partition_hist_pallas_small", "%partition_hist_pallas_c1024",
+        "%partition_hist_pallas_c4096", "%histogram_pallas_rows_factored"}
 
 
 def test_predict_blocked_compiles(one_chip):

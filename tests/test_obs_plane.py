@@ -327,13 +327,21 @@ def test_span_events_validate_and_nest(tmp_path):
     assert outer["phase"] == "x"
 
 
-def test_span_off_is_shared_nullcontext():
+def test_span_off_keeps_the_memory_record_only(monkeypatch):
+    """The telemetry-off pin, restated: with no run active a span draws no
+    id, touches no Telemetry and writes nothing; the bounded in-memory
+    record (always on, like obs.recompile) is all it does."""
     assert obs.active() is None
-    s1 = spans.span("a")
-    s2 = spans.span("b", k=1)
-    assert s1 is s2  # the shared nullcontext: zero allocations when off
-    with s1:
-        pass
+    drawn = []
+    monkeypatch.setattr(spans, "new_id", lambda: drawn.append(1) or "x")
+    spans.reset()
+    with spans.span("a") as outer:
+        with spans.span("b", k=1):
+            pass
+    assert not drawn and outer.tele is None and outer.span_id is None
+    a, b = spans.records("a")[0], spans.records("b")[0]
+    assert b["parent"] == a["id"] and a["parent"] == 0
+    assert a["start"] <= b["start"] <= b["end"] <= a["end"]
 
 
 def test_serving_request_span_lifeline(tmp_path):

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import pytest
 
 from lightgbm_tpu.core.partition import (CHUNK, SMALL_CHUNK, _ALIGN,
+                                         _MID_MAX, bucket_name, bucket_of,
                                          fold_hist, fused_bucket_plan,
                                          level_plan,
                                          partition_hist_level_pallas,
@@ -193,6 +194,30 @@ def _toy_booster(n, monkeypatch_learner=None, iters=2, **params):
     if monkeypatch_learner is not None:
         monkeypatch_learner(booster.learner)
     return booster
+
+
+@pytest.mark.parametrize("rows,bucket", [
+    (0, "small"), (SMALL_CHUNK - _ALIGN, "small"),
+    (SMALL_CHUNK - _ALIGN + 1, "c1024"), (_MID_MAX, "c1024"),
+    (_MID_MAX + 1, "c4096"), (1 << 20, "c4096")])
+def test_bucket_of_agrees_with_the_builders_searchsorted(rows, bucket):
+    """The host's ``bucket_of`` names the variant the builder's dispatch
+    takes: same bounds, same side."""
+    plan = fused_bucket_plan(1 << 20)
+    names = [bucket_name(s, c) for s, c, _ in plan]
+    assert names == ["small", "c1024", "c4096"]
+    # as build_tree_partitioned's _fused_split selects its branch
+    bounds = jnp.asarray([b for (_, _, b) in plan[:-1]], jnp.int32)
+    builder = int(jnp.searchsorted(bounds, jnp.int32(rows)))
+    assert int(bucket_of(rows, plan)) == builder
+    assert names[builder] == bucket
+    assert bucket_of([rows, rows], plan).tolist() == [builder, builder]
+
+
+def test_bucket_of_a_plan_of_one_bucket():
+    plan = fused_bucket_plan(512)
+    assert [bucket_name(s, c) for s, c, _ in plan] == ["c1024"]
+    assert bucket_of([0, 17, 512], plan).tolist() == [0, 0, 0]
 
 
 def _pin_interpret(learner):

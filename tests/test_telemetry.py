@@ -337,9 +337,10 @@ def test_resumed_run_iterations_not_inflated(tmp_path):
 
 def test_telemetry_off_hot_loop_makes_zero_calls(monkeypatch, tmp_path):
     """With telemetry disabled (the default), a fused-scan training run and
-    a predict loop must record NOTHING: no events, no metric touches, no
-    span allocations, no exporter listener thread (round 14 extends the
-    spy over obs/spans.py and obs/exporter.py).
+    a predict loop must export NOTHING: no events, no metric touches, no
+    span ids drawn or span events written, no exporter listener thread
+    (round 14 extends the spy over obs/spans.py and obs/exporter.py).  A
+    span's in-memory record (always on, like obs.recompile) is all it does.
     The resilience paths are held to the same contract: a degraded-predict
     fallback and a retried I/O fault are counted in their always-on module
     counters but make zero telemetry calls when no run is active."""
@@ -355,16 +356,17 @@ def test_telemetry_off_hot_loop_makes_zero_calls(monkeypatch, tmp_path):
 
     for name in ("event", "counter", "gauge", "histogram", "time_block"):
         monkeypatch.setattr(Telemetry, name, spy(name))
-    # span + exporter paths: zero Span constructions, zero record_span
-    # emissions, zero exporter starts with telemetry off
+    # span + exporter paths: zero ids drawn, zero record_span emissions,
+    # zero exporter starts with telemetry off
     from lightgbm_tpu.obs import exporter as obs_exporter
     from lightgbm_tpu.obs import spans as obs_spans
     monkeypatch.setattr(
         obs_spans, "record_span",
         lambda *a, **k: calls.append(("record_span", a)))
     monkeypatch.setattr(
-        obs_spans.Span, "__init__",
-        lambda self, *a, **k: calls.append(("Span", a)))
+        obs_spans, "new_id",
+        lambda *a, **k: calls.append(("new_id", a)))
+    obs_spans.reset()
     monkeypatch.setattr(
         obs_exporter, "start_exporter",
         lambda *a, **k: calls.append(("start_exporter", a)))
@@ -426,8 +428,12 @@ def test_telemetry_off_hot_loop_makes_zero_calls(monkeypatch, tmp_path):
     assert not any(t.name == "lgbm-tpu-metrics"
                    for t in threading.enumerate()), \
         "exporter listener running with telemetry off"
-    with obs_spans.span("noop"):  # the off-path span is the nullcontext
+    with obs_spans.span("noop"):  # the off-path span: memory only
         pass
+    kept = obs_spans.totals()
+    assert kept["noop"]["count"] == 1 and "fused_train_chunk" in kept
+    off = obs_spans.span("off")
+    assert off.tele is None and off.span_id is None
     # degraded predict: the fallback counter must not touch Telemetry
     import lightgbm_tpu.core.predict_fused as pf
     real_pb = pf.predict_blocked

@@ -104,3 +104,35 @@ def test_bench_split_cost_importable():
     icept, slope = bench_split_cost.fit_line([1.0, 2.0, 3.0],
                                              [3.0, 5.0, 7.0])
     assert icept == pytest.approx(1.0) and slope == pytest.approx(2.0)
+
+
+def test_aggregate_xplane_reads_a_recorded_trace(tmp_path):
+    """``tools/profile_tree.py::aggregate_xplane`` (the microbenches' and the
+    knockout bench's reader) on a trace recorded on the chip, through
+    ``jax.profiler.ProfileData``: no other package is needed."""
+    import gzip
+    where = tmp_path / "plugins" / "profile" / "run"
+    where.mkdir(parents=True)
+    fixture = os.path.join(REPO, "benchmarks", "tests", "fixtures",
+                           "tiny.xplane.pb.gz")
+    with gzip.open(fixture, "rb") as src:
+        (where / "host.xplane.pb").write_bytes(src.read())
+    sys.path.insert(0, REPO)
+    try:
+        from tools.profile_tree import aggregate_xplane
+    finally:
+        sys.path.pop(0)
+    rows = aggregate_xplane(str(tmp_path), top=40)
+    by_name = {name: (ms, count) for name, ms, count in rows}
+    # one traced chunk of 2 trees x 15 leaves: 28 split launches
+    assert by_name["%partition_hist_pallas"][1] == 28
+    assert by_name["%partition_hist_pallas"][0] == pytest.approx(1.964, abs=1e-3)
+    assert rows == sorted(rows, key=lambda r: -r[1])
+
+
+def test_no_tool_needs_tensorflow():
+    hits = [p for p in glob.glob(os.path.join(REPO, "tools", "*.py"))
+            + glob.glob(os.path.join(REPO, "lightgbm_tpu", "**", "*.py"),
+                        recursive=True)
+            if "tensorflow" in open(p).read()]
+    assert hits == []

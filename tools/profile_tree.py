@@ -3,10 +3,8 @@
 The counterpart of the reference's ``Timer``/``FunctionTimer`` aggregation
 (include/LightGBM/utils/common.h:1032-1093) for DEVICE time: host-side timers
 only see dispatch on an async runtime, so this captures a ``jax.profiler``
-trace and aggregates the XLA
-op durations from the xplane protobuf directly (the
-tensorboard_plugin_profile converter is broken against the installed
-TF/protobuf pair).
+trace and aggregates the XLA op durations from the xplane file with
+``jax.profiler.ProfileData``.
 
 Usage:
     python tools/profile_tree.py [rows] [leaves] [max_bin]   # tree build
@@ -32,32 +30,35 @@ import numpy as np
 
 
 def aggregate_xplane(trace_dir: str, top: int = 25):
-    """[(name, total_ms, count)] by device time from the newest xplane.pb."""
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
+    """[(name, total_ms, count)] by device time from the newest xplane.pb,
+    read with ``jax.profiler.ProfileData`` (nothing but jax).  An op event's
+    name is its whole HLO line, ``%<op>.<n> = ...``; events are grouped by
+    ``%<op>``, so a kernel is found by the ``name`` its ``pallas_call``
+    gives.  Durations are summed as they are: a ``%while`` holds its body's
+    events (``benchmarks/trace_reduce.py`` is the reducer that takes own
+    times and the busy union)."""
+    from jax.profiler import ProfileData
     paths = sorted(glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True),
                    key=os.path.getmtime)
     if not paths:
         raise SystemExit("no xplane.pb under %s — did the profiler run?"
                          % trace_dir)
-    xs = xplane_pb2.XSpace()
-    with open(paths[-1], "rb") as fh:
-        xs.ParseFromString(fh.read())
-    plane = next((p for p in xs.planes if "TPU" in p.name), None)
+    planes = list(ProfileData.from_file(paths[-1]).planes)
+    plane = next((p for p in planes if "TPU" in p.name), None)
     if plane is None:
         raise SystemExit("no TPU device plane in the trace (planes: %s) — "
                          "this tool needs a TPU backend"
-                         % [p.name for p in xs.planes])
-    ev_meta = plane.event_metadata
+                         % [p.name for p in planes])
     agg = collections.Counter()
     cnt = collections.Counter()
     for line in plane.lines:
         if line.name != "XLA Ops":
             continue
         for ev in line.events:
-            key = re.sub(r"[.\d]+$", "", ev_meta[ev.metadata_id].name)
-            agg[key] += ev.duration_ps
+            key = re.sub(r"[.\d]+$", "", ev.name.split(" = ", 1)[0])
+            agg[key] += ev.duration_ns
             cnt[key] += 1
-    return [(name, t / 1e9, cnt[name]) for name, t in agg.most_common(top)]
+    return [(name, t / 1e6, cnt[name]) for name, t in agg.most_common(top)]
 
 
 def main() -> None:
