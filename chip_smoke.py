@@ -212,6 +212,8 @@ def phase_kernels(ctx):
                     % (num_bins, small, chunk, wc, hist_left, int(w_nl),
                        dev))
 
+    _check_row_state_pass(n_pad)
+
     # the two variants that are off by default (hist_precision=quantized,
     # tree_grow_mode=level) compile too, so they owe the chip the same check
     num_bins = 256
@@ -257,6 +259,62 @@ def phase_kernels(ctx):
         say("  bins=256 small=%s chunk=%d: quantized split exact; level "
             "launch of %d windows equals sequential splits"
             % (small, chunk, len(wins)))
+
+
+def _check_row_state_pass(n_pad):
+    """The carried store's hand-over pass (core/row_state.py) against its
+    plain form: every byte of the store, for both carried objectives, the
+    bagging hash inside Mosaic included."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.boosting.gbdt import _carried_fns
+    from lightgbm_tpu.core import partition as P
+    from lightgbm_tpu.core import row_state as RS
+    from lightgbm_tpu.objective import create_objective
+    n = n_pad - P.CHUNK
+    rng = np.random.RandomState(9)
+    host = _row_store(n_pad, 256, seed=9)
+    cols = ((VOFF + 8, np.concatenate([rng.permutation(n),
+                                       np.arange(n, n_pad)]).astype(np.int32)),
+            (VOFF + 12, (rng.choice([-1.0, 1.0], n_pad)
+                         * rng.choice([1.0, 3.0], n_pad)).astype(np.float32)),
+            (VOFF + 16, rng.normal(size=n_pad).astype(np.float32)))
+    for off, col in cols:
+        host[:, off:off + 4] = col.view(np.uint8).reshape(n_pad, 4)
+    rows = jnp.asarray(host)
+    begin = np.concatenate([[0], np.sort(rng.choice(
+        np.arange(1, n), LEAVES - 1, replace=False))]).astype(np.int32)
+    wcount = np.diff(np.concatenate([begin, [n]])).astype(np.int32)
+    slots = rng.permutation(LEAVES)          # leaves are not in window order
+    begins, values = RS.leaf_windows(
+        jnp.asarray(begin[slots]), jnp.asarray(wcount[slots]),
+        jnp.asarray(rng.normal(scale=0.1, size=LEAVES).astype(np.float32)),
+        jnp.int32(LEAVES), n)
+    gh = np.zeros(128, bool)
+    gh[VOFF:VOFF + 8] = True
+    for objective, bag in (("binary", (0.8, 5)), ("regression", None)):
+        _, grad_fn = _carried_fns(
+            create_objective(objective, binary_config(objective=objective)),
+            n - 300, bag, 3)
+        got, got_g, got_h = jax.jit(lambda r: RS.row_state_pass(
+            r, begins, values, grad_fn, jnp.int32(6), voff=VOFF))(rows)
+        want, want_g, want_h = jax.jit(lambda r: RS.advance_row_state_xla(
+            r, begins, values, grad_fn, jnp.int32(6), voff=VOFF, n=n))(rows)
+        got, want = np.asarray(got), np.asarray(want)
+        # score, order, aux, bins: the same bytes; gradients: the same
+        # numbers up to what Mosaic's exp and XLA's may differ in
+        np.testing.assert_array_equal(got[:, ~gh], want[:, ~gh])
+        assert np.any(got[:, VOFF + 16:VOFF + 20] != host[:, VOFF + 16:VOFF + 20])
+        g = [np.ascontiguousarray(m[:, VOFF:VOFF + 8]).view(np.float32)
+             for m in (got, want)]
+        np.testing.assert_allclose(g[0], g[1], rtol=1e-6, atol=1e-9)
+        np.testing.assert_allclose([float(got_g), float(got_h)],
+                                   [float(want_g), float(want_h)], rtol=1e-5)
+        say("  row_state_pass %s%s: store bytes equal the plain form's; "
+            "%d of %d gradient words differ in a bit"
+            % (objective, " bagged" if bag else "",
+               int(np.sum(g[0].view(np.int32) != g[1].view(np.int32))),
+               g[0].size))
 
 
 def phase_train(ctx):

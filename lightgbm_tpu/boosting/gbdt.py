@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import Config
+from ..core.row_state import f32_col, i32_col
 from ..core.tree import Tree
 from ..core.tree_learner import (SerialTreeLearner, TreeArrays,
                                  build_tree_partitioned, route_binned,
@@ -105,20 +106,54 @@ def _bag_uniforms(row_ids, seed: int, it_window):
     x = x ^ (x >> 13)
     x = x * jnp.uint32(3266489917)
     x = x ^ (x >> 16)
-    return x.astype(jnp.float32) * jnp.float32(1.0 / 4294967296.0)
+    # u32 -> f32 through two exact 16-bit halves: their one rounded add is the
+    # conversion's own rounding, bit for bit, and Mosaic (which has no
+    # u32 -> f32 cast) can compile it inside the row store's hand-over pass
+    hi = jax.lax.bitcast_convert_type(x >> 16, jnp.int32)
+    lo = jax.lax.bitcast_convert_type(x & jnp.uint32(0xFFFF), jnp.int32)
+    xf = hi.astype(jnp.float32) * jnp.float32(65536.0) + lo.astype(jnp.float32)
+    return xf * jnp.float32(1.0 / 4294967296.0)
 
 
-def _bag_mask_for(row_ids, seed: int, it, freq: int, frac: float):
-    """(mask f32 0/1, realized count i32) for iteration ``it`` — the ONE
-    implementation both the fused scan and the host per-iteration path use;
-    bit-exact agreement between them is asserted by
+def _bag_mask(row_ids, seed: int, it, freq: int, frac: float):
+    """Bagging mask (f32 0/1) of iteration ``it`` — the ONE implementation
+    the fused scan, the carried store's hand-over pass and the host
+    per-iteration path use; bit-exact agreement between them is asserted by
     tests/test_fused_valid_bagging.py."""
     itw = it - jax.lax.rem(it, jnp.int32(freq))
     u = _bag_uniforms(row_ids, seed, itw)
     # frac may be a per-row array (pos/neg balanced bagging) or a scalar
-    mask = (u < jnp.asarray(frac, jnp.float32)).astype(jnp.float32)
+    return (u < jnp.asarray(frac, jnp.float32)).astype(jnp.float32)
+
+
+def _bag_mask_for(row_ids, seed: int, it, freq: int, frac: float):
+    """(mask f32 0/1, realized count i32) for iteration ``it``."""
+    mask = _bag_mask(row_ids, seed, it, freq, frac)
     cnt = jnp.maximum(jnp.sum(mask, dtype=jnp.float32), 1.0).astype(jnp.int32)
     return mask, cnt
+
+
+def _carried_fns(objective, num_data: int, bag, bag_seed: int):
+    """What carried-row-store training computes per row from what rides the
+    store, both element-wise: ``live(order, it)``, 1.0 for a real row in
+    iteration ``it``'s bag (``bag``: ``_fused_bag()``) and else 0.0, and
+    ``grad_fn(score, aux, order, it) -> (grad, hess)``, which the tree
+    builder's hand-over pass calls tile by tile (core/row_state.py)."""
+    def live(order, it):
+        m = (order < num_data).astype(jnp.float32)
+        if bag is not None:
+            # the store is PERMUTED, so the mask must be keyed by each row's
+            # ORIGINAL id (the order bytes) — exactly what the stateless
+            # hash provides
+            frac, freq = bag
+            m = m * _bag_mask(order, bag_seed, it, freq, frac)
+        return m
+
+    def grad_fn(score, aux, order, it):
+        g, h = objective.pointwise_gradients(score, aux)
+        m = live(order, it)
+        return g * m, h * m
+    return live, grad_fn
 
 
 def _add_valid_outputs(vscores, kk, arr, feat, vbins, num_leaves,
@@ -825,7 +860,7 @@ class GBDT:
         fm = jnp.ones((self.train_data.num_features,), bool)
         nd = jnp.int32(n)
         lay = learner.row_layout()
-        voff, aoff, soff = lay["voff"], lay["aoff"], lay["soff"]
+        voff, soff = lay["voff"], lay["soff"]
         aux = learner.pad_rows(objective.carry_aux().astype(jnp.float32))
         kwargs = dict(num_leaves=learner.num_leaves,
                       max_depth=learner.max_depth, params=learner.params,
@@ -849,82 +884,66 @@ class GBDT:
                       quant_seed=learner.quant_seed,
                       carried=True)
 
-        def f32col(rows, off):
-            w = jax.lax.bitcast_convert_type(
-                rows[:, off:off + 4], jnp.int32).reshape(rows.shape[0])
-            return jax.lax.bitcast_convert_type(w, jnp.float32)
-
         bag = self._fused_bag()
         bag_seed = int(self.config.bagging_seed)
         vbins = [vs["bins"] for vs in self.valid_sets]
         L = learner.num_leaves
 
+        live, grad_fn = _carried_fns(objective, n, bag, bag_seed)
+
         def one_iter_of(bins):
             def one_iter(carry, it):
-                rows, vscores = carry
-                # a named scope like the tree builder's (obs/scopes.py)
-                with jax.named_scope("gbdt.gradients"):
-                    score = f32col(rows, soff)
-                    auxv = f32col(rows, aoff)
-                    order = jax.lax.bitcast_convert_type(
-                        rows[:, voff + 8:voff + 12], jnp.int32
-                    ).reshape(rows.shape[0])
-                    validf = (order < n).astype(jnp.float32)
-                    g, h = objective.pointwise_gradients(score, auxv)
-                    g = g * validf
-                    h = h * validf
-                    if bag is not None:
-                        # the store is PERMUTED, so the mask must be keyed by
-                        # each row's ORIGINAL id (the order bytes) — exactly
-                        # what the stateless hash provides
-                        frac, freq = bag
-                        mask, _ = _bag_mask_for(order, bag_seed, it, freq,
-                                                frac)
-                        mask = mask * validf
-                        nd_it = jnp.maximum(
-                            jnp.sum(mask, dtype=jnp.float32), 1.0
-                        ).astype(jnp.int32)
-                        g = g * mask
-                        h = h * mask
-                    else:
-                        nd_it = nd
-                arr, rows = build_tree_partitioned(
-                    bins, g[:ntot], h[:ntot], nd_it, fm, feat,
-                    rows_carry=rows, score_rate=jnp.float32(rate),
-                    quant_it=it, **kwargs)
+                rows, sums, vscores = carry
+                if bag is not None:
+                    # the bagged row count depends on order, it and the seed
+                    # only: one column of the store, read when bagging is on
+                    nd_it = jnp.maximum(
+                        jnp.sum(live(i32_col(rows, voff + 8), it),
+                                dtype=jnp.float32), 1.0).astype(jnp.int32)
+                else:
+                    nd_it = nd
+                # the store's gradient bytes are current: the last tree's
+                # pass (or the prologue) wrote them; this tree's pass writes
+                # its score and the gradients of iteration it + 1
+                arr, rows, sums = build_tree_partitioned(
+                    bins, None, None, nd_it, fm, feat,
+                    rows_carry=rows, root_sums=sums,
+                    score_rate=jnp.float32(rate), quant_it=it,
+                    grad_fn=grad_fn, **kwargs)
                 arr = arr._replace(
                     leaf_value=arr.leaf_value * rate,
                     internal_value=arr.internal_value * rate)
                 vscores = _add_valid_outputs(
                     vscores, 0, arr, feat, vbins, L,
                     learner.has_categorical)
-                return (rows, vscores), (arr,)
+                return (rows, sums, vscores), (arr,)
             return one_iter
 
         def fused(score, vscores, it0):
             bins, aux_arg = learner.bins, aux
-            # construct the initial store from the ORIGINAL row order; the
-            # num_leaves=1 build is a no-op tree whose only effect is the
-            # store construction (leaf values stay 0, score unchanged)
+            score0 = score[0, :ntot]
+            # the first tree's gradients, in the ORIGINAL row order; every
+            # later tree's come out of the tree before it (named scopes like
+            # the tree builder's: obs/scopes.py)
+            with jax.named_scope("gbdt.gradients"):
+                g0, h0 = grad_fn(score0, aux_arg,
+                                 jnp.arange(ntot, dtype=jnp.int32), it0)
+                sums0 = (jnp.sum(g0), jnp.sum(h0))
+            # construct the initial store: a num_leaves=1 build whose only
+            # effect is the store construction
             init_kwargs = dict(kwargs)
             init_kwargs["num_leaves"] = 1
-            # the store-construction no-op build never looks at gradients
-            # (all zero); keep it on the exact path
+            # quantization belongs to the trees that read the store
             init_kwargs["hist_precision"] = "exact"
-            zero = jnp.zeros((ntot,), jnp.float32)
             _, rows0 = build_tree_partitioned(
-                bins, zero, zero, nd, fm, feat,
-                extra=(aux_arg, score[0, :ntot]),
-                score_rate=jnp.float32(rate), **init_kwargs)
-            (rows_fin, vs_out), stacked = _scan_grouped(
-                one_iter_of(bins), (rows0, tuple(vscores)),
+                bins, g0, h0, nd, fm, feat, extra=(aux_arg, score0),
+                **init_kwargs)
+            (rows_fin, _, vs_out), stacked = _scan_grouped(
+                one_iter_of(bins), (rows0, sums0, tuple(vscores)),
                 it0 + jnp.arange(k, dtype=jnp.int32), self._trees_per_chunk())
-            sc = f32col(rows_fin, soff)
-            order = jax.lax.bitcast_convert_type(
-                rows_fin[:, voff + 8:voff + 12], jnp.int32
-            ).reshape(rows_fin.shape[0])
-            score_out = jnp.zeros((ntot,), jnp.float32).at[order].set(
-                sc, mode="drop")
+            score_out = jnp.zeros((ntot,), jnp.float32).at[
+                i32_col(rows_fin, voff + 8)].set(
+                    f32_col(rows_fin, soff), mode="drop")
             return score_out[None], vs_out, stacked
 
         return _hoisted_jit(fused, self.train_score,

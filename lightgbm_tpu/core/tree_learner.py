@@ -39,6 +39,7 @@ from .histogram import (histogram_rows, pack_nibbles,
 from .partition import (CHUNK as _PCHUNK, fold_hist, fused_bucket_plan,
                         partition_hist_level_pallas, partition_hist_pallas)
 from .quant import quantize_gradients
+from .row_state import advance_row_state, f32_col, i32_col, leaf_windows
 from .split import (BestSplit, FeatureInfo, SplitParams, best_split_numerical,
                     dequantize_hist, per_feature_best,
                     per_feature_best_combined, reduce_feature_best, sync_best,
@@ -186,22 +187,6 @@ def _ffill_nonzero(x: jax.Array) -> jax.Array:
     return x
 
 
-def _ffill_pair(flag: jax.Array, val: jax.Array):
-    """Forward-fill (flag, val) pairs: positions with flag==0 take the last
-    flagged value.  Lets the carried-mode score update spread each window's
-    leaf value across its rows WITHOUT a per-row gather (log-doubling,
-    ~20 vector passes instead of ~8 ns/row of gather descriptors)."""
-    n = flag.shape[0]
-    shift = 1
-    while shift < n:
-        fsh = jnp.concatenate([jnp.zeros((shift,), flag.dtype), flag[:-shift]])
-        vsh = jnp.concatenate([jnp.zeros((shift,), val.dtype), val[:-shift]])
-        val = jnp.where(flag > 0, val, vsh)
-        flag = jnp.where(flag > 0, flag, fsh)
-        shift *= 2
-    return flag, val
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("num_leaves", "max_depth", "params", "num_bins",
@@ -209,7 +194,7 @@ def _ffill_pair(flag: jax.Array, val: jax.Array):
                      "feat_num_bins", "packed_cols", "axis_name",
                      "comm_mode", "num_shards", "carried", "top_k",
                      "hist_pool_slots", "bucket_plan", "pallas_interpret",
-                     "tree_grow_mode", "hist_precision"))
+                     "tree_grow_mode", "hist_precision", "grad_fn"))
 def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                            num_data: jax.Array, feature_mask: jax.Array,
                            feat: FeatureInfo, *, num_leaves: int,
@@ -232,7 +217,8 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                            tree_grow_mode: str = "leaf",
                            hist_precision: str = "exact",
                            quant_it=None, quant_seed=0,
-                           rows_carry=None, extra=None, score_rate=None):
+                           rows_carry=None, extra=None, score_rate=None,
+                           grad_fn=None, root_sums=None):
     """Leaf-wise growth with per-leaf physical row partitions.
 
     The TPU counterpart of the reference's ``DataPartition``
@@ -281,6 +267,16 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     reference — which refunds cached candidate gains of other leaves when a
     feature becomes used (:63-79 UpdateLeafBestSplits) — cached leaf bests
     here keep their original penalty until the leaf is re-evaluated.
+    ``carried``: the store also holds each row's aux value and running score
+    (``extra`` when it is constructed, from ``grad``/``hess`` in original row
+    order; a ``num_leaves=1`` build with no ``grad_fn`` does only that and
+    returns ``(tree, rows)``).  A build handed ``rows_carry`` takes its
+    gradients from the store and their totals from ``root_sums``, and ends
+    with the hand-over pass (core/row_state.py): scores take the tree's leaf
+    values times ``score_rate`` and ``grad_fn(score, aux, order, quant_it +
+    1)`` (``quant_it``: this tree's boosting iteration) writes the next
+    tree's gradients; it returns ``(tree, rows, root_sums)``
+    for the next build.
     """
     if pallas_interpret and jax.default_backend() == "tpu":
         # with a chip attached the override would quietly swap the compiled
@@ -315,11 +311,10 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     assert not (carried and lazy_on), \
         "carried row-store training and lazy CEGB are mutually exclusive"
     # carried mode appends two f32 columns after the order: the objective's
-    # per-row aux value and the running score — the whole boosting state then
-    # rides the partition permutation and no per-row gather/scatter is needed
-    # between iterations (see ObjectiveFunction.carry_aux)
-    aoff = voff + 12
-    soff = voff + 16
+    # per-row aux value (voff + 12) and the running score (voff + 16) — the
+    # whole boosting state then rides the partition permutation and no per-row
+    # gather/scatter is needed between iterations (see
+    # ObjectiveFunction.carry_aux; core/row_state.py advances it per tree)
     bitoff = voff + (20 if carried else 12)
     bitbytes = -(-f // 8) if lazy_on else 0
     W = -(-(bitoff + bitbytes) // 128) * 128
@@ -350,10 +345,12 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         it_q = (jnp.asarray(quant_it, jnp.int32) if quant_it is not None
                 else jnp.int32(0))
         if rows_carry is not None:
-            # carried mode: grad/hess arrive in the PERMUTED row order; key
-            # the stream by the original ids riding the store's order bytes
-            rid = jax.lax.bitcast_convert_type(
-                rows_carry[:n, voff + 8:voff + 12], jnp.int32)
+            # carried mode: the store holds the real-valued gradients the
+            # last pass wrote, in the PERMUTED row order; key the stream by
+            # the original ids riding the store's order bytes
+            grad = f32_col(rows_carry[:n], voff)
+            hess = f32_col(rows_carry[:n], voff + 4)
+            rid = i32_col(rows_carry[:n], voff + 8)
         else:
             rid = jnp.arange(n, dtype=jnp.int32)
         if axis_name and comm_mode != "feature":
@@ -372,17 +369,21 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # (obs/scopes.py).
     with jax.named_scope("tree.store"):
         if rows_carry is not None:
-            # boosting state already lives (permuted) in the store; refresh only
-            # the gradient/hessian bytes for this iteration
+            # the whole boosting state already lives (permuted) in the store,
+            # this tree's gradients included: the last tree's hand-over pass
+            # (core/row_state.py) wrote them
             n_arr = n + (_PCHUNK if fused else 0)
             assert rows_carry.shape == (n_arr, W), \
                 f"carried row store shape {rows_carry.shape} != {(n_arr, W)}"
-            gb = jax.lax.bitcast_convert_type(grad.astype(f32), jnp.uint8)
-            hb = jax.lax.bitcast_convert_type(hess.astype(f32), jnp.uint8)
-            ghb = jnp.concatenate([gb, hb], axis=1)
-            if n_arr > n:
-                ghb = jnp.pad(ghb, ((0, n_arr - n), (0, 0)))
-            rows0 = rows_carry.at[:, voff:voff + 8].set(ghb)
+            rows0 = rows_carry
+            if quantized:
+                # the integers need a global scale first, so they are stored
+                # here, over the real values they were rounded from
+                ghb = jnp.concatenate(
+                    [jax.lax.bitcast_convert_type(grad.astype(f32), jnp.uint8),
+                     jax.lax.bitcast_convert_type(hess.astype(f32), jnp.uint8)],
+                    axis=1)
+                rows0 = rows0.at[:n, voff:voff + 8].set(ghb)
         else:
             if bpc == 2:
                 bins_u8 = jax.lax.bitcast_convert_type(
@@ -392,16 +393,28 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             parts = [bins_u8]
             if voff > nbytes_bins:
                 parts.append(jnp.zeros((n, voff - nbytes_bins), jnp.uint8))
-            parts.append(jax.lax.bitcast_convert_type(grad.astype(f32), jnp.uint8))
-            parts.append(jax.lax.bitcast_convert_type(hess.astype(f32), jnp.uint8))
-            parts.append(jax.lax.bitcast_convert_type(
-                jnp.arange(n, dtype=jnp.int32), jnp.uint8))
+            state = [grad.astype(f32), hess.astype(f32),
+                     jnp.arange(n, dtype=jnp.int32)]
             if carried:
-                aux0, score0 = extra
-                parts.append(jax.lax.bitcast_convert_type(
-                    aux0.astype(f32), jnp.uint8))
-                parts.append(jax.lax.bitcast_convert_type(
-                    score0.astype(f32), jnp.uint8))
+                # the five columns as ONE [n, 20] part, each byte selected
+                # and shifted out of its column's word: every part of the
+                # concatenation costs the compiler a store-sized temporary,
+                # and this construction is the chunk program's peak memory
+                # (8 stores with seven parts, 4 with three; a bitcast and
+                # reshape of the stacked words is as lean but compiles to a
+                # relayout program that grows by 3 B a row)
+                state += [x.astype(f32) for x in extra]     # aux, score
+                lane = jnp.arange(20, dtype=jnp.int32)[None, :]
+                cols = [jax.lax.bitcast_convert_type(x, jnp.int32)[:, None]
+                        for x in state]
+                word = cols[4]
+                for c in (3, 2, 1, 0):
+                    word = jnp.where(lane // 4 == c, cols[c], word)
+                parts.append(((word >> (8 * (lane % 4))) & 255
+                              ).astype(jnp.uint8))
+            else:
+                parts += [jax.lax.bitcast_convert_type(x, jnp.uint8)
+                          for x in state]
             if lazy_on:
                 # rows that already paid lazy feature costs in EARLIER trees
                 # (feature_used_in_data_ lives for the whole training,
@@ -779,8 +792,11 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # ---- root ----
     with jax.named_scope("tree.root"):
         hist0 = hist_rows(rows0, jnp.int32(0), jnp.int32(n))
-        sum_g = jnp.sum(grad)
-        sum_h = jnp.sum(hess)
+        if grad is None:
+            sum_g, sum_h = root_sums    # the hand-over pass summed them
+        else:
+            sum_g = jnp.sum(grad)
+            sum_h = jnp.sum(hess)
         # reduce_hist also DEQUANTIZES under hist_precision=quantized, so it
         # runs unconditionally (identity for the serial exact path)
         hist0 = reduce_hist(hist0)
@@ -1351,31 +1367,35 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         state = jax.lax.fori_loop(1, L, body, state)
 
     with jax.named_scope("tree.finish"):
+        t = state.tree
+        if carried:
+            # row_leaf is returned EMPTY: the permuted-order assignment would
+            # corrupt original-order consumers (rollback, stall trim), which
+            # route the tree over the bins instead (gbdt._gather_tree_output).
+            t = t._replace(row_leaf=jnp.zeros((0,), jnp.int32))
+            if grad_fn is None:
+                # the chunk's store construction: a one-leaf build whose
+                # leaf value is 0 moves no score
+                assert L == 1 and rows_carry is None
+                return t, state.rows
+            # hand the store to the next tree: each row's score takes its
+            # window's (shrinkage-scaled) leaf value and the next gradients
+            # are written beside it, in place — no per-row gather/scatter
+            begins, values = leaf_windows(state.begin, state.wcount,
+                                          t.leaf_value * score_rate,
+                                          t.num_leaves, n)
+            rows_out, next_g, next_h = advance_row_state(
+                state.rows, begins, values, grad_fn, quant_it + 1, voff=voff,
+                n=n, use_pallas=fused, interpret=pallas_interpret)
+            return t, rows_out, (next_g, next_h)
         # reconstruct per-row leaf assignment from the windows + permutation
         # (n_arr covers the fused path's spare CHUNK; those rows sit past every
         # window, pick up a garbage leaf id, and are sliced away)
-        t = state.tree
         n_arr = state.rows.shape[0]
         valid = (jnp.arange(L) < t.num_leaves) & (state.wcount > 0)
         mark_pos = jnp.where(valid, state.begin, n_arr)
         marks = jnp.zeros((n_arr,), jnp.int32).at[mark_pos].set(
             jnp.arange(L, dtype=jnp.int32) + 1, mode="drop")
-        if carried:
-            # The score column is updated in place by forward-filling each
-            # window's (shrinkage-scaled) leaf value — no per-row gather/scatter.
-            # row_leaf is returned EMPTY: the permuted-order assignment would
-            # corrupt original-order consumers (rollback, stall trim), which
-            # route the tree over the bins instead (gbdt._gather_tree_output).
-            lv = t.leaf_value * score_rate
-            vmarks = jnp.zeros((n_arr,), f32).at[mark_pos].set(lv, mode="drop")
-            _, leaf_val_pos = _ffill_pair(marks, vmarks)
-            score_old = jax.lax.bitcast_convert_type(
-                state.rows[:, soff:soff + 4], jnp.int32).reshape(n_arr)
-            score_new = (jax.lax.bitcast_convert_type(score_old, f32)
-                         + leaf_val_pos)
-            rows_out = state.rows.at[:, soff:soff + 4].set(
-                jax.lax.bitcast_convert_type(score_new, jnp.uint8))
-            return t._replace(row_leaf=jnp.zeros((0,), jnp.int32)), rows_out
         leaf_of_pos = _ffill_nonzero(marks) - 1
         order = jax.lax.bitcast_convert_type(
             state.rows[:, voff + 8:voff + 12], jnp.int32).reshape(n_arr)
@@ -1974,7 +1994,7 @@ class SerialTreeLearner:
         voff = -(-(ncols * bpc) // 4) * 4
         n = self.bins.shape[0]
         fused = self.use_pallas
-        return {"voff": voff, "aoff": voff + 12, "soff": voff + 16,
+        return {"voff": voff, "soff": voff + 16,
                 "n_arr": n + (_PCHUNK if fused else 0)}
 
     def route_bins_matrix(self) -> jax.Array:

@@ -128,6 +128,32 @@ def test_partition_hist_level_compiles(one_chip, small, chunk):
         kernel="partition_hist_level_pallas_" + P.bucket_name(small, chunk))
 
 
+@pytest.mark.parametrize("objective,bag,width,voff", [
+    ("binary", None, W, VOFF),              # higgs_train's pass
+    ("binary", (0.8, 5), W, VOFF),          # the bagging hash, inside Mosaic
+    ("regression", None, W, VOFF),
+    ("regression", (0.5, 1), 1024, 968),    # wide store: one lane block
+    ("binary", None, 256, 120),             # the slab straddles lane blocks
+    ("binary", None, 512, 250),             # ... in an odd block: all of W
+])
+def test_row_state_pass_compiles(one_chip, objective, bag, width, voff):
+    from lightgbm_tpu.boosting.gbdt import _carried_fns
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.core import row_state as RS
+    from lightgbm_tpu.objective import create_objective
+    cfg = Config(objective=objective, verbosity=-1)
+    _, grad_fn = _carried_fns(create_objective(objective, cfg), 1 << 20,
+                              bag, 3)
+    text = _compile(lambda r, b, v, it: RS.row_state_pass(
+        r, b, v, grad_fn, it, voff=voff),
+        one_chip, _sds((N_PAD, width), jnp.uint8), _sds((255,), jnp.int32),
+        _sds((255,), jnp.float32), _sds((), jnp.int32),
+        kernel="row_state_pass")
+    # what the kernel metrics match by prefix must not match this one
+    assert "%partition_hist_pallas" not in text
+    assert "%histogram_pallas_rows" not in text
+
+
 def test_bucket_names_are_the_three_the_metrics_read():
     assert [P.bucket_name(s, c) for s, c, _ in P.fused_bucket_plan(1 << 20)] \
         == ["small", "c1024", "c4096"]
@@ -232,6 +258,11 @@ def test_chunk_program_scopes_cover_the_glue(chunk_text):
     assert {k.rsplit(".", 1)[0] for k in kernels} >= {
         "%partition_hist_pallas_small", "%partition_hist_pallas_c1024",
         "%partition_hist_pallas_c4096", "%histogram_pallas_rows_factored"}
+    # the store's hand-over pass: glue_finish_ holds it, and it is the only
+    # kernel there (row_pass_ms_per_tree.train reads it by this name)
+    passes = {op: s for op, s in scope_of.items()
+              if op.startswith("%row_state_pass")}
+    assert passes and set(passes.values()) == {"tree.finish"}
 
 
 def test_predict_blocked_compiles(one_chip):
