@@ -11,6 +11,7 @@ from ..utils.log import Log
 
 class DART(GBDT):
     fuse_iters = False
+    shard_row_state = False
     lazy_trees = False  # dropout shrinks/re-adds host trees every iteration
     # dropout rescales OLD trees' leaf values in place and appends to the
     # tree-weight history — effects the pre-chunk score/model refs cannot

@@ -33,10 +33,12 @@ from ..core.tree_learner import (SerialTreeLearner, TreeArrays,
                                  build_tree_partitioned, route_binned,
                                  tree_from_arrays, tree_output_binned)
 from ..parallel import create_tree_learner
+from ..parallel.learners import arg_specs
 from ..io.dataset import BinnedDataset
 from ..metric.metric import Metric, create_metrics
 from ..objective import ObjectiveFunction, create_objective
 from ..obs import active as _telemetry_active
+from ..obs import comm as _comm
 from ..obs import compile as _compile
 from ..obs import devmem as _devmem
 from ..obs import launches as _launches
@@ -83,7 +85,12 @@ def _hoisted_jit(fused, *example_args):
         return jitted(consts, *args)
 
     call.lower = lambda *args: jitted.lower(consts, *args)
+    call._cache_size = jitted._cache_size
     return call
+
+
+def _mul_mask(grad, hess, mask):
+    return grad * mask, hess * mask
 
 
 def _bag_uniforms(row_ids, seed: int, it_window):
@@ -425,7 +432,6 @@ class GBDT:
         # cached fused programs close over the old learner/objective
         self._fused_cache = {}
         self._fuse_failed = False
-        self._balanced_frac = None  # labels may have changed
         self.num_tree_per_iteration = (objective.num_model_per_iteration
                                        if objective else max(1, self.num_class))
         self.learner = create_tree_learner(train_data, self.config,
@@ -452,6 +458,37 @@ class GBDT:
                 self.class_need_train = [
                     self.objective.class_need_train(k)
                     for k in range(self.num_tree_per_iteration)]
+        # Per-row state lives with its rows: where the learner shards rows
+        # over a mesh, scores, the objective's per-row constants, gradients
+        # and the bag mask are created in the row blocks of learner.bins
+        # (padded to its row count) and every program between two builds is
+        # shard-local (_row_program).
+        self._rows_sharded = bool(
+            self.shard_row_state and self.learner.row_sharding is not None
+            and (self.objective is None
+                 or (self.objective.deterministic_gradients
+                     and self.objective.shard_rows(self.learner.shard_rows))))
+        if self.learner.row_sharding is not None and not self._rows_sharded:
+            # the contract's gap, said aloud: nothing lists these programs,
+            # so count_row_collectives() has no count to give (None)
+            Log.warning(
+                "tree_learner=%s shards the rows over %d devices, but %s keeps "
+                "scores and gradients whole on one device: row-sized arrays "
+                "cross the devices every iteration", self.config.tree_learner,
+                self.learner.num_shards,
+                "boosting=%s" % self.config.boosting
+                if not self.shard_row_state else
+                "objective=%s" % getattr(self.objective, "name", "custom"))
+        self._row_fns: Dict = {}
+        self._row_texts: Dict[str, str] = {}   # compiled, as they are asked for
+        self._row_valid = None   # f32 1/0: a real row, or the learner's padding
+        self._bag_frac = None
+        if self._rows_sharded:
+            self.train_score = self.learner.shard_rows(self.train_score)
+            self._row_valid = self.learner.shard_rows(
+                np.ones(self.num_data, np.float32))
+            self._row_ids = self.learner.shard_rows(
+                np.arange(np_total, dtype=np.int32))
         self.train_metrics = []
         # plain bagging uses the stateless _bag_uniforms hash; this
         # sequential stream remains for GOSS's sampling (goss.py)
@@ -514,6 +551,32 @@ class GBDT:
                                 num_leaves=int(self.config.num_leaves))
             return arrays.leaf_value[leaf]
         return arrays.leaf_value[arrays.row_leaf]
+
+    def _add_tree_output(self, arrays: TreeArrays, class_id: int) -> None:
+        """train_score[class_id] += the tree's output on its own rows."""
+        if self._rows_sharded and arrays.row_leaf.shape[0]:
+            sharding = self.train_score.sharding
+
+            def update(score, leaf_value, row_leaf):
+                return jax.lax.with_sharding_constraint(
+                    score.at[class_id].add(leaf_value[row_leaf]), sharding)
+            self.train_score = self._row_program(
+                "update_score/%d" % class_id, update, self.train_score,
+                arrays.leaf_value, arrays.row_leaf)
+        else:
+            self.train_score = self.train_score.at[class_id].add(
+                self._gather_tree_output(arrays))
+
+    def _masked_gradients(self, gk, hk):
+        """One class's gradients as the learner takes them: padded to its row
+        count, zero on rows out of the bag and on a sharded learner's
+        padding rows (whose gradients were computed like any row's)."""
+        gk = self.learner.pad_rows(gk)
+        hk = self.learner.pad_rows(hk)
+        mask = self.bag_mask if self.bag_mask is not None else self._row_valid
+        if mask is not None:
+            gk, hk = self._row_program("mask", _mul_mask, gk, hk, mask)
+        return gk, hk
 
     def _tree_to_device(self, tree: Tree) -> TreeArrays:
         """Rebuild a device-routable TreeArrays from a host tree (bin thresholds)."""
@@ -621,26 +684,34 @@ class GBDT:
         plain = cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0
         if (balanced or plain) and it % cfg.bagging_freq == 0:
             n = self.num_data
-            if balanced:
-                # per-class Bernoulli fractions over the SAME stateless
-                # uniforms as plain bagging (gbdt.cpp:185-206 balanced
-                # bagging; independent-draw semantics as documented on
-                # _bag_uniforms).  Labels and the two fractions are
-                # iteration-invariant, so the [n] array is built once.
-                frac = getattr(self, "_balanced_frac", None)
-                if frac is None:
+            # The fraction is iteration-invariant, so it is built once: a
+            # scalar, or per row — per-class Bernoulli fractions over the
+            # SAME stateless uniforms as plain bagging (gbdt.cpp:185-206
+            # balanced bagging; independent-draw semantics as documented on
+            # _bag_uniforms), and 0 on a sharded learner's padding rows.
+            frac = self._bag_frac
+            if frac is None:
+                if balanced:
                     label = np.asarray(self.train_data.metadata.label)[:n]
-                    frac = jnp.where(jnp.asarray(label > 0),
-                                     jnp.float32(cfg.pos_bagging_fraction),
-                                     jnp.float32(cfg.neg_bagging_fraction))
-                    self._balanced_frac = frac
-            else:
-                frac = float(cfg.bagging_fraction)
+                    frac = self._place_rows(jnp.where(
+                        jnp.asarray(label > 0),
+                        jnp.float32(cfg.pos_bagging_fraction),
+                        jnp.float32(cfg.neg_bagging_fraction)))
+                elif self._rows_sharded:
+                    frac = self._row_valid * jnp.float32(cfg.bagging_fraction)
+                else:
+                    frac = float(cfg.bagging_fraction)
+                self._bag_frac = frac
+            seed, freq = int(cfg.bagging_seed), int(cfg.bagging_freq)
+            row_ids = (self._row_ids if self._rows_sharded
+                       else jnp.arange(n, dtype=jnp.int32))
             # same stateless hash as the fused path, so fused and
             # per-iteration training produce identical masks
-            mask, cnt = _bag_mask_for(
-                jnp.arange(n, dtype=jnp.int32), int(cfg.bagging_seed),
-                jnp.int32(it), int(cfg.bagging_freq), frac)
+            mask, cnt = self._row_program(
+                "bag_mask",
+                lambda ids, it_, frac_: _bag_mask_for(ids, seed, it_, freq,
+                                                      frac_),
+                row_ids, jnp.int32(it), frac)
             self.bag_mask = self.learner.pad_rows(mask)
             self.bag_data_cnt = int(cnt)
         elif self.bag_mask is None:
@@ -674,12 +745,74 @@ class GBDT:
                             "slow convergence", self.objective.name)
         return 0.0
 
+    def _place_rows(self, arr) -> jax.Array:
+        """A per-row array (rows last) where this booster keeps its rows."""
+        if self._rows_sharded:
+            return self.learner.shard_rows(arr)
+        return jnp.asarray(arr)
+
+    def _row_program(self, name: str, fn, *args):
+        """``fn(*args)``, a step of the iteration on per-row arrays.  Where
+        the rows are sharded it runs as ONE jitted program kept under
+        ``name`` (arrays ``fn`` closes over become arguments, so they keep
+        their sharding): the iteration's programs can then be listed and
+        read (:meth:`iteration_program_texts`).  ``fn`` must close over
+        nothing that changes between calls.  Elsewhere it runs as it is."""
+        if not self._rows_sharded:
+            return fn(*args)
+        prog = self._row_fns.get(name)
+        if prog is None:
+            prog = self._row_fns[name] = _hoisted_jit(fn, *args)
+            prog.specs = arg_specs(args)
+        out = prog(*args)
+        _recompile.note_dispatch("row_program", name, prog._cache_size(),
+                                 watch="row_program/%s/%d" % (name, id(prog)))
+        return out
+
+    def iteration_program_texts(self) -> Optional[List[str]]:
+        """The compiled texts of the programs one boosting iteration runs
+        when the rows are sharded (bag mask, gradients, masking, the
+        learner's build, score update) — those that have run.  None when
+        per-row state is not sharded.  Lowers again and asks the compiler,
+        which the persistent cache answers."""
+        if not self._rows_sharded:
+            return None
+        for name, prog in self._row_fns.items():
+            if name not in self._row_texts:
+                self._row_texts[name] = prog.lower(
+                    *prog.specs).compile().as_text()
+        build = self.learner.compiled_build()
+        return list(self._row_texts.values()) + (
+            [build.as_text()] if build is not None else [])
+
+    def count_row_collectives(self) -> Optional[int]:
+        """Collectives of :meth:`iteration_program_texts` with a row-sized
+        operand or result; noted in ``obs.comm``.  The data-parallel
+        contract says 0."""
+        texts = self.iteration_program_texts()
+        if texts is None:
+            return None
+        rows = self.num_data + self.learner.padded_rows
+        n = _comm.count_row_collectives(
+            texts, (rows, rows // self.learner.num_shards))
+        _comm.note_row_collectives(n)
+        return n
+
     def _get_gradients(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        score = self.train_score[:, :self.num_data]
-        if self.num_tree_per_iteration == 1:
-            g, h = self.objective.get_gradients(score[0])
-            return g[None, :], h[None, :]
-        return self.objective.get_gradients(score)
+        K, obj = self.num_tree_per_iteration, self.objective
+
+        def gradients(score):
+            if K == 1:
+                g, h = obj.get_gradients(score[0])
+                return g[None, :], h[None, :]
+            return obj.get_gradients(score)
+
+        if self._rows_sharded:
+            # scores and the objective's constants at the learner's row
+            # count; the padding rows' values are masked before the build
+            return self._row_program("gradients", gradients,
+                                     self.train_score)
+        return gradients(self.train_score[:, :self.num_data])
 
     def get_training_score(self) -> jnp.ndarray:
         """Scores used for gradient computation this iteration (DART overrides)."""
@@ -723,8 +856,10 @@ class GBDT:
             # async detection: the reduction rides the device queue and is
             # fetched in the next _poll_stop batch — no per-iteration sync
             self._fin_handles.append(
-                (self.iter_,
-                 jnp.isfinite(grad).all() & jnp.isfinite(hess).all()))
+                (self.iter_, self._row_program(
+                    "finite", lambda g, h: (jnp.isfinite(g).all()
+                                            & jnp.isfinite(h).all()),
+                    grad, hess)))
         with _spans.span("gbdt.bagging"):
             self._bagging(self.iter_)
             grad, hess = self._adjust_gradients_for_bagging(grad, hess)
@@ -735,11 +870,7 @@ class GBDT:
         for k in range(K):
             if self.class_need_train[k] and self.train_data.num_features > 0:
                 any_trained = True
-                gk = self.learner.pad_rows(grad[k])
-                hk = self.learner.pad_rows(hess[k])
-                if self.bag_mask is not None:
-                    gk = gk * self.bag_mask
-                    hk = hk * self.bag_mask
+                gk, hk = self._masked_gradients(grad[k], hess[k])
                 with FunctionTimer("TreeLearner::Train(dispatch)"):
                     arrays = self.learner.train(gk, hk, self.bag_data_cnt,
                                                 feature_mask,
@@ -750,8 +881,7 @@ class GBDT:
                     internal_value=arrays.internal_value * rate)
                 with FunctionTimer("GBDT::UpdateScore(dispatch)"), \
                         _spans.span("gbdt.update_score"):
-                    self.train_score = self.train_score.at[k].add(
-                        self._gather_tree_output(scaled))
+                    self._add_tree_output(scaled, k)
                     for vs in self.valid_sets:
                         self._route_arrays_valid(scaled, k, vs)
                 idx = len(self._models)
@@ -795,6 +925,10 @@ class GBDT:
     # bagging is an in-scan deterministic hash mask (_bag_uniforms).
 
     fuse_iters = True  # subclasses with per-iteration host logic opt out
+    # ... and so do those whose iteration reads whole-table arrays on one
+    # device (GOSS's top-k, DART's dropped trees, RF's cached gradients):
+    # their per-row state is not created with a row-sharding learner's rows
+    shard_row_state = True
 
     def _can_fuse_iters(self) -> bool:
         if not (self.fuse_iters and self.lazy_trees
@@ -1262,11 +1396,7 @@ class GBDT:
             new_tree = Tree(1)
             arrays = None
             if self.class_need_train[k] and self.train_data.num_features > 0:
-                gk = self.learner.pad_rows(grad[k])
-                hk = self.learner.pad_rows(hess[k])
-                if self.bag_mask is not None:
-                    gk = gk * self.bag_mask
-                    hk = hk * self.bag_mask
+                gk, hk = self._masked_gradients(grad[k], hess[k])
                 with FunctionTimer("TreeLearner::Train"):
                     arrays = self.learner.train(gk, hk, self.bag_data_cnt,
                                                 feature_mask,
@@ -1282,8 +1412,7 @@ class GBDT:
                 scaled = arrays._replace(
                     leaf_value=arrays.leaf_value * self.shrinkage_rate)
                 with FunctionTimer("GBDT::UpdateScore"):
-                    self.train_score = self.train_score.at[k].add(
-                        self._gather_tree_output(scaled))
+                    self._add_tree_output(scaled, k)
                     for vs in self.valid_sets:
                         self._add_tree_score_valid(len(self.models), new_tree, k,
                                                    vs)
@@ -1651,7 +1780,7 @@ class GBDT:
         self._feat_rng.set_state(decode_rng_state(meta["feat_rng"]))
         self._es_state = {(ds, name): (cur, it)
                           for ds, name, cur, it in meta.get("es_state", [])}
-        self.train_score = jnp.asarray(ts)
+        self.train_score = self._place_rows(ts)
         for i, vs in enumerate(self.valid_sets):
             vs["score"] = jnp.asarray(np.asarray(arrays["valid_score_%d" % i]))
         ln = self.learner
@@ -1847,7 +1976,7 @@ class GBDT:
                 score[k] += new_vals[lp]
         pad = np.zeros((K, self.train_score.shape[1] - self.num_data),
                        dtype=np.float32)
-        self.train_score = jnp.asarray(
+        self.train_score = self._place_rows(
             np.concatenate([score.astype(np.float32), pad], axis=1))
         self._drop_rollback_caches()
 

@@ -31,6 +31,7 @@ from ..utils.log import Log
 
 class GOSS(GBDT):
     fuse_iters = False
+    shard_row_state = False
     def __init__(self, config, train_data=None, objective=None, mesh=None):
         super().__init__(config, train_data, objective, mesh=mesh)
         if config.top_rate + config.other_rate > 1.0:

@@ -14,6 +14,7 @@ K_EPSILON = 1e-15
 
 class RF(GBDT):
     fuse_iters = False
+    shard_row_state = False
     average_output = True
 
     def __init__(self, config, train_data=None, objective=None, mesh=None):

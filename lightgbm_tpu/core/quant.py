@@ -69,8 +69,9 @@ def quantize_gradients(grad: jax.Array, hess: jax.Array, row_ids: jax.Array,
     gmax = jnp.max(jnp.abs(grad))
     hmax = jnp.max(hess)
     if axis_name:
-        gmax = jax.lax.pmax(gmax, axis_name)
-        hmax = jax.lax.pmax(hmax, axis_name)
+        with jax.named_scope("comm.sums"):
+            gmax = jax.lax.pmax(gmax, axis_name)
+            hmax = jax.lax.pmax(hmax, axis_name)
     tiny = jnp.float32(1e-30)
     s_g = jnp.maximum(gmax, tiny) / jnp.float32(GRAD_LEVELS)
     s_h = jnp.maximum(hmax, tiny) / jnp.float32(HESS_LEVELS)

@@ -592,8 +592,28 @@ def reduce_feature_best(fb: FeatureBest, feature_ids: jax.Array) -> BestSplit:
 def sync_best(best: BestSplit, axis_name: str) -> BestSplit:
     """Allreduce-argmax of per-shard best splits across a mesh axis — the XLA
     equivalent of ``SyncUpGlobalBestSplit`` (parallel_tree_learner.h:190-213):
-    all_gather the candidates and pick max gain, ties to the smaller feature id."""
-    g = BestSplit(*[jax.lax.all_gather(x, axis_name) for x in best])  # [d] each
+    ONE all_gather of the candidate record and pick max gain, ties to the
+    smaller feature id.  The record is the 12 scalars and the [W] bitset as
+    32-bit words (bitcasts, so every field arrives bit for bit): one
+    collective of 4 * (12 + W) bytes a chip, not one per field."""
+    def words(x):
+        if x.dtype == jnp.bool_:
+            x = x.astype(jnp.uint32)
+        return jax.lax.bitcast_convert_type(x, jnp.uint32).reshape(-1)
+
+    with jax.named_scope("comm.best_split"):
+        g = jax.lax.all_gather(jnp.concatenate([words(x) for x in best]),
+                               axis_name)                       # [d, 12 + W]
+    fields, at = [], 0
+    for x in best:
+        w = g[:, at:at + max(x.size, 1)]
+        at += w.shape[1]
+        if x.dtype == jnp.bool_:
+            w = w != 0
+        else:
+            w = jax.lax.bitcast_convert_type(w, x.dtype)
+        fields.append(w.reshape((g.shape[0],) + x.shape))
+    g = BestSplit(*fields)
     max_gain = jnp.max(g.gain)
     tie_feat = jnp.where(g.gain == max_gain, g.feature, jnp.int32(2**31 - 1))
     i = jnp.argmin(tie_feat)

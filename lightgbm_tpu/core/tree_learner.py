@@ -528,22 +528,24 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # real-valued f32 and downstream code is unchanged.
             if axis_name and not feat_mode and not vote_mode:
                 hb = h.astype(jnp.bfloat16)
-                if rs:
-                    hb = jax.lax.psum_scatter(hb, axis_name,
-                                              scatter_dimension=0,
-                                              tiled=True)
-                else:
-                    hb = jax.lax.psum(hb, axis_name)
+                with jax.named_scope("comm.hist_reduce"):
+                    if rs:
+                        hb = jax.lax.psum_scatter(hb, axis_name,
+                                                  scatter_dimension=0,
+                                                  tiled=True)
+                    else:
+                        hb = jax.lax.psum(hb, axis_name)
                 h = hb.astype(jnp.float32)
             return dequantize_hist(h, qscale)
         if not axis_name or feat_mode or vote_mode:
             # feature: rows replicated, local histogram IS global;
             # voting: histograms stay local, only elected rows are summed
             return h
-        if rs:
-            return jax.lax.psum_scatter(h, axis_name, scatter_dimension=0,
-                                        tiled=True)
-        return jax.lax.psum(h, axis_name)
+        with jax.named_scope("comm.hist_reduce"):
+            if rs:
+                return jax.lax.psum_scatter(h, axis_name,
+                                            scatter_dimension=0, tiled=True)
+            return jax.lax.psum(h, axis_name)
 
     if fused:
         # Round-7 size-bucketed fused dispatch: the split window's row count
@@ -638,15 +640,17 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             fb_local = _apply_contri(fb_local, jnp.arange(f, dtype=jnp.int32))
             kk = min(top_k, f)
             top_gain, top_ids = jax.lax.top_k(fb_local.gain, kk)
-            all_ids = jax.lax.all_gather(top_ids, axis_name).reshape(-1)
-            all_ok = jax.lax.all_gather(top_gain, axis_name
-                                        ).reshape(-1) > K_MIN_SCORE
+            with jax.named_scope("comm.best_split"):
+                all_ids = jax.lax.all_gather(top_ids, axis_name).reshape(-1)
+                all_ok = jax.lax.all_gather(top_gain, axis_name
+                                            ).reshape(-1) > K_MIN_SCORE
             votes = jax.ops.segment_sum(all_ok.astype(f32), all_ids,
                                         num_segments=f)
             key = votes - jnp.arange(f, dtype=f32) / (f + 1.0)  # ties: low id
             elected = jnp.sort(
                 jax.lax.top_k(key, min(2 * kk, f))[1]).astype(jnp.int32)
-            he = jax.lax.psum(h[elected], axis_name)
+            with jax.named_scope("comm.hist_reduce"):
+                he = jax.lax.psum(h[elected], axis_name)
             feat_e = FeatureInfo(*[None if a is None else a[elected]
                                    for a in feat])
             fb = per_feature_best_combined(
@@ -803,8 +807,9 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         if axis_name and not feat_mode:
             # root aggregate Allreduce (data_parallel_tree_learner.cpp:99-146);
             # feature mode replicates the rows, so local sums are already global
-            sum_g = jax.lax.psum(sum_g, axis_name)
-            sum_h = jax.lax.psum(sum_h, axis_name)
+            with jax.named_scope("comm.sums"):
+                sum_g = jax.lax.psum(sum_g, axis_name)
+                sum_h = jax.lax.psum(sum_h, axis_name)
         if quantized:
             # root totals were summed over the INTEGER gradients: scale them
             # back so leaf outputs / gains live in the real-valued domain
@@ -820,7 +825,8 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             ucnt0 = jnp.sum(((pb0 >> jnp.asarray(fi0 % 8)) & 1).astype(f32),
                             axis=0)
             if axis_name:
-                ucnt0 = jax.lax.psum(ucnt0, axis_name)
+                with jax.named_scope("comm.sums"):
+                    ucnt0 = jax.lax.psum(ucnt0, axis_name)
         else:
             ucnt0 = jnp.zeros((f,), f32)
         if cegb is not None:
@@ -967,8 +973,9 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # on the serial learner too
             hist_small = reduce_hist(hist_small)
             if axis_name and lazy_on:
-                used_l = jax.lax.psum(used_l, axis_name)
-                used_r = jax.lax.psum(used_r, axis_name)
+                with jax.named_scope("comm.sums"):
+                    used_l = jax.lax.psum(used_l, axis_name)
+                    used_r = jax.lax.psum(used_r, axis_name)
 
         def sel(new, old):
             """Masked state write: keep ``old`` on dead iterations."""
@@ -1790,6 +1797,10 @@ class SerialTreeLearner:
         with _span("ingest.upload"):
             self.bins = jnp.asarray(self._pad_host_rows(binned))
             self.bins.block_until_ready()
+
+    # where a [rows] array lives when the learner shards its rows over a
+    # mesh (parallel/learners.py); one device holds them all here
+    row_sharding = None
 
     def pad_rows(self, arr: jax.Array, value=0.0) -> jax.Array:
         """Pad a per-row array up to num_data + padded_rows (idempotent)."""

@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import io
 import json
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
@@ -34,6 +36,9 @@ from .binning import BinMapper, BinType, MissingType
 from .metadata import Metadata
 from ..obs.spans import span as _span
 from ..utils.log import Log
+
+# threads that bin columns side by side (each holds a few row-length temporaries)
+_BIN_THREADS = min(16, os.cpu_count() or 1)
 
 
 class BinnedDataset:
@@ -145,9 +150,13 @@ class BinnedDataset:
         col_dtype = (np.uint8 if max(self.num_bin_per_feature, default=2) <= 256
                      else np.uint16)
         with _span("ingest.bin_columns"):
-            cols = [self.bin_mappers[i].values_to_bins(
-                        data[:, i]).astype(col_dtype)
-                    for i in self.used_feature_idx]
+            # one thread a column: the search and the element-wise passes of
+            # values_to_bins release the GIL, and the columns are independent
+            def bin_column(i):
+                return self.bin_mappers[i].values_to_bins(
+                    data[:, i]).astype(col_dtype)
+            with ThreadPoolExecutor(max_workers=_BIN_THREADS) as pool:
+                cols = list(pool.map(bin_column, self.used_feature_idx))
             if reference is not None:
                 self.feature_groups = [list(g)
                                        for g in reference.feature_groups]

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -52,6 +53,21 @@ class ObjectiveFunction:
         """score: [num_model_per_iteration, N] (or [N]) raw scores -> (grad, hess)
         of the same shape."""
         raise NotImplementedError
+
+    # ---- row-sharded training (parallel learners that shard rows) ----
+
+    def shard_rows(self, place) -> bool:
+        """Hand every per-row device constant (label, weights, what ``init``
+        derived from them: any array whose last axis is the rows) to
+        ``place``, which pads the rows to the learner's count and puts them
+        where the learner keeps its rows; ``get_gradients`` then takes scores
+        of that length and stays shard-local.  False from an objective whose
+        gradients read other rows (ranking): its state stays where it was."""
+        for name, value in list(vars(self).items()):
+            if (isinstance(value, jax.Array) and value.ndim
+                    and value.shape[-1] == self.num_data):
+                setattr(self, name, place(value))
+        return True
 
     # ---- carried-row-store training (boosting/gbdt.py fused path) ----
     # Objectives whose gradients are a pointwise function of (score, one f32

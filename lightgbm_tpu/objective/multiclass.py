@@ -64,6 +64,10 @@ class MulticlassOVA(ObjectiveFunction):
         for b in self._binaries:
             b.init(metadata, num_data)
 
+    def shard_rows(self, place) -> bool:
+        return super().shard_rows(place) and all(
+            b.shard_rows(place) for b in self._binaries)
+
     def get_gradients(self, score):
         grads, hesses = [], []
         for k, b in enumerate(self._binaries):

@@ -151,6 +151,9 @@ class LambdarankNDCG(ObjectiveFunction):
                 [disc, np.full(max_s - disc.shape[0], disc[-1], np.float32)])
         self._discounts = jnp.asarray(disc[:max_s])
 
+    def shard_rows(self, place) -> bool:
+        return False    # a query's gradients read its other rows
+
     def get_gradients(self, score):
         score = jnp.asarray(score, dtype=jnp.float32).reshape(-1)
         score_pad = jnp.concatenate([score, jnp.zeros((1,), jnp.float32)])
@@ -227,6 +230,9 @@ class RankXENDCG(ObjectiveFunction):
                 "labels": jnp.asarray(label_pad[idx]),
                 "mask": jnp.asarray(idx < num_data),
             })
+
+    def shard_rows(self, place) -> bool:
+        return False    # a query's gradients read its other rows
 
     def get_gradients(self, score):
         score = jnp.asarray(score, dtype=jnp.float32).reshape(-1)

@@ -33,11 +33,26 @@ from ..core.partition import CHUNK as _PCHUNK
 from ..core.split import FeatureInfo
 from ..core.tree_learner import (Comm, SerialTreeLearner, TreeArrays,
                                  build_tree_partitioned)
+from ..obs import comm as _comm
+from ..obs import launches as _launches
+from ..obs import recompile as _recompile
+from ..obs.spans import span as _span
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
+
+
+def arg_specs(args):
+    """The shapes of a dispatch's arguments, each with the sharding it was
+    committed to (an uncommitted array goes wherever the program runs): what
+    ``.lower`` needs to produce the program that dispatch ran, without
+    keeping the arrays alive."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding if a.committed else None),
+        args)
 
 
 def default_mesh(num_devices: Optional[int] = None, axis: str = "data") -> Mesh:
@@ -331,6 +346,9 @@ class _ParallelTreeLearner(SerialTreeLearner):
                          num_shards=self.num_shards, top_k=int(config.top_k))
         self._repad(dataset)
         self._build_fn = self._make_build_fn()
+        self._build_specs = None
+        self._compiled_build = None
+        self._comm_per_build = None
 
     # ---- shape preparation ----
 
@@ -362,8 +380,44 @@ class _ParallelTreeLearner(SerialTreeLearner):
                     is_categorical=pad_with(self.feat.is_categorical, False),
                     monotone=pad_with(self.feat.monotone, 0))
 
+        # where a [rows] array lives: the contiguous row blocks of self.bins;
+        # None where rows are replicated (feature mode)
+        self.row_sharding = (None if self.mode == "feature"
+                             else NamedSharding(self.mesh, P(self.axis)))
         row_spec = P() if self.mode == "feature" else P(self.axis, None)
-        self.bins = jax.device_put(binned, NamedSharding(self.mesh, row_spec))
+        # the one sharded transfer of the binned table, waited for so that
+        # the span holds it (as ingest.upload does on one device)
+        with _span("ingest.shard_upload"):
+            self.bins = jax.device_put(binned,
+                                       NamedSharding(self.mesh, row_spec))
+            self.bins.block_until_ready()
+
+    # ---- per-row state lives with its rows ----
+
+    def shard_rows(self, arr, axis: int = -1, value=0.0) -> jax.Array:
+        """``arr`` with its row axis padded to the learner's row count and
+        placed in the row blocks of ``self.bins`` (a no-op for an array that
+        is there already)."""
+        axis %= arr.ndim
+        spec = [None] * arr.ndim
+        spec[axis] = self.axis
+        sharding = NamedSharding(self.mesh, P(*spec))
+        short = self.num_data + self.padded_rows - arr.shape[axis]
+        if short > 0:
+            widths = [(0, 0)] * arr.ndim
+            widths[axis] = (0, short)
+            xp = jnp if isinstance(arr, jax.Array) else np
+            arr = xp.pad(arr, widths, constant_values=value)
+        elif (isinstance(arr, jax.Array)
+              and not isinstance(arr, jax.core.Tracer)
+              and arr.sharding.is_equivalent_to(sharding, arr.ndim)):
+            return arr
+        return jax.device_put(arr, sharding)
+
+    def pad_rows(self, arr: jax.Array, value=0.0) -> jax.Array:
+        if self.row_sharding is None:
+            return super().pad_rows(arr, value)
+        return self.shard_rows(arr, axis=0, value=value)
 
     # ---- compiled build ----
     # Every parallel learner composes over the SAME partitioned base builder
@@ -417,13 +471,47 @@ class _ParallelTreeLearner(SerialTreeLearner):
                                  np.zeros(self.feature_pad, dtype=bool)])
         return self.pad_rows(grad), self.pad_rows(hess), jnp.asarray(fm)
 
+    def _dispatch_build(self, *args):
+        """One tree build: its launches and collectives recorded at the
+        dispatch, its argument shapes kept for :meth:`compiled_build`."""
+        _launches.record(self.effective_grow_mode(), self.launches_per_tree())
+        _comm.record(self.comm_per_build)
+        if self._build_specs is None:
+            self._build_specs = arg_specs(args)
+        with _span("dp.build_tree"):
+            out = self._build_fn(*args)
+        _recompile.note_dispatch("dp_build_tree", self.mode,
+                                 self._build_fn._cache_size(),
+                                 watch="dp_build_tree/%d" % id(self._build_fn))
+        return out
+
+    def compiled_build(self):
+        """The sharded build program as dispatched (``.as_text()``,
+        ``.memory_analysis()``), or None before the first build.  Lowers
+        again and asks the compiler, which the persistent cache answers."""
+        if self._compiled_build is None and self._build_specs is not None:
+            self._compiled_build = self._build_fn.lower(
+                *self._build_specs).compile()
+        return self._compiled_build
+
+    def comm_per_build(self):
+        """(collectives, bytes of their operands on one chip) that one tree
+        build takes part in, read off the compiled build program
+        (``obs.comm.per_run``: a collective of the builder's loop counts once
+        a trip); None before the first build."""
+        if self._comm_per_build is None and self._build_specs is not None:
+            self._comm_per_build = _comm.per_run(
+                self.compiled_build().as_text(),
+                unknown_trips=self.num_leaves - 1)
+        return self._comm_per_build
+
     def train(self, grad: jax.Array, hess: jax.Array, num_data_in_bag,
               feature_mask=None, iteration=0) -> TreeArrays:
         grad, hess, fm = self._prep_train(grad, hess, feature_mask)
-        return self._build_fn(self.bins, grad, hess,
-                              jnp.asarray(num_data_in_bag, dtype=jnp.int32),
-                              fm, self.feat,
-                              jnp.asarray(iteration, jnp.int32))
+        return self._dispatch_build(
+            self.bins, grad, hess,
+            jnp.asarray(num_data_in_bag, dtype=jnp.int32), fm, self.feat,
+            jnp.asarray(iteration, jnp.int32))
 
 
 class DataParallelTreeLearner(_ParallelTreeLearner):
@@ -446,6 +534,7 @@ class PartitionedDataParallelTreeLearner(_ParallelTreeLearner):
     (data_parallel_tree_learner.cpp:149-240) at the partitioned builder's
     per-leaf cost instead of full-data streaming per split."""
     mode = "data_part"
+    comm_mode = "psum"
     # no feature sharding here, so EFB group columns and 4-bit packing apply
     supports_groups = True
     supports_packing = True
@@ -500,11 +589,11 @@ class PartitionedDataParallelTreeLearner(_ParallelTreeLearner):
             # repadded rows (mesh-divisible) after the serial-side init
             self.cegb_paid = jnp.zeros(
                 (grad.shape[0], self.cegb_paid.shape[1]), jnp.uint8)
-        out = self._build_fn(self.bins, grad, hess,
-                             jnp.asarray(num_data_in_bag, dtype=jnp.int32),
-                             fm, self.feat, cegb_args,
-                             self.cegb_paid if lazy else (),
-                             jnp.asarray(iteration, jnp.int32))
+        out = self._dispatch_build(
+            self.bins, grad, hess,
+            jnp.asarray(num_data_in_bag, dtype=jnp.int32), fm, self.feat,
+            cegb_args, self.cegb_paid if lazy else (),
+            jnp.asarray(iteration, jnp.int32))
         if lazy:
             arrays, self.cegb_paid = out
         else:
