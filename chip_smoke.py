@@ -212,6 +212,7 @@ def phase_kernels(ctx):
                     % (num_bins, small, chunk, wc, hist_left, int(w_nl),
                        dev))
 
+    _check_right_block_phases()
     _check_row_state_pass(n_pad)
 
     # the two variants that are off by default (hist_precision=quantized,
@@ -259,6 +260,26 @@ def phase_kernels(ctx):
         say("  bins=256 small=%s chunk=%d: quantized split exact; level "
             "launch of %d windows equals sequential splits"
             % (small, chunk, len(wins)))
+
+
+def _check_right_block_phases():
+    """The split kernel's copy-back and finals at chosen right-block phases
+    and sizes, compiled.  On the chip the flush rings' word view is a bitcast
+    of the VMEM ref (row 4 i + k taken to be byte k of word i); interpret
+    mode cannot store through one and runs the value-level bitcast, so
+    tier-1's cases (tests/test_partition_blend.py) owe the chip the same
+    comparison with ``partition_hist_xla``, every byte of the store."""
+    from tests.test_partition_blend import (CHUNKS, PHASES, RIGHT_ROWS,
+                                            check_right_block)
+    for chunk in CHUNKS:
+        for ph in PHASES:
+            for nr in RIGHT_ROWS:
+                for hist_left in (1, 0):
+                    check_right_block(chunk, ph, nr, interpret=False,
+                                      hist_left=hist_left)
+        say("  chunk=%d: right blocks of %s rows copied back at phases %s, "
+            "both histogram sides: rows, nl and histogram equal"
+            % (chunk, RIGHT_ROWS, PHASES))
 
 
 def _check_row_state_pass(n_pad):

@@ -348,9 +348,108 @@ def _hist_tile(ti_c, hist_ref, scal_ref, start, cnt, *, num_features,
                       num_bins=num_bins, contract_dim=0)
 
 
+# ---- the flush rings' packed word view ----
+# A [.., TS, W] u8 staging tile is [.., TS // 4, W] 32-bit words, four
+# consecutive rows a word, the lowest row in the low byte.  Merging placed
+# rows into a tile under a ROW-RANGE mask then takes one word mask
+# ([TS // 4, W] i32: 4 vregs at W = 128) and three bit ops a vreg, where a u8
+# select under a [TS, 1] i32 mask (16 vregs, one lane in 128 used) has to be
+# broadcast over the lanes and packed 32 -> 16 -> 8 bit first: the static
+# schedule for the described v5e spent 192 bundles a phase-C subtile on 8
+# vregs of payload that way (PERF.md §5, tools/kernel_bundles.py).
+_WPT = TS // 4       # word rows per staging tile
+
+
+class _WordRef:
+    """A u8 [.., R, W] VMEM scratch, loaded and stored as its [.., R // 4, W]
+    i32 words.  On the chip that is a bitcast of the ref (no data moves);
+    interpret mode refuses a store through a bitcast ref, so there the same
+    bytes go through the value-level ``pltpu.bitcast`` of the u8 tile."""
+
+    def __init__(self, ref, interpret):
+        self._interpret = interpret
+        self._ref = ref if interpret else ref.bitcast(jnp.int32)
+
+    def load(self, lead, w0=0):
+        """The tile of ``_WPT`` word rows that starts at word row ``w0``."""
+        if self._interpret:
+            return pltpu.bitcast(self._ref[lead, 4 * w0:4 * (w0 + _WPT), :],
+                                 jnp.int32)
+        return self._ref[lead, w0:w0 + _WPT, :]
+
+    def store(self, lead, words):
+        if self._interpret:
+            self._ref[lead, :, :] = pltpu.bitcast(words, jnp.uint8)
+        else:
+            self._ref[lead, :, :] = words
+
+
+def _word_base(W):
+    """Loop-invariant base of :func:`_rows_from`: the first tile row of each
+    word, ``4 * word row``, over the lanes."""
+    return 4 * jax.lax.broadcasted_iota(jnp.int32, (_WPT, W), 0)
+
+
+def _rows_from(base, start):
+    """[TS // 4, W] i32 word mask of a staging tile: all ones in the bytes of
+    tile rows >= ``start`` (a scalar in [0, TS]), zero in the bytes below.
+    Only the word that holds row ``start`` is cut; what it keeps is one
+    scalar shift, and the vector work is two compares and two selects."""
+    cut = jnp.int32(-1) << (8 * (start & 3))
+    first = start - (start & 3)
+    return jnp.where(base > first, -1, jnp.where(base == first, cut, 0))
+
+
+def _merge_rows(upper, lower, m):
+    """Rows >= start from ``upper``, rows below from ``lower`` (words under
+    the mask ``m`` of :func:`_rows_from`)."""
+    return lower ^ ((lower ^ upper) & m)
+
+
+def _next_slot(slot, nb_ring):
+    """The ring slot after ``slot`` (a scalar compare, not a divide)."""
+    return jnp.where(slot == nb_ring - 1, 0, slot + 1)
+
+
+def _walk_ring(lo, hi, fn, nb_ring):
+    """``fn(m, slot)`` for the tiles ``m`` of [lo, hi) in turn, with ``slot
+    = m % nb_ring`` carried on from one divide a walk, not divided a tile:
+    the flush loops are scalar-only, so a tile's divide is latency nothing
+    hides (0.125 ns a window row on the chip, PERF.md §6 PR 31)."""
+    def body(m, slot):
+        fn(m, slot)
+        return _next_slot(slot, nb_ring)
+
+    jax.lax.fori_loop(lo, hi, body, jax.lax.rem(lo, nb_ring))
+
+
+def _append_placed(ring, streams, base):
+    """Merge one subtile's placed rows into the flush rings.  Each stream is
+    ``(cur, nxt, comp, start, n)``: ``comp`` (words) holds ``n`` rows at the
+    circular positions [start, start + n) mod TS and ZEROS everywhere else
+    (the one-hot placement), so the open tile ``cur`` takes every row >=
+    ``start`` from it, with no upper bound: a tile's rows past its fill
+    point are never read before they are filled (the finals mask by their
+    pending count, the copy-back by ``nr``).  Rows below ``start`` stay:
+    earlier subtiles', or the prefilled head's.  A range that wraps opens
+    ``nxt`` with a plain store of ``comp``: the wrapped rows lie below the
+    new tile's fill point ``start + n - TS``, and everything above it is
+    past the fill point again.  The streams' merges come first, in one
+    basic block where their chains overlap; the wraps are branches (``nxt``
+    may still be in flight when nothing wraps)."""
+    for cur, _, comp, start, _ in streams:
+        ring.store(cur, _merge_rows(comp, ring.load(cur),
+                                    _rows_from(base, start)))
+    for _, nxt, comp, start, n in streams:
+        @pl.when(start + n > TS)
+        def _wrap(nxt=nxt, comp=comp):
+            ring.store(nxt, comp)
+
+
 def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                            packed, exact, f_shard=False, dbg_skip="",
-                           chunk=CHUNK, multiwin=False, quantized=False):
+                           chunk=CHUNK, multiwin=False, quantized=False,
+                           interpret=False):
     # f_shard: the histogrammed feature window starts at scal[12 + B//32]
     # (feature-parallel shards build only their own F/d block while routing
     # on the full row store); num_features is then the WINDOW's width
@@ -379,6 +478,11 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
         wc = scal[1]
         gcol = scal[2]
         hist_left = scal[9]
+        # every merge of placed rows into a staging tile works on the words
+        stage_w = _WordRef(stage, interpret)
+        comp_w = _WordRef(comp_buf, interpret)
+        tmp_w = _WordRef(tmp, interpret)
+        wbase = _word_base(W)
 
         wb_al = pl.multiple_of((wb // _ALIGN) * _ALIGN, _ALIGN)
         headL = wb - wb_al
@@ -417,24 +521,30 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                     inbuf.at[j], sem_in.at[j]).start()
 
         iota2ts1 = jax.lax.broadcasted_iota(jnp.int32, (2 * TS, 1), 0)
-        iota_ts = jax.lax.broadcasted_iota(jnp.int32, (TS, 1), 0)
         totals_on = "totals" not in dbg_skip and "prefix" not in dbg_skip
         nsub = chunk // T
         npk = chunk // _LANE                   # lane-packed rows (row r ->
                                                # [r // 128, r % 128])
 
-        def wait_left(m):
-            sl = jax.lax.rem(m, nb_ring)
-            pltpu.make_async_copy(
+        # the flush of stream tile m from its ring slot sl = m % nb_ring
+        def flush_left(m, sl):
+            return pltpu.make_async_copy(
                 stage.at[sl], rows_ref.at[pl.ds(left_dst(m), TS)],
-                sem_fl.at[sl]).wait()
+                sem_fl.at[sl])
 
-        def wait_right(m):
-            sl = jax.lax.rem(m, nb_ring)
-            pltpu.make_async_copy(
+        def flush_right(m, sl):
+            return pltpu.make_async_copy(
                 stage.at[nb_ring + sl],
                 scratch_ref.at[pl.ds(pl.multiple_of(m * TS, _ALIGN), TS)],
-                sem_fr.at[sl]).wait()
+                sem_fr.at[sl])
+
+        def await_left(lo, hi):
+            _walk_ring(lo, hi, lambda m, sl: flush_left(m, sl).wait(),
+                       nb_ring)
+
+        def await_right(lo, hi):
+            _walk_ring(lo, hi, lambda m, sl: flush_right(m, sl).wait(),
+                       nb_ring)
 
         # ---- software pipeline (rounds 6-7) ----
         # The round-5 kernel ran A -> B -> totals-DMA-wait -> C per chunk:
@@ -601,69 +711,43 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             # await ring slots this chunk will reuse (flushes older than the
             # ring depth)
             if "flush" not in dbg_skip:
-                wdL = jax.lax.fori_loop(
-                    wdL, jnp.maximum(wdL, k1L - nb_ring + 1),
-                    lambda m, w: (wait_left(m), w + 1)[1], wdL)
-                wdR = jax.lax.fori_loop(
-                    wdR, jnp.maximum(wdR, k1R - nb_ring + 1),
-                    lambda m, w: (wait_right(m), w + 1)[1], wdR)
+                doneL = jnp.maximum(wdL, k1L - nb_ring + 1)
+                doneR = jnp.maximum(wdR, k1R - nb_ring + 1)
+                await_left(wdL, doneL)
+                await_right(wdR, doneR)
+                wdL, wdR = doneL, doneR
+
+            # ring slots of the two streams' open tiles: one rem a chunk,
+            # then a subtile moves on by at most one slot (nls, nrs <= TS)
+            curL = jax.lax.rem((headL + fillL) // TS, nb_ring)
+            curR = jax.lax.rem(fillR // TS, nb_ring)
 
             for s in range(nsub) if "phaseC" not in dbg_skip else []:
-                compL = comp_buf[bankb, s * 2 * TS:s * 2 * TS + TS, :]
-                compR = comp_buf[bankb, s * 2 * TS + TS:(s + 1) * 2 * TS, :]
+                # subtile s's rows in the two streams' open tiles: count and
+                # first tile row a side (the stream positions are >= 0)
                 nls = totals_sm[bankt, 0, s]
                 nrs = totals_sm[bankt, 0, nsub + s]
                 baseL = fillL + totals_sm[bankt, 1, s] - nls
                 baseR = fillR + totals_sm[bankt, 1, nsub + s] - nrs
-                startL = jax.lax.rem(headL + baseL, TS)
-                startR = jax.lax.rem(baseR, TS)
-                curL = jax.lax.rem((headL + baseL) // TS, nb_ring)
-                nxtL = jax.lax.rem((headL + baseL) // TS + 1, nb_ring)
-                curR = nb_ring + jax.lax.rem(baseR // TS, nb_ring)
-                nxtR = nb_ring + jax.lax.rem(baseR // TS + 1, nb_ring)
-
-                # blend the unwrapped circular ranges (masks in i32: Mosaic
-                # cannot truncate i8 bool vectors to i1)
-                maskLu = ((iota_ts >= startL).astype(jnp.int32)
-                          * (iota_ts < startL + nls).astype(jnp.int32))
-                stage[curL, :, :] = jnp.where(maskLu == 1, compL,
-                                              stage[curL, :, :])
-                maskRu = ((iota_ts >= startR).astype(jnp.int32)
-                          * (iota_ts < startR + nrs).astype(jnp.int32))
-                stage[curR, :, :] = jnp.where(maskRu == 1, compR,
-                                              stage[curR, :, :])
-
-                @pl.when(startL + nls > TS)
-                def _wrap_left():
-                    maskLw = (iota_ts < startL + nls - TS).astype(jnp.int32)
-                    stage[nxtL, :, :] = jnp.where(maskLw == 1, compL,
-                                                  stage[nxtL, :, :])
-
-                @pl.when(startR + nrs > TS)
-                def _wrap_right():
-                    maskRw = (iota_ts < startR + nrs - TS).astype(jnp.int32)
-                    stage[nxtR, :, :] = jnp.where(maskRw == 1, compR,
-                                                  stage[nxtR, :, :])
+                startL = (headL + baseL) & (TS - 1)
+                startR = baseR & (TS - 1)
+                nxtL = _next_slot(curL, nb_ring)
+                nxtR = _next_slot(curR, nb_ring)
+                _append_placed(stage_w, [
+                    (curL, nxtL, comp_w.load(bankb, s * 2 * _WPT),
+                     startL, nls),
+                    (nb_ring + curR, nb_ring + nxtR,
+                     comp_w.load(bankb, s * 2 * _WPT + _WPT), startR, nrs)],
+                    wbase)
+                curL = jnp.where(startL + nls >= TS, nxtL, curL)
+                curR = jnp.where(startR + nrs >= TS, nxtR, curR)
 
             # start this chunk's completed-tile flushes (scalar-only loops)
-            def start_left(m, _):
-                sl = jax.lax.rem(m, nb_ring)
-                pltpu.make_async_copy(
-                    stage.at[sl], rows_ref.at[pl.ds(left_dst(m), TS)],
-                    sem_fl.at[sl]).start()
-                return 0
-
-            def start_right(m, _):
-                sl = jax.lax.rem(m, nb_ring)
-                pltpu.make_async_copy(
-                    stage.at[nb_ring + sl],
-                    scratch_ref.at[pl.ds(pl.multiple_of(m * TS, _ALIGN), TS)],
-                    sem_fr.at[sl]).start()
-                return 0
-
             if "flush" not in dbg_skip:
-                jax.lax.fori_loop(nfL, k1L, start_left, 0)
-                jax.lax.fori_loop(nfR, k1R, start_right, 0)
+                _walk_ring(nfL, k1L,
+                           lambda m, sl: flush_left(m, sl).start(), nb_ring)
+                _walk_ring(nfR, k1R,
+                           lambda m, sl: flush_right(m, sl).start(), nb_ring)
 
             return accL, accR, k1L, k1R, wdL, wdR
 
@@ -694,10 +778,8 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
 
         # drain the outstanding async flushes
         if "flush" not in dbg_skip:
-            jax.lax.fori_loop(wdL, nfL,
-                              lambda m, w: (wait_left(m), w + 1)[1], wdL)
-            jax.lax.fori_loop(wdR, nfR,
-                              lambda m, w: (wait_right(m), w + 1)[1], wdR)
+            await_left(wdL, nfL)
+            await_right(wdR, nfR)
 
         # ---- final right partial flush (scratch is all ours: no RMW,
         # garbage tail rows are masked by nr during copy-back) ----
@@ -722,10 +804,9 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                                         tmp.at[0], sem_pre)
             cpa.start()
             cpa.wait()
-            keep = iota_ts < pend_l
-            tmp[0, :, :] = jnp.where(keep,
-                                     stage[jax.lax.rem(nfL, nb_ring), :, :],
-                                     tmp[0, :, :])
+            tmp_w.store(0, _merge_rows(
+                tmp_w.load(0), stage_w.load(jax.lax.rem(nfL, nb_ring)),
+                _rows_from(wbase, pend_l)))
             cpb = pltpu.make_async_copy(tmp.at[0], rows_ref.at[pl.ds(src, TS)],
                                         sem_pre)
             cpb.start()
@@ -796,11 +877,21 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             d0 = wb + nl
             d_al = pl.multiple_of((d0 // _ALIGN) * _ALIGN, _ALIGN)
             ph = d0 - d_al
-            # constant row-rotation one-hot: source row j -> stage (j+ph)%TS
+            # constant row-rotation one-hot: source row j -> stage row
+            # p = (j + ph) % TS, which the dot puts out at q = (p % 4) *
+            # TS // 4 + p // 4: byte k of every word as one contiguous
+            # [TS // 4, W] block, so the words are three shifts and ors of
+            # whole vregs and not a 32 -> 16 -> 8 bit pack
+            # (rot is [q, j]: a plain [TS, TS] @ [TS, W] dot, no transpose
+            # of the constant in every trip)
+            q = jax.lax.broadcasted_iota(jnp.int32, (TS, 1), 0)
             rot[...] = (jax.lax.rem(
-                jax.lax.broadcasted_iota(jnp.int32, (TS, 1), 0) + ph, TS)
-                == jax.lax.broadcasted_iota(jnp.int32, (1, TS), 1)
+                jax.lax.broadcasted_iota(jnp.int32, (1, TS), 1) + ph, TS)
+                == 4 * jax.lax.rem(q, _WPT) + q // _WPT
             ).astype(jnp.int8)
+            # ph is the loop's constant: rows >= ph of a tile come from the
+            # source tile that fills it, rows below from the one before
+            m_ph = _rows_from(wbase, ph)
             # head prefill: keep rows [d_al, d0) (tail of the left block)
             cph = pltpu.make_async_copy(
                 rows_ref.at[pl.ds(d_al, _ALIGN)],
@@ -813,7 +904,7 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 scratch_ref.at[pl.ds(0, TS)], tmp.at[0], sem_in.at[0]).start()
 
             def cb_body(k, carry):
-                fill, nf = carry
+                fill, nf, cur = carry          # cur: tile nf's ring slot
                 slot = jax.lax.rem(k, 2)
                 pltpu.make_async_copy(
                     scratch_ref.at[pl.ds(pl.multiple_of(k * TS, _ALIGN), TS)],
@@ -830,18 +921,17 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 tr = jax.lax.dot_general(
                     rot[...],
                     jax.lax.bitcast_convert_type(tmp[slot, :, :], jnp.int8),
-                    (((0,), (0,)), ((), ())),
+                    (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.int32)
-                comp = (tr & 255).astype(jnp.uint8)              # [TS, W]
+                comp = ((tr[0:_WPT] & 255)
+                        | ((tr[_WPT:2 * _WPT] & 255) << 8)
+                        | ((tr[2 * _WPT:3 * _WPT] & 255) << 16)
+                        | (tr[3 * _WPT:] << 24))                 # [TS//4, W]
+                # the last tile's rows past nvs are the scratch's garbage
+                # tail: they land past the fill point, which _final_cb masks
                 nvs = jnp.minimum(nr - k * TS, TS)
-                # valid source rows j < nvs sit at p=(ph+j)%TS
-                pj = jax.lax.rem(iota_ts - ph + TS, TS)          # j of pos p
-                cur = jax.lax.rem(nf, nb_ring)
-                nxt = jax.lax.rem(nf + 1, nb_ring)
-                mask_u = ((iota_ts >= ph).astype(jnp.int32)
-                          * (pj < nvs).astype(jnp.int32))
-                stage[cur, :, :] = jnp.where(mask_u == 1, comp,
-                                             stage[cur, :, :])
+                nxt = _next_slot(cur, nb_ring)
+                stage_w.store(cur, _merge_rows(comp, stage_w.load(cur), m_ph))
                 cross = ph + nvs >= TS
 
                 @pl.when(cross)
@@ -859,14 +949,14 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                         rows_ref.at[pl.ds(
                             pl.multiple_of(d_al + nf * TS, _ALIGN), TS)],
                         sem_cb.at[cur]).start()
-                    mask_w = ((iota_ts < ph).astype(jnp.int32)
-                              * (pj < nvs).astype(jnp.int32))
-                    stage[nxt, :, :] = jnp.where(mask_w == 1, comp,
-                                                 stage[nxt, :, :])
+                    # rows >= ph are the next trip's, or past the fill
+                    stage_w.store(nxt, comp)
 
-                return fill + nvs, nf + jnp.where(cross, 1, 0)
+                return (fill + nvs, nf + jnp.where(cross, 1, 0),
+                        jnp.where(cross, nxt, cur))
 
-            fill, nf = jax.lax.fori_loop(0, ncbk, cb_body, (zero, zero))
+            fill, nf, cur = jax.lax.fori_loop(0, ncbk, cb_body,
+                                              (zero, zero, zero))
             for j in range(1, nb_ring):
                 @pl.when(nf - j >= 0)
                 def _drain_cb(j=j):
@@ -886,10 +976,9 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                                             tmp.at[0], sem_pre)
                 cpa.start()
                 cpa.wait()
-                keep = iota_ts < pend
-                tmp[0, :, :] = jnp.where(keep,
-                                         stage[jax.lax.rem(nf, nb_ring), :, :],
-                                         tmp[0, :, :])
+                tmp_w.store(0, _merge_rows(
+                    tmp_w.load(0), stage_w.load(cur),
+                    _rows_from(wbase, pend)))
                 cpb = pltpu.make_async_copy(tmp.at[0],
                                             rows_ref.at[pl.ds(src, TS)],
                                             sem_pre)
@@ -1178,7 +1267,7 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
         n_pad=n_pad, W=W, num_features=num_features, num_bins=num_bins,
         voff=voff, bpc=bpc, packed=packed, exact=exact, f_shard=f_shard,
         dbg_skip=dbg_skip, chunk=chunk, multiwin=multiwin,
-        quantized=quantized)
+        quantized=quantized, interpret=interpret)
     rows_new, _scratch, hist, nl = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -8,18 +8,26 @@ Two measurements:
    in-kernel knockouts (a zeroed input folds every downstream op away).
    This measures the floor — round 5 measured ~0.26 ns/row.
 
-2. IN-KERNEL phase A (``--in-kernel``, round 6): the REAL fused kernel
-   (partition_hist_pallas) on a large window with phases B/C, flushes and
-   the histogram knocked out (``dbg_skip="phaseB,phaseC,flush,hist"``) —
-   i.e. stream + convert + extract + route + prefix + the banked totals
-   DMA, under the round-6 software pipeline.  The gap between this number
-   and the isolated replica IS the per-chunk scheduling overhead the
-   pipeline exists to hide; the round-6 acceptance bar is <= 1.4 ns/row
-   (round 5 measured 2.8).  Outputs are WRONG under knockouts — this mode
-   is timing-only.
+2. IN-KERNEL (``--in-kernel``): the REAL fused kernel
+   (partition_hist_pallas, the ``c4096`` bucket) on one large window.
+   First with phases B/C, flushes and the histogram knocked out
+   (``dbg_skip="phaseB,phaseC,flush,hist"``): stream + convert + extract +
+   route + prefix + the banked totals DMA under the software pipeline; the
+   gap to the isolated replica is per-chunk scheduling.  Then the whole
+   kernel less the histogram (``dbg_skip="hist"``), whose outputs are
+   right.  ``--route`` says where the rows go: ``left`` (every row left:
+   the right block and its copy-back are empty), ``right`` (every row
+   right: every row is also copied back) or ``half``.  Whole-kernel
+   ``right`` minus ``left`` is the copy-back's cost a right row, which no
+   knockout can isolate.  Knockout deltas fold constants; trust whole-kernel
+   A/B between two commits.  The static reading of the same phases is
+   ``tools/kernel_bundles.py``.
 """
-import sys
+import contextlib
 import os
+import shutil
+import sys
+import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -29,16 +37,26 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from lightgbm_tpu.core.partition import CHUNK, T      # the kernel's own
 from tools.profile_tree import aggregate_xplane
 
-CHUNK = 2048
 W = 128
-T = 128
 LANE = 128
 REPS = 16
 GRID = 32
 NSUB = CHUNK // T
 NPK = CHUNK // LANE
+
+
+@contextlib.contextmanager
+def _trace_dir():
+    """A profiler directory of this run's own (under TMPDIR), removed after
+    its reading: two commits timed in one call never see each other's."""
+    path = tempfile.mkdtemp(prefix="lgbm_tpu_pha_")
+    try:
+        yield path
+    finally:
+        shutil.rmtree(path, ignore_errors=True)
 
 
 def _consume(o_ref, arrs):
@@ -120,36 +138,40 @@ def _bench(name, stage, x):
     ))
     r = fn(x)
     r.block_until_ready()
-    trace_dir = "/tmp/lgbm_tpu_pha/" + "".join(c for c in name if c.isalnum())
-    with jax.profiler.trace(trace_dir):
-        r = fn(x)
-        r.block_until_ready()
-        float(jax.device_get(r[0, 0]))
-    rows = aggregate_xplane(trace_dir, top=40)
+    with _trace_dir() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            r = fn(x)
+            r.block_until_ready()
+            float(jax.device_get(r[0, 0]))
+        rows = aggregate_xplane(trace_dir, top=40)
     ms = max(rows, key=lambda q: q[1])[1]
     print("%-30s %9.3f ms   %.3f ns/row"
           % (name, ms, ms * 1e6 / (GRID * REPS * CHUNK)))
 
 
-def bench_in_kernel(n_rows=2_097_152, num_bins=64, reps=3):
-    """Whole-kernel phase-A timing: the real pipelined kernel with phase
-    B/C, flushes and the histogram knocked out.  Prints in-kernel phase-A
-    ns/row — the round-6 acceptance number (<= 1.4)."""
-    import time
-    from lightgbm_tpu.core.partition import CHUNK as PCHUNK
+# split threshold on a column of uniform bins: every row left (the right
+# block and its copy-back are empty), every row right, or half and half
+ROUTES = {"left": lambda num_bins: num_bins,
+          "right": lambda num_bins: -1,
+          "half": lambda num_bins: num_bins // 2 - 1}
+
+
+def bench_in_kernel(route="left", n_rows=2_097_152, num_bins=256, reps=3):
+    """Whole-kernel timing of the real pipelined kernel on one window of
+    ``n_rows`` rows at Higgs' shape (F = 28, W = 128, values at byte 28 as
+    ``build_tree_partitioned`` lays them out): phase A alone by knockout,
+    then everything but the histogram.  Prints ns a window row."""
     from lightgbm_tpu.core.partition import partition_hist_pallas
 
-    f, WK, voff = 28, 128, 32
-    n_pad = ((n_rows // PCHUNK) + 1) * PCHUNK
+    f, WK, voff = 28, 128, 28
+    n_pad = ((n_rows // CHUNK) + 2) * CHUNK     # the builder's spare chunk
     rng = np.random.RandomState(0)
     rows = np.zeros((n_pad, WK), np.uint8)
     rows[:, :f] = rng.randint(0, num_bins, size=(n_pad, f))
     rows[:, voff:voff + 8] = rng.randint(0, 255, size=(n_pad, 8))
     scal = np.zeros(12 + num_bins // 32, np.int32)
-    # threshold >= every bin -> all rows route LEFT: the right-block
-    # copy-back (not part of phase A, and not knockable via dbg_skip) is
-    # empty, so the timing isolates stream + phase A + totals pipeline
-    scal[:12] = [0, n_rows, 2, num_bins, 1, 0, num_bins, 0, 0, 1, 0, 1]
+    scal[:12] = [0, n_rows, 2, ROUTES[route](num_bins), 1, 0, num_bins, 0, 0,
+                 1, 0, 1]
     r = jnp.asarray(rows)
     s = jnp.asarray(scal)
 
@@ -157,39 +179,47 @@ def bench_in_kernel(n_rows=2_097_152, num_bins=64, reps=3):
         out = partition_hist_pallas(r, s, num_features=f, num_bins=num_bins,
                                     voff=voff, dbg_skip=skip)
         jax.block_until_ready(out[0])
-        trace_dir = ("/tmp/lgbm_tpu_pha/inkernel_"
-                     + "".join(c for c in skip if c.isalnum()))
-        with jax.profiler.trace(trace_dir):
-            for _ in range(reps):
-                out = partition_hist_pallas(
-                    r, s, num_features=f, num_bins=num_bins, voff=voff,
-                    dbg_skip=skip)
-                jax.block_until_ready(out[0])
-            float(jax.device_get(out[2][0, 0]))
-        best = max(aggregate_xplane(trace_dir, top=40),
-                   key=lambda q: q[1])[1] / reps
-        return best
+        with _trace_dir() as trace_dir:
+            with jax.profiler.trace(trace_dir):
+                for _ in range(reps):
+                    out = partition_hist_pallas(
+                        r, s, num_features=f, num_bins=num_bins, voff=voff,
+                        dbg_skip=skip)
+                    jax.block_until_ready(out[0])
+                float(jax.device_get(out[2][0, 0]))
+            best = max(aggregate_xplane(trace_dir, top=40),
+                       key=lambda q: q[1])[1] / reps
+        return best, int(out[2][0, 0])
 
-    ms_a = run("phaseB,phaseC,flush,hist")
-    print("in-kernel phase A (pipelined, %.1fM-row window): %.3f ms = "
-          "%.3f ns/row" % (n_rows / 1e6, ms_a, ms_a * 1e6 / n_rows))
-    ms_full = run("hist")
-    print("in-kernel A+B+C (no hist):                       %.3f ms = "
-          "%.3f ns/row" % (ms_full, ms_full * 1e6 / n_rows))
+    ms_a, _ = run("phaseB,phaseC,flush,hist")
+    print("route=%s in-kernel phase A (pipelined, %.1fM-row window, %d "
+          "bins): %.3f ms = %.3f ns/row"
+          % (route, n_rows / 1e6, num_bins, ms_a, ms_a * 1e6 / n_rows))
+    ms_full, nl = run("hist")
+    print("route=%s whole kernel less the histogram, %d rows left of %d: "
+          "%.3f ms = %.3f ns/row"
+          % (route, nl, n_rows, ms_full, ms_full * 1e6 / n_rows))
+    return ms_full * 1e6 / n_rows
 
 
 def main():
     import argparse
     ap = argparse.ArgumentParser(
         description="phase-A microbenchmark: isolated compute replica by "
-                    "default, whole-kernel pipelined phase A with "
-                    "--in-kernel (the round-6 acceptance bar)")
+                    "default; with --in-kernel the REAL fused kernel, phase "
+                    "A by knockout and whole less the histogram")
     ap.add_argument("--in-kernel", action="store_true",
-                    help="time the REAL fused kernel with B/C/flush/hist "
-                         "knocked out")
+                    help="time the REAL fused kernel on one large window")
+    ap.add_argument("--route", nargs="+", choices=sorted(ROUTES),
+                    default=["left"],
+                    help="where the window's rows go; several routes run "
+                         "in turn, and right minus left is the copy-back")
     args = ap.parse_args()
     if args.in_kernel:
-        bench_in_kernel()
+        ns = {route: bench_in_kernel(route) for route in args.route}
+        if "left" in ns and "right" in ns:
+            print("copy-back (whole kernel, right minus left): %.3f ns a "
+                  "right row" % (ns["right"] - ns["left"]))
         return
     x = jnp.asarray(np.random.RandomState(0).randint(0, 64, (CHUNK, W)),
                     jnp.uint8)
@@ -198,8 +228,8 @@ def main():
     _bench("1: + extract/reshape", 1, x)
     _bench("2: + route/sel", 2, x)
     _bench("3: + S/prefix/totals", 3, x)
-    print("run with --in-kernel for the pipelined whole-kernel phase-A "
-          "number (the round-6 acceptance bar)")
+    print("run with --in-kernel [--route left right half] for the real "
+          "kernel")
 
 
 if __name__ == "__main__":
