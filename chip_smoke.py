@@ -5,8 +5,10 @@
 
 One process, no network, data made from a seed, everything written under
 ``chip_smoke_out/``.  Drives the system through the entry points a user calls
-(``GBDT.train_chunk`` as bench.py and the CLI do, ``lightgbm_tpu.train``, the
-CLI, ``Booster.predict``, ``pred_contrib``, ``lgb.serve``) with the COMPILED
+(``GBDT.train_chunk`` as the CLI does, ``lightgbm_tpu.train``, the CLI,
+``Booster.predict``, ``pred_contrib``, ``lgb.serve``) on the benchmark's own
+task (``benchmarks/datagen.py`` with the generator of
+``benchmarks/configs/higgs-10m5.json``) with the COMPILED
 Pallas kernels, and checks what comes out by the repo's own means: the kernels
 against their plain-XLA references, the Pallas learner against the XLA learner
 every CPU test uses, loaded-model scores against in-memory ones, SHAP
@@ -37,7 +39,7 @@ SEED = 0
 F = 28                      # Higgs width
 MAX_BIN = 255
 LEAVES = 255
-ROWS_TRAIN = 10_500_000     # Higgs rows (bench.py's shape)
+ROWS_TRAIN = 10_500_000     # Higgs rows (the higgs_train cell's shape)
 ROWS_HELD_OUT = 1_050_000   # scored through the fused predictor
 ROWS_SMALL = 1 << 20        # entry-point / agreement phases
 ROWS_CLI = 32_768           # file-backed CLI dataset (text parse bound)
@@ -46,19 +48,30 @@ ROWS_4CHIP = 4 << 20
 ITERS = 5
 ITERS_SMALL = 4
 VOFF = 28                   # row-store layout build_tree_partitioned gives F=28
+# Held-out AUC that phase_entry_points' lightgbm_tpu.train call must pass.
+# The same call on the CPU (XLA learner; ROWS_SMALL rows, ITERS_SMALL rounds,
+# seed SEED + 1) reads 0.78165, and 8192 rows x 16 rounds x 15 leaves read
+# 0.78906 (tests/test_chip_smoke_data.py); no model passes the task's Bayes
+# AUC, the generator's ``bayes_auc`` (0.860).
+AUC_FLOOR = 0.76
 
 
 def say(msg):
     print(msg, flush=True)
 
 
-def higgs_like(n, n_test, seed):
-    """The synthetic Higgs-shaped task of bench.py: [n + n_test, 28] f32."""
-    rng = np.random.RandomState(seed)
-    X = rng.normal(size=(n + n_test, F)).astype(np.float32)
-    logit = (X[:, 0] * 2 + X[:, 1] ** 2 - X[:, 2] * X[:, 3]
-             + rng.normal(scale=0.5, size=n + n_test))
-    y = (logit > 0).astype(np.float64)
+def benchmark_table(n, n_test, seed):
+    """``n`` training and ``n_test`` held-out rows of the ``higgs_train``
+    cell's task: read from the benchmark, not copied (Bayes AUC 0.860)."""
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    try:
+        import datagen
+    finally:
+        sys.path.pop(0)
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "higgs-10m5.json")) as fh:
+        gen = json.load(fh)["generator"]
+    X, y = datagen.make(seed, n + n_test, F, gen)
     return X[:n], y[:n], X[n:], y[n:]
 
 
@@ -339,13 +352,13 @@ def _check_row_state_pass(n_pad):
 
 
 def phase_train(ctx):
-    """The fused k-iteration path at the full Higgs shape, as bench.py and
-    CLI task=train run it."""
+    """The fused k-iteration path at the full Higgs shape, as
+    ``benchmarks/run.py`` and CLI task=train run it."""
     import jax.numpy as jnp
     from lightgbm_tpu.io.dataset import BinnedDataset
     n = ROWS_TRAIN
     t0 = time.perf_counter()
-    X, y, Xt, yt = higgs_like(n, ROWS_HELD_OUT, SEED)
+    X, y, Xt, yt = benchmark_table(n, ROWS_HELD_OUT, SEED)
     t1 = time.perf_counter()
     ds = BinnedDataset.from_matrix(X, label=y, max_bin=MAX_BIN)
     t2 = time.perf_counter()
@@ -408,7 +421,7 @@ def phase_entry_points(ctx):
     chip has one owner, so no ``python -m lightgbm_tpu`` child."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu import cli, obs
-    X, y, Xh, yh = higgs_like(ROWS_SMALL, ROWS_SMALL // 8, SEED + 1)
+    X, y, Xh, yh = benchmark_table(ROWS_SMALL, ROWS_SMALL // 8, SEED + 1)
     tele = os.path.join(OUT, "api_train.jsonl")
     params = dict(objective="binary", num_leaves=LEAVES, learning_rate=0.1,
                   max_bin=MAX_BIN, verbosity=-1, telemetry_out=tele)
@@ -420,7 +433,7 @@ def phase_entry_points(ctx):
     assert len(spans) == ITERS_SMALL, "tree_build spans: %d" % len(spans)
     nl = check_trees(bst._booster.models, LEAVES)
     a = auc(yh, bst.predict(Xh))
-    assert a > 0.9, a
+    assert a > AUC_FLOOR, a
     say("  lightgbm_tpu.train: %d rows x %d, %d rounds on the per-iteration "
         "path (%d tree_build spans), leaves %r, held-out AUC %.5f"
         % (len(X), F, ITERS_SMALL, len(spans), nl, a))
@@ -497,7 +510,7 @@ def phase_agree_with_xla_learner(ctx):
 
 def phase_contrib(ctx):
     import lightgbm_tpu as lgb
-    X, y, _, _ = higgs_like(ROWS_CONTRIB_TRAIN, 0, SEED + 2)
+    X, y, _, _ = benchmark_table(ROWS_CONTRIB_TRAIN, 0, SEED + 2)
     # a SMALL model: the contrib program is O(depth^2) and its compile is
     # the cost here (a finding for the roadmap, not something this fixes)
     bst = lgb.train(dict(objective="binary", num_leaves=15, max_bin=MAX_BIN,
@@ -548,7 +561,7 @@ def phase_data_parallel(ctx):
     from lightgbm_tpu.parallel import (DataParallelTreeLearner, default_mesh,
                                        sharded_predict)
     n = ROWS_4CHIP
-    X, y, Xh, yh = higgs_like(n, n // 8, SEED + 3)
+    X, y, Xh, yh = benchmark_table(n, n // 8, SEED + 3)
     ds = BinnedDataset.from_matrix(X, label=y, max_bin=MAX_BIN)
     mesh = default_mesh(4)
     booster = make_gbdt(

@@ -161,8 +161,8 @@ PARAMS = [
 #   a padded device dispatch; bit-exact vs predict_blocked
 # - predict_precision: serving/predict tier — exact (the default: bit-exact
 #   f32 scores) or bf16 (lossy: bf16 leaf values + accumulate; leaf routing
-#   stays bit-exact).  The bf16 tier's measured score error is budget-gated
-#   (PERF_BUDGETS.json bf16_max_score_delta via tools/perf_gate.py); contrib
+#   stays bit-exact).  The bf16 tier's measured score error is budgeted
+#   (PERF_BUDGETS.json bf16_max_score_delta, tests/test_precision.py); contrib
 #   requests have no lossy tier
 # - metrics_port: serve the live observability plane (obs/exporter.py:
 #   /metrics Prometheus text, /healthz, /summary.json) from an HTTP

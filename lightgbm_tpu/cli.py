@@ -230,9 +230,9 @@ class Application:
                     from .checkpoint import cleanup_checkpoints
                     cleanup_checkpoints(cfg.output_model)
             if tele is not None:
-                # GBDT.train recorded the run gauges; fold in the MFU estimate
-                # and write <telemetry_out>.summary.json — one flag turned this
-                # run into a BENCH artifact.  The CLI owns the run: close it.
+                # GBDT.train recorded the run gauges; write
+                # <telemetry_out>.summary.json — one flag made this run
+                # self-recording.  The CLI owns the run: close it.
                 from . import obs
                 from .obs.report import finalize_run
                 # iterations trained THIS process only: a resumed run's wall

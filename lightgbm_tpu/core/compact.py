@@ -22,9 +22,9 @@ through the same text round-trip as ``online.controller._freeze_generation``
 (round 17): the distilled booster re-loads from its own model string,
 carries the parent's score fingerprints (so score-PSI baselines follow the
 swap, same as a retrain), and hot-swaps into a ``ModelRegistry`` like any
-other generation.  Every artifact it emits carries measured
-``max_score_delta`` / AUC delta / tree+byte reduction, gated by
-``tools/perf_gate.py`` against ``PERF_BUDGETS.json``.
+other generation.  Its stats carry the measured ``max_score_delta`` /
+AUC delta / tree+byte reduction, which ``tests/test_precision.py`` holds
+to the ``compact_*`` lines of ``PERF_BUDGETS.json``.
 """
 from __future__ import annotations
 
@@ -378,7 +378,7 @@ def measure_compaction(booster, gen, X: np.ndarray,
                        y: Optional[np.ndarray] = None) -> Dict:
     """Measured deltas of the distilled generation vs its parent on real
     rows: ``max_score_delta`` over raw scores and (with labels) the AUC
-    delta — the numbers the perf gate checks against PERF_BUDGETS.json."""
+    delta — the numbers the tests hold to PERF_BUDGETS.json's budgets."""
     s_in = np.asarray(booster.predict(X, raw_score=True),
                       dtype=np.float64).reshape(len(X), -1)
     s_out = np.asarray(gen.predict(X, raw_score=True),

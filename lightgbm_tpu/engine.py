@@ -175,7 +175,7 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
                                   key=lambda cb: getattr(cb, "order", 0))
 
     # telemetry: a telemetry_out param turns this run self-recording (JSONL
-    # events + <out>.summary.json); a run configured by the caller (bench.py)
+    # events + <out>.summary.json); a run configured by the caller
     # is recorded into but finalized by its owner.  Under a pod every rank
     # records into its own <out>.rank<k>.jsonl shard (obs.configure picks
     # the path) and only the leader writes the merged summary at finalize;

@@ -14,8 +14,8 @@ instance configured — the default — every instrumentation site is a
 tests/test_telemetry.py).
 
 Enable from any entry point with the ``telemetry_out`` (JSONL path) and
-``telemetry_freq`` (per-iteration event cadence) params; ``engine.train``,
-the CLI and ``bench.py`` all finalize the run into
+``telemetry_freq`` (per-iteration event cadence) params; ``engine.train``
+and the CLI finalize the run into
 ``<telemetry_out>.summary.json`` via :func:`~.report.finalize_run`.
 ``metrics_port`` additionally serves the run live over HTTP.  Under a
 multi-process pod each host writes its own ``<out>.rank<k>.jsonl`` shard

@@ -1,10 +1,10 @@
 """End-of-run telemetry summary: JSON artifact + human table.
 
-The summary is shaped like the BENCH_r*.json trajectory entries this repo's
-perf history uses (``metric``/``value``/``unit`` headline + named
-sub-sections), so ``bench.py``, ``tools/head_to_head.py`` and the PERF.md
-hardware protocols can consume a telemetry artifact directly: one flag
-(``telemetry_out=...``) turns ANY run into a BENCH artifact.
+One flag (``telemetry_out=...``) gives ANY run a summary: a
+``metric``/``value``/``unit`` headline plus named sub-sections, what
+``tools/obs_report.py`` renders and ``tools/perf_gate.py`` holds to the
+declared counts.  It is an operator's account of one run on whatever
+device it ran on; the record of speed is ``PERF_LEDGER.jsonl``.
 
 Layout::
 
@@ -29,7 +29,6 @@ Layout::
                   "evictions": n, "swaps": n, "readmits": n,
                   "queue_depth": {histogram summary},
                   "wall_s": x|null},             # only when the run served
-      "mfu": x|null, "device_util": y|null,
       "events": <event count>
     }
 """
@@ -287,8 +286,6 @@ def summarize(tele: Telemetry, extra: Optional[Dict[str, Any]] = None
         "tree_kernel_launches": run_launches,
         "tree_kernel_launch_total": launch_total,
         "resilience": resilience,
-        "mfu": gauges.get("mfu"),
-        "device_util": gauges.get("device_util"),
         "events": getattr(tele, "event_count", len(tele.events)),
         # pod provenance: which host produced this summary (rank None =
         # single-process run)
@@ -352,8 +349,8 @@ def summarize(tele: Telemetry, extra: Optional[Dict[str, Any]] = None
     # kernel-plan provenance (round 18, lightgbm_tpu/plan): which planner
     # produced the dispatch shapes behind this artifact's numbers —
     # analytic | tuned | pinned per site, plus the engaged cache and the
-    # always-on fallback counter.  BENCH artifacts carry this so a tuned
-    # number is never mistaken for an analytic one (perf_gate checks it).
+    # always-on fallback counter.  A summary carries this so a tuned
+    # run is never mistaken for an analytic one (perf_gate checks it).
     stamps = getattr(tele, "plan_stamps", None)
     if stamps:
         from ..plan import cache as _plan_cache
@@ -396,8 +393,6 @@ def human_table(summary: Dict[str, Any]) -> str:
     row("iterations", num(summary.get("iterations"), "%d")
         if summary.get("iterations") is not None else "-")
     row("wall_s", num(summary.get("wall_s")))
-    row("mfu", num(summary.get("mfu")))
-    row("device_util", num(summary.get("device_util")))
     row("recompiles (total)", "%d" % summary.get("recompile_total", 0))
     for key, n in sorted((summary.get("recompiles") or {}).items()):
         row("  recompile %s" % key, "%d" % n)
@@ -632,31 +627,24 @@ def finalize_run(tele: Telemetry, gbdt=None, wall_s: Optional[float] = None,
                  iters: Optional[int] = None,
                  extra: Optional[Dict[str, Any]] = None,
                  summary_path: Optional[str] = None) -> Dict[str, Any]:
-    """Close out a telemetry run: record headline gauges, the MFU estimate
-    (when a booster is at hand), write ``<out>.summary.json`` next to the
-    JSONL, emit a ``run_end`` event, and return the summary dict.
+    """Close out a telemetry run: record headline gauges, write
+    ``<out>.summary.json`` next to the JSONL, emit a ``run_end`` event, and
+    return the summary dict.
 
     Gauges the training driver already recorded WIN: ``GBDT.train`` times
     the train loop only, while a CLI caller's ``wall_s`` spans dataset
     loading and compile too — overwriting would make the same training
     produce different row-trees/s headlines per entry point.  The
     ``wall_s``/``iters`` arguments are the fallback for runs that never
-    went through a recording driver (bench's timed window)."""
+    went through a recording driver."""
     from ..utils.log import Log
     if wall_s is not None and tele.gauge("train_wall_s").value is None:
         tele.gauge("train_wall_s").set(wall_s)
     if iters is not None and tele.gauge("train_iterations").value is None:
         tele.gauge("train_iterations").set(iters)
-    eff_wall = tele.gauge("train_wall_s").value
-    eff_iters = tele.gauge("train_iterations").value
     if gbdt is not None:
         if tele.gauge("train_rows").value is None:
             tele.gauge("train_rows").set(int(gbdt.num_data))
-        if eff_wall:
-            from .mfu import record_training_estimate
-            record_training_estimate(
-                tele, gbdt, eff_wall,
-                iters=int(eff_iters) if eff_iters else None)
         # split/gain feature importance rides the summary: the quality
         # table ranks drifted features by importance x PSI, and the
         # artifact should carry the ranking weights it used (top 50 by

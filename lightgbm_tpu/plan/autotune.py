@@ -22,8 +22,8 @@ For each (shape-class, device_kind) the tuner:
 Any candidate is numerics-safe: plans change dispatch shape only, and
 every kernel variant is pinned bit-exact against the others — the tuner
 races performance, never correctness.  Off-TPU the fused kernels run in
-interpret mode (walls are mechanism-proof, not evidence; the BENCH
-protocol runs this on hardware).
+interpret mode (walls are mechanism-proof, not evidence; the race has
+not been run on the chip).
 
 Driven by ``tools/bench_autotune.py``; tested with an injected timer in
 tests/test_plan.py (ranking logic is deterministic under synthetic
@@ -294,7 +294,7 @@ def run_sweep(shapes, *, cache_path: Optional[str] = None, reps: int = 4,
               fixture_rows: Optional[int] = None, trees: int = 8,
               progress=None) -> Dict[str, Any]:
     """Tune every shape class, persist the winners, return the report
-    ``tools/bench_autotune.py`` turns into the BENCH_autotune artifact.
+    ``tools/bench_autotune.py`` prints and writes to its ``--json`` path.
 
     ``fixture_rows`` caps the synthetic workload's row count (off-TPU
     smoke runs) while the persisted entry stays keyed by the REQUESTED

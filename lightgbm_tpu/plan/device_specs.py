@@ -3,12 +3,12 @@
 Before this module, device constants were scattered and re-hardcoded:
 the ~16 MiB v5e VMEM note lived in a ``core/histogram.py`` docstring, the
 4 MiB factored-histogram accumulator gate was a literal in
-``_use_factored``, ``core/predict_fused.py`` carried its own
-``BLOCK_VMEM_BYTES``, and ``obs/mfu.py`` kept the HBM-bandwidth / peak-MACs
-table.  The kernel planner (``plan/planner.py``) and the MFU estimator both
-need those numbers per ``device_kind``, so they live here — adding a
-backend becomes "add a spec row + run the tuner" (ROADMAP item 4), not
-"re-derive every constant".
+``_use_factored`` and ``core/predict_fused.py`` carried its own
+``BLOCK_VMEM_BYTES``.  The kernel planner (``plan/planner.py``) needs those
+numbers per ``device_kind``, so they live here — adding a backend becomes
+"add a spec row + run the tuner" (ROADMAP item 4), not "re-derive every
+constant".  A chip's peak FLOP/s and bytes/s are the benchmark's, in
+``benchmarks/peaks.json``: nothing in the program reads them.
 
 Dependency-free by design: ``core/histogram.py`` and
 ``core/predict_fused.py`` import this at module load, so it must never
@@ -25,41 +25,29 @@ from typing import NamedTuple, Optional
 
 
 class DeviceSpec(NamedTuple):
-    """Hardware envelope of one accelerator kind.
-
-    ``hbm_bw`` / ``peak_macs`` are ``None`` on the CPU row: utilization
-    ratios stay ``None`` rather than a made-up number (obs/mfu.py
-    contract)."""
+    """Hardware envelope of one accelerator kind."""
     kind: str                     # canonical name (substring-matched)
     vmem_bytes: int               # per-core VMEM
-    hbm_bw: Optional[float]       # HBM bytes/s
-    peak_macs: Optional[float]    # bf16 MACs/s (FLOP/s / 2)
 
-
-# v5e peaks (Google Cloud documentation, "TPU v5e")
-V5E_PEAK_BW = 819e9      # HBM bytes/s
-V5E_PEAK_MACS = 98.5e12  # bf16 MACs/s (197 TFLOP/s)
 
 # the "~16 MiB v5e VMEM" every round-5..7 kernel constant was tuned inside
 # (previously a core/histogram.py docstring note)
 V5E_VMEM_BYTES = 16 << 20
 
-# Substring-matched IN ORDER against the lowercased ``device_kind`` —
-# same matching discipline obs/mfu.py always used ("v5 lite" before "v5e"
-# so both spellings of the same chip hit one row).  MACs = FLOP/2 (the
-# reference numbers quote FLOP/s).
+# Substring-matched IN ORDER against the lowercased ``device_kind``
+# ("v5 lite" before "v5e" so both spellings of the same chip hit one row).
 SPECS = (
-    DeviceSpec("v5 lite", V5E_VMEM_BYTES, V5E_PEAK_BW, V5E_PEAK_MACS),
-    DeviceSpec("v5e", V5E_VMEM_BYTES, V5E_PEAK_BW, V5E_PEAK_MACS),
-    DeviceSpec("v5p", 16 << 20, 2765e9, 229e12),   # 2.765 TB/s, 459 TFLOP/s
-    DeviceSpec("v4", 16 << 20, 1228e9, 137.5e12),  # 1.228 TB/s, 275 TFLOP/s
-    DeviceSpec("v3", 16 << 20, 900e9, 61.5e12),    # 900 GB/s, 123 TFLOP/s
-    DeviceSpec("v6", 32 << 20, 1640e9, 459e12),    # v6e: 1.64 TB/s, 918 TF
+    DeviceSpec("v5 lite", V5E_VMEM_BYTES),
+    DeviceSpec("v5e", V5E_VMEM_BYTES),
+    DeviceSpec("v5p", 16 << 20),
+    DeviceSpec("v4", 16 << 20),
+    DeviceSpec("v3", 16 << 20),
+    DeviceSpec("v6", 32 << 20),
 )
 
 # hosts whose platform is ``cpu`` (tests, rehearsals): v5e-shaped VMEM budgets
-# keep the analytic planner byte-equal to what the chip plans; no peaks
-CPU_SPEC = DeviceSpec("cpu", V5E_VMEM_BYTES, None, None)
+# keep the analytic planner byte-equal to what the chip plans
+CPU_SPEC = DeviceSpec("cpu", V5E_VMEM_BYTES)
 
 # path-matrix VMEM budget per predict scan block (f32 bytes) — the former
 # ``predict_fused.BLOCK_VMEM_BYTES`` literal; device-independent until the
@@ -70,8 +58,8 @@ PREDICT_BLOCK_VMEM_BYTES = 1 << 20
 def spec_for(device_kind: str) -> DeviceSpec:
     """The spec row of ``device_kind`` (substring match, first hit);
     ``"cpu"`` — what :func:`current_device_kind` answers on a host without
-    a chip — gets :data:`CPU_SPEC`.  A kind with no row raises: budgets and
-    peaks quoted for a device nobody looked up would be made-up numbers."""
+    a chip — gets :data:`CPU_SPEC`.  A kind with no row raises: budgets
+    quoted for a device nobody looked up would be made-up numbers."""
     kind = str(device_kind).lower()
     if kind == "cpu":
         return CPU_SPEC

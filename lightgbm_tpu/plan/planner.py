@@ -63,8 +63,8 @@ class Plan(NamedTuple):
     ascending, last ``None``); ``hist_factored``/``hist_groups`` describe
     the histogram layout for this (F, B); ``predict_block_vmem_bytes``
     sizes ``tree_block``'s G and ``predict_buckets`` is the serving row
-    ladder.  ``provenance`` is stamped into telemetry so BENCH artifacts
-    record which plan produced a number."""
+    ladder.  ``provenance`` is stamped into telemetry so a run's summary
+    records which plan it ran."""
     bucket_plan: Tuple            # fused split dispatch schedule (leaf-wise)
     level_ladder: Tuple           # level-mode per-level bucket-class set
     hist_factored: bool           # factored hi/lo vs classic one-hot layout

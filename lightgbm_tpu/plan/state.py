@@ -16,8 +16,8 @@ precedence:
 
 Every resolution can be stamped into the active telemetry run
 (:func:`stamp`): a ``kind="plan"`` event per (site, key) plus a
-``tele.plan_stamps`` dict the summary renders as the "plan" block — BENCH
-artifacts record which plan produced a number.
+``tele.plan_stamps`` dict the summary renders as the "plan" block, so a
+run's summary records which plan it ran.
 """
 from __future__ import annotations
 

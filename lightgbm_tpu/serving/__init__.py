@@ -18,7 +18,7 @@ users into those cached bucket dispatches:
 - SLO instrumentation — per-model latency/occupancy/queue-depth histograms
   and eviction/swap counters through the ``obs`` registry (zero telemetry
   calls when no run is active), rendered as the ``serving`` block of the
-  telemetry summary and driven by ``tools/bench_serve.py``.
+  telemetry summary.
 
 Entry points: ``lightgbm_tpu.serve(...)`` (engine), ``Booster.serve()``,
 CLI ``task=serve``; ``lightgbm_tpu.serve_and_train(...)`` / ``task=online``

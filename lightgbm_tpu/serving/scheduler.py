@@ -260,7 +260,7 @@ class Server:
 
         ``precision="bf16"`` routes the request through the lossy serving
         tier (bf16 leaf values + accumulate; routing bit-exact) whose
-        measured error is budget-gated in PERF_BUDGETS.json.  Tiers never
+        measured error tests hold to PERF_BUDGETS.json's budget.  Tiers never
         share a dispatch (the batch key carries the tier), and contrib
         requests have no lossy tier."""
         precision = str(precision)

@@ -1,7 +1,7 @@
 """Where compiled programs and tuned kernel plans persist between runs.
 
 One rule for every entry point (CLI, ``engine.train``/``engine.serve``,
-``bench.py``, ``chip_smoke.py``, the test suite): if
+``benchmarks/run.py``, ``chip_smoke.py``, the test suite): if
 ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and the program sets
 no cache directory in code; otherwise a FIXED directory inside the checkout.
 The path is part of a cache entry's key, so a directory that moves (a temp

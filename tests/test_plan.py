@@ -14,7 +14,7 @@ Pins the acceptance contract of ISSUE 14:
   caches degrade to analytic with ONE warning and the always-on
   ``plan_cache_fallbacks`` counter.
 - PROVENANCE: stamps reach the telemetry summary (and the perf gate
-  checks them on BENCH artifacts).
+  checks them there).
 """
 import json
 import os
@@ -109,31 +109,18 @@ def test_resolve_analytic_equals_site_defaults():
 
 
 def test_device_specs_single_source_of_truth():
-    """obs/mfu.py's peaks table and the VMEM budgets all come from
-    plan/device_specs.py — one row per device_kind."""
-    from lightgbm_tpu.obs import mfu
-
-    class _Dev:
-        platform = "tpu"
-        device_kind = "TPU v5 lite"
-
-    assert mfu.device_peaks(_Dev()) == {
-        "bw": device_specs.V5E_PEAK_BW, "macs": device_specs.V5E_PEAK_MACS,
-        "kind": "tpu v5 lite"}
+    """The VMEM budgets all come from plan/device_specs.py — one row
+    per device_kind."""
     v5e = device_specs.spec_for("tpu v5 lite")
     assert v5e.vmem_bytes == 16 << 20
     assert device_specs.hist_accum_budget_bytes("v5e") == 4 << 20
     # a host without a chip keeps the v5e-shaped budgets (analytic
-    # byte-equality everywhere) but reports no peaks; a device nobody
-    # looked up is an error, not a default
+    # byte-equality everywhere); a device nobody looked up is an error,
+    # not a default
     cpu = device_specs.spec_for("cpu")
     assert cpu.vmem_bytes == 16 << 20
-    assert cpu.hbm_bw is None and cpu.peak_macs is None
     with pytest.raises(ValueError, match="warp-drive-9000"):
         device_specs.spec_for("warp-drive-9000")
-    _Dev.device_kind = "warp-drive-9000"
-    with pytest.raises(ValueError):
-        mfu.device_peaks(_Dev())
     from lightgbm_tpu.core.predict_fused import BLOCK_VMEM_BYTES
     assert BLOCK_VMEM_BYTES == device_specs.PREDICT_BLOCK_VMEM_BYTES
 

@@ -10,8 +10,7 @@ budget.  Serving-side: exact and bf16 requests NEVER share a dispatch
 (the batch key carries the tier), contrib has no lossy tier anywhere on
 the ladder, a quantize-only compacted republish is a pure jit-cache hit,
 and the quality plane folds both tiers' scores through the same training
-fingerprint (no per-tier baselines, no per-tier false alarms).  The perf
-gate is pinned operational: a doctored over-budget artifact must FAIL.
+fingerprint (no per-tier baselines, no per-tier false alarms).
 """
 import json
 import os
@@ -33,6 +32,11 @@ from lightgbm_tpu.serving import Server
 from lightgbm_tpu.utils.log import LightGBMError
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _budgets():
+    with open(os.path.join(REPO, "PERF_BUDGETS.json")) as fh:
+        return json.load(fh)["budgets"]
 
 
 @pytest.fixture(autouse=True)
@@ -97,8 +101,7 @@ def test_bf16_deterministic_bounded_and_distinct(model):
     declared budget — routing exactness keeps the error at leaf-rounding
     scale, not misroute scale."""
     b, X = model
-    with open(os.path.join(REPO, "PERF_BUDGETS.json")) as fh:
-        budget = float(json.load(fh)["budgets"]["bf16_max_score_delta"])
+    budget = float(_budgets()["bf16_max_score_delta"])
     exact = b.predict(X[:400], raw_score=True)
     bf16_a = b.predict(X[:400], raw_score=True, precision="bf16")
     bf16_b = b.predict(X[:400], raw_score=True, precision="bf16")
@@ -119,8 +122,10 @@ def test_bf16_ensemble_halves_leaf_bytes(model):
     fpb = FusedPredictor(b.models, precision="bf16")
     assert fpb.ens.path_sign.dtype == "bfloat16"
     assert fpb.ens.leaf_value.dtype == "bfloat16"
-    assert (fpb.ens.path_sign.nbytes + fpb.ens.leaf_value.nbytes) * 2 \
-        == fp.ens.path_sign.nbytes + fp.ens.leaf_value.nbytes
+    tier_bytes = fpb.ens.path_sign.nbytes + fpb.ens.leaf_value.nbytes
+    exact_bytes = fp.ens.path_sign.nbytes + fp.ens.leaf_value.nbytes
+    assert tier_bytes * 2 == exact_bytes
+    assert tier_bytes / exact_bytes <= _budgets()["bf16_bytes_ratio_max"]
 
 
 # ---- validation + contrib rejection (no silent upgrades) ----
@@ -218,14 +223,13 @@ def test_compact_booster_reduces_and_stays_in_budget(model):
                   n=2000, features=10)
     gen, stats = compact_booster(b, leaf_codes=255, prune_frac=0.05,
                                  leaf_cap=24)
-    assert stats["tree_reduction"] > 0.0
-    assert stats["byte_reduction"] > 0.0
+    budgets = _budgets()
+    assert stats["tree_reduction"] >= budgets["compact_tree_reduction_min"]
+    assert stats["byte_reduction"] >= budgets["compact_byte_reduction_min"]
     assert stats["max_leaves_out"] <= 24 < stats["max_leaves_in"]
     y = (np.asarray(b.predict(X, raw_score=True)) > 0).astype(np.float64)
     meas = measure_compaction(b, gen, X[:1000], y=y[:1000])
     assert meas["max_score_delta"] <= stats["declared_max_score_delta"]
-    with open(os.path.join(REPO, "PERF_BUDGETS.json")) as fh:
-        budgets = json.load(fh)["budgets"]
     assert meas["auc_delta"] <= budgets["compact_auc_delta_max"]
     # immutable-generation discipline: text round-trip is exact
     gen2 = GBDT(gen.config)
@@ -288,54 +292,6 @@ def test_quality_plane_no_per_tier_false_alarm(model):
     assert info["score_psi"] is not None
     assert info["level"] == "ok", \
         "mixed-tier traffic on in-distribution rows must not alarm"
-
-
-# ---- the gate is operational: doctored artifacts FAIL ----
-
-def test_perf_gate_fails_doctored_over_budget_artifact(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import perf_gate
-    finally:
-        sys.path.pop(0)
-    src = os.path.join(REPO, "BENCH_precision_interp.json")
-    with open(src) as fh:
-        doc = json.load(fh)
-    budgets = os.path.join(REPO, "PERF_BUDGETS.json")
-    # the committed artifact passes as-is
-    assert perf_gate.run_gate([src], budgets) == 0
-    with open(budgets) as fh:
-        bspec = json.load(fh)["budgets"]
-    # doctor 1: bf16 delta over budget
-    bad = json.loads(json.dumps(doc))
-    bad["precision"]["bf16"]["max_score_delta"] = \
-        bspec["bf16_max_score_delta"] * 2.0
-    p1 = str(tmp_path / "over_delta.json")
-    with open(p1, "w") as fh:
-        json.dump(bad, fh)
-    assert perf_gate.run_gate([p1], budgets) == 1
-    # doctor 2: compaction AUC over budget
-    bad = json.loads(json.dumps(doc))
-    bad["compaction"]["auc_delta"] = bspec["compact_auc_delta_max"] * 3.0
-    p2 = str(tmp_path / "over_auc.json")
-    with open(p2, "w") as fh:
-        json.dump(bad, fh)
-    assert perf_gate.run_gate([p2], budgets) == 1
-    # doctor 3: a lossy tier with no declared budget line fails loudly
-    bad = json.loads(json.dumps(doc))
-    bad["precision"]["f8"] = dict(bad["precision"]["bf16"])
-    p3 = str(tmp_path / "no_budget.json")
-    with open(p3, "w") as fh:
-        json.dump(bad, fh)
-    assert perf_gate.run_gate([p3], budgets) == 1
-    # doctor 4: measured compaction delta above its own declared bound
-    bad = json.loads(json.dumps(doc))
-    bad["compaction"]["max_score_delta"] = \
-        bad["compaction"]["declared_max_score_delta"] * 1.5
-    p4 = str(tmp_path / "bound_broken.json")
-    with open(p4, "w") as fh:
-        json.dump(bad, fh)
-    assert perf_gate.run_gate([p4], budgets) == 1
 
 
 # ---- obs: tier split renders live and from raw events ----

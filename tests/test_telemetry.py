@@ -190,16 +190,11 @@ def test_summary_artifact_contents(tmp_path):
                for k in summary["histograms"])
     # recompile counts are keyed per (function, shape bucket)
     assert any(k.startswith("fused_train|") for k in summary["recompiles"])
-    # MFU estimate fields present (ratios None off-accelerator, but the
-    # analytic flop/byte gauges must be there)
-    assert "mfu" in summary and "device_util" in summary
     # resilience rollup (round 11): the fault counters ride every summary
     res = summary["resilience"]
     assert res["preemptions"] == 0 and res["io_retries"] == 0
     assert res["predict_fallbacks"] == 0 and res["checkpoint_skipped"] == 0
     assert res["preempt_checkpoint_s"]["count"] == 0
-    assert summary["gauges"]["est_macs"] > 0
-    assert summary["gauges"]["est_bytes"] > 0
     # the driver's train-loop gauges win over finalize_run's wall_s arg
     assert summary["wall_s"] != 1.0
     assert summary["value"] == pytest.approx(

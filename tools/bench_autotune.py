@@ -1,30 +1,27 @@
 #!/usr/bin/env python
-"""Kernel-plan autotuning sweep -> persisted plan cache + BENCH artifact.
+"""Kernel-plan autotuning sweep -> persisted plan cache.
 
 Runs the empirical planner (``lightgbm_tpu/plan/autotune.py``) over a
 shape grid: for every (shape-class, device_kind) it races the candidate
 tilings — bucket-ladder variants of the fused split dispatch and
 tree-block VMEM budgets of the blocked predict — with walls ranked on
 the compile-accounting steady-median machinery (warm loads and compiles
-never pollute the ranking), then
-
-- persists the winners into the atomic, versioned JSON plan cache
-  (``--cache-out``, default next to the XLA compilation cache — exactly
-  where the CLI / engine look for it), and
-- writes a ``BENCH_autotune`` artifact (``--json``): the full candidate
-  table, winner and margin per shape, in the BENCH shape
-  ``tools/perf_gate.py`` knows how to gate.
+never pollute the ranking), then persists the winners into the atomic,
+versioned JSON plan cache (``--cache-out``, default next to the XLA
+compilation cache — exactly where the CLI / engine look for it).  With
+``--json`` it also writes the full candidate table, winner and margin per
+shape.
 
 Off-TPU the fused kernels run in interpret mode (``--interpret`` is
-implied): candidate walls are interpreter-priced and NON-EVIDENCE — the
-artifact is a mechanism proof.  The hardware protocol (PERF.md round 18)
-is this command on a real TPU with the default grid.
+implied): candidate walls are interpreter-priced and NON-EVIDENCE — a
+mechanism proof.  No cell of the benchmark runs a tuned plan and the
+planner has not been raced on the chip (ROADMAP C3).
 
 Examples::
 
     python tools/bench_autotune.py --shape 65536:28:256 --reps 4 \
-        --cache-out /tmp/plan_cache.json --json BENCH_autotune.json
-    python tools/bench_autotune.py --grid default   # PERF.md protocol
+        --cache-out /tmp/plan_cache.json --json /tmp/autotune.json
+    python tools/bench_autotune.py --grid default
 """
 import argparse
 import json
@@ -33,7 +30,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# the PERF.md round-18 grid: Higgs-like tall, wide-F factored, wide-F
+# the round-18 grid: Higgs-like tall, wide-F factored, wide-F
 # classic, multiclass — one row per workload-zoo shape family
 DEFAULT_GRID = ("1048576:28:256", "65536:968:64", "65536:600:256",
                 "262144:54:64:5")
@@ -52,14 +49,13 @@ def parse_shape(spec: str):
 
 def build_parser():
     ap = argparse.ArgumentParser(
-        description="Kernel-plan autotuning sweep (plan cache + "
-                    "BENCH_autotune artifact)")
+        description="Kernel-plan autotuning sweep (writes the plan cache)")
     ap.add_argument("--shape", action="append", type=parse_shape,
                     metavar="N:F:BINS[:K]", default=None,
                     help="shape class to tune (repeatable); default: "
                          "one small smoke shape")
     ap.add_argument("--grid", choices=["default"], default=None,
-                    help="use the PERF.md round-18 shape grid")
+                    help="use the round-18 shape grid")
     ap.add_argument("--reps", type=int, default=4,
                     help="steady-state repetitions per candidate "
                          "(first dispatch is the counted miss)")
@@ -71,8 +67,8 @@ def build_parser():
     ap.add_argument("--cache-out", default=None,
                     help="plan cache path (default: the location the "
                          "CLI/engine probe, next to the XLA cache)")
-    ap.add_argument("--json", default="BENCH_autotune.json",
-                    help="BENCH artifact path")
+    ap.add_argument("--json", default=None,
+                    help="also write the candidate tables here")
     ap.add_argument("--scale-rows", type=int, default=None,
                     help="cap synthetic fixture rows (tuning still keys "
                          "the cache by the REQUESTED shape class); use "
@@ -123,10 +119,11 @@ def main(argv=None) -> int:
         "cache": cache_path,
         "shapes": sweep["shapes"],
     }
-    with open(args.json, "w") as fh:
-        json.dump(artifact, fh, indent=1)
     print("plan cache -> %s" % cache_path)
-    print("artifact   -> %s" % args.json)
+    if args.json:
+        with open(args.json, "w") as fh:
+            json.dump(artifact, fh, indent=1)
+        print("candidates -> %s" % args.json)
     return 0
 
 

@@ -1,23 +1,14 @@
 #!/usr/bin/env python
-"""End-to-end performance-forensics drill (round 16) + baseline generator.
+"""End-to-end performance-forensics drill (round 16).
 
-Two modes:
-
-- default (the DRILL): prove the whole forensics plane live on this box.
-  Trains a small model, arms EVERYTHING (alert engine with a doctored
-  p99 rule, flight recorder, live exporter), serves traffic, and asserts:
-  a burn-rate alert fires on ``/alerts``; the alert triggers EXACTLY ONE
-  profiler capture artifact (bounded, never recursive); ``/metrics``
-  scrapes well-formed with compile accounting (and device-memory gauges
-  on backends that report them); steady-state recompiles stay 0 with
-  everything armed.  Exit 0 = the acceptance drill passed.
-
-- ``--baseline OUT.json``: record a HEALTHY run's telemetry summary as a
-  committed perf-gate baseline (``PERF_BUDGETS.json`` names it under
-  ``baselines.telemetry``): telemetry from process start so warmup
-  compiles land in the compile section, the repo alert rules armed (zero
-  fired on a healthy run), a steady timed window with the
-  ``recompiles_timed_window`` gauge pinned the way bench.py pins it.
+Proves the whole forensics plane live on this box.  Trains a small model,
+arms EVERYTHING (alert engine with a doctored p99 rule, flight recorder,
+live exporter), serves traffic, and asserts: a burn-rate alert fires on
+``/alerts``; the alert triggers EXACTLY ONE profiler capture artifact
+(bounded, never recursive); ``/metrics`` scrapes well-formed with compile
+accounting (and device-memory gauges on backends that report them);
+steady-state recompiles stay 0 with everything armed.  Exit 0 = the
+acceptance drill passed.
 
 Small CPU shapes; runs anywhere with ``JAX_PLATFORMS=cpu``.
 """
@@ -148,64 +139,11 @@ def run_drill(workdir: str) -> int:
     return 0
 
 
-def run_baseline(out_json: str, workdir: str) -> int:
-    from lightgbm_tpu import obs
-    from lightgbm_tpu.obs import alerts as obs_alerts
-    from lightgbm_tpu.obs.report import finalize_run
-    from lightgbm_tpu.serving import Server
-    out = os.path.join(workdir, "baseline.jsonl")
-    booster, X = _build()
-    # telemetry from the very start: the warmup compiles ARE the compile
-    # section this baseline pins the regression factor against
-    tele = obs.configure(out=out, freq=1, entry="forensics_baseline")
-    obs_alerts.install(tele, rules_path=os.path.join(REPO,
-                                                     "PERF_BUDGETS.json"),
-                       interval_s=0.2)
-    t0 = time.perf_counter()
-    booster.train_chunk(4)     # compiles
-    booster.train_chunk(4)     # steady: prices them
-    booster.predict(X[:600])
-    booster.predict(X[:600])
-    with Server(max_batch_wait_us=0) as srv:
-        srv.register("baseline", booster)
-        for _ in range(8):
-            srv.predict("baseline", X[:64])
-        # the timed steady window, pinned the way bench.py pins it
-        obs.recompile.reset()
-        booster.train_chunk(4)
-        for _ in range(8):
-            srv.predict("baseline", X[:64])
-        tele.gauge("recompiles_timed_window").set(obs.recompile.total())
-    time.sleep(0.5)  # a few alert-engine ticks over the final state
-    summary = finalize_run(tele, gbdt=booster,
-                           wall_s=time.perf_counter() - t0, iters=12)
-    obs.disable()
-    fired = (summary.get("alerts") or {}).get("fired_total", 0)
-    if fired:
-        print("healthy baseline fired %d alert(s) — refusing to commit it"
-              % fired, file=sys.stderr)
-        return 1
-    with open(out_json, "w") as fh:
-        json.dump(summary, fh, indent=1, default=str)
-    print("wrote baseline %s (compile %.4gs over %d keys, alerts 0, "
-          "recompiles_timed_window %d)"
-          % (out_json,
-             (summary.get("compile") or {}).get("compile_seconds_total", 0),
-             len((summary.get("compile") or {}).get("keys", {})),
-             int(summary["gauges"]["recompiles_timed_window"])))
-    return 0
-
-
 def build_parser():
     ap = argparse.ArgumentParser(
         description="end-to-end performance-forensics drill (doctored p99 "
                     "breach -> burn-rate alert -> one flight-recorder "
-                    "capture; /metrics well-formed; steady recompiles 0) "
-                    "or, with --baseline, record a healthy telemetry "
-                    "summary as the committed perf-gate baseline")
-    ap.add_argument("--baseline", metavar="OUT.json", default=None,
-                    help="record a healthy-run summary artifact instead "
-                         "of running the drill")
+                    "capture; /metrics well-formed; steady recompiles 0)")
     ap.add_argument("--workdir", default=None,
                     help="scratch dir (default: a fresh tempdir)")
     return ap
@@ -218,8 +156,6 @@ def main(argv=None) -> int:
     workdir = args.workdir or tempfile.mkdtemp(prefix="forensics_drill_")
     from lightgbm_tpu.utils.log import Log
     Log.reset_level(30)
-    if args.baseline:
-        return run_baseline(args.baseline, workdir)
     return run_drill(workdir)
 
 

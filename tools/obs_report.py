@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Render a telemetry JSONL (lightgbm_tpu/obs) into human/trace artifacts.
 
-Any run with ``telemetry_out=<path>`` set (engine.train, the CLI,
-bench.py) writes a schema-versioned JSONL event stream plus
+Any run with ``telemetry_out=<path>`` set (engine.train, the CLI)
+writes a schema-versioned JSONL event stream plus
 ``<path>.summary.json``; a pod run writes one ``<path>.rank<k>.jsonl``
 shard per host.  This tool turns those into things people read:
 
@@ -484,7 +484,7 @@ def summary_from_events(events):
         "histograms": {k: h.summary() for k, h in sorted(hists.items())},
         "counters": {"events_" + k: v for k, v in sorted(counters.items())},
         "host_phases": {}, "gauges": {},
-        "mfu": None, "device_util": None, "events": n_events,
+        "events": n_events,
     }
 
 

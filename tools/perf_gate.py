@@ -1,46 +1,26 @@
 #!/usr/bin/env python
-"""Perf gate: compare telemetry/BENCH artifacts against declared budgets.
+"""Gate a run's telemetry summary against the declared counts.
 
-The repo's perf invariants lived in prose (PERF.md) and in eyeballs; this
-tool makes them a gate a CI step (or an operator after a hardware pass)
-can run::
+    python tools/perf_gate.py out.jsonl.summary.json [more.summary.json ...]
 
-    python tools/perf_gate.py                       # committed artifacts
-    python tools/perf_gate.py out.jsonl.summary.json BENCH_serve.json
+Reads the ``budgets`` of ``PERF_BUDGETS.json`` (repo root; ``--budgets``
+overrides) and fails a summary (``<telemetry_out>.summary.json``) on what a
+run COUNTS, never on what it timed:
 
-``PERF_BUDGETS.json`` (repo root; ``--budgets`` overrides) declares the
-budgets:
+- ``recompiles_steady`` -- the ``recompiles_timed_window`` gauge a driver
+  pins after warm-up (a plain run includes its warm-up compiles and
+  carries no such gauge);
+- ``serving_dropped`` / ``serving_rejected_max`` / ``serving_failed_max``
+  -- the serving tier's never-drop contract;
+- ``alerts_fired_max`` -- live alerts a healthy run fired;
+- ``plan_cache_fallbacks_max`` -- tuned-plan cache reads that fell back,
+  and every stamped plan site names a known provenance;
+- a watchdog stall recorded by the resilience plane.
 
-- ``recompiles_steady == 0`` — the steady-state no-recompile invariant,
-  checked on bench/serve artifacts that carry the gauge and on telemetry
-  summaries recorded after warmup;
-- ``serving_dropped == 0`` / ``serving_rejected_max`` /
-  ``serving_failed_max`` — the serving tier's never-drop contract;
-- level-mode launch structure — ``launches/tree <= depth * classes``
-  (and strictly fewer than leaf-wise) on split-cost artifacts;
-- regression factors (``serve_p99_regression``,
-  ``ns_per_row_p50_regression``) vs the committed baseline artifacts named
-  under ``baselines`` — a new artifact may not be worse than baseline by
-  more than the factor;
-- quality-plane budgets — a monitor-on serving summary keeps
-  ``serving.dropped == 0`` (plus the recompile gauge above) and every
-  model's ``quality.*.overhead_ns_per_row`` under
-  ``quality_overhead_ns_per_row_max``;
-- forensics budgets (round 16) — a summary carrying an ``alerts``
-  section fired at most ``alerts_fired_max`` live alerts (0: a healthy
-  baseline never pages), and its ``compile.compile_seconds_total`` may
-  not exceed the committed telemetry baseline's by more than
-  ``compile_seconds_regression``;
-- explanations budgets (round 19) — a bench-serve artifact carrying a
-  ``contrib`` block completed every contrib window (failed == 0, and the
-  artifact-wide dropped/recompile gauges cover contrib traffic too) with
-  the worst contrib p99 within ``contrib_p99_factor`` of the same
-  artifact's score headline.
-
-Artifact types live in one declarative REGISTRY table (predicate +
-gate function per type), so one invocation can gate a mixed pile; an
-artifact matching no registry row fails loudly naming the file.  Exit
-status: 0 all pass, 1 any breach, 2 unreadable/unidentifiable input.
+Speed is not gated here: it is measured on the chip by
+``benchmarks/run.py`` and recorded in ``PERF_LEDGER.jsonl``.  Exit status:
+0 all pass, 1 any breach, 2 an unreadable file or one that is not a
+telemetry summary.
 """
 import argparse
 import json
@@ -48,13 +28,14 @@ import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
 
 DEFAULT_BUDGETS = os.path.join(REPO, "PERF_BUDGETS.json")
 
+KNOWN_PROVENANCE = ("analytic", "tuned", "pinned")
+
 
 class Gate:
-    """Collects per-check verdicts; one artifact may yield several."""
+    """Collects per-check verdicts; one summary yields several."""
 
     def __init__(self):
         self.failures = 0
@@ -67,8 +48,9 @@ class Gate:
         print("%s %s: %s (%s)" % ("PASS" if ok else "FAIL",
                                   os.path.basename(artifact), name, detail))
 
-    def skip(self, artifact: str, name: str, why: str) -> None:
-        print("SKIP %s: %s (%s)" % (os.path.basename(artifact), name, why))
+    def at_most(self, artifact: str, name: str, value, budget) -> None:
+        self.check(artifact, name, int(value) <= int(budget),
+                   "%s <= %s" % (value, budget))
 
 
 def _load(path: str):
@@ -76,517 +58,76 @@ def _load(path: str):
         return json.load(fh)
 
 
-def _baseline(budgets_path: str, budgets: dict, key: str):
-    rel = (budgets.get("baselines") or {}).get(key)
-    if not rel:
-        return None, None
-    path = os.path.join(os.path.dirname(os.path.abspath(budgets_path)), rel)
-    if not os.path.exists(path):
-        return None, path
-    return _load(path), path
-
-
-def sniff(doc) -> str:
-    """Artifact type from the registry (first matching row)."""
-    if not isinstance(doc, dict):
-        return "unknown"
-    for kind, match, _gate in REGISTRY:
-        if match(doc):
-            return kind
-    return "unknown"
-
-
-def gate_serve(g: Gate, path: str, doc: dict, b: dict, baseline) -> None:
-    g.check(path, "serving dropped", int(doc.get("dropped", 0))
-            <= int(b.get("serving_dropped", 0)),
-            "dropped=%s" % doc.get("dropped"))
-    g.check(path, "serving rejected", int(doc.get("rejected", 0))
-            <= int(b.get("serving_rejected_max", 0)),
-            "rejected=%s" % doc.get("rejected"))
-    if "recompiles_steady" in doc:
-        g.check(path, "recompiles steady",
-                int(doc["recompiles_steady"])
-                <= int(b.get("recompiles_steady", 0)),
-                "recompiles_steady=%s" % doc["recompiles_steady"])
-    online = doc.get("online")
-    if online is not None:
-        # the train-while-serve cell (bench_serve --online): the timed
-        # windows are only evidence of serving-under-retrain if a swap
-        # actually landed inside them
-        g.check(path, "online retrain swaps", int(doc.get("swaps", 0)) >= 1,
-                "swaps=%s cycles=%s" % (doc.get("swaps"),
-                                        online.get("cycles")))
-        factor = b.get("serve_p99_online_factor")
-        if factor and baseline and baseline.get("value"):
-            worst = float(doc.get("value", 0.0))
-            base = float(baseline["value"])
-            g.check(path, "online p99 vs serve baseline",
-                    worst <= base * float(factor),
-                    "p99-under-retrain %.4gs vs serve %.4gs "
-                    "(bar %.4gs = %.2fx)"
-                    % (worst, base, base * float(factor), float(factor)))
-        elif factor:
-            g.skip(path, "online p99 vs serve baseline",
-                   "no serve baseline artifact")
-        return
-    factor = b.get("serve_p99_regression")
-    if factor and baseline and baseline.get("value"):
-        worst = float(doc.get("value", 0.0))
-        base = float(baseline["value"])
-        g.check(path, "serve p99 regression",
-                worst <= base * float(factor),
-                "worst p99 %.4gs vs baseline %.4gs (bar %.4gs = %.2fx)"
-                % (worst, base, base * float(factor), float(factor)))
-    elif factor:
-        g.skip(path, "serve p99 regression", "no serve baseline artifact")
-    # explanations cells (round 19, bench_serve --contrib): every contrib
-    # window completed, and the worst contrib p99 stays within the
-    # declared factor of the SAME artifact's score headline — TreeSHAP is
-    # O(depth^2)/row vs O(depth) for a score, so the factor budgets the
-    # inherent cost without letting it regress silently
-    ctb = doc.get("contrib")
-    if ctb is not None:
-        cells = ctb.get("grid") or []
-        g.check(path, "contrib cells complete",
-                bool(cells) and all(int(c.get("failed", 0)) == 0
-                                    for c in cells),
-                "cells=%d failed=%s" % (len(cells),
-                                        sum(int(c.get("failed", 0))
-                                            for c in cells)))
-        cfac = b.get("contrib_p99_factor")
-        score_p99 = doc.get("value")
-        if cfac and ctb.get("value") is not None and score_p99:
-            worst_c = float(ctb["value"])
-            bar = float(score_p99) * float(cfac)
-            g.check(path, "contrib p99 vs score cells",
-                    worst_c <= bar,
-                    "contrib p99 %.4gs vs score %.4gs (bar %.4gs = %.0fx)"
-                    % (worst_c, float(score_p99), bar, float(cfac)))
-        elif cfac:
-            g.skip(path, "contrib p99 vs score cells",
-                   "no score headline to compare against")
-    # lossy-tier cells (round 20, bench_serve --precision): every tier's
-    # measured score delta within its declared per-tier budget, every
-    # window complete — the error budget is a gate, not a footnote
-    for tier, block in sorted((doc.get("precision") or {}).items()):
-        bkey = "%s_max_score_delta" % tier
-        bar = b.get(bkey)
-        md = block.get("max_score_delta")
-        if bar is None:
-            g.check(path, "budget declared [%s]" % tier, False,
-                    "lossy tier %r has no %s line in the budgets"
-                    % (tier, bkey))
-        else:
-            g.check(path, "score delta within budget [%s]" % tier,
-                    md is not None and float(md) <= float(bar),
-                    "max|delta| %s <= %s" % (md, bar))
-        cells = block.get("grid") or []
-        g.check(path, "tier cells complete [%s]" % tier,
-                bool(cells) and all(int(c.get("failed", 0)) == 0
-                                    for c in cells),
-                "cells=%d failed=%s"
-                % (len(cells), sum(int(c.get("failed", 0))
-                                   for c in cells)))
-
-
-def gate_split_cost(g: Gate, path: str, doc: dict, b: dict) -> None:
-    lvl = doc.get("level")
-    if not lvl:
-        g.skip(path, "level launch structure", "no level block")
-        return
-    per_tree = (lvl.get("launches_per_tree") or {})
-    level = per_tree.get("level")
-    leaf = per_tree.get("leaf")
-    depth = lvl.get("depth")
-    classes = lvl.get("bucket_classes")
-    if level is None or depth is None or classes is None:
-        g.skip(path, "level launch structure", "level block incomplete")
-    else:
-        bound = float(depth) * float(classes)
-        g.check(path, "level launches/tree <= depth*classes",
-                float(level) <= bound,
-                "%.1f <= %d*%d" % (float(level), depth, classes))
-        if leaf is not None:
-            g.check(path, "level launches/tree < leaf-wise",
-                    float(level) < float(leaf),
-                    "%.1f < %.1f" % (float(level), float(leaf)))
-    amort = lvl.get("intercept_amortization")
-    bar = b.get("level_intercept_amortization_min")
-    if amort is not None and bar is not None:
-        g.check(path, "level intercept amortization",
-                float(amort) >= float(bar),
-                "%.2fx >= %.2fx" % (float(amort), float(bar)))
-
-
-def gate_autotune(g: Gate, path: str, doc: dict, b: dict) -> None:
-    """BENCH_autotune artifacts (round 18): every tuned shape raced a
-    real field of candidates, produced a winner, and the winner never
-    LOST to the analytic incumbent (margin >= the declared floor — the
-    tuner may tie analytic, i.e. pick it, but a cache that persists a
-    slower-than-analytic plan is a regression by construction)."""
-    shapes = doc.get("shapes") or []
-    g.check(path, "autotune shapes present", len(shapes) >= 1,
-            "shapes=%d" % len(shapes))
-    min_cands = int(b.get("plan_autotune_min_candidates", 2))
-    margin_min = float(b.get("plan_autotune_margin_min", 1.0))
-    for res in shapes:
-        key = res.get("key", "?")
-        cands = res.get("candidates") or []
-        g.check(path, "candidates raced [%s]" % key,
-                len(cands) >= min_cands,
-                "%d >= %d" % (len(cands), min_cands))
-        win = res.get("winner") or {}
-        plan = win.get("plan") or {}
-        g.check(path, "winner persisted [%s]" % key,
-                bool(plan) and plan.get("provenance") == "tuned",
-                "winner=%s provenance=%s" % (win.get("name"),
-                                             plan.get("provenance")))
-        for metric, m in sorted((res.get("margin") or {}).items()):
-            g.check(path, "winner margin %s [%s]" % (metric, key),
-                    float(m) >= margin_min,
-                    "%.3fx >= %.2fx (analytic/winner steady p50)"
-                    % (float(m), margin_min))
-
-
-def gate_precision(g: Gate, path: str, doc: dict, b: dict) -> None:
-    """BENCH_precision artifacts (round 20): every lossy path within its
-    declared error budget, the exact path untouched, and the lossy tiers
-    actually paying for themselves (bytes-per-row-tree win; compaction at
-    or above its declared reduction floors).  Budgets are per-tier
-    (``<tier>_max_score_delta``) so a future f8 tier gets its own line."""
-    tiers = doc.get("precision") or {}
-    for tier, cell in sorted(tiers.items()):
-        bkey = "%s_max_score_delta" % tier
-        bar = b.get(bkey)
-        if bar is None:
-            g.check(path, "budget declared [%s]" % tier, False,
-                    "lossy tier %r has no %s line in the budgets — every "
-                    "lossy path must carry a declared budget" % (tier, bkey))
-            continue
-        md = cell.get("max_score_delta")
-        g.check(path, "score delta within budget [%s]" % tier,
-                md is not None and float(md) <= float(bar),
-                "max|delta| %s <= %s" % (md, bar))
-        bratio = cell.get("bytes_ratio")
-        bmax = b.get("%s_bytes_ratio_max" % tier)
-        if bratio is not None and bmax is not None:
-            g.check(path, "bytes/row-tree win [%s]" % tier,
-                    float(bratio) <= float(bmax),
-                    "%.3fx <= %.3fx (ens bytes vs exact)"
-                    % (float(bratio), float(bmax)))
-        if cell.get("recompiles_steady") is not None:
-            g.check(path, "recompiles steady [%s]" % tier,
-                    int(cell["recompiles_steady"])
-                    <= int(b.get("recompiles_steady", 0)),
-                    "recompiles_steady=%s" % cell["recompiles_steady"])
-    comp = doc.get("compaction")
-    if comp is not None:
-        bar = b.get("compact_auc_delta_max")
-        ad = comp.get("auc_delta")
-        if bar is not None:
-            g.check(path, "compaction auc delta",
-                    ad is not None and float(ad) <= float(bar),
-                    "auc_delta %s <= %s" % (ad, bar))
-        g.check(path, "compaction declared bound holds",
-                comp.get("max_score_delta") is not None
-                and comp.get("declared_max_score_delta") is not None
-                and float(comp["max_score_delta"])
-                <= float(comp["declared_max_score_delta"]),
-                "measured %s <= declared %s"
-                % (comp.get("max_score_delta"),
-                   comp.get("declared_max_score_delta")))
-        for metric, floor_key in (("tree_reduction",
-                                   "compact_tree_reduction_min"),
-                                  ("byte_reduction",
-                                   "compact_byte_reduction_min")):
-            floor = b.get(floor_key)
-            val = comp.get(metric)
-            if floor is not None and val is not None:
-                g.check(path, "compaction %s" % metric,
-                        float(val) >= float(floor),
-                        "%.3f >= %.3f" % (float(val), float(floor)))
-    if not tiers and comp is None:
-        g.skip(path, "precision budgets", "no lossy cells in artifact")
-
-
-def gate_ingest(g: Gate, path: str, doc: dict, b: dict) -> None:
-    """BENCH_ingest artifact (tools/bench_ingest.py): the streaming loader
-    must be bit-identical to the one-shot path, match the serial store under
-    2-virtual-rank sharded assembly, and buy its bounded RSS without giving
-    back more throughput than the declared factor."""
-    g.check(path, "ingest bit-identical digests",
-            doc.get("bit_identical") is True,
-            "streaming sha256(mappers+store+label) == in-memory, all cells")
-    g.check(path, "ingest sharded assembly matches serial",
-            doc.get("sharded_digest_match") is True,
-            str(doc.get("sharded_error",
-                        "2-rank schema digests agree, concat store == serial")))
-    ceil = b.get("ingest_rss_ratio_max")
-    if ceil is not None and doc.get("rss_ratio") is not None:
-        g.check(path, "ingest streaming peak-RSS ratio",
-                float(doc["rss_ratio"]) <= float(ceil),
-                "%.3f <= %.3f" % (float(doc["rss_ratio"]), float(ceil)))
-    else:
-        g.skip(path, "ingest streaming peak-RSS ratio",
-               "no ingest_rss_ratio_max budget or ratio in artifact")
-    floor = b.get("ingest_rows_per_s_factor_min")
-    if floor is not None and doc.get("rows_per_s_factor") is not None:
-        g.check(path, "ingest streaming rows/s factor",
-                float(doc["rows_per_s_factor"]) >= float(floor),
-                "%.3f >= %.3f" % (float(doc["rows_per_s_factor"]),
-                                  float(floor)))
-    else:
-        g.skip(path, "ingest streaming rows/s factor",
-               "no ingest_rows_per_s_factor_min budget or factor in artifact")
-
-
-def gate_hist_quant(g: Gate, path: str, doc: dict, b: dict) -> None:
-    """BENCH_hist_quant artifacts (round 22, tools/bench_hist_quant.py):
-    quantized-gradient training is LOSSY, so an artifact with no declared
-    budget line FAILS outright (the round-20 rule: the error budget is a
-    gate, not a footnote).  Within budgets, the score/AUC deltas must
-    hold, the operand halving must be real, and the correctness half of
-    the contract — seed-determinism and XLA-vs-Pallas bit-parity — must
-    be true, not approximately true."""
-    q = doc.get("quant") or {}
-    for bkey, field, label in (
-            ("quant_max_score_delta", "max_score_delta", "score delta"),
-            ("quant_auc_delta_max", "auc_delta", "auc delta")):
-        bar = b.get(bkey)
-        if bar is None:
-            g.check(path, "budget declared [%s]" % bkey, False,
-                    "lossy quantized artifact has no %s line in the "
-                    "budgets — every lossy path must carry a declared "
-                    "budget" % bkey)
-            continue
-        val = q.get(field)
-        g.check(path, "%s within budget [quant]" % label,
-                val is not None and float(val) <= float(bar),
-                "%s %s <= %s" % (field, val, bar))
-    ratio = (doc.get("operand") or {}).get("bytes_ratio")
-    rmax = b.get("quant_bytes_ratio_max")
-    if ratio is not None and rmax is not None:
-        g.check(path, "operand bytes/row halved",
-                float(ratio) <= float(rmax),
-                "%.3f <= %.3f (2-row vs 4-row bf16 operand)"
-                % (float(ratio), float(rmax)))
-    g.check(path, "quantized training deterministic",
-            q.get("deterministic") is True,
-            "same seed twice -> byte-identical scores")
-    g.check(path, "backend bit-parity",
-            q.get("backend_bit_exact") is True,
-            "XLA fallback == fused Pallas interpret, bit-exact")
-
-
-def gate_bench_line(g: Gate, path: str, doc: dict, b: dict) -> None:
-    if "recompiles_steady" in doc:
-        g.check(path, "recompiles steady",
-                int(doc["recompiles_steady"])
-                <= int(b.get("recompiles_steady", 0)),
-                "recompiles_steady=%s" % doc["recompiles_steady"])
-    else:
-        g.skip(path, "recompiles steady", "gauge not in artifact")
-
-
-def gate_summary(g: Gate, path: str, doc: dict, b: dict,
-                 baseline_summary, forensics_baseline=None) -> None:
+def gate_summary(g: Gate, path: str, doc: dict, b: dict) -> None:
     gauges = doc.get("gauges") or {}
-    # bench self-recording runs carry the timed-window gauge; plain runs
-    # include warmup compiles, where a zero bar would be meaningless
     if gauges.get("recompiles_timed_window") is not None:
-        g.check(path, "recompiles steady",
-                int(gauges["recompiles_timed_window"])
-                <= int(b.get("recompiles_steady", 0)),
-                "recompiles_timed_window=%s"
-                % gauges["recompiles_timed_window"])
+        g.at_most(path, "recompiles steady",
+                  gauges["recompiles_timed_window"],
+                  b.get("recompiles_steady", 0))
     res = doc.get("resilience") or {}
     if res.get("watchdog_stall_s") is not None:
         g.check(path, "no watchdog stall", False,
                 "watchdog_stall_s=%s" % res["watchdog_stall_s"])
     srv = doc.get("serving")
     if srv:
-        g.check(path, "serving failed", int(srv.get("failed", 0))
-                <= int(b.get("serving_failed_max", 0)),
-                "failed=%s" % srv.get("failed", 0))
-        g.check(path, "serving rejected", int(srv.get("rejected", 0))
-                <= int(b.get("serving_rejected_max", 0)),
-                "rejected=%s" % srv.get("rejected", 0))
+        g.at_most(path, "serving failed", srv.get("failed", 0),
+                  b.get("serving_failed_max", 0))
+        g.at_most(path, "serving rejected", srv.get("rejected", 0),
+                  b.get("serving_rejected_max", 0))
         if srv.get("dropped") is not None:
-            g.check(path, "serving dropped", int(srv["dropped"])
-                    <= int(b.get("serving_dropped", 0)),
-                    "dropped=%s" % srv["dropped"])
-    # quality-plane budgets: a monitor-on run must keep its host-side
-    # folding cost under the declared ns/row cap (the recompile and
-    # dropped checks above already pin the other monitor-on invariants)
-    qual = doc.get("quality") or {}
-    cap = b.get("quality_overhead_ns_per_row_max")
-    for m, info in sorted((qual.get("models") or {}).items()):
-        ov = info.get("overhead_ns_per_row")
-        if cap is not None and ov is not None:
-            g.check(path, "quality overhead ns/row [%s]" % m,
-                    float(ov) <= float(cap),
-                    "%.1f <= %.1f" % (float(ov), float(cap)))
-    factor = b.get("ns_per_row_p50_regression")
-    cur = ((doc.get("ns_per_row") or {}).get("p50"))
-    base = ((baseline_summary or {}).get("ns_per_row") or {}).get("p50") \
-        if baseline_summary else None
-    if factor and cur is not None and base:
-        g.check(path, "ns/row p50 regression",
-                float(cur) <= float(base) * float(factor),
-                "%.4g vs baseline %.4g (%.2fx bar)"
-                % (float(cur), float(base), float(factor)))
-    elif factor and cur is not None:
-        g.skip(path, "ns/row p50 regression", "no telemetry baseline")
-    # forensics budgets (round 16): a healthy baseline artifact fired
-    # zero live alerts, and its compile wall-seconds may not regress
-    # beyond the declared factor (a kernel change that doubles compile
-    # time is a real cost the autotuner data must not silently absorb)
+            g.at_most(path, "serving dropped", srv["dropped"],
+                      b.get("serving_dropped", 0))
     al = doc.get("alerts")
     if al is not None:
-        g.check(path, "alerts fired", int(al.get("fired_total", 0))
-                <= int(b.get("alerts_fired_max", 0)),
-                "fired_total=%s" % al.get("fired_total", 0))
-    # the compile factor compares against the dedicated forensics
-    # baseline (a run recorded WITH warmup compiles in frame); the
-    # ns/row baseline above stays reserved for a steady-state BENCH
-    # artifact — the two are different regimes by construction
-    # kernel-plan provenance (round 18): a summary carrying a plan block
-    # must name a known provenance for every stamped site — a BENCH
-    # number whose plan cannot be identified is not reproducible — and a
-    # steady-state baseline may not have absorbed plan-cache fallbacks.
-    # Summaries from before the planner (no block) pass untouched.
+        g.at_most(path, "alerts fired", al.get("fired_total", 0),
+                  b.get("alerts_fired_max", 0))
+    # a summary from a run with no planner site carries no plan block
     plan = doc.get("plan")
     if plan is not None:
         sites = plan.get("sites") or {}
-        known = ("analytic", "tuned", "pinned")
-        ok = bool(sites) and all(i.get("provenance") in known
-                                 for i in sites.values())
-        g.check(path, "plan provenance", ok,
+        g.check(path, "plan provenance",
+                bool(sites) and all(i.get("provenance") in KNOWN_PROVENANCE
+                                    for i in sites.values()),
                 "%s over sites %s" % (plan.get("provenance"),
                                       sorted(sites) or "none"))
         if plan.get("cache_fallbacks") is not None:
-            fb_max = int(b.get("plan_cache_fallbacks_max", 0))
-            g.check(path, "plan cache fallbacks",
-                    int(plan["cache_fallbacks"]) <= fb_max,
-                    "%s <= %d" % (plan["cache_fallbacks"], fb_max))
-    cfac = b.get("compile_seconds_regression")
-    ccur = (doc.get("compile") or {}).get("compile_seconds_total")
-    cmp_base = forensics_baseline or baseline_summary
-    cbase = ((cmp_base or {}).get("compile")
-             or {}).get("compile_seconds_total") if cmp_base else None
-    if cfac and ccur is not None and cbase:
-        g.check(path, "compile seconds regression",
-                float(ccur) <= float(cbase) * float(cfac),
-                "%.4gs vs baseline %.4gs (%.2fx bar)"
-                % (float(ccur), float(cbase), float(cfac)))
-    elif cfac and ccur is not None:
-        g.skip(path, "compile seconds regression",
-               "no telemetry baseline with a compile section")
+            g.at_most(path, "plan cache fallbacks", plan["cache_fallbacks"],
+                      b.get("plan_cache_fallbacks_max", 0))
 
 
-# ---- the artifact-type registry ----------------------------------------
-#
-# One declarative row per artifact type the gate understands:
-# (kind, match predicate, gate callable taking (g, path, doc, budgets,
-# ctx)) where ctx holds the shared baseline artifacts.  sniff() and
-# run_gate() both walk THIS table — adding an artifact type is one row
-# plus its gate function, never a second if-chain — and an artifact
-# matching no row fails loudly naming the file.  Order matters: the
-# metric-tagged types come before the loose key-shape fallbacks.
-
-def _metric(name):
-    return lambda doc: doc.get("metric") == name
-
-
-REGISTRY = (
-    ("bench_wrapper", lambda d: isinstance(d.get("parsed"), dict),
-     None),  # unwrapped in run_gate, then re-sniffed
-    ("summary", _metric("telemetry_run"),
-     lambda g, p, d, b, ctx: gate_summary(
-         g, p, d, b, ctx["telemetry"],
-         forensics_baseline=ctx["forensics"])),
-    ("autotune", _metric("plan_autotune"),
-     lambda g, p, d, b, ctx: gate_autotune(g, p, d, b)),
-    ("precision", _metric("precision_tiers"),
-     lambda g, p, d, b, ctx: gate_precision(g, p, d, b)),
-    ("hist_quant", _metric("hist_quant"),
-     lambda g, p, d, b, ctx: gate_hist_quant(g, p, d, b)),
-    ("ingest", _metric("ingest_stream"),
-     lambda g, p, d, b, ctx: gate_ingest(g, p, d, b)),
-    ("serve", lambda d: "grid" in d and "dropped" in d,
-     lambda g, p, d, b, ctx: gate_serve(g, p, d, b, ctx["serve"])),
-    ("split_cost",
-     lambda d: "level" in d or ("points" in d and "fits" in d),
-     lambda g, p, d, b, ctx: gate_split_cost(g, p, d, b)),
-    ("bench_line", lambda d: "metric" in d and "value" in d,
-     lambda g, p, d, b, ctx: gate_bench_line(g, p, d, b)),
-)
-
-_GATERS = {kind: gate for kind, _m, gate in REGISTRY}
-
-
-def run_gate(artifacts, budgets_path: str) -> int:
+def run_gate(summaries, budgets_path: str) -> int:
     try:
-        spec = _load(budgets_path)
-    except (OSError, ValueError) as exc:
+        b = _load(budgets_path).get("budgets") or {}
+    except (OSError, ValueError, AttributeError) as exc:
         print("cannot read budgets %s: %s" % (budgets_path, exc),
               file=sys.stderr)
         return 2
-    b = spec.get("budgets") or {}
-    ctx = {"serve": _baseline(budgets_path, spec, "serve")[0],
-           "telemetry": _baseline(budgets_path, spec, "telemetry")[0],
-           "forensics": _baseline(budgets_path, spec, "forensics")[0]}
-    if not artifacts:
-        # default: gate the committed baseline artifacts themselves (the
-        # self-consistency run CI uses)
-        artifacts = [p for _, p in
-                     ((_k, os.path.join(os.path.dirname(
-                         os.path.abspath(budgets_path)), rel))
-                      for _k, rel in (spec.get("baselines") or {}).items())
-                     if os.path.exists(p)]
-        if not artifacts:
-            print("no artifacts given and no baselines exist",
-                  file=sys.stderr)
-            return 2
     g = Gate()
     rc = 0
-    for path in artifacts:
+    for path in summaries:
         try:
             doc = _load(path)
         except (OSError, ValueError) as exc:
-            print("cannot read artifact %s: %s" % (path, exc),
+            print("cannot read summary %s: %s" % (path, exc),
                   file=sys.stderr)
             rc = 2
             continue
-        kind = sniff(doc)
-        if kind == "bench_wrapper":
-            doc, kind = doc["parsed"], sniff(doc["parsed"])
-        gater = _GATERS.get(kind)
-        if gater is None:
-            print("cannot identify artifact %s: no registry row matches "
-                  "(keys: %s; known types: %s)"
-                  % (path, sorted(doc)[:8] if isinstance(doc, dict)
-                     else type(doc).__name__,
-                     ", ".join(k for k, _m, gt in REGISTRY if gt)),
-                  file=sys.stderr)
+        if not isinstance(doc, dict) or doc.get("metric") != "telemetry_run":
+            print("%s is not a telemetry summary (no metric=telemetry_run)"
+                  % path, file=sys.stderr)
             rc = 2
             continue
-        gater(g, path, doc, b, ctx)
+        gate_summary(g, path, doc, b)
     print("perf gate: %d checks, %d failed" % (g.checks, g.failures))
-    if g.failures:
-        return 1
-    return rc
+    return 1 if g.failures else rc
 
 
 def build_parser():
     ap = argparse.ArgumentParser(
-        description="gate telemetry summaries / BENCH artifacts against "
-                    "the declared perf budgets (PERF_BUDGETS.json); "
-                    "nonzero exit on any breach")
-    ap.add_argument("artifacts", nargs="*",
-                    help="artifact JSON paths (telemetry .summary.json, "
-                         "BENCH_serve, BENCH_split_cost, bench.py output); "
-                         "default: the budgets' committed baselines")
+        description="gate telemetry summaries against the declared counts "
+                    "(PERF_BUDGETS.json); nonzero exit on any breach")
+    ap.add_argument("summaries", nargs="+",
+                    help="telemetry <out>.summary.json paths")
     ap.add_argument("--budgets", default=DEFAULT_BUDGETS,
                     help="budgets spec (default: repo PERF_BUDGETS.json)")
     return ap
@@ -594,7 +135,7 @@ def build_parser():
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    return run_gate(args.artifacts, args.budgets)
+    return run_gate(args.summaries, args.budgets)
 
 
 if __name__ == "__main__":

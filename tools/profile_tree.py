@@ -15,7 +15,8 @@ root, default /tmp/lgbm_tpu_prof, one ``capture_<n>_profile_tree/`` dir
 with a ``capture.json`` per invocation) — the SAME artifact shape the
 triggered path (``/debug/profile``, the flight recorder) produces, so
 this aggregation works on either.  Prints the top ops by total device
-time, grouped by op name with counts — the numbers recorded in PERF.md.
+time, grouped by op name with counts.  The record of where a tree's time
+goes is the benchmark's traced run (``benchmarks/run.py --trace 1``).
 """
 import collections
 import glob
@@ -73,7 +74,7 @@ def main() -> None:
                     help="profile the fused train_chunk path instead")
     ap.add_argument("--nsrow", action="store_true",
                     help="also print per-op device time per logical "
-                         "row-visit (PERF.md per-phase unit)")
+                         "row-visit")
     ap.add_argument("--out", default="/tmp/lgbm_tpu_prof",
                     help="capture root (obs/profiling layout: one "
                          "capture_<n>_profile_tree/ dir per invocation)")
@@ -144,10 +145,9 @@ def main() -> None:
                              mode="tree", rows=n, leaves=leaves,
                              max_bin=max_bin)
 
-    # --nsrow: also print each op's device time per LOGICAL row-visit, the
-    # unit PERF.md's per-phase table uses.  Row-visits are exact from the
-    # trained tree (every row passes one window per level) — the same
-    # accounting bench.py uses for device_util.
+    # --nsrow: also print each op's device time per LOGICAL row-visit.
+    # Row-visits are exact from the trained tree (every row passes one
+    # window per level).
     visits = None
     if cli.nsrow:
         if chunk:
