@@ -17,8 +17,11 @@ learner's copy/kernel overlap (src/treelearner/gpu_tree_learner.cpp:952-1055)
   permutation matmul on the MXU — left rows compact to the window's front
   (in-place, behind the read cursor), right rows stream to a scratch region
   and are copied back after the left block settles.  Every HBM touch is a
-  contiguous >=64 KB DMA at a 32-row-aligned offset: zero per-row descriptors,
-  no switch, cost proportional to the window.
+  contiguous DMA at a 32-row-aligned offset: the window, the smaller child's
+  block and the scratch for the copy-back are READ a ``chunk`` at a time
+  (512 KB at chunk=4096, 128 KB at 1024), the flush rings and the copy-back
+  WRITE 16 KB tiles of TS rows; zero per-row descriptors, no switch, cost
+  proportional to the window.
 - The smaller child's histogram (serial_tree_learner.cpp:347-356 subtraction
   trick feeds on it) accumulates in the same pass from the same VMEM tiles —
   the routing/scatter/histogram fusion PERF.md round 3 listed as the next
@@ -88,7 +91,11 @@ TS = 128             # staging/flush tile (rows per contiguous write-back)
 # now that dest math is lane-major.
 NIN = 3              # input-chunk ring depth: two reads in flight so the
                      # read DMA wait overlaps the previous chunk's phase
-                     # A/B matmuls AND the trailing phase C (round 6)
+                     # A/B matmuls AND the trailing phase C (round 6).  The
+                     # ring serves three readers in turn: the window (phases
+                     # A-C), the smaller child's block (``hist_pass``) and
+                     # the scratch for the copy-back; each awaits every read
+                     # it started before the next begins
 _MID_MAX = 16384     # bucket bound: windows <= this use the 1024-row chunk
 
 assert T == TS and T % _ALIGN == 0 and T == _LANE
@@ -869,9 +876,13 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 hist_pass(scratch_ref, 0, 0, nr)
 
         # ---- copy right block back: scratch[0:nr] -> rows[wb+nl ...) ----
-        # Same streamed-append machinery (double-buffered reads, nb_ring-deep
-        # async flush ring on the left slots), with a constant row rotation
-        # by the destination's 32-row phase.
+        # Same streamed-append machinery (chunk reads through the input ring,
+        # nb_ring-deep async flush ring on the left slots), with a constant
+        # row rotation by the destination's 32-row phase.  One window on the
+        # chip (2,097,152 rows, F = 28, histogram knocked out, PERF.md §6
+        # PR 33): 1.031 ns a right row, whole kernel every row right less
+        # every row left; 2.944 with ONE 16 KB read of the scratch in flight
+        # a 128-row tile, which the loop waited for about 0.38 us a tile.
         @pl.when(nr > 0)
         def _copy_back():
             d0 = wb + nl
@@ -897,30 +908,35 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 rows_ref.at[pl.ds(d_al, _ALIGN)],
                 stage.at[0, pl.ds(0, _ALIGN)], sem_pre)
             cph.start()
+            ncbk = (nr + TS - 1) // TS          # 128-row tiles in all
+            ncc = (nr + chunk - 1) // chunk     # chunk reads of the scratch
+
+            # The scratch comes in a chunk at a time through the input ring,
+            # idle since ``hist_pass`` awaited its last read, NIN - 1 reads
+            # in flight (the same reads ``hist_pass(scratch_ref, 0, 0, nr)``
+            # makes, so inside the store's padding contract); the tile loop
+            # takes TS-row tiles out of VMEM, so the one wait a chunk lands
+            # behind chunk // TS tiles of work.  The tile loop is rolled: 32
+            # tiles unrolled read 0.11 ns a right row slower on the chip.
+            def read_cb(c, slot):
+                return pltpu.make_async_copy(
+                    scratch_ref.at[pl.ds(pl.multiple_of(c * chunk, _ALIGN),
+                                         chunk)],
+                    inbuf.at[slot], sem_in.at[slot])
+
+            for j in range(NIN - 1):
+                @pl.when(j < ncc)
+                def _prologue_cb(j=j):
+                    read_cb(j, j).start()
             cph.wait()
-            ncbk = (nr + TS - 1) // TS
 
-            pltpu.make_async_copy(
-                scratch_ref.at[pl.ds(0, TS)], tmp.at[0], sem_in.at[0]).start()
-
-            def cb_body(k, carry):
+            def cb_tile(slot, k0, k, carry):
                 fill, nf, cur = carry          # cur: tile nf's ring slot
-                slot = jax.lax.rem(k, 2)
-                pltpu.make_async_copy(
-                    scratch_ref.at[pl.ds(pl.multiple_of(k * TS, _ALIGN), TS)],
-                    tmp.at[slot], sem_in.at[slot]).wait()
-
-                @pl.when(k + 1 < ncbk)
-                def _prefetch_cb():
-                    nxt_in = 1 - slot
-                    pltpu.make_async_copy(
-                        scratch_ref.at[pl.ds(
-                            pl.multiple_of((k + 1) * TS, _ALIGN), TS)],
-                        tmp.at[nxt_in], sem_in.at[nxt_in]).start()
-
                 tr = jax.lax.dot_general(
                     rot[...],
-                    jax.lax.bitcast_convert_type(tmp[slot, :, :], jnp.int8),
+                    jax.lax.bitcast_convert_type(
+                        inbuf[slot, pl.ds(pl.multiple_of((k - k0) * TS, TS),
+                                          TS), :], jnp.int8),
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.int32)
                 comp = ((tr[0:_WPT] & 255)
@@ -955,8 +971,27 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 return (fill + nvs, nf + jnp.where(cross, 1, 0),
                         jnp.where(cross, nxt, cur))
 
-            fill, nf, cur = jax.lax.fori_loop(0, ncbk, cb_body,
-                                              (zero, zero, zero))
+            def cb_chunk(c, carry):
+                fill, nf, cur, slot = carry    # slot: chunk c's, c % NIN
+                read_cb(c, slot).wait()
+
+                @pl.when(c + NIN - 1 < ncc)
+                def _prefetch_cb():
+                    # chunk c + NIN - 1 lands where chunk c - 1 was
+                    read_cb(c + NIN - 1,
+                            jnp.where(slot == 0, NIN - 1, slot - 1)).start()
+
+                # the last chunk is partial: ncbk tiles in all, not chunk //
+                # TS of every chunk (past them is the scratch's garbage, and
+                # a tile of it merged in would take the last tile's rows)
+                k0 = c * (chunk // TS)
+                fill, nf, cur = jax.lax.fori_loop(
+                    k0, jnp.minimum(k0 + chunk // TS, ncbk),
+                    functools.partial(cb_tile, slot, k0), (fill, nf, cur))
+                return fill, nf, cur, _next_slot(slot, NIN)
+
+            fill, nf, cur, _ = jax.lax.fori_loop(
+                0, ncc, cb_chunk, (zero, zero, zero, zero))
             for j in range(1, nb_ring):
                 @pl.when(nf - j >= 0)
                 def _drain_cb(j=j):
@@ -1284,15 +1319,16 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
             ],
             scratch_shapes=[
                 pltpu.VMEM((NIN, chunk, W), jnp.uint8),  # streamed chunk ring
+                                                         # (window, hist, cb)
                 pltpu.VMEM((2 * nb_ring, TS, W), jnp.uint8),  # L/R flush rings
                 pltpu.VMEM((T, T), jnp.int8),            # upper-tri prefix ones
                 pltpu.VMEM((TS, TS), jnp.int8),          # copy-back rotation
-                pltpu.VMEM((2, TS, W), jnp.uint8),       # RMW/cb-read bounce
+                pltpu.VMEM((1, TS, W), jnp.uint8),       # finals' RMW bounce
                 pltpu.VMEM((totk + 1, 2 * TS * nsub, W),
                            jnp.uint8),                   # placed, group banks
                 pltpu.VMEM((2 * totk, 2, _LANE), jnp.int32),   # totals banks
                 pltpu.SMEM((2 * totk, 2, _LANE), jnp.int32),   # totals land
-                pltpu.SemaphoreType.DMA((NIN,)),         # chunk/cb reads
+                pltpu.SemaphoreType.DMA((NIN,)),         # chunk ring reads
                 pltpu.SemaphoreType.DMA,                 # prefills + finals
                 pltpu.SemaphoreType.DMA((nb_ring,)),     # left flush ring
                 pltpu.SemaphoreType.DMA((nb_ring,)),     # right flush ring

@@ -48,3 +48,40 @@ def test_report_folds_repeated_segments():
     text = KB.report(b + [KB.Bundle(0, False, [])] + b[1:], top=1)
     assert "loop at bundle 0: 6 own bundles (0 more in nested loops)" in text
     assert "segments: 3, 2" in text and "ops: vst 4" in text
+
+
+def _nest(depths):
+    """Bundles of one two-bundle loop body a depth, in that order."""
+    out = []
+    for d in depths:
+        out += [KB.Bundle(d, True, ["vld"]), KB.Bundle(d, False, ["vst"])]
+    return out
+
+
+PIPELINED_DEPTHS = [1, 2, 3, 3, 3, 3, 1, 2, 2, 2, 2, 1, 1, 1, 2, 1, 2, 1, 2]
+
+
+def test_the_copy_back_has_an_outer_and_an_inner_body_by_name():
+    loops = KB.loop_bodies(_nest(PIPELINED_DEPTHS))
+    names = KB.loop_names(loops)
+    assert len(names) == len(loops) == 19
+    assert names[0].startswith("pipe_body") and names[1].startswith("chunk_c")
+    assert names[-2:] == ["copy-back cb_chunk: a chunk read of the scratch",
+                          "copy-back cb_tile: a 128-row tile"]
+    text = KB.report(_nest(PIPELINED_DEPTHS), nest=KB.PIPELINED_LOOPS)
+    assert "  loop at bundle 34 [copy-back cb_chunk: a chunk read of the " \
+        "scratch]: 2 own bundles (2 more in nested loops)" in text
+    assert "    loop at bundle 36 [copy-back cb_tile: a 128-row tile]: 2 " \
+        "own bundles (0 more in nested loops)" in text
+
+
+@pytest.mark.parametrize("depths", [
+    PIPELINED_DEPTHS[:-1],                    # the parent's: one copy-back body
+    [1],                                      # the small kernel has no loop nest
+    PIPELINED_DEPTHS + [1],
+])
+def test_another_loop_nest_goes_unnamed(depths):
+    assert KB.loop_names(KB.loop_bodies(_nest(depths))) is None
+    text = KB.report(_nest(depths), nest=KB.PIPELINED_LOOPS)
+    assert "bodies unnamed" in text and "[" not in text.split("\n", 2)[2]
+    assert "unnamed" not in KB.report(_nest(depths))

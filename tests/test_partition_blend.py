@@ -129,14 +129,18 @@ def check_right_block(chunk, ph, nr, interpret, hist_left=0):
     phase ``ph``, ending at the store's last legal row with that phase (the
     phase is the end's less ``nr``: at the limit itself for ``nr`` = 128 at
     phase 0, 127 and 4095 at 1, 1, 129 and 4097 at 31): rows, histogram and
-    left count against ``partition_hist_xla``."""
-    n_pad = 4 * P.CHUNK
+    left count against ``partition_hist_xla``.  The store grows with ``nr``
+    and keeps a chunk of rows after the window: EVERY row of it is compared,
+    which is what catches a tile of the scratch's garbage tail flushed past
+    the window from a partial last chunk read."""
+    n_pad = (4 + nr // (2 * P.CHUNK)) * P.CHUNK
     limit = n_pad - P.CHUNK                   # a window may end here, no later
     end = limit - (limit - nr - ph) % P._ALIGN
     nl = 290
     wc = nl + nr
     wb = end - wc
     assert (wb + nl) % P._ALIGN == ph and limit - P._ALIGN < end <= limit
+    assert wb >= TS                           # a neighbour's rows before it
     rows = jnp.asarray(_store(n_pad, wb, wc, nl, seed=97 * ph + nr))
     scal = np.zeros(12 + NUM_BINS // 32, np.int32)
     scal[:12] = [wb, wc, 2, THR, 1, 0, NUM_BINS, 0, 0, hist_left, 0, 1]
@@ -155,8 +159,11 @@ def check_right_block(chunk, ph, nr, interpret, hist_left=0):
         rtol=2e-3, atol=2e-3)
 
 
-# chip_smoke.py's kernel phase runs the same cases compiled
-RIGHT_ROWS = [1, 127, 128, 129, 4095, 4097]
+# chip_smoke.py's kernel phase runs the same cases compiled.  The copy-back
+# reads the scratch a chunk at a time (PR 33): for each chunk size one under,
+# at and one over one and two chunk reads, and a partial third
+RIGHT_ROWS = [1, 127, 128, 129, 1023, 1024, 1025, 2047, 2049, 3073,
+              4095, 4096, 4097, 8191, 8192, 8193, 12289]
 PHASES = [0, 1, 31]
 CHUNKS = [P.CHUNK, P.SMALL_CHUNK]
 

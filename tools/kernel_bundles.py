@@ -12,7 +12,11 @@ it writes ``DIR/*-<kernel>.<n>-<k>-final_bundles.txt``, one line a bundle
 This tool compiles the named variant in a child process (the compile aborts
 on a missing report template AFTER the files are written: tolerated), then
 prints, per loop body, its bundles, its straight-line segments (an unrolled
-Python loop shows as ``32 x 52``) and its most frequent opcodes.
+Python loop shows as ``32 x 52``) and its most frequent opcodes.  The dump
+carries no source names, so the pipelined kernel's bodies are named by their
+place in the loop nest (``PIPELINED_LOOPS``, the order of
+``core/partition.py::_make_partition_kernel``); a nest of another shape is
+printed without names.
 
     python tools/kernel_bundles.py                       # c4096, F=28, 256 bins
     python tools/kernel_bundles.py --bucket c1024 --features 67
@@ -95,6 +99,43 @@ def loop_bodies(bundles):
     return loops
 
 
+# The pipelined kernel's loop bodies in program order, (name, nested bodies):
+# what ``_make_partition_kernel`` rolls (its unrolled Python loops show as
+# segments).  The copy-back has two since PR 33: a chunk read of the scratch,
+# and the 128-row tiles taken out of it.
+_FLUSH_LOOPS = [("await_left, a tile", []), ("await_right, a tile", []),
+                ("flush_left start, a tile", []),
+                ("flush_right start, a tile", [])]
+PIPELINED_LOOPS = [
+    ("pipe_body: chunk_ab, phases A + B of a chunk",
+     [("chunk_c: phase C of the chunk totk behind", _FLUSH_LOOPS)]),
+    ("chunk_c: phase C of the trailing chunks", _FLUSH_LOOPS),
+    ("drain: await_left, a tile", []),
+    ("drain: await_right, a tile", []),
+    ("hist_pass of the left block: a chunk", [("a feature group", [])]),
+    ("hist_pass of the right block: a chunk", [("a feature group", [])]),
+    ("copy-back cb_chunk: a chunk read of the scratch",
+     [("copy-back cb_tile: a 128-row tile", [])]),
+]
+
+
+def loop_names(loops, nest=PIPELINED_LOOPS):
+    """A name for each of ``loops`` (``loop_bodies``' list) from ``nest``
+    walked in the same order, or ``None`` when the depths do not match it
+    (another kernel, or the kernel's loops have changed: update ``nest``)."""
+    flat = []
+
+    def walk(entries, depth):
+        for name, nested in entries:
+            flat.append((depth, name))
+            walk(nested, depth + 1)
+
+    walk(nest, 1)
+    if [d for d, _ in flat] != [lp["depth"] for lp in loops]:
+        return None
+    return [name for _, name in flat]
+
+
 def _runs(values):
     """[52, 52, 52, 49] -> "3 x 52, 49"."""
     out = []
@@ -106,14 +147,21 @@ def _runs(values):
     return ", ".join("%d x %d" % (n, v) if n > 1 else str(v) for n, v in out)
 
 
-def report(bundles, top=8):
-    """The text: per loop body its bundles, segments and ``top`` opcodes."""
+def report(bundles, top=8, nest=None):
+    """The text: per loop body its bundles, segments and ``top`` opcodes,
+    and its name where the loops are ``nest``'s (``loop_names``)."""
     lines = ["%d bundles, %d outside every loop"
              % (len(bundles), sum(1 for b in bundles if b.depth == 0))]
-    for lp in loop_bodies(bundles):
+    loops = loop_bodies(bundles)
+    names = loop_names(loops, nest) if nest else None
+    if nest and names is None:
+        lines.append("(the loop nest is not the one named in this tool: "
+                     "bodies unnamed)")
+    for i, lp in enumerate(loops):
         lines.append(
-            "%sloop at bundle %d: %d own bundles (%d more in nested loops)"
-            % ("  " * lp["depth"], lp["at"], lp["own"], lp["nested"]))
+            "%sloop at bundle %d%s: %d own bundles (%d more in nested loops)"
+            % ("  " * lp["depth"], lp["at"],
+               " [%s]" % names[i] if names else "", lp["own"], lp["nested"]))
         pad = "  " * lp["depth"] + "  "
         if len(lp["segments"]) > 1:
             lines.append(pad + "segments: " + _runs(lp["segments"]))
@@ -180,7 +228,8 @@ def main():
             bundles = parse_bundles(fh)
     finally:
         shutil.rmtree(out_dir, ignore_errors=True)
-    print(report(bundles))
+    print(report(bundles,
+                 nest=None if args.bucket == "small" else PIPELINED_LOOPS))
 
 
 if __name__ == "__main__":
