@@ -1,0 +1,33 @@
+"""``readers/roofline.py`` for a table the program bundled (EFB): the split
+kernel's share of its roofline, in %, with the work counted over what the
+kernel reads of a row, not over the configuration's features.  The kernel
+moves one byte per GROUP column and histograms ``kernel_bins`` bins of each;
+counted over the 700 one-hot features the bytes would be 20 times what moves.
+``args["prefixes"]`` names the kernel's ops; ``args["columns"]`` and
+``args["bins"]`` name the job's counters that hold the two widths."""
+import roofline
+import trace_reduce
+
+
+def read(args, ctx):
+    trace, job = ctx["trace"], ctx["job"]
+    trees = job.traced_trees
+    columns = job.counters.get(args["columns"])
+    bins = job.counters.get(args["bins"])
+    if trace is None or not trees or not columns or not bins:
+        return None
+    kernel_s = trace_reduce.own_of(trace["own"], args["prefixes"]) / 1e9
+    if kernel_s <= 0:
+        return None
+    nbytes, ops, window_rows, small_rows = roofline.split_work(
+        trees, features=int(columns), bins=int(bins))
+    least, bound = roofline.least_seconds(
+        nbytes, ops, roofline.peaks(ctx["device_kind"]))
+    print("roofline of %r over %d traced trees at %d device columns of %d "
+          "bins: %d window rows (%.3f ns of kernel each), %d smaller-child "
+          "rows; %.4g bytes, %.4g ops; least time %.6f s, set by %s; kernel "
+          "%.6f s"
+          % (args["prefixes"], len(trees), columns, bins, window_rows,
+             1e9 * kernel_s / window_rows, small_rows, nbytes, ops, least,
+             bound, kernel_s), flush=True)
+    return 100.0 * least / kernel_s

@@ -461,11 +461,14 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         if unpack_lanes is None:
             return h
         lidx, lmask = unpack_lanes
-        hf = jnp.take_along_axis(h[feat.group], lidx[:, None, :], axis=2)
-        hf = hf * lmask[:, None, :]
-        rest = jnp.sum(hf, axis=2)
-        return hf.at[:, 0, 0].set(sg - rest[:, 0]).at[:, 1, 0].set(
-            sh - rest[:, 1])
+        # a scope of its own inside tree.root / tree.find_split, entered on
+        # the grouped path only: an ungrouped table's program is unchanged
+        with jax.named_scope("tree.unpack"):
+            hf = jnp.take_along_axis(h[feat.group], lidx[:, None, :], axis=2)
+            hf = hf * lmask[:, None, :]
+            rest = jnp.sum(hf, axis=2)
+            return hf.at[:, 0, 0].set(sg - rest[:, 0]).at[:, 1, 0].set(
+                sh - rest[:, 1])
 
     # Collective comm modes over ``axis_name`` (rows sharded unless noted):
     # - "rs": the reference DataParallelTreeLearner structure

@@ -34,11 +34,65 @@ import numpy as np
 from . import sample as _sample
 from .binning import BinMapper, BinType, MissingType
 from .metadata import Metadata
+from ..obs import efb as _efb_counters
 from ..obs.spans import span as _span
 from ..utils.log import Log
 
 # threads that bin columns side by side (each holds a few row-length temporaries)
 _BIN_THREADS = min(16, os.cpu_count() or 1)
+
+
+def _csr_to_csc(indptr, indices, values, num_col):
+    """(row ids, values, column starts [num_col + 1]) of a CSR table's
+    non-zeros by column, rows ascending within a column, in O(nnz).
+
+    The non-zeros are cut into one run per thread; each run is sorted stably
+    by column (narrow keys: numpy sorts 16-bit keys by radix, and at tens of
+    millions of non-zeros every int64 copy is a pass over half a gigabyte),
+    and a run's stretch of a column lands behind the earlier runs' stretches
+    of it, which is the stable order of the whole."""
+    n = len(indptr) - 1
+    vals = np.asarray(values)
+    if vals.dtype not in (np.float32, np.float64):
+        vals = vals.astype(np.float64)
+    wide = num_col >= 1 << 16
+    key = np.asarray(indices).astype(np.int64 if wide else np.uint16,
+                                     copy=False)
+    nnz = len(key)
+    row_of = np.repeat(np.arange(n, dtype=np.int32 if n < 1 << 31
+                                 else np.int64), np.diff(indptr))
+    at_dtype = np.int32 if nnz < 1 << 31 else np.int64
+    edges = np.arange(num_col + 1).astype(key.dtype)
+    threads = max(1, min(_BIN_THREADS, nnz >> 16))
+    bounds = [nnz * t // threads for t in range(threads + 1)]
+
+    def sort_run(t):
+        k = key[bounds[t]:bounds[t + 1]]
+        order = np.argsort(k, kind="stable")
+        k = k[order]
+        # edges[num_col] wraps to 0 at 16 bits: the last edge is the length
+        starts = np.searchsorted(k, edges[:-1])
+        return order, np.append(starts, len(k))
+
+    with ThreadPoolExecutor(max_workers=threads) as pool:
+        runs = list(pool.map(sort_run, range(threads)))
+        counts = np.stack([np.diff(starts) for _, starts in runs])
+        col_start = np.concatenate([[0], np.cumsum(counts.sum(axis=0))])
+        # where run t's stretch of column c begins in the whole
+        begins = col_start[:-1] + np.cumsum(counts, axis=0) - counts
+        rows_by_col = np.empty(nnz, row_of.dtype)
+        vals_by_col = np.empty(nnz, vals.dtype)
+
+        def place(t):
+            order, starts = runs[t]
+            dest = np.repeat((begins[t] - starts[:-1]).astype(at_dtype),
+                             counts[t])
+            dest += np.arange(len(order), dtype=at_dtype)
+            at = slice(bounds[t], bounds[t + 1])
+            rows_by_col[dest] = row_of[at][order]
+            vals_by_col[dest] = vals[at][order]
+        list(pool.map(place, range(threads)))
+    return rows_by_col, vals_by_col, col_start
 
 
 class BinnedDataset:
@@ -61,6 +115,8 @@ class BinnedDataset:
         self.group_idx: Optional[np.ndarray] = None  # [F_used] -> group column
         self.bin_offset: Optional[np.ndarray] = None  # [F_used] first group code
         self.num_bin_per_group: List[int] = []
+        # rows of the table in which one feature of a group overwrote another
+        self.conflict_rows: int = 0
 
     # ---- construction ----
 
@@ -164,13 +220,16 @@ class BinnedDataset:
                 self.bin_offset = reference.bin_offset
                 self.num_bin_per_group = list(reference.num_bin_per_group)
             elif not schema_adopted:
-                self.feature_groups = (self._find_groups_from_cols(cols)
+                self.feature_groups = (self._find_groups_from_cols(
+                                           cols, bin_construct_sample_cnt,
+                                           data_random_seed)
                                        if enable_bundle
                                        else [[j] for j in range(len(cols))])
                 self._assign_group_layout()
             self.binned = self._bundle_columns(cols)
         if keep_raw:
             self.raw_data = data
+        _efb_counters.record(self)
         return self
 
     @classmethod
@@ -379,8 +438,6 @@ class BinnedDataset:
         exists.  Numerical features only; ``raw_data`` is not kept (refit and
         raw-value prediction paths need dense input)."""
         indptr = np.asarray(indptr, dtype=np.int64)
-        col_idx = np.asarray(indices, dtype=np.int64)
-        vals = np.asarray(values, dtype=np.float64)
         self = cls()
         self.num_data = n = int(len(indptr) - 1)
         self.num_total_features = f_total = int(num_col)
@@ -396,22 +453,14 @@ class BinnedDataset:
         self.feature_names = (list(feature_names) if feature_names is not None
                               else ["Column_%d" % i for i in range(f_total)])
 
-        # CSR -> CSC in O(nnz): per-nonzero row ids, stably sorted by column
-        row_of = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
-        order = np.argsort(col_idx, kind="stable")
-        col_sorted = col_idx[order]
-        rows_by_col = row_of[order]
-        vals_by_col = vals[order]
-        col_start = np.searchsorted(col_sorted, np.arange(f_total + 1))
+        with _span("ingest.csr_to_csc"):
+            rows_by_col, vals_by_col, col_start = _csr_to_csc(
+                indptr, indices, values, f_total)
 
-        # same hash-priority draw as the dense/streaming constructors
-        # (identical indices for identical (n, seed) — the loaders' shared
-        # sampling discipline since round 21)
-        sample_idx, sample_keys = _sample.bottom_k_indices(
-            n, bin_construct_sample_cnt, data_random_seed)
-        total = len(sample_idx)
-        in_sample = np.zeros(n, dtype=bool)
-        in_sample[sample_idx] = True
+        def column(i):
+            """(row ids, float64 values) of column ``i``'s non-zeros."""
+            s, e = col_start[i], col_start[i + 1]
+            return rows_by_col[s:e], vals_by_col[s:e].astype(np.float64)
 
         if reference is not None:
             if reference.num_total_features != f_total:
@@ -420,21 +469,31 @@ class BinnedDataset:
             self.bin_mappers = reference.bin_mappers
             self.feature_names = reference.feature_names
         else:
-            self.bin_mappers = []
-            for f in range(f_total):
-                s, e = col_start[f], col_start[f + 1]
-                v = vals_by_col[s:e]
-                v = v[in_sample[rows_by_col[s:e]]]
-                v = v[(v != 0.0) | np.isnan(v)]
-                m = BinMapper()
-                fmax = (int(max_bin_by_feature[f]) if max_bin_by_feature
-                        else int(max_bin))
-                m.find_bin(v, total, fmax, min_data_in_bin,
-                           min_split_data=min_data_in_leaf,
-                           bin_type=BinType.NUMERICAL,
-                           use_missing=use_missing,
-                           zero_as_missing=zero_as_missing)
-                self.bin_mappers.append(m)
+            with _span("ingest.find_bins"):
+                # same hash-priority draw as the dense/streaming constructors
+                # (identical indices for identical (n, seed) — the loaders'
+                # shared sampling discipline since round 21)
+                sample_idx, sample_keys = _sample.bottom_k_indices(
+                    n, bin_construct_sample_cnt, data_random_seed)
+                total = len(sample_idx)
+                in_sample = np.zeros(n, dtype=bool)
+                in_sample[sample_idx] = True
+
+                def find(f):
+                    r, v = column(f)
+                    v = v[in_sample[r]]
+                    v = v[(v != 0.0) | np.isnan(v)]
+                    m = BinMapper()
+                    fmax = (int(max_bin_by_feature[f]) if max_bin_by_feature
+                            else int(max_bin))
+                    m.find_bin(v, total, fmax, min_data_in_bin,
+                               min_split_data=min_data_in_leaf,
+                               bin_type=BinType.NUMERICAL,
+                               use_missing=use_missing,
+                               zero_as_missing=zero_as_missing)
+                    return m
+                with ThreadPoolExecutor(max_workers=_BIN_THREADS) as pool:
+                    self.bin_mappers = list(pool.map(find, range(f_total)))
 
         self.used_feature_idx = [i for i, m in enumerate(self.bin_mappers)
                                  if not m.is_trivial]
@@ -443,89 +502,106 @@ class BinnedDataset:
         self.num_bin_per_feature = [self.bin_mappers[i].num_bin
                                     for i in self.used_feature_idx]
 
-        # per-used-feature sparse codes (nonzero positions only)
-        rows_f: List[np.ndarray] = []
-        codes_f: List[np.ndarray] = []
-        zero_bin: List[int] = []
-        for j, i in enumerate(self.used_feature_idx):
-            s, e = col_start[i], col_start[i + 1]
-            m = self.bin_mappers[i]
-            rows_f.append(rows_by_col[s:e])
-            codes_f.append(m.values_to_bins(vals_by_col[s:e]).astype(np.int32))
-            zero_bin.append(int(m.values_to_bins(np.zeros(1))[0]))
+        with _span("ingest.bin_columns"):
+            # per-used-feature sparse codes (nonzero positions only)
+            def code(i):
+                r, v = column(i)
+                m = self.bin_mappers[i]
+                return (r, m.values_to_bins(v),
+                        int(m.values_to_bins(np.zeros(1))[0]))
+            with ThreadPoolExecutor(max_workers=_BIN_THREADS) as pool:
+                coded = list(pool.map(code, self.used_feature_idx))
+            rows_f = [c[0] for c in coded]
+            codes_f = [c[1] for c in coded]
+            zero_bin = [c[2] for c in coded]
 
-        if reference is not None:
-            self.feature_groups = [list(g) for g in reference.feature_groups]
-            self.group_idx = reference.group_idx
-            self.bin_offset = reference.bin_offset
-            self.num_bin_per_group = list(reference.num_bin_per_group)
-        elif enable_bundle:
-            # sampled active bitmaps (code != 0) straight from the sparse
-            # codes; the 64Ki sub-sample is the bottom-eff-by-key subset —
-            # the same rows schema_from_sample's dense scan would use
-            samp_pos = np.full(n, -1, dtype=np.int64)
-            eff = min(total, self._EFB_SAMPLE)
-            efb_rows = sample_idx[_sample.efb_positions(sample_keys, eff)]
-            samp_pos[efb_rows] = np.arange(eff)
-            active = []
-            for j in range(len(self.used_feature_idx)):
-                a = np.zeros(eff, dtype=bool)
-                pos = samp_pos[rows_f[j][codes_f[j] != 0]]
-                a[pos[pos >= 0]] = True
-                active.append(a)
-            self.feature_groups = self._find_groups(active)
-            self._assign_group_layout()
-        else:
-            self.feature_groups = [[j] for j in
-                                   range(len(self.used_feature_idx))]
-            self._assign_group_layout()
-        max_nb = max(self.num_bin_per_group, default=2)
-        dtype = np.uint8 if max_nb <= 256 else np.uint16
-        out = np.zeros((n, len(self.feature_groups)), dtype=dtype)
-        for g, feats in enumerate(self.feature_groups):
-            if len(feats) == 1 and zero_bin[feats[0]]:
-                out[:, g] = dtype(zero_bin[feats[0]])
-        # row-windowed scatter: per-feature nonzeros are row-ascending (the
-        # stable CSC sort preserves CSR row order), so each window is a
-        # searchsorted slice and ``data_chunk_rows=0`` is the one-window
-        # case — byte-identical output by disjointness of the windows
-        step = (int(data_chunk_rows) if int(data_chunk_rows or 0) > 0
-                else max(n, 1))
-        for r0 in range(0, max(n, 1), step):
-            r1 = min(r0 + step, n)
-            for g, feats in enumerate(self.feature_groups):
-                if len(feats) == 1:
-                    j = feats[0]
-                    lo = np.searchsorted(rows_f[j], r0)
-                    hi = np.searchsorted(rows_f[j], r1)
-                    out[rows_f[j][lo:hi], g] = codes_f[j][lo:hi].astype(dtype)
-                else:
-                    for j in feats:  # push order: later features win conflicts
+        with _span("ingest.find_groups"):
+            if reference is not None:
+                self.feature_groups = [list(g)
+                                       for g in reference.feature_groups]
+                self.group_idx = reference.group_idx
+                self.bin_offset = reference.bin_offset
+                self.num_bin_per_group = list(reference.num_bin_per_group)
+            elif enable_bundle:
+                # sampled active bitmaps (code != 0) straight from the sparse
+                # codes; the 64Ki sub-sample is the bottom-eff-by-key subset —
+                # the same rows schema_from_sample's dense scan would use
+                samp_pos = np.full(n, -1, dtype=np.int32)
+                eff = min(total, self._EFB_SAMPLE)
+                efb_rows = sample_idx[_sample.efb_positions(sample_keys, eff)]
+                samp_pos[efb_rows] = np.arange(eff, dtype=np.int32)
+                active = []
+                for j in range(len(self.used_feature_idx)):
+                    a = np.zeros(eff, dtype=bool)
+                    pos = samp_pos[rows_f[j][codes_f[j] != 0]]
+                    a[pos[pos >= 0]] = True
+                    active.append(a)
+                self.feature_groups = self._find_groups(active)
+                self._assign_group_layout()
+            else:
+                self.feature_groups = [[j] for j in
+                                       range(len(self.used_feature_idx))]
+                self._assign_group_layout()
+
+        with _span("ingest.bundle_columns"):
+            max_nb = max(self.num_bin_per_group, default=2)
+            dtype = np.uint8 if max_nb <= 256 else np.uint16
+            # row-windowed scatter: per-feature nonzeros are row-ascending
+            # (the stable CSC sort preserves CSR row order), so each window
+            # is a searchsorted slice and ``data_chunk_rows=0`` is the
+            # one-window case — byte-identical output by disjointness of the
+            # windows.  A group's column is filled as one contiguous array
+            # (a scatter into [N, G] strides over the whole table for every
+            # feature), the groups side by side, and laid into rows at the end
+            step = (int(data_chunk_rows) if int(data_chunk_rows or 0) > 0
+                    else max(n, 1))
+
+            def group_column(feats):
+                # a feature alone keeps its own codes, its zeros' among them
+                col = np.full(n, zero_bin[feats[0]] if len(feats) == 1 else 0,
+                              dtype=dtype)
+                conflicts = 0
+                for r0 in range(0, max(n, 1), step):
+                    r1 = min(r0 + step, n)
+                    for j in feats:   # push order: later features win conflicts
                         lo = np.searchsorted(rows_f[j], r0)
                         hi = np.searchsorted(rows_f[j], r1)
-                        c = codes_f[j][lo:hi]
-                        r = rows_f[j][lo:hi]
+                        r, c = rows_f[j][lo:hi], codes_f[j][lo:hi]
+                        if len(feats) == 1:
+                            col[r] = c.astype(dtype)
+                            continue
                         nz = c != 0
-                        out[r[nz], g] = (self.bin_offset[j]
-                                         + c[nz] - 1).astype(dtype)
+                        r = r[nz]
+                        conflicts += int(np.count_nonzero(col[r]))
+                        col[r] = (self.bin_offset[j] + c[nz] - 1).astype(dtype)
+                return col, conflicts
+            with ThreadPoolExecutor(max_workers=_BIN_THREADS) as pool:
+                made = list(pool.map(group_column, self.feature_groups))
+            out = np.empty((n, len(self.feature_groups)), dtype=dtype)
+            for g, (col, conflicts) in enumerate(made):
+                out[:, g] = col
+                self.conflict_rows += conflicts
         self.binned = out
         self.raw_data = None
+        _efb_counters.record(self)
         return self
 
     # ---- EFB bundling (dataset.cpp:92-290) ----
 
     _EFB_SAMPLE = 65536
 
-    def _find_groups_from_cols(self, cols: List[np.ndarray]) -> List[List[int]]:
+    def _find_groups_from_cols(self, cols: List[np.ndarray],
+                               sample_cnt: int, seed: int) -> List[List[int]]:
+        """Groups from whole binned columns (bin mappers handed in, so no
+        schema came from a sample): the conflict scan reads the rows every
+        other constructor's would, the bottom-64Ki by key of the
+        bin-construct draw, so a table gets the same groups however it came."""
         nf = len(cols)
         if nf <= 1:
             return [[j] for j in range(nf)]
-        n = self.num_data
-        if n > self._EFB_SAMPLE:
-            rng = np.random.RandomState(1)
-            rows = np.sort(rng.choice(n, self._EFB_SAMPLE, replace=False))
-        else:
-            rows = slice(None)
+        idx, keys = _sample.bottom_k_indices(self.num_data, sample_cnt, seed)
+        rows = idx[_sample.efb_positions(keys, min(len(idx),
+                                                   self._EFB_SAMPLE))]
         active = [np.asarray(c[rows] != 0) for c in cols]
         return self._find_groups(active)
 
@@ -615,6 +691,7 @@ class BinnedDataset:
             for j in feats:   # push order: later features win conflicts
                 b = cols[j]
                 nz = b != 0
+                self.conflict_rows += int(np.count_nonzero(gcol[nz]))
                 gcol[nz] = self.bin_offset[j] + b[nz] - 1
             out[:, g] = gcol.astype(dtype)
         return out

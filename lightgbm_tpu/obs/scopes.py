@@ -27,6 +27,7 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%[^\s(]+)\s*\(.*\{\s*$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=(%[^\s,}]+)")
 _NAME = re.compile(r"%[\w.\-]+")
+_TRANSFORMED = re.compile(r"\b(?:vmap|jvp|transpose)\(([\w.]+)\)")
 
 
 def op_scopes(hlo_text: str, scopes: Iterable[str]) -> Dict[str, str]:
@@ -34,7 +35,8 @@ def op_scopes(hlo_text: str, scopes: Iterable[str]) -> Dict[str, str]:
     text of a compiled program, ``Compiled.as_text()``).  In this order:
 
     1. an instruction counts under the innermost of ``scopes`` on its own
-       ``op_name`` path (a fusion under the one its own ``op_name`` names);
+       ``op_name`` path (a fusion under the one its own ``op_name`` names; a
+       scope opened under ``vmap`` counts as the scope);
     2. a fusion whose own ``op_name`` names none takes the scope that most
        instructions of the computation it calls name;
     3. an instruction the compiler made, with no path of the program on it
@@ -47,8 +49,9 @@ def op_scopes(hlo_text: str, scopes: Iterable[str]) -> Dict[str, str]:
     wanted = set(scopes)
 
     def innermost(path: str) -> Optional[str]:
-        return next((part for part in reversed(path.split("/"))
-                     if part in wanted), None)
+        # a scope opened under a transformation is written "vmap(tree.unpack)"
+        return next((part for part in reversed(_TRANSFORMED.sub(
+            r"\1", path).split("/")) if part in wanted), None)
 
     def most(found) -> Optional[str]:
         found = [s for s in found if s is not None]

@@ -108,6 +108,29 @@ def test_partition_hist_compiles(one_chip, small, chunk, num_bins):
         kernel="partition_hist_pallas_" + P.bucket_name(small, chunk))
 
 
+# expo_onehot_train: 700 one-hot columns bundled into nine EFB group columns,
+# so the gradients sit at byte 12 of the 128-byte row, not at 28
+F_EFB, VOFF_EFB = 9, 12
+
+
+@pytest.mark.parametrize("small,chunk", [
+    (s, c) for s, c, _ in P.fused_bucket_plan(1 << 20)])
+def test_partition_hist_compiles_at_nine_group_columns(one_chip, small, chunk):
+    _compile(lambda r, s: P.partition_hist_pallas(
+        r, s, num_features=F_EFB, num_bins=256, voff=VOFF_EFB, chunk=chunk,
+        small=small),
+        one_chip, _sds((N_PAD, W), jnp.uint8), _sds((12 + 8,), jnp.int32),
+        kernel="partition_hist_pallas_" + P.bucket_name(small, chunk))
+
+
+def test_histogram_rows_compiles_at_nine_group_columns(one_chip):
+    assert H._use_factored(F_EFB, 256, False)
+    _compile(lambda r, s, c: H.histogram_pallas_rows(
+        r, 256, s, c, num_features=F_EFB, voff=VOFF_EFB),
+        one_chip, _sds((N_PAD, W), jnp.uint8), _sds((), jnp.int32),
+        _sds((), jnp.int32), kernel="histogram_pallas_rows_factored")
+
+
 @pytest.mark.parametrize("small,chunk", [
     (s, c) for s, c, _ in P.fused_bucket_plan(1 << 20)])
 def test_partition_hist_quantized_compiles(one_chip, small, chunk):
@@ -130,6 +153,7 @@ def test_partition_hist_level_compiles(one_chip, small, chunk):
 
 @pytest.mark.parametrize("objective,bag,width,voff", [
     ("binary", None, W, VOFF),              # higgs_train's pass
+    ("binary", None, W, 12),                # expo_onehot_train's: 9 columns
     ("binary", (0.8, 5), W, VOFF),          # the bagging hash, inside Mosaic
     ("regression", None, W, VOFF),
     ("regression", (0.5, 1), 1024, 968),    # wide store: one lane block

@@ -38,6 +38,7 @@ ENTRY %main.9 (Arg_0.1: f32[8]) -> f32[8] {
   %copy.8 = f32[8]{0} copy(%Arg_0.1)
   %copy.10 = f32[8]{0} copy(%Arg_0.1)
   %subtract.12 = f32[8]{0} subtract(%copy.10, %add.2), metadata={op_name="jit(f)/tree.store/sub"}
+  %multiply.13 = f32[8]{0} multiply(%Arg_0.1, %Arg_0.1), metadata={op_name="jit(f)/tree.store/vmap(tree.root)/mul"}
   ROOT %tuple.9 = (f32[8]{0}) tuple(%multiply.7, %copy.8)
 }
 '''
@@ -55,6 +56,7 @@ SCOPES = ["tree.store", "tree.root", "tree.finish"]
     ("%copy.10", "tree.store", "else its users' scope"),
     ("%copy.8", UNSCOPED, "nothing to inherit from"),
     ("%neg.1", "tree.finish", "instructions inside a fusion are mapped too"),
+    ("%multiply.13", "tree.root", "a scope opened under vmap is the scope"),
 ])
 def test_op_scopes(instruction, scope, why):
     assert op_scopes(HLO, SCOPES)[instruction] == scope, why

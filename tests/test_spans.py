@@ -145,6 +145,19 @@ def test_set_up_spans_of_one_booster():
     assert tot["jax.backend_compile"]["total_s"] >= 0.0
 
 
+def test_set_up_spans_of_a_csr_ingest():
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    rng = np.random.default_rng(3)
+    level = rng.integers(0, 20, size=(4000, 3)) + np.arange(3) * 20
+    indptr = np.arange(4001) * 3
+    spans.reset()
+    BinnedDataset.from_csr(indptr, level.reshape(-1), np.ones(12000), 60,
+                           label=(level[:, 0] % 2).astype(np.float32))
+    assert {n: t["count"] for n, t in spans.totals().items()} == {
+        "ingest.csr_to_csc": 1, "ingest.find_bins": 1, "ingest.bin_columns": 1,
+        "ingest.find_groups": 1, "ingest.bundle_columns": 1}
+
+
 def test_spans_one_train_one_iter_opens():
     g = _booster(feature_fraction=0.5)     # off the fused path
     assert not g._can_fuse_iters()
