@@ -32,6 +32,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..io.binning import MissingType
 
@@ -138,11 +139,13 @@ def _avalanche_u32(x):
     return x ^ (x >> 16)
 
 
-def _extra_trees_mask(feat: FeatureInfo, sum_grad, sum_hess, t,
-                      params: SplitParams):
+def _extra_trees_draw(num_bin, sum_grad, sum_hess, params: SplitParams,
+                      fid=None):
     """One random candidate threshold per (feature, leaf) — the reference's
     ``rand_threshold_gen_`` draw under ``extra_trees`` (config.h:318,
-    feature_histogram.hpp use_rand_threshold).  The draw is a stateless hash
+    feature_histogram.hpp use_rand_threshold), for the features ``fid`` (any
+    shape; every feature in order when None) of ``num_bin`` bins.  The draw
+    is a stateless hash
     of (extra_seed, feature index, leaf-total bits), so it is deterministic
     for a given dataset/seed yet varies across leaves and trees — a
     sequential RNG stream would not survive the vmapped per-leaf scan or
@@ -152,16 +155,15 @@ def _extra_trees_mask(feat: FeatureInfo, sum_grad, sum_hess, t,
         sum_grad.astype(f32), jnp.int32).astype(jnp.uint32)
         ^ (jax.lax.bitcast_convert_type(
             sum_hess.astype(f32), jnp.int32).astype(jnp.uint32) << 1))
-    F = feat.num_bin.shape[0]
-    fid = jnp.arange(F, dtype=jnp.uint32)
+    if fid is None:
+        fid = jnp.arange(num_bin.shape[0], dtype=jnp.uint32)
     x = fid * jnp.uint32(2654435761)
     x = x ^ (salt + jnp.uint32(params.extra_seed & 0xFFFFFFFF)
              * jnp.uint32(0x9E3779B9))
     x = _avalanche_u32(x)
     # thresholds live in [0, nb - 2] (bin <= t goes left)
-    ncand = jnp.maximum(feat.num_bin - 1, 1).astype(jnp.uint32)
-    rbin = jax.lax.rem(x, ncand).astype(jnp.int32)
-    return t == rbin[:, None]
+    ncand = jnp.maximum(num_bin - 1, 1).astype(jnp.uint32)
+    return jax.lax.rem(x, ncand).astype(jnp.int32)
 
 
 def threshold_l1(s, l1):
@@ -191,6 +193,25 @@ def _split_gains(gl, hl, gr, hr, p: SplitParams):
     gain = (leaf_split_gain_given_output(gl, hl, p.lambda_l1, p.lambda_l2, lo)
             + leaf_split_gain_given_output(gr, hr, p.lambda_l1, p.lambda_l2, ro))
     return gain, lo, ro
+
+
+def _evaluate_candidates(gl, hl, cl, gr, hr, cr, valid, params: SplitParams,
+                         min_gain_shift, cmin, cmax, mono):
+    """(gain or -inf, left output, right output) of every candidate from its
+    two sides' sums: the minimums on both sides, outputs clamped into the
+    leaf's bounds, the monotone ordering (``mono`` in {-1, 0, +1} a
+    candidate, or None) and gain strictly above the parent's
+    (feature_histogram.hpp:559-575)."""
+    ok = (valid
+          & (cl >= params.min_data_in_leaf) & (cr >= params.min_data_in_leaf)
+          & (hl >= params.min_sum_hessian_in_leaf)
+          & (hr >= params.min_sum_hessian_in_leaf))
+    gain, lo, ro = _split_gains_clamped(gl, hl, gr, hr, params,
+                                        params.lambda_l2, cmin, cmax)
+    if mono is not None:
+        ok &= ~(((mono > 0) & (lo > ro)) | ((mono < 0) & (lo < ro)))
+    ok &= gain > min_gain_shift
+    return jnp.where(ok, gain, K_MIN_SCORE), lo, ro
 
 
 def per_feature_best(hist: jax.Array, feat: FeatureInfo, feature_mask: jax.Array,
@@ -278,18 +299,12 @@ def per_feature_best(hist: jax.Array, feat: FeatureInfo, feature_mask: jax.Array
                                  params.lambda_l2, params.max_delta_step)
     min_gain_shift = gain_shift + params.min_gain_to_split
 
+    mono = (feat.monotone[:, None]
+            if cmin is not None and feat.monotone is not None else None)
+
     def evaluate(gl, hl, cl, gr, hr, cr, valid):
-        ok = (valid
-              & (cl >= params.min_data_in_leaf) & (cr >= params.min_data_in_leaf)
-              & (hl >= params.min_sum_hessian_in_leaf)
-              & (hr >= params.min_sum_hessian_in_leaf))
-        gain, lo, ro = _split_gains_clamped(gl, hl, gr, hr, params,
-                                            params.lambda_l2, cmin, cmax)
-        if cmin is not None and feat.monotone is not None:
-            mono = feat.monotone[:, None]
-            ok &= ~(((mono > 0) & (lo > ro)) | ((mono < 0) & (lo < ro)))
-        ok &= gain > min_gain_shift
-        return jnp.where(ok, gain, K_MIN_SCORE), lo, ro
+        return _evaluate_candidates(gl, hl, cl, gr, hr, cr, valid, params,
+                                    min_gain_shift, cmin, cmax, mono)
 
     if threshold_mask is not None:
         valid0 = valid0 & threshold_mask[None, :]
@@ -297,7 +312,8 @@ def per_feature_best(hist: jax.Array, feat: FeatureInfo, feature_mask: jax.Array
     elif params.extra_trees:
         # forced splits (threshold_mask) bypass the randomization, matching
         # the reference's GatherInfoForThreshold
-        et_mask = _extra_trees_mask(feat, sum_grad, sum_hess, t, params)
+        et_mask = t == _extra_trees_draw(feat.num_bin, sum_grad, sum_hess,
+                                         params)[:, None]
         valid0 = valid0 & et_mask
         valid1 = valid1 & et_mask
     gain0, lo0, ro0 = evaluate(left_g0, left_h0, left_c0,
@@ -587,6 +603,236 @@ def reduce_feature_best(fb: FeatureBest, feature_ids: jax.Array) -> BestSplit:
         right_output=fb.right_output[best_f],
         cat_bitset=fb.cat_bitset[best_f],
     )
+
+
+class GroupLanes(NamedTuple):
+    """What each lane of a bundled table's group histogram ``[G, 2, Bg]``
+    holds and hosts: static maps built once a learner by :func:`group_lanes`.
+    A group's lanes ARE its features' bins 1..nb-1 laid end to end from
+    ``bin_offset`` (io/dataset.py ``_assign_group_layout``), so a feature is
+    a SEGMENT of lanes and the split search scans it where it lies
+    (:func:`group_scans`, :func:`group_best`): lane ``l`` of feature ``f``
+    holds bin ``k = l - bin_offset[f] + 1`` and hosts the threshold
+    ``t = k - 1``, whose left side is bin 0 plus the bins before ``k``.
+    Everything :func:`per_feature_best` works out of ``nb``,
+    ``missing_type`` and ``default_bin`` a feature is worked out here a lane,
+    on the host: inside the tree's loop the maps are operands."""
+    feature: jax.Array    # [G, Bg] i32 inner feature id; F where no feature's bin lives
+    threshold: jax.Array  # [G, Bg] i32 t = k - 1
+    num_bin: jax.Array    # [G, Bg] i32 the lane's feature's; 0 where none
+    monotone: jax.Array   # [G, Bg] i32
+    # [2, G, Bg] bool, direction 0 (missing/default LEFT) and 1 (RIGHT):
+    # lanes left out of the direction's running sums (the default bin's own
+    # in zero mode; for direction 0 the NaN bin's too), and its candidates
+    drop: jax.Array
+    valid: jax.Array
+    zero_is_default: jax.Array   # [G, Bg] bool: bin 0 on neither left side
+    # [2 G Bg] i32 over (direction, group, lane): the feature, and the place
+    # in the reference's order of preference INSIDE a feature (direction 0
+    # first and from the largest t down, then direction 1 from the smallest)
+    feature2: jax.Array
+    order: jax.Array
+    # [4, G Bg] i32, what a winner's record reads at its lane: feature, t,
+    # zero_is_default, two bins and NaN (default_left = false, :128-130)
+    record: jax.Array
+    # [G, Bg, 2 Bg] bf16 zeros and ones, lane k by lane j: k is of j's segment
+    # and not before j (the first Bg columns), and before j (the last Bg)
+    scan: jax.Array
+
+
+def group_lanes(group_idx, bin_offset, num_bin, missing_type, default_bin,
+                monotone, num_groups: int, group_bins: int) -> GroupLanes:
+    """The lane maps of a group layout (host arrays in, device arrays out).
+    Lane 0 of a group and the lanes above its last feature belong to no
+    feature; a lane past ``group_bins`` is dropped."""
+    nf = len(num_bin)
+    feature = np.full((num_groups, group_bins), nf, dtype=np.int32)
+    t = np.zeros((num_groups, group_bins), dtype=np.int32)
+    for f in range(nf):
+        ts = np.arange(int(num_bin[f]) - 1, dtype=np.int32)
+        lane = int(bin_offset[f]) + ts
+        ok = (lane >= 0) & (lane < group_bins)
+        feature[int(group_idx[f]), lane[ok]] = f
+        t[int(group_idx[f]), lane[ok]] = ts[ok]
+    is_feature = feature < nf
+
+    def a_lane(per_feature):
+        return np.append(np.asarray(per_feature, dtype=np.int32),
+                         np.int32(0))[feature]
+    nb, mt, dbin = a_lane(num_bin), a_lane(missing_type), a_lane(default_bin)
+    # per_feature_best's modes and candidate ranges, a lane (k = t + 1):
+    # two directions only with a missing bin and > 2 bins; zero mode skips
+    # the default bin and cannot place t at default_bin - 1 (direction 0) or
+    # at default_bin (direction 1); NaN mode keeps the last bin off
+    # direction 0's right side and t under it
+    has_missing = (mt != int(MissingType.NONE)) & (nb > 2)
+    zero2 = has_missing & (mt == int(MissingType.ZERO))
+    nan2 = has_missing & (mt == int(MissingType.NAN))
+    at_default = zero2 & (t + 1 == dbin)
+    skip0 = at_default | (nan2 & (t + 1 == nb - 1))
+    valid = np.stack([is_feature & ~skip0,
+                      is_feature & has_missing & ~(zero2 & (t == dbin))])
+    zero_is_default = zero2 & (dbin == 0)
+    two_bin_nan = (mt == int(MissingType.NAN)) & (nb <= 2)
+    same = (feature[:, :, None] == feature[:, None, :]) \
+        & is_feature[:, :, None]
+    k_before_j = np.tri(group_bins, k=-1, dtype=bool).T
+    return GroupLanes(
+        feature=jnp.asarray(feature), threshold=jnp.asarray(t),
+        num_bin=jnp.asarray(nb), monotone=jnp.asarray(a_lane(monotone)),
+        drop=jnp.asarray(np.stack([skip0, at_default])),
+        valid=jnp.asarray(valid),
+        zero_is_default=jnp.asarray(zero_is_default),
+        feature2=jnp.asarray(np.tile(feature.reshape(-1), 2)),
+        order=jnp.asarray(np.concatenate(
+            [group_bins - 1 - t.reshape(-1), group_bins + t.reshape(-1)])),
+        record=jnp.asarray(np.stack([
+            feature, t, zero_is_default, two_bin_nan]).reshape(4, -1)
+            .astype(np.int32)),
+        scan=jnp.asarray(np.concatenate(
+            [same & ~k_before_j, same & k_before_j], axis=2),
+            dtype=jnp.bfloat16))
+
+
+class GroupScans(NamedTuple):
+    """A leaf's group histogram scanned in place (all ``[.., G, Bg]``)."""
+    after: jax.Array   # [3]: grad, hess, count of the lane's own bin and the
+                       # feature's bins after it, less what direction 0 skips
+    before: jax.Array  # [3]: of the bins 1..k-1 before it, less the default bin
+    bin0: jax.Array    # [2]: grad, hess of the feature's bin 0, from the totals
+
+
+def group_scans(hist: jax.Array, lanes: GroupLanes, sum_grad: jax.Array,
+                sum_hess: jax.Array, num_data: jax.Array) -> GroupScans:
+    """What takes the unbundling's place: the segmented running sums of one
+    leaf's group histogram ``[G, 2, Bg]`` and the shared default bin recovered
+    from the leaf totals (dataset.h:501 FixHistogram), on the group's own
+    lanes.  Counts as :func:`per_feature_best` makes them,
+    ``round(h * cnt_factor)`` a bin.
+
+    The sums are ONE product with ``lanes.scan``: a lane's sum is made of its
+    own segment's lanes alone (a level of a few hundred rows keeps its own
+    rounding beside a group that holds the whole leaf, which a difference of
+    group-wide prefixes would not give it), and an empty bin adds an exact
+    zero, so two thresholds that cut the same rows tie exactly, as in the
+    reference's serial scan.  f32 in, exactly, at any matmul precision: each
+    value goes in as three parts of bf16's eight bits, which the unit
+    multiplies by 0 or 1 and adds up in f32."""
+    f32 = jnp.float32
+    Bg = hist.shape[-1]
+    cnt_factor = num_data.astype(f32) / (sum_hess + 2 * K_EPSILON)
+    x = jnp.stack([hist[:, 0, :], hist[:, 1, :],
+                   jnp.round(hist[:, 1, :] * cnt_factor)])        # [3, G, Bg]
+    # the reference's right-to-left scan accumulates the RIGHT side, its
+    # left-to-right scan the left (feature_histogram.hpp:535-650); the two
+    # plain rows give the segment's own sum, for bin 0
+    rows = jnp.concatenate([jnp.where(lanes.drop[0], 0.0, x),
+                            jnp.where(lanes.drop[1], 0.0, x), x[:2]])
+    # reduce_precision, not a round trip through bf16: the compiler may drop
+    # a pair of converts as excess precision, never this
+    hi = jax.lax.reduce_precision(rows, exponent_bits=8, mantissa_bits=7)
+    mid = jax.lax.reduce_precision(rows - hi, exponent_bits=8, mantissa_bits=7)
+    lo = (rows - hi) - mid
+    lo, mid, hi = jnp.einsum("prgk,gkj->prgj", jnp.stack([lo, mid, hi]),
+                             lanes.scan.astype(f32))
+    sums = (lo + mid) + hi                                    # [8, G, 2 Bg]
+    own = sums[6:, :, :Bg] + sums[6:, :, Bg:]
+    totals = jnp.stack([sum_grad, sum_hess]).astype(f32)[:, None, None]
+    return GroupScans(after=sums[:3, :, :Bg], before=sums[3:6, :, Bg:],
+                      bin0=totals - own)
+
+
+def _two_sides(scans: GroupScans, zero_is_default, total_g, total_h,
+               num_data_f):
+    """((gl, hl, cl, gr, hr, cr) of direction 0, of direction 1) from the
+    scans: direction 0 (missing/default LEFT, the reference's dir=-1 scan)
+    accumulated the right side, direction 1 (dir=+1) the left; the other
+    side is what the leaf's totals leave.  Lane arrays, or one lane's values.
+    """
+    right_g0 = scans.after[0]
+    right_h0 = scans.after[1] + K_EPSILON
+    right_c0 = scans.after[2]
+    bin0_g = jnp.where(zero_is_default, 0.0, scans.bin0[0])
+    bin0_h = jnp.where(zero_is_default, 0.0, scans.bin0[1])
+    left_g1 = bin0_g + scans.before[0]
+    left_h1 = bin0_h + scans.before[1] + K_EPSILON
+    left_c1 = jnp.round(bin0_h * (num_data_f / total_h)) + scans.before[2]
+    return ((total_g - right_g0, total_h - right_h0, num_data_f - right_c0,
+             right_g0, right_h0, right_c0),
+            (left_g1, left_h1, left_c1,
+             total_g - left_g1, total_h - left_h1, num_data_f - left_c1))
+
+
+def group_best(scans: GroupScans, lanes: GroupLanes, valid: jax.Array,
+               sum_grad: jax.Array, sum_hess: jax.Array, num_data: jax.Array,
+               params: SplitParams, feat_num_bins: int, cmin=None, cmax=None,
+               lane_contri=None) -> BestSplit:
+    """Best numerical split of one leaf of a bundled table, searched on the
+    ``G x Bg`` lanes of its group histogram: :func:`per_feature_best`'s
+    formulas a lane, and :func:`reduce_feature_best`'s winner with the same
+    tie rules.  ``valid`` [2, G, Bg]: ``lanes.valid`` of the features in this
+    tree's feature_fraction draw; ``lane_contri`` [2 G Bg], as
+    ``lanes.feature2``: the candidate's ``feature_contri``, or None.
+    """
+    G, Bg = lanes.feature.shape
+    total_h = sum_hess + 2 * K_EPSILON  # feature_histogram.hpp:88
+    total_g = sum_grad
+    num_data_f = num_data.astype(jnp.float32)
+    sides0, sides1 = _two_sides(scans, lanes.zero_is_default, total_g,
+                                total_h, num_data_f)
+    if params.extra_trees:
+        valid = valid & (lanes.threshold == _extra_trees_draw(
+            lanes.num_bin, sum_grad, sum_hess, params,
+            fid=lanes.feature.astype(jnp.uint32)))
+    gain_shift = leaf_split_gain(total_g, total_h, params.lambda_l1,
+                                 params.lambda_l2, params.max_delta_step)
+    min_gain_shift = gain_shift + params.min_gain_to_split
+    mono = lanes.monotone if cmin is not None else None
+    gain0, _, _ = _evaluate_candidates(*sides0, valid[0], params,
+                                       min_gain_shift, cmin, cmax, mono)
+    gain1, _, _ = _evaluate_candidates(*sides1, valid[1], params,
+                                       min_gain_shift, cmin, cmax, mono)
+
+    # the winner over (direction, lane): the largest reported gain; among
+    # equals the smaller feature id; inside that feature the largest scanned
+    # gain, then lanes.order (per_feature_best's argmaxes and
+    # reduce_feature_best's, as maxima and one ranked minimum)
+    raw = jnp.stack([gain0, gain1]).reshape(-1)
+    shown = raw - min_gain_shift
+    if lane_contri is not None:
+        shown = shown * lane_contri
+    shown = jnp.where(raw > K_MIN_SCORE, shown, K_MIN_SCORE)
+    best_f = jnp.min(jnp.where(shown == jnp.max(shown), lanes.feature2,
+                               jnp.int32(2**31 - 1)))
+    in_f = lanes.feature2 == best_f
+    best_raw = jnp.max(jnp.where(in_f, raw, K_MIN_SCORE))
+    at = jnp.argmin(jnp.where(in_f & (raw == best_raw), lanes.order,
+                              jnp.int32(2**31 - 1))).astype(jnp.int32)
+    lane = at % (G * Bg)
+    use1 = at >= G * Bg
+
+    # the winner's own record from its lane's scans (one lane, not stacked
+    # arrays of them): the same formulas over again
+    def one(x):
+        return jax.lax.dynamic_index_in_dim(
+            x.reshape(x.shape[:-2] + (-1,)), lane, axis=-1, keepdims=False)
+    feature, thr, zero_is_default, two_bin_nan = \
+        jax.lax.dynamic_index_in_dim(lanes.record, lane, axis=1,
+                                     keepdims=False)
+    gl, hl, cl, gr, hr, cr = (
+        jnp.where(use1, a1, a0) for a0, a1 in zip(*_two_sides(
+            GroupScans(*[one(x) for x in scans]), zero_is_default != 0,
+            total_g, total_h, num_data_f)))
+    _, lo, ro = _split_gains_clamped(gl, hl, gr, hr, params, params.lambda_l2,
+                                     cmin, cmax)
+    return BestSplit(
+        gain=jax.lax.dynamic_index_in_dim(shown, at, keepdims=False),
+        feature=feature, threshold=thr,
+        default_left=~use1 & (two_bin_nan == 0),
+        left_sum_grad=gl, left_sum_hess=hl - K_EPSILON, left_count=cl,
+        right_sum_grad=gr, right_sum_hess=hr - K_EPSILON, right_count=cr,
+        left_output=lo, right_output=ro,
+        cat_bitset=jnp.zeros((feat_num_bins // 32,), dtype=jnp.uint32))
 
 
 def sync_best(best: BestSplit, axis_name: str) -> BestSplit:

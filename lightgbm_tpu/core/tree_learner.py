@@ -41,12 +41,13 @@ from .partition import (CHUNK as _PCHUNK, fold_hist, fused_bucket_plan,
 from .quant import quantize_gradients
 from .row_state import advance_row_state, f32_col, i32_col, leaf_windows
 from .split import (BestSplit, FeatureInfo, SplitParams, best_split_numerical,
-                    dequantize_hist, per_feature_best,
-                    per_feature_best_combined, reduce_feature_best, sync_best,
-                    K_MIN_SCORE)
+                    dequantize_hist, group_best, group_lanes, group_scans,
+                    per_feature_best, per_feature_best_combined,
+                    reduce_feature_best, sync_best, K_MIN_SCORE)
 from .tree import Tree
 from ..io.binning import BinType, MissingType
 from ..io.dataset import BinnedDataset
+from ..obs import efb as _efb_counters
 from ..obs.spans import span as _span
 from ..utils.timer import FunctionTimer
 
@@ -185,6 +186,13 @@ def _ffill_nonzero(x: jax.Array) -> jax.Array:
         x = jnp.where(x > 0, x, shifted)
         shift *= 2
     return x
+
+
+def group_search_applies(grouped: bool, has_categorical: bool,
+                         has_cegb: bool) -> bool:
+    """Whether a learner's split search runs on the group histogram's own
+    lanes: decided by what the table and the configuration are."""
+    return grouped and not has_categorical and not has_cegb
 
 
 @functools.partial(
@@ -460,7 +468,7 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         (dataset.h:501 FixHistogram)."""
         if unpack_lanes is None:
             return h
-        lidx, lmask = unpack_lanes
+        lidx, lmask, _ = unpack_lanes
         # a scope of its own inside tree.root / tree.find_split, entered on
         # the grouped path only: an ungrouped table's program is unchanged
         with jax.named_scope("tree.unpack"):
@@ -614,6 +622,19 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         return fb._replace(gain=jnp.where(
             fb.gain > K_MIN_SCORE, fb.gain * contri[ids], fb.gain))
 
+    # A bundled table with no categorical feature and no CEGB is searched in
+    # group space (split.group_best); the categorical search sorts a
+    # feature's bins and CEGB caches every feature's candidate, so both keep
+    # the per-feature block that ``unpack`` makes.
+    group_search = group_search_applies(unpack_lanes is not None,
+                                        has_categorical, cegb is not None)
+    if group_search:
+        lanes = unpack_lanes[2]
+        lanes_valid = lanes.valid & feature_mask[
+            jnp.minimum(lanes.feature, f - 1)]
+        lane_contri = (None if contri is None else
+                       contri[jnp.minimum(lanes.feature2, f - 1)])
+
     def best_of(h, sg, sh, cnt, cmn, cmx, used=None, ucnt=None):
         """Best split of a leaf; with CEGB also returns the per-feature
         candidates (the reference's splits_per_leaf_ cache,
@@ -662,6 +683,15 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 cmin=cmn if has_monotone else None,
                 cmax=cmx if has_monotone else None)
             return reduce_feature_best(_apply_contri(fb, elected), elected)
+        if group_search:
+            # a bundled table: the search runs on the group histogram's own
+            # lanes; tree.unpack holds what took the unbundling's place
+            with jax.named_scope("tree.unpack"):
+                scans = group_scans(h, lanes, sg, sh, cnt)
+            return group_best(scans, lanes, lanes_valid, sg, sh, cnt, params, B,
+                              cmin=cmn if has_monotone else None,
+                              cmax=cmx if has_monotone else None,
+                              lane_contri=lane_contri)
         fb = per_feature_best_combined(
             unpack(h, sg, sh), feat, feature_mask, sg, sh, cnt, params,
             any_categorical=has_categorical,
@@ -688,7 +718,7 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         (avoids unpacking all F features in the growth loop)."""
         if unpack_lanes is None:
             return jax.lax.dynamic_index_in_dim(h, ffeat, axis=0)
-        lidx, lmask = unpack_lanes
+        lidx, lmask, _ = unpack_lanes
         hg = jax.lax.dynamic_index_in_dim(h, feat.group[ffeat], axis=0,
                                           keepdims=False)      # [2, Bg]
         hf = jnp.take(hg, lidx[ffeat], axis=1) * lmask[ffeat][None, :]
@@ -1638,7 +1668,12 @@ class SerialTreeLearner:
             lidx = np.clip(np.asarray(dataset.bin_offset)[:, None] + lanes - 1,
                            0, self.num_bins - 1).astype(np.int32)
             lmask = ((lanes >= 1) & (lanes < nb[:, None])).astype(np.float32)
-            self.unpack_lanes = (jnp.asarray(lidx), jnp.asarray(lmask))
+            self.unpack_lanes = (
+                jnp.asarray(lidx), jnp.asarray(lmask),
+                group_lanes(dataset.group_idx, dataset.bin_offset, nb,
+                            dataset.missing_types(), dataset.default_bins(),
+                            self.monotone, len(dataset.feature_groups),
+                            self.num_bins))
         else:
             self.num_bins = _pad_bins_pow2(dataset.max_num_bin)
             self.feat_bins = self.num_bins   # scans run on the kernel block
@@ -1666,6 +1701,12 @@ class SerialTreeLearner:
         self._upload_bins(matrix)
         self.forced = self._load_forced_splits(config, dataset)
         self.cegb = self._init_cegb(config, dataset)
+        if self.grouped:
+            in_groups = group_search_applies(True, self.has_categorical,
+                                             self.cegb is not None)
+            _efb_counters.record_search_lanes(
+                len(dataset.feature_groups) * self.num_bins if in_groups
+                else dataset.num_features * self.feat_bins)
         # histogram_pool_size MB -> LRU slot count (reference HistogramPool,
         # feature_histogram.hpp:687; <=0 keeps one slot per leaf)
         pool_mb = float(getattr(config, "histogram_pool_size", -1.0))

@@ -194,21 +194,14 @@ _NO_GLUE = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast",
             "copy-done", "slice-start", "slice-done", "iota"}
 
 
-@pytest.fixture(scope="module")
-def chunk_text(one_chip, monkeypatch_module):
-    """Compiled text of a fused chunk of 2 trees x 255 leaves on 2^16 rows of
-    Higgs width, as ``GBDT.chunk_program_text`` gives it on the chip.  The
-    booster is built on the CPU; its fused step is lowered for the described
-    chip from shapes, the way ``_hoisted_jit`` lowers it from arrays."""
-    import numpy as np
+def _chunk_program_text(one_chip, monkeypatch_module, ds):
+    """Compiled text of a fused chunk of 2 trees x 255 leaves on ``ds``, as
+    ``GBDT.chunk_program_text`` gives it on the chip.  The booster is built on
+    the CPU; its fused step is lowered for the described chip from shapes,
+    the way ``_hoisted_jit`` lowers it from arrays."""
     from lightgbm_tpu.boosting import gbdt as G
     from lightgbm_tpu.config import Config
-    from lightgbm_tpu.io.dataset import BinnedDataset
     from lightgbm_tpu.objective import create_objective
-    rng = np.random.default_rng(0)
-    X = rng.standard_normal((1 << 16, F)).astype(np.float32)
-    y = (X[:, 0] + X[:, 1] * X[:, 2] + rng.standard_normal(len(X)) > 0)
-    ds = BinnedDataset.from_matrix(X, label=y.astype(np.float32), max_bin=255)
     cfg = Config(verbosity=-1, objective="binary", num_leaves=255,
                  max_bin=255, min_data_in_leaf=0,
                  min_sum_hessian_in_leaf=100.0)
@@ -228,6 +221,44 @@ def chunk_text(one_chip, monkeypatch_module):
         lambda consts, *args: jax.core.eval_jaxpr(closed.jaxpr, consts, *args)
     ).lower(*_on_chip(one_chip, (spec(closed.consts),) + tuple(spec(flat)))
             ).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def chunk_text(one_chip, monkeypatch_module):
+    """The chunk program on 2^16 rows of Higgs width."""
+    import numpy as np
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    rng = np.random.default_rng(0)
+    X = rng.standard_normal((1 << 16, F)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] + rng.standard_normal(len(X)) > 0)
+    ds = BinnedDataset.from_matrix(X, label=y.astype(np.float32), max_bin=255)
+    return _chunk_program_text(one_chip, monkeypatch_module, ds)
+
+
+@pytest.fixture(scope="module")
+def grouped_chunk_text(one_chip, monkeypatch_module):
+    """The chunk program at the ``expo-onehot`` widths: 700 features in 9
+    group columns of 256 bins (seven one-hot blocks, the two widest filling
+    a group each, and two numeric columns alone), handed over as CSR."""
+    import numpy as np
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    rng = np.random.default_rng(0)
+    n, blocks = 1 << 16, (12, 31, 7, 29, 255, 255, 109)
+    starts = np.concatenate([[0], np.cumsum(blocks)])
+    assert starts[-1] + 2 == 700
+    indices = np.stack([starts[b] + rng.integers(0, blocks[b], size=n)
+                        for b in range(len(blocks))]
+                       + [np.full(n, 698), np.full(n, 699)], axis=1)
+    values = np.ones(indices.shape, np.float32)
+    values[:, -2:] = rng.standard_normal((n, 2))
+    ds = BinnedDataset.from_csr(
+        np.arange(0, indices.size + 1, indices.shape[1], dtype=np.int64),
+        indices.reshape(-1).astype(np.int32), values.reshape(-1), 700,
+        label=(rng.random(n) < 0.4).astype(np.float32), max_bin=255,
+        min_data_in_leaf=0)
+    assert (ds.num_features, ds.binned.shape[1], ds.max_group_bin) \
+        == (700, 9, 256)
+    return _chunk_program_text(one_chip, monkeypatch_module, ds)
 
 
 @pytest.fixture(scope="module")
@@ -287,6 +318,28 @@ def test_chunk_program_scopes_cover_the_glue(chunk_text):
     passes = {op: s for op, s in scope_of.items()
               if op.startswith("%row_state_pass")}
     assert passes and set(passes.values()) == {"tree.finish"}
+
+
+def test_grouped_chunk_program_compiles_with_the_search_on_group_lanes(
+        grouped_chunk_text):
+    """G = 9, Bg = 256, F = 700: the chip's compiler takes the segmented
+    scans (one product with the static 0/1 matrix, under ``tree.unpack``
+    inside the loop's ``tree.find_split`` and the root's) and keeps no array
+    of 700 x 256 lanes in the tree's loop."""
+    import re
+    from lightgbm_tpu.obs.scopes import op_scopes
+    text = grouped_chunk_text
+    glue = _glue_instructions(text)
+    scope_of = op_scopes(text, SCOPES + ["tree.unpack"])
+    assert [op for op in glue if scope_of[op] == "tree.unpack"]
+    scans = [ln for ln in text.splitlines()
+             if "tree.unpack" in ln and " convolution(" in ln]
+    assert any("tree.find_split/vmap(tree.unpack)" in ln for ln in scans)
+    assert any("tree.root/tree.unpack" in ln for ln in scans)
+    for line in text.splitlines():
+        if "tree.find_split" in line:
+            for dims in re.findall(r"\w+\[([\d,]+)\]", line):
+                assert "700" not in dims.split(","), line[:300]
 
 
 def test_predict_blocked_compiles(one_chip):

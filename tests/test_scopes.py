@@ -113,3 +113,71 @@ def test_scopes_and_kernel_names_change_no_equation_and_no_tree(monkeypatch):
     assert "name=partition_hist_pallas_small" in jaxpr
     assert jaxpr == jaxpr_plain
     assert model == model_plain
+
+
+def _grouped_chunk_text(categorical=()):
+    """(features, feature bins, compiled text of the fused chunk) of a small
+    bundled table: two one-hot blocks of 40 and 25 levels and two numeric
+    columns (``categorical`` of them whole numbers, taken as categories), 67
+    features in 4 device columns."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    from lightgbm_tpu.objective import create_objective
+    rng = np.random.RandomState(3)
+    n = 4096
+    a, b = rng.randint(0, 40, size=n), rng.randint(0, 25, size=n)
+    X = np.zeros((n, 67), np.float32)
+    X[np.arange(n), a] = 1
+    X[np.arange(n), 40 + b] = 1
+    X[:, 65:] = rng.normal(size=(n, 2))
+    for column in categorical:
+        X[:, column] = rng.randint(0, 6, size=n)
+    y = (rng.normal(size=40)[a] + rng.normal(size=25)[b] + X[:, 65]
+         + rng.normal(size=n) > 0)
+    ds = BinnedDataset.from_matrix(X, label=y.astype(np.float32), max_bin=255,
+                                   min_data_in_leaf=0,
+                                   categorical_feature=categorical)
+    assert [len(g) for g in ds.feature_groups] == [40, 25, 1, 1]
+    cfg = Config(objective="binary", num_leaves=15, min_data_in_leaf=0,
+                 min_sum_hessian_in_leaf=1.0, verbosity=-1)
+    g = G.GBDT(cfg, ds, create_objective("binary", cfg))
+    g.learner.use_pallas = g.learner.pallas_interpret = True
+    g.train_chunk(2)
+    assert g.iter_ == 2 and not g._fuse_failed and g.learner.grouped
+    return ds.num_features, g.learner.feat_bins, g.chunk_program_text(2)
+
+
+def _shapes_under(text, scope):
+    """[dims] of every array an instruction under ``scope`` makes or reads."""
+    import re
+    found = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name and scope in name.group(1):
+            found += [tuple(int(d) for d in dims.split(","))
+                      for dims in re.findall(r"\w+\[([\d,]+)\]", line)]
+    return found
+
+
+def test_a_bundled_table_is_searched_on_its_group_lanes():
+    """The grouped chunk program: ``tree.unpack`` still inside ``tree.root``
+    and (under the children's vmap) ``tree.find_split``, around the scans
+    that took the unbundling's place, and nowhere under ``tree.find_split``
+    an array of features x feature bins."""
+    F, feat_bins, text = _grouped_chunk_text()
+    assert "tree.root/tree.unpack/" in text
+    assert "tree.find_split/vmap(tree.unpack)/" in text
+    shapes = _shapes_under(text, "tree.find_split")
+    assert shapes and (4, 2, 256) in [s[-3:] for s in shapes]
+    wide = [s for s in shapes
+            if F in s and int(np.prod(s)) >= F * feat_bins]
+    assert not wide, sorted(set(wide))
+
+
+def test_a_categorical_feature_keeps_the_unbundled_search():
+    """The control of the test above, and the fallback: the categorical
+    search sorts a feature's bins, so the per-feature block is made, as
+    every bundled table's was."""
+    F, feat_bins, text = _grouped_chunk_text(categorical=(66,))
+    shapes = _shapes_under(text, "tree.find_split")
+    assert [s for s in shapes if F in s and int(np.prod(s)) >= F * feat_bins]
