@@ -18,6 +18,7 @@ TPU-first notes:
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -43,6 +44,7 @@ from ..obs import compile as _compile
 from ..obs import devmem as _devmem
 from ..obs import launches as _launches
 from ..obs import recompile as _recompile
+from ..obs import sampling as _sampling
 from ..obs import spans as _spans
 from ..resilience import PROGRAM_ERRORS as _PROGRAM_ERRORS
 from ..resilience import preemption_requested as _preemption_requested
@@ -93,10 +95,24 @@ def _mul_mask(grad, hess, mask):
     return grad * mask, hess * mask
 
 
+def _hash_u32(ids, seed: int, key):
+    """A stateless integer hash (xxhash-style avalanche) of (id, seed, key),
+    uint32 throughout: the one source of randomness of the bag and of the
+    feature mask, reproducible from any execution order."""
+    x = ids.astype(jnp.uint32) * jnp.uint32(2654435761)
+    x = x ^ (jnp.uint32(seed & 0xFFFFFFFF)
+             + key.astype(jnp.uint32) * jnp.uint32(0x9E3779B9))
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(2246822519)
+    x = x ^ (x >> 13)
+    x = x * jnp.uint32(3266489917)
+    return x ^ (x >> 16)
+
+
 def _bag_uniforms(row_ids, seed: int, it_window):
     """Deterministic per-row uniforms in [0, 1) for bagging, keyed by
-    (original row id, bagging window).  A stateless integer hash (xxhash-
-    style avalanche) instead of a sequential RNG stream so the SAME mask is
+    (original row id, bagging window).  A stateless integer hash
+    (:func:`_hash_u32`) instead of a sequential RNG stream so the SAME mask is
     reproducible from any execution order — per-iteration host path, fused
     lax.scan, and the carried row store (where rows are permuted and only
     their original ids are at hand) all agree bit-exactly.
@@ -105,14 +121,7 @@ def _bag_uniforms(row_ids, seed: int, it_window):
     (gbdt.cpp:160-276): each row is an independent Bernoulli(p) draw, so
     ``bag_data_cnt`` is the realized count.  Quality-equivalent; pinned by
     tests/test_boosting.py bagging windows."""
-    x = row_ids.astype(jnp.uint32) * jnp.uint32(2654435761)
-    x = x ^ (jnp.uint32(seed & 0xFFFFFFFF)
-             + it_window.astype(jnp.uint32) * jnp.uint32(0x9E3779B9))
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(2246822519)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(3266489917)
-    x = x ^ (x >> 16)
+    x = _hash_u32(row_ids, seed, it_window)
     # u32 -> f32 through two exact 16-bit halves: their one rounded add is the
     # conversion's own rounding, bit for bit, and Mosaic (which has no
     # u32 -> f32 cast) can compile it inside the row store's hand-over pass
@@ -120,6 +129,32 @@ def _bag_uniforms(row_ids, seed: int, it_window):
     lo = jax.lax.bitcast_convert_type(x & jnp.uint32(0xFFFF), jnp.int32)
     xf = hi.astype(jnp.float32) * jnp.float32(65536.0) + lo.astype(jnp.float32)
     return xf * jnp.float32(1.0 / 4294967296.0)
+
+
+def features_used(num_features: int, fraction: float) -> int:
+    """Features a tree may split on under ``feature_fraction``."""
+    if fraction >= 1.0 or num_features <= 1:
+        return num_features
+    return max(1, int(round(num_features * fraction)))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def feature_mask_of(num_features: int, used: int, seed: int, it):
+    """[num_features] bool: the features iteration ``it`` may split on, a
+    pure function of (``feature_fraction_seed``, iteration).  Every feature
+    id is hashed with the seed and the iteration (:func:`_hash_u32`) and the
+    ``used`` smallest hashes are taken, equal hashes to the smaller id (a
+    stable sort).  The ONE draw the fused scan, ``train_one_iter`` and RF
+    share, so a model trained per iteration and one trained in fused chunks
+    are equal tree for tree and a resume or a rollback needs no RNG state.
+
+    Differs from the reference's ``Random::Sample`` stream
+    (serial_tree_learner.cpp BeforeTrain): the same count of features, other
+    features for the same seed."""
+    order = jnp.argsort(_hash_u32(jnp.arange(num_features, dtype=jnp.int32),
+                                  seed, jnp.asarray(it, jnp.int32)),
+                        stable=True)
+    return jnp.zeros((num_features,), bool).at[order[:used]].set(True)
 
 
 def _bag_mask(row_ids, seed: int, it, freq: int, frac: float):
@@ -432,6 +467,7 @@ class GBDT:
         # cached fused programs close over the old learner/objective
         self._fused_cache = {}
         self._fuse_failed = False
+        self._fuse_refusals_said = set()
         self.num_tree_per_iteration = (objective.num_model_per_iteration
                                        if objective else max(1, self.num_class))
         self.learner = create_tree_learner(train_data, self.config,
@@ -493,8 +529,10 @@ class GBDT:
         # plain bagging uses the stateless _bag_uniforms hash; this
         # sequential stream remains for GOSS's sampling (goss.py)
         self._bag_rng = np.random.RandomState(int(self.config.bagging_seed))
-        self._feat_rng = np.random.RandomState(
-            int(self.config.feature_fraction_seed))
+        bag = self._fused_bag()
+        _sampling.record_config(
+            train_data.num_features, self._features_used(),
+            *(bag if bag is not None else (1.0, 0)))
         self.bag_mask: Optional[jnp.ndarray] = None
         self.bag_data_cnt = self.num_data
         self._boosted_from_average = False
@@ -717,16 +755,20 @@ class GBDT:
         elif self.bag_mask is None:
             self.bag_data_cnt = self.num_data
 
-    def _feature_mask(self) -> Optional[jnp.ndarray]:
-        ff = float(self.config.feature_fraction)
-        nf = self.train_data.num_features
-        if ff >= 1.0 or nf <= 1:
+    def _features_used(self) -> int:
+        return features_used(self.train_data.num_features,
+                             float(self.config.feature_fraction))
+
+    def _feature_mask(self, it=None) -> Optional[jnp.ndarray]:
+        """The feature mask of iteration ``it`` (this iteration when None;
+        a traced scalar inside the fused scan), or None when every feature is
+        searched: :func:`feature_mask_of`, which holds no state."""
+        nf, used = self.train_data.num_features, self._features_used()
+        if used >= nf:
             return None
-        used = max(1, int(round(nf * ff)))
-        chosen = self._feat_rng.choice(nf, size=used, replace=False)
-        mask = np.zeros(nf, dtype=bool)
-        mask[chosen] = True
-        return jnp.asarray(mask)
+        return feature_mask_of(nf, used,
+                               int(self.config.feature_fraction_seed),
+                               self.iter_ if it is None else it)
 
     # ---- boosting (gbdt.cpp:143-158, 322-368) ----
 
@@ -907,6 +949,7 @@ class GBDT:
                         "that meet the split requirements")
             return True
         self.iter_ += 1
+        _sampling.record_trees("per_iteration", K, self.bag_data_cnt)
         if self.iter_ - self._last_poll >= self._poll_freq:
             return self._poll_stop()
         return False
@@ -915,14 +958,17 @@ class GBDT:
     #
     # Per-iteration training makes ~10 jitted dispatches per tree, each a
     # host round-trip with the device idle in between.
-    # When the iteration has no host-side decisions (no feature sampling, no
-    # leaf renewal, device-traceable objective, serial learner) the whole
-    # k-iteration boosting loop runs as ONE compiled lax.scan: gradients ->
-    # tree build -> score update per step, trees emitted as stacked
-    # TreeArrays.  Validation sets ride the scan as extra score carries
-    # (each tree routes the valid bins on device; metrics are computed on
-    # the host at chunk ends, which train() aligns to metric_freq), and
-    # bagging is an in-scan deterministic hash mask (_bag_uniforms).
+    # When the iteration has no host-side decisions (no leaf renewal,
+    # device-traceable objective, serial learner) the whole k-iteration
+    # boosting loop runs as ONE compiled lax.scan: gradients -> tree build ->
+    # score update per step, trees emitted as stacked TreeArrays.  Validation
+    # sets ride the scan as extra score carries (each tree routes the valid
+    # bins on device; metrics are computed on the host at chunk ends, which
+    # train() aligns to metric_freq).  Row and column subsampling ride it
+    # too, both stateless functions of the iteration: bagging is an in-scan
+    # deterministic hash mask (_bag_uniforms) and feature_fraction an in-scan
+    # per-tree feature mask (feature_mask_of), each entered only when it is
+    # on, so a program that samples nothing is the program it always was.
 
     fuse_iters = True  # subclasses with per-iteration host logic opt out
     # ... and so do those whose iteration reads whole-table arrays on one
@@ -930,31 +976,44 @@ class GBDT:
     # their per-row state is not created with a row-sharding learner's rows
     shard_row_state = True
 
-    def _can_fuse_iters(self) -> bool:
-        if not (self.fuse_iters and self.lazy_trees
-                and self.objective is not None
-                and not self.objective.is_renew_tree_output
-                and self.objective.deterministic_gradients):
-            return False
+    def _fuse_refusal(self) -> Optional[str]:
+        """Why ``train_chunk`` trains per iteration, in a few words, or None
+        when it runs fused chunks.  ``feature_fraction`` and plain bagging
+        refuse nothing: both are drawn inside the scan."""
+        if not self.fuse_iters:
+            return "boosting=%s decides on the host every iteration" \
+                % type(self).__name__.lower()
+        if not self.lazy_trees:
+            return "host trees are built eagerly (lazy_trees off)"
+        if self.objective is None:
+            return "no objective (custom gradients)"
+        if self.objective.is_renew_tree_output:
+            return "objective=%s renews leaf outputs on the host" \
+                % self.objective.name
+        if not self.objective.deterministic_gradients:
+            return "objective=%s draws its gradients from a host stream" \
+                % self.objective.name
         if not self.train_data.num_features:
-            return False
+            return "the data set has no usable feature"
         if not all(self.class_need_train):
-            return False
-        cfg = self.config
-        if float(cfg.feature_fraction) < 1.0:
-            return False
+            return "a class needs no training"
         if self._balanced_bagging():
             # the in-scan mask hashes original row ids against ONE scalar
             # fraction; per-class fractions need the labels, which do not
             # ride the (permuted) row store — per-iteration path applies them
-            return False
+            return "pos/neg_bagging_fraction need the labels beside the bag"
         if getattr(self.learner, "comm", None) is not None:
-            return False  # parallel learners keep the per-iteration path
+            return "tree_learner=%s builds under shard_map, one tree a call" \
+                % self.config.tree_learner
         if getattr(self.learner, "cegb", None) is not None:
-            return False  # CEGB carries feature-used state across iterations
+            return "CEGB carries feature-used state across iterations"
         if self._fuse_failed:
-            return False
-        return True
+            return "a fused chunk failed earlier (non-finite scores or an " \
+                   "objective that does not trace)"
+        return None
+
+    def _can_fuse_iters(self) -> bool:
+        return self._fuse_refusal() is None
 
     _fuse_failed = False
 
@@ -1020,6 +1079,7 @@ class GBDT:
 
         bag = self._fused_bag()
         bag_seed = int(self.config.bagging_seed)
+        sample_features = self._features_used() < self.train_data.num_features
         vbins = [vs["bins"] for vs in self.valid_sets]
         L = learner.num_leaves
 
@@ -1028,19 +1088,24 @@ class GBDT:
         def one_iter_of(bins):
             def one_iter(carry, it):
                 rows, sums, vscores = carry
-                if bag is not None:
-                    # the bagged row count depends on order, it and the seed
-                    # only: one column of the store, read when bagging is on
-                    nd_it = jnp.maximum(
-                        jnp.sum(live(i32_col(rows, voff + 8), it),
-                                dtype=jnp.float32), 1.0).astype(jnp.int32)
-                else:
-                    nd_it = nd
+                nd_it, fm_it = nd, fm
+                if bag is not None or sample_features:
+                    # what this tree samples, each drawn only when it is on
+                    with jax.named_scope("gbdt.sample"):
+                        if bag is not None:
+                            # the bagged row count depends on order, it and
+                            # the seed only: one column of the store
+                            nd_it = jnp.maximum(
+                                jnp.sum(live(i32_col(rows, voff + 8), it),
+                                        dtype=jnp.float32),
+                                1.0).astype(jnp.int32)
+                        if sample_features:
+                            fm_it = self._feature_mask(it)
                 # the store's gradient bytes are current: the last tree's
                 # pass (or the prologue) wrote them; this tree's pass writes
                 # its score and the gradients of iteration it + 1
                 arr, rows, sums = build_tree_partitioned(
-                    bins, None, None, nd_it, fm, feat,
+                    bins, None, None, nd_it, fm_it, feat,
                     rows_carry=rows, root_sums=sums,
                     score_rate=jnp.float32(rate), quant_it=it,
                     grad_fn=grad_fn, **kwargs)
@@ -1050,7 +1115,9 @@ class GBDT:
                 vscores = _add_valid_outputs(
                     vscores, 0, arr, feat, vbins, L,
                     learner.has_categorical)
-                return (rows, sums, vscores), (arr,)
+                out = (arr,)
+                return (rows, sums, vscores), (
+                    out if bag is None else (out, nd_it))
             return one_iter
 
         def fused(score, vscores, it0):
@@ -1078,6 +1145,10 @@ class GBDT:
             score_out = jnp.zeros((ntot,), jnp.float32).at[
                 i32_col(rows_fin, voff + 8)].set(
                     f32_col(rows_fin, soff), mode="drop")
+            if bag is not None:
+                # a bagged chunk also hands out its k realised bag counts
+                stacked, bag_rows = stacked
+                return score_out[None], vs_out, stacked, bag_rows
             return score_out[None], vs_out, stacked
 
         return _hoisted_jit(fused, self.train_score,
@@ -1114,12 +1185,24 @@ class GBDT:
 
         bag = self._fused_bag()
         bag_seed = int(self.config.bagging_seed)
+        sample_features = self._features_used() < self.train_data.num_features
         vbins = [vs["bins"] for vs in self.valid_sets]
         L = learner.num_leaves
 
         def one_iter_of(bins):
             def one_iter(carry, it):
                 score, vscores = carry
+                nd_it, fm_it = nd, fm
+                if bag is not None or sample_features:
+                    # what this tree samples, each drawn only when it is on
+                    with jax.named_scope("gbdt.sample"):
+                        if bag is not None:
+                            frac, freq = bag
+                            mask, nd_it = _bag_mask_for(
+                                jnp.arange(n, dtype=jnp.int32), bag_seed, it,
+                                freq, frac)
+                        if sample_features:
+                            fm_it = self._feature_mask(it)
                 with jax.named_scope("gbdt.gradients"):
                     live = score[:, :n]
                     g, h = objective.get_gradients(
@@ -1127,19 +1210,13 @@ class GBDT:
                     g = jnp.reshape(g, (K, n))
                     h = jnp.reshape(h, (K, n))
                     if bag is not None:
-                        frac, freq = bag
-                        mask, nd_it = _bag_mask_for(
-                            jnp.arange(n, dtype=jnp.int32), bag_seed, it,
-                            freq, frac)
                         g = g * mask[None, :]
                         h = h * mask[None, :]
-                    else:
-                        nd_it = nd
                 outs = []
                 for kk in range(K):
                     gk = jnp.pad(g[kk], (0, pad))
                     hk = jnp.pad(h[kk], (0, pad))
-                    arr = build_tree_partitioned(bins, gk, hk, nd_it, fm,
+                    arr = build_tree_partitioned(bins, gk, hk, nd_it, fm_it,
                                                  feat, quant_it=it, **kwargs)
                     arr = arr._replace(
                         leaf_value=arr.leaf_value * rate,
@@ -1149,13 +1226,19 @@ class GBDT:
                         vscores, kk, arr, feat, vbins, L,
                         learner.has_categorical)
                     outs.append(arr)
-                return (score, vscores), tuple(outs)
+                outs = tuple(outs)
+                return (score, vscores), (
+                    outs if bag is None else (outs, nd_it))
             return one_iter
 
         def fused(score, vscores, it0):
             (score, vs_out), stacked = _scan_grouped(
                 one_iter_of(learner.bins), (score, tuple(vscores)),
                 it0 + jnp.arange(k, dtype=jnp.int32), self._trees_per_chunk())
+            if bag is not None:
+                # a bagged chunk also hands out its k realised bag counts
+                stacked, bag_rows = stacked
+                return score, vs_out, stacked, bag_rows
             return score, vs_out, stacked
 
         return _hoisted_jit(fused, self.train_score,
@@ -1208,7 +1291,15 @@ class GBDT:
                           tuple(vs["score"] for vs in self.valid_sets),
                           len(self._models), self.iter_,
                           self.bag_mask, self.bag_data_cnt)
-        if not self._can_fuse_iters():
+        refused = self._fuse_refusal()
+        if refused is not None:
+            if refused not in self._fuse_refusals_said:
+                # once a reason: a job meant for the fused path that trains
+                # one tree a host round says so, here and in the spans
+                self._fuse_refusals_said.add(refused)
+                Log.info("train_chunk trains per iteration, not in fused "
+                         "chunks: %s", refused)
+                _spans.note("gbdt.per_iteration: " + refused, 0.0)
             tele = _telemetry_active()
             t0 = time.perf_counter()
             it0 = self.iter_
@@ -1248,7 +1339,7 @@ class GBDT:
                 _spans.span("fused_train_chunk"), \
                 _watch("fused_train_chunk", compile_key=int(num_iters),
                        first_iter=int(self.iter_), iters=int(num_iters)):
-            new_score, new_vscores, stacked = fn(
+            new_score, new_vscores, stacked, *bag_rows = fn(
                 self.train_score,
                 tuple(vs["score"] for vs in self.valid_sets),
                 jnp.int32(self.iter_))
@@ -1262,6 +1353,9 @@ class GBDT:
         _launches.record(self.learner.effective_grow_mode(),
                          self.learner.launches_per_tree(),
                          trees=num_iters * K)
+        # the k bag counts stay on the device until somebody asks
+        _sampling.record_trees("fused", num_iters * K,
+                               bag_rows[0] if bag_rows else self.num_data)
         first_idx = len(self._models)
         first_iter = self.iter_
         self._last_iter_arrays = []
@@ -1439,6 +1533,8 @@ class GBDT:
                 del self.models[-self.num_tree_per_iteration:]
             return True
         self.iter_ += 1
+        _sampling.record_trees("per_iteration", self.num_tree_per_iteration,
+                               self.bag_data_cnt)
         return False
 
     def _adjust_gradients_for_bagging(self, grad, hess):
@@ -1637,8 +1733,9 @@ class GBDT:
     def capture_train_state(self):
         """(meta, arrays, model_str): EVERYTHING future iterations read.
 
-        The model string alone loses the bagging/feature-fraction RNG
-        streams, early-stopping bookkeeping, CEGB paid-cost state and the
+        The model string alone loses GOSS's sampling stream (the bag and the
+        feature mask are stateless functions of the iteration and need
+        none), early-stopping bookkeeping, CEGB paid-cost state and the
         f32 score caches, so an init_model resume silently diverges; this
         captures all of it.  Scores go as binary arrays — DART's dropout
         makes the incremental f32 score sum order-dependent, so a replay of
@@ -1663,7 +1760,6 @@ class GBDT:
             "num_init_iteration": int(self.num_init_iteration),
             "shrinkage_rate": float(self.shrinkage_rate),
             "bag_rng": encode_rng_state(self._bag_rng),
-            "feat_rng": encode_rng_state(self._feat_rng),
             "es_state": [[ds, name, float(cur), int(it)]
                          for (ds, name), (cur, it)
                          in sorted(self._es_state.items())],
@@ -1777,7 +1873,8 @@ class GBDT:
         self.num_init_iteration = int(meta["num_init_iteration"])
         self.shrinkage_rate = float(meta["shrinkage_rate"])
         self._bag_rng.set_state(decode_rng_state(meta["bag_rng"]))
-        self._feat_rng.set_state(decode_rng_state(meta["feat_rng"]))
+        # (a checkpoint from before the stateless feature mask also carries
+        # "feat_rng", a stream nothing draws from any more: not read)
         self._es_state = {(ds, name): (cur, it)
                           for ds, name, cur, it in meta.get("es_state", [])}
         self.train_score = self._place_rows(ts)

@@ -7,6 +7,7 @@ import numpy as np
 
 from .gbdt import GBDT
 from ..core.tree import Tree
+from ..obs import sampling as _sampling
 from ..utils.log import Log
 
 K_EPSILON = 1e-15
@@ -131,4 +132,6 @@ class RF(GBDT):
                 del self.models[-self.num_tree_per_iteration:]
             return True
         self.iter_ += 1
+        _sampling.record_trees("per_iteration", self.num_tree_per_iteration,
+                               self.bag_data_cnt)
         return False

@@ -1,9 +1,9 @@
 """Fault-tolerant training checkpoints: versioned ``TrainState`` snapshots.
 
 On TPU pods preemption is routine; a run that cannot resume *bit-exactly*
-loses hours of work.  The model string alone is not enough — bagging /
-feature-fraction / DART RNG streams, DART drop history, early-stopping
-bookkeeping, CEGB paid-cost state and the score cache all feed future
+loses hours of work.  The model string alone is not enough — GOSS / DART
+RNG streams (the bag and the feature mask are stateless), DART drop history,
+early-stopping bookkeeping, CEGB paid-cost state and the score cache all feed future
 iterations, so an ``init_model``-style resume silently diverges from the
 uninterrupted run.  A checkpoint captures ALL of it:
 

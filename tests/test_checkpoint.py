@@ -164,8 +164,8 @@ def test_resume_bit_exact_rf(tmp_path):
 
 
 def test_resume_bit_exact_feature_fraction(tmp_path):
-    # feature_fraction < 1 disables fusion and draws from _feat_rng every
-    # iteration — the per-iteration RNG stream must continue, not restart
+    # the feature mask is a stateless function of (seed, iteration) since
+    # PR 36: the resumed run draws iteration k's mask, not iteration 0's
     params = dict(BASE, feature_fraction=0.6)
     full, resumed, _ = run_full_and_resumed(params, tmp_path=tmp_path)
     assert full == resumed

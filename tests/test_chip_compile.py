@@ -194,17 +194,18 @@ _NO_GLUE = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast",
             "copy-done", "slice-start", "slice-done", "iota"}
 
 
-def _chunk_program_text(one_chip, monkeypatch_module, ds):
+def _chunk_program_text(one_chip, monkeypatch_module, ds, **sampling):
     """Compiled text of a fused chunk of 2 trees x 255 leaves on ``ds``, as
     ``GBDT.chunk_program_text`` gives it on the chip.  The booster is built on
     the CPU; its fused step is lowered for the described chip from shapes,
-    the way ``_hoisted_jit`` lowers it from arrays."""
+    the way ``_hoisted_jit`` lowers it from arrays.  ``sampling``: row and
+    column subsampling parameters, none by default."""
     from lightgbm_tpu.boosting import gbdt as G
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.objective import create_objective
     cfg = Config(verbosity=-1, objective="binary", num_leaves=255,
                  max_bin=255, min_data_in_leaf=0,
-                 min_sum_hessian_in_leaf=100.0)
+                 min_sum_hessian_in_leaf=100.0, **sampling)
     g = G.GBDT(cfg, ds, create_objective("binary", cfg))
     g.learner.use_pallas = True          # the chip's path, not the CPU's
     taken = {}
@@ -223,16 +224,21 @@ def _chunk_program_text(one_chip, monkeypatch_module, ds):
             ).compile().as_text()
 
 
-@pytest.fixture(scope="module")
-def chunk_text(one_chip, monkeypatch_module):
-    """The chunk program on 2^16 rows of Higgs width."""
+def _higgs_width_table():
     import numpy as np
     from lightgbm_tpu.io.dataset import BinnedDataset
     rng = np.random.default_rng(0)
     X = rng.standard_normal((1 << 16, F)).astype(np.float32)
     y = (X[:, 0] + X[:, 1] * X[:, 2] + rng.standard_normal(len(X)) > 0)
-    ds = BinnedDataset.from_matrix(X, label=y.astype(np.float32), max_bin=255)
-    return _chunk_program_text(one_chip, monkeypatch_module, ds)
+    return BinnedDataset.from_matrix(X, label=y.astype(np.float32),
+                                     max_bin=255)
+
+
+@pytest.fixture(scope="module")
+def chunk_text(one_chip, monkeypatch_module):
+    """The chunk program on 2^16 rows of Higgs width."""
+    return _chunk_program_text(one_chip, monkeypatch_module,
+                               _higgs_width_table())
 
 
 @pytest.fixture(scope="module")
@@ -318,6 +324,26 @@ def test_chunk_program_scopes_cover_the_glue(chunk_text):
     passes = {op: s for op, s in scope_of.items()
               if op.startswith("%row_state_pass")}
     assert passes and set(passes.values()) == {"tree.finish"}
+
+
+def test_subsampled_chunk_program_compiles_with_its_draws_under_a_scope(
+        one_chip, monkeypatch_module):
+    """The ``higgs-10m5-sub`` chunk: the feature mask and the bag count
+    change every scan step, the hand-over pass recomputes the bag from the
+    order bytes tile by tile.  The chip's compiler takes it, keeps the draws
+    under ``gbdt.sample`` inside the loop, and the pass is still one kernel."""
+    from lightgbm_tpu.obs.scopes import op_scopes
+    text = _chunk_program_text(
+        one_chip, monkeypatch_module, _higgs_width_table(),
+        feature_fraction=0.8, bagging_fraction=0.8, bagging_freq=5)
+    glue = _glue_instructions(text)
+    scope_of = op_scopes(text, SCOPES + ["gbdt.sample"])
+    drawn = {op: glue[op] for op in glue if scope_of[op] == "gbdt.sample"}
+    assert "sort" in drawn.values(), drawn       # the mask's ranking
+    assert "gbdt.sample/" in text and "while/body" in "".join(
+        ln for ln in text.splitlines() if "gbdt.sample/" in ln)
+    passes = [op for op in scope_of if op.startswith("%row_state_pass")]
+    assert len(passes) == 1 and scope_of[passes[0]] == "tree.finish"
 
 
 def test_grouped_chunk_program_compiles_with_the_search_on_group_lanes(

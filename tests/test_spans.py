@@ -159,7 +159,9 @@ def test_set_up_spans_of_a_csr_ingest():
 
 
 def test_spans_one_train_one_iter_opens():
-    g = _booster(feature_fraction=0.5)     # off the fused path
+    # balanced bagging keeps the per-iteration path (feature_fraction, this
+    # test's lever before PR 36, trains in fused chunks now)
+    g = _booster(pos_bagging_fraction=0.5, bagging_freq=1)
     assert not g._can_fuse_iters()
     g.train_one_iter()
     spans.reset()
