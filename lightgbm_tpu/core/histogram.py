@@ -349,14 +349,33 @@ def _hist_channels(quantized: bool = False) -> int:
 
 def _factored_geometry(num_features: int, num_bins: int,
                        quantized: bool = False):
-    """(p, G): features per MXU group and group count.  Each group's left
-    operand stacks p features' value-weighted hi one-hots as
+    """(p, G): features per MXU group and group count.  A group's two
+    operands are p features' value-weighted hi one-hots stacked as
     [p*nch*nhi = 128, R] (nch = 4, or 2 quantized — the integer operand
-    packs TWICE the features per group); the right stacks their lo
-    one-hots [p*nlo, R]."""
+    packs TWICE the features per group) and their lo one-hots [p*nlo, R];
+    the contraction over R puts the LO rows on the result's sublanes and the
+    128 weighted-hi rows on its lanes (see _accum_factored_group)."""
     nhi, _ = _hilo_factors(num_bins)
     p = max(1, _LANE // (_hist_channels(quantized) * nhi))
     return p, -(-num_features // p)
+
+
+# bin columns one extraction dot pulls out of a tile: one i32 sublane tile,
+# so the [8, R] codes and their hi / lo parts cost what ONE row of them did
+_BLOCK_ROWS = 8
+
+
+def _group_block(num_features: int, num_bins: int,
+                 quantized: bool = False):
+    """(k, blocks): feature groups a BLOCK and the block count.  A block is
+    the groups whose k * p <= 8 bin columns one extraction dot makes (four
+    groups at 256 bins, two at 64 bins or quantized, one where p = 8: the
+    nibble-packed 32-lane geometry) — a constant number of groups, so the
+    program stays O(p) in F; every kernel runs the blocks in a rolled loop
+    (_accum_factored_all)."""
+    p, G = _factored_geometry(num_features, num_bins, quantized)
+    k = max(1, min(_BLOCK_ROWS // p, G))
+    return k, -(-G // k)
 
 
 def _use_factored(num_features: int, num_bins: int,
@@ -369,11 +388,15 @@ def _use_factored(num_features: int, num_bins: int,
     (row, feature) plus a p x p all-pairs MXU block per feature group (only
     the diagonal is read) — per-feature cost near-independent of B, so it
     wins essentially everywhere the accumulator fits on-chip.  The bound
-    below caps the [G*128, p*nlo] f32 accumulator at the device's
+    below caps the [G*p*nlo, 128] f32 accumulator at the device's
     accumulator budget — a quarter of VMEM, 4 MiB on the 16 MiB v5e
     (``plan/device_specs.py``, round 18: previously a literal here) — so
     it fits alongside the partition kernel's ~5 MiB of round-6 pipelined
     streaming scratch (NIN=3 input ring + double-banked placement tiles).
+    The accumulator's minor dimension is a whole 128 lanes, so the product
+    below is the bytes VMEM really holds: the [G*128, p*nlo] layout before
+    PR 37 had the same element count and was lane-padded to four times it
+    at 256 bins (917 KB at F = 28, where this counts 229).
 
     A PINNED kernel plan (``plan/state.py``, tests and the autotuner)
     overrides the choice outright; the layout is baked into compiled
@@ -394,124 +417,151 @@ def _use_factored(num_features: int, num_bins: int,
     return out[0] * out[1] * 4 <= budget
 
 
-def _accum_factored_group(ti_bf, v4T, out_ref, g, *, num_features: int,
-                          num_bins: int, bpc: int, packed: bool, f_base=0,
-                          quantized: bool = False):
+def _accum_factored_group(hi, lo, v32, out_ref, g, *, oh_t, num_bins: int):
     """ONE feature group's factored-MXU histogram accumulation, with the
-    group index ``g`` a TRACED scalar — the building block both of the
-    grid-over-groups standalone kernel (g = pl.program_id) and of the fused
-    kernel's rolled ``fori_loop`` over groups.  The round-5 layout unrolled a
-    Python loop over all G groups (and an extraction matrix with one row per
-    FEATURE), which at wide F (Bosch F=968) blew Mosaic compiles past 10
-    minutes; here program size is O(p) regardless of F.
+    group index ``g`` a TRACED scalar.
 
-    ti_bf: [R, W] bf16 row-store tile (byte values exact in bf16);
-    v4T: [4, R] (grad_hi, hess_hi, grad_lo, hess_lo) from
-    :func:`_extract_values_T`; out_ref: [G*p*4*nhi, p*nlo] f32 — the group's
-    [p*4*nhi, p*nlo] block is += accumulated at a dynamic sublane offset.
+    hi, lo: [p, R] i32 — the group's bin codes split as ``bin = hi * nlo +
+    lo``, ``lo`` at -1 (no one-hot row) for a feature past the last
+    (:func:`_accum_factored_block` makes both for a block of groups at
+    once); v32: [nch, R] f32, the value rows of :func:`_extract_values_T`
+    widened once a block; ``oh_t``: the operands' type (bf16; f32 in exact
+    mode); out_ref: [G*p*nlo, p*nch*nhi = 128] f32 — the group's
+    [p*nlo, 128] block is += accumulated at a dynamic sublane offset.
 
-    The bin one-hot build costs nhi + nlo compares per (row, feature) —
-    near-independent of B — and the value weighting rides the hi side of a
-    [p*4*nhi, R] @ [R, p*nlo] contraction whose p x p feature cross-blocks
-    are discarded except the diagonal (see _fold_factored)."""
+    The one-hot build costs nhi + nlo compares per (row, feature) —
+    near-independent of B.  The weighted hi one-hot is a SELECT of the value
+    row on the compare's own mask (v5e has no bf16 ALU: a bf16 multiply is
+    two unpacks, an f32 multiply and a pack; the select makes the same
+    operand, x * 1 and x * 0 of a finite x, to the sign of a zero), and the
+    contraction ``lo_big[p*nlo, R] . a_big[128, R]^T`` is lane-dense: a
+    128-row K-chunk pops p*nlo / 8 = 4 result vregs of 128 useful lanes,
+    where ``a_big . lo_big^T`` popped 16 of 32.  Its p x p feature
+    cross-blocks are discarded except the diagonal (see _fold_factored)."""
     nhi, nlo = _hilo_factors(num_bins)
-    p, _ = _factored_geometry(num_features, num_bins, quantized)
-    nch = _hist_channels(quantized)
-    exact = v4T.dtype == jnp.float32
-    oh_t = v4T.dtype
-    W = ti_bf.shape[1]
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-    f0 = f_base + g * p
-    # dynamic byte-column selection matrix for the group's bin codes: the
-    # row index rides a broadcasted iota compared against the traced f0, so
-    # ONE [nbrow, W] @ [R, W]^T dot extracts the whole group at any F
-    if packed:
-        # p is even for every packed geometry (p = 32 // nhi, nhi <= 8 at
-        # the 32-lane packed block) and callers keep f_base even, so the
-        # group covers whole bytes and nibble parity is q % 2
-        nbrow = max(p // 2, 1)
-        rowsel = (f0 // 2) + jax.lax.broadcasted_iota(
-            jnp.int32, (nbrow, 1), 0)
-    elif bpc == 2:
-        nbrow = 2 * p
-        k2 = jax.lax.broadcasted_iota(jnp.int32, (nbrow, 1), 0)
-        rowsel = 2 * (f0 + k2 // 2) + jax.lax.rem(k2, 2)
-    else:
-        nbrow = p
-        rowsel = f0 + jax.lax.broadcasted_iota(jnp.int32, (nbrow, 1), 0)
-    E = (iota_w == rowsel).astype(jnp.bfloat16)            # [nbrow, W]
-    colsT = jax.lax.dot_general(
-        E, ti_bf, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(jnp.int32)   # [nbrow, R]
+    p, nch = hi.shape[0], v32.shape[0]
     iota_hi = jax.lax.broadcasted_iota(jnp.int32, (nhi, 1), 0)
     iota_lo = jax.lax.broadcasted_iota(jnp.int32, (nlo, 1), 0)
-    sh = nlo.bit_length() - 1
     a_blocks = []
     lo_blocks = []
     for q in range(p):
-        if packed:
-            byte = colsT[q // 2:q // 2 + 1, :]
-            colf = (byte >> (4 * (q % 2))) & 15
-        elif bpc == 2:
-            colf = colsT[2 * q:2 * q + 1, :] | (colsT[2 * q + 1:2 * q + 2, :]
-                                                << 8)
-        else:
-            colf = colsT[q:q + 1, :]
-        # num_features is the histogrammed WINDOW's width (f_base is the
-        # absolute byte offset of its first feature), so validity is local
-        # traced bool scalar: the last group's tail features mask to zero
-        # contribution.  The mask rides the [1, R] i32 bin codes (-1 matches
-        # no iota row): Mosaic has no select on i1 vectors, so masking the
-        # boolean one-hots themselves does not legalize on the chip.
-        valid = g * p + q < num_features
-        hi_oh = (jnp.where(valid, colf >> sh, -1)
-                 == iota_hi).astype(oh_t)                     # [nhi, R]
-        lo_oh = (jnp.where(valid, colf & (nlo - 1), -1)
-                 == iota_lo).astype(oh_t)                     # [nlo, R]
+        hi_mask = hi[q:q + 1, :] == iota_hi                   # [nhi, R]
         for c in range(nch):
-            a_blocks.append(v4T[c:c + 1, :] * hi_oh)
-        lo_blocks.append(lo_oh)
-    a_big = jnp.concatenate(a_blocks, axis=0)              # [p*nch*nhi, R]
-    lo_big = jnp.concatenate(lo_blocks, axis=0)            # [p*nlo, R]
+            a_blocks.append(jnp.where(hi_mask, v32[c:c + 1, :], 0.0))
+        lo_blocks.append(jnp.where(lo[q:q + 1, :] == iota_lo, 1.0, 0.0))
+    # stacked in f32, where a block is whole (8, 128) tiles, and packed to
+    # the operands' type once: blocks packed one by one are laid out again
+    # by the concatenation (an unpack and a pack a vreg)
+    a_big = jnp.concatenate(a_blocks, axis=0).astype(oh_t)   # [128, R]
+    lo_big = jnp.concatenate(lo_blocks, axis=0).astype(oh_t)  # [p*nlo, R]
     acc = jax.lax.dot_general(
-        a_big, lo_big, (((1,), (1,)), ((), ())),
+        lo_big, a_big, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST if exact else None)
-    rows = a_big.shape[0]
+        precision=(jax.lax.Precision.HIGHEST if oh_t == jnp.float32
+                   else None))                             # [p*nlo, 128]
+    rows = p * nlo
     off = pl.multiple_of(g * rows, rows)
     out_ref[pl.ds(off, rows), :] += acc
+
+
+def _accum_factored_block(ti_bf, v4T, out_ref, gb, *, num_features: int,
+                          num_bins: int, bpc: int, packed: bool, f_base=0,
+                          quantized: bool = False):
+    """One BLOCK of k feature groups (:func:`_group_block`), the block index
+    ``gb`` a TRACED scalar — the body of the rolled loop every kernel runs
+    (the standalone one a row tile, the fused ones a chunk).  The round-5
+    layout unrolled a Python loop over all G groups (and an extraction
+    matrix with one row per FEATURE), which at wide F (Bosch F=968) blew
+    Mosaic compiles past 10 minutes; here program size is O(p) regardless
+    of F.
+
+    ti_bf: [R, W] bf16 row-store tile (byte values exact in bf16); v4T:
+    [nch, R] (grad_hi, hess_hi, grad_lo, hess_lo) from
+    :func:`_extract_values_T`.
+
+    ONE ``E[k*p, W] @ ti_bf^T`` dot pulls the block's k * p bin codes out
+    of the tile (the tile crosses the MXU once a block; a dot a group
+    pushed all of it for p = 2 columns), every table kind a row a feature:
+    a two-byte code is both its bytes weighted 1 and 256 in E, a nibble-
+    packed one its byte, shifted out below.  The selection rows ride a
+    broadcasted iota compared against the traced first feature, so the dot
+    serves any F.  Groups past G in the last block are skipped."""
+    _, nlo = _hilo_factors(num_bins)
+    p, G = _factored_geometry(num_features, num_bins, quantized)
+    k, blocks = _group_block(num_features, num_bins, quantized)
+    W = ti_bf.shape[1]
+    iota_w = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    r = jax.lax.broadcasted_iota(jnp.int32, (k * p, 1), 0)
+    g0 = gb * k
+    f = f_base + g0 * p + r               # each row's absolute feature
+    if packed:
+        # callers keep f_base even and p is even for every packed geometry
+        # (p = 32 // nhi, nhi <= 8 at the 32-lane packed block), so a
+        # feature's nibble parity is its row's
+        E = (iota_w == f // 2).astype(jnp.bfloat16)
+    elif bpc == 2:
+        E = ((iota_w == 2 * f).astype(jnp.bfloat16)
+             + (iota_w == 2 * f + 1).astype(jnp.bfloat16) * 256)
+    else:
+        E = (iota_w == f).astype(jnp.bfloat16)              # [k*p, W]
+    codes = jax.lax.dot_general(
+        E, ti_bf, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)   # [k*p, R]
+    if packed:
+        codes = (codes >> (4 * jax.lax.rem(r, 2))) & 15
+    # num_features is the histogrammed WINDOW's width (f_base is the
+    # absolute byte offset of its first feature), so validity is local: the
+    # last group's tail features get lo = -1, which matches no iota row, so
+    # their (diagonal) blocks accumulate zeros.  The mask rides the i32 bin
+    # codes: Mosaic has no select on i1 vectors, so masking the boolean
+    # one-hots themselves does not legalize on the chip.
+    hi = codes >> (nlo.bit_length() - 1)
+    lo = jnp.where(g0 * p + r < num_features, codes & (nlo - 1), -1)
+    v32 = v4T.astype(jnp.float32)
+    for j in range(k):
+        def _group(j=j):
+            _accum_factored_group(hi[j * p:(j + 1) * p], lo[j * p:(j + 1) * p],
+                                  v32, out_ref, g0 + j, oh_t=v4T.dtype,
+                                  num_bins=num_bins)
+        if j < G - (blocks - 1) * k:      # in every block, the last too
+            _group()
+        else:
+            pl.when(g0 + j < G)(_group)
 
 
 def _accum_factored_all(ti_bf, v4T, out_ref, *, num_features: int,
                         num_bins: int, bpc: int, packed: bool, f_base=0,
                         quantized: bool = False):
-    """Rolled loop over every feature group (the fused partition kernel's
-    in-kernel histogram; the standalone kernel puts groups on the grid)."""
-    _, G = _factored_geometry(num_features, num_bins, quantized)
+    """Rolled loop over every block of feature groups: the fused partition
+    kernels' in-kernel histogram of a chunk, the standalone kernel's of a
+    row tile."""
+    _, blocks = _group_block(num_features, num_bins, quantized)
 
-    def body(g, _):
-        _accum_factored_group(ti_bf, v4T, out_ref, g,
+    def body(gb, _):
+        _accum_factored_block(ti_bf, v4T, out_ref, gb,
                               num_features=num_features, num_bins=num_bins,
                               bpc=bpc, packed=packed, f_base=f_base,
                               quantized=quantized)
         return 0
 
-    jax.lax.fori_loop(0, G, body, 0)
+    jax.lax.fori_loop(0, blocks, body, 0)
 
 
 def _fold_factored(raw, num_features: int, num_bins: int,
                    quantized: bool = False):
-    """[G*128, p*nlo] factored accumulator -> [F, 2, B] f32 (grad = hi + lo
-    value channels, hess likewise; bin = hi * nlo + lo).  Quantized
-    accumulators already carry exactly the 2 (grad, hess) integer channels —
-    no fold, just the diagonal gather."""
+    """[G*p*nlo, p*nch*nhi] factored accumulator -> [F, 2, B] f32 (grad =
+    hi + lo value channels, hess likewise; bin = hi * nlo + lo: the
+    accumulator holds a group as (feature, lo) x (feature, channel, hi), of
+    which the feature diagonal is the histogram).  Quantized accumulators
+    already carry exactly the 2 (grad, hess) integer channels — no fold,
+    just the diagonal gather."""
     nhi, nlo = _hilo_factors(num_bins)
     p, G = _factored_geometry(num_features, num_bins, quantized)
     nch = _hist_channels(quantized)
-    d = raw.reshape(G, p, nch, nhi, p, nlo)
+    d = raw.reshape(G, p, nlo, p, nch, nhi)
     idx = jnp.arange(p)
-    diag = d[:, idx, :, :, idx, :]          # [p, G, nch, nhi, nlo]
-    h = diag.transpose(1, 0, 2, 3, 4).reshape(G * p, nch, nhi * nlo)
+    diag = d[:, idx, :, idx, :, :]          # [p, G, nlo, nch, nhi]
+    h = diag.transpose(1, 0, 3, 4, 2).reshape(G * p, nch, nhi * nlo)
     h = h[:num_features]
     if quantized:
         return h
@@ -522,7 +572,7 @@ def _factored_out_shape(num_features: int, num_bins: int,
                         quantized: bool = False):
     nhi, nlo = _hilo_factors(num_bins)
     p, G = _factored_geometry(num_features, num_bins, quantized)
-    return (G * p * _hist_channels(quantized) * nhi, p * nlo)
+    return (G * p * nlo, p * _hist_channels(quantized) * nhi)
 
 
 def _extract_values_T(ti_bf, *, voff: int, exact: bool, inwT=None,
@@ -531,13 +581,12 @@ def _extract_values_T(ti_bf, *, voff: int, exact: bool, inwT=None,
     [4, W] @ [R, W]^T dot pulls the four 16-bit halves, the f32s are rebuilt
     via i32 OR (the wrap restores the sign bit; the OBVIOUS shifted-slice OR
     chain is miscompiled on v5e — see _f32_from_bytes), and the hi/lo bf16
-    split makes the v4T operand of :func:`_accum_factored_group`.
+    split makes the v4T operand of :func:`_accum_factored_block`.
 
-    The per-group bin extraction moved into _accum_factored_group itself
-    (dynamic group index); values are extracted ONCE per tile and reused by
-    every group.  Keeping every per-row intermediate LANE-major ([k, R])
-    matters as much as the dot: sliced [R, 1] intermediates are 128x
-    vreg-padded."""
+    The bin extraction is the block step's (dynamic block index); values
+    are extracted ONCE per tile and reused by every group.  Keeping every
+    per-row intermediate LANE-major ([k, R]) matters as much as the dot:
+    sliced [R, 1] intermediates are 128x vreg-padded."""
     W = ti_bf.shape[1]
     f32 = jnp.float32
     iota_w = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
@@ -649,42 +698,38 @@ def _hist_kernel_rows(win_ref, rows_ref, out_ref, w_sc, v4_sc, *,
                                num_bins=num_bins, contract_dim=0)
 
 
-def _hist_kernel_rows_fac(win_ref, rows_ref, out_ref, tib_sc, v4_sc, *,
+def _hist_kernel_rows_fac(win_ref, rows_ref, out_ref, *,
                           num_features: int, num_bins: int, row_tile: int,
                           packed: bool, voff: int, bpc: int,
                           exact: bool = False, quantized: bool = False):
-    """Factored-MXU variant of _hist_kernel_rows, GRID over feature groups:
-    grid = (row tiles, G), one [p*4*nhi, R] @ [R, p*nlo] group block per
-    step (see _accum_factored_group).  out_ref: [G*128, p*nlo] f32 — fold
-    with _fold_factored.  win_ref[2] is the feature-window base
-    (feature-parallel shards).  The bf16 tile and the v4T value operand are
-    staged once per row tile (at g == 0) and reused by every group."""
+    """Factored-MXU variant of _hist_kernel_rows, grid = (row tiles,): a
+    step makes the tile's bf16 copy and its v4T value operand once and
+    runs every block of feature groups over them in the fused kernels'
+    rolled loop (see _accum_factored_all).  The blocks are not a grid axis
+    because a grid step costs beyond its bundles: one window on the chip
+    at F = 28 reads 2.328 ns a row with them there, 2.216 so (PERF.md §5).
+    out_ref: [G*p*nlo, 128] f32 — fold with _fold_factored.  win_ref[2] is
+    the feature-window base (feature-parallel shards)."""
     i = pl.program_id(0)
-    g = pl.program_id(1)
 
-    @pl.when((i == 0) & (g == 0))
+    @pl.when(i == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     start, count = win_ref[0], win_ref[1]
     base = i * row_tile
-    active = (base < start + count) & (base + row_tile > start)
 
-    @pl.when(active & (g == 0))
-    def _stage_tile():
-        tib_sc[...] = rows_ref[...].astype(jnp.int32).astype(jnp.bfloat16)
+    @pl.when((base < start + count) & (base + row_tile > start))
+    def _accum():
+        tib = rows_ref[...].astype(jnp.int32).astype(jnp.bfloat16)
         posT = base + jax.lax.broadcasted_iota(jnp.int32, (1, row_tile), 1)
         inwT = ((posT >= start).astype(jnp.float32)
                 * (posT < start + count).astype(jnp.float32))
-        v4_sc[...] = _extract_values_T(tib_sc[...], voff=voff, exact=exact,
-                                       inwT=inwT, quantized=quantized)
-
-    @pl.when(active)
-    def _accum():
-        _accum_factored_group(tib_sc[...], v4_sc[...], out_ref, g,
-                              num_features=num_features, num_bins=num_bins,
-                              bpc=bpc, packed=packed, f_base=win_ref[2],
-                              quantized=quantized)
+        v4T = _extract_values_T(tib, voff=voff, exact=exact, inwT=inwT,
+                                quantized=quantized)
+        _accum_factored_all(tib, v4T, out_ref, num_features=num_features,
+                            num_bins=num_bins, bpc=bpc, packed=packed,
+                            f_base=win_ref[2], quantized=quantized)
 
 
 @functools.partial(jax.jit, static_argnames=("num_features", "num_bins",
@@ -723,29 +768,26 @@ def histogram_pallas_rows(rows: jax.Array, num_bins: int, start: jax.Array,
     nch = _hist_channels(quantized)
     v4_dtype = jnp.float32 if exact else jnp.bfloat16
 
-    def _in_idx(i, g, win_ref):
+    def _in_idx(i, *rest):
         # tiles outside the window revisit block 0 (Mosaic elides the
-        # re-fetch); the group/tile grid axis never moves the input block
+        # re-fetch); the classic path's lane-tile grid axis never moves the
+        # input block
+        win_ref = rest[-1]
         active = ((i * row_tile < win_ref[0] + win_ref[1])
                   & ((i + 1) * row_tile > win_ref[0]))
         return (jnp.where(active, i, 0), 0)
 
     if _use_factored(num_features, num_bins, quantized):
         out_shape = _factored_out_shape(num_features, num_bins, quantized)
-        _, G = _factored_geometry(num_features, num_bins, quantized)
         kernel = functools.partial(
             _hist_kernel_rows_fac, num_features=num_features,
             num_bins=num_bins, row_tile=row_tile, packed=packed, voff=voff,
             bpc=bpc, exact=exact, quantized=quantized)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n // row_tile, G),
+            grid=(n // row_tile,),
             in_specs=[pl.BlockSpec((row_tile, width), _in_idx)],
-            out_specs=pl.BlockSpec(out_shape, lambda i, g, w: (0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((row_tile, width), jnp.bfloat16),  # staged tile
-                pltpu.VMEM((nch, row_tile), v4_dtype),        # v4T values
-            ],
+            out_specs=pl.BlockSpec(out_shape, lambda i, w: (0, 0)),
         )
         raw = pl.pallas_call(
             kernel,

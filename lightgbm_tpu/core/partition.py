@@ -310,9 +310,9 @@ def _hist_tile(ti_c, hist_ref, scal_ref, start, cnt, *, num_features,
     adds)."""
     rows_n = ti_c.shape[0]
     if _use_factored(num_features, num_bins, quantized):
-        # rolled fori_loop over feature groups (round 6): program size is
-        # O(p) in F, so wide-F row stores compile instead of unrolling
-        # hundreds of groups
+        # rolled fori_loop over blocks of feature groups (round 6; blocks
+        # since PR 37): program size is O(p) in F, so wide-F row stores
+        # compile instead of unrolling hundreds of groups
         ti_bf_h = ti_c.astype(jnp.bfloat16)
         posT = jax.lax.broadcasted_iota(jnp.int32, (1, rows_n), 1)
         inwT = ((posT >= start).astype(jnp.float32)
@@ -822,10 +822,13 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
         # ---- smaller child's histogram from its CONTIGUOUS block ----
         # Post-partition the smaller child is contiguous (left block in
         # rows_ref, right block in scratch).  With the factored hi/lo build
-        # (histogram._accum_factored_group) the per-row cost is nhi + nlo
-        # compares per feature instead of B — near-independent of max_bin —
-        # and the outer product rides the MXU contraction; wide-F datasets
-        # fall back to the classic packed one-hot tiles.
+        # (histogram._accum_factored_block: one extraction dot a block of
+        # feature groups, then a group's select-weighted hi one-hots
+        # [128, chunk] as the MXU's weights under its lo one-hots, into the
+        # lane-dense [G*p*nlo, 128] accumulator) the per-row cost is nhi +
+        # nlo compares per feature instead of B — near-independent of
+        # max_bin; wide-F datasets fall back to the classic packed one-hot
+        # tiles.
         if "hist" not in dbg_skip:
             def hist_pass(src_ref, base_al, head, cnt):
                 nh = (head + cnt + chunk - 1) // chunk
@@ -1202,7 +1205,7 @@ def partition_hist_pallas(rows: jax.Array, scal: jax.Array,
 
     Returns (rows_new [N_pad, W] u8 — the window stably partitioned in place,
     hist_raw f32 — smaller child's histogram in the kernel's accumulator
-    layout (factored [G*128, p*nlo] or classic [4, f_pad*num_bins]; fold
+    layout (factored [G*p*nlo, 128] or classic [4, f_pad*num_bins]; fold
     with :func:`fold_hist`), nl [1, 1] i32 — left-child row count).
     """
     return _partition_call(rows, scal, num_features=num_features,

@@ -68,7 +68,7 @@ class Plan(NamedTuple):
     bucket_plan: Tuple            # fused split dispatch schedule (leaf-wise)
     level_ladder: Tuple           # level-mode per-level bucket-class set
     hist_factored: bool           # factored hi/lo vs classic one-hot layout
-    hist_groups: int              # grid-over-groups G of the factored path
+    hist_groups: int              # feature groups G of the factored path
     hist_accum_budget_bytes: int  # factored-accumulator VMEM gate
     predict_block_vmem_bytes: int # path-matrix budget per predict block
     predict_buckets: Tuple        # serving row-padding ladder

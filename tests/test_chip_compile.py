@@ -108,25 +108,45 @@ def test_partition_hist_compiles(one_chip, small, chunk, num_bins):
         kernel="partition_hist_pallas_" + P.bucket_name(small, chunk))
 
 
-# expo_onehot_train: 700 one-hot columns bundled into nine EFB group columns,
-# so the gradients sit at byte 12 of the 128-byte row, not at 28
-F_EFB, VOFF_EFB = 9, 12
+# the other cells' device columns: expo_onehot_train's 700 one-hot columns
+# bundled into nine EFB group columns (5 feature groups: a block of four and
+# one of one; the gradients at byte 12 of the 128-byte row, not 28) and
+# criteo_dp4_train's 67 (34 groups: eight whole blocks and one of two)
+OTHER_CELLS = [(9, 12), (67, 68)]
 
 
+@pytest.mark.parametrize("f,voff", OTHER_CELLS)
 @pytest.mark.parametrize("small,chunk", [
     (s, c) for s, c, _ in P.fused_bucket_plan(1 << 20)])
-def test_partition_hist_compiles_at_nine_group_columns(one_chip, small, chunk):
+def test_partition_hist_compiles_at_the_other_cells_columns(
+        one_chip, small, chunk, f, voff):
     _compile(lambda r, s: P.partition_hist_pallas(
-        r, s, num_features=F_EFB, num_bins=256, voff=VOFF_EFB, chunk=chunk,
+        r, s, num_features=f, num_bins=256, voff=voff, chunk=chunk,
         small=small),
         one_chip, _sds((N_PAD, W), jnp.uint8), _sds((12 + 8,), jnp.int32),
         kernel="partition_hist_pallas_" + P.bucket_name(small, chunk))
 
 
-def test_histogram_rows_compiles_at_nine_group_columns(one_chip):
-    assert H._use_factored(F_EFB, 256, False)
+@pytest.mark.parametrize("f,voff", OTHER_CELLS)
+def test_histogram_rows_compiles_at_the_other_cells_columns(one_chip, f,
+                                                             voff):
+    assert H._use_factored(f, 256, False)
+    assert H._group_block(f, 256) == (4, -(-f // 8))
     _compile(lambda r, s, c: H.histogram_pallas_rows(
-        r, 256, s, c, num_features=F_EFB, voff=VOFF_EFB),
+        r, 256, s, c, num_features=f, voff=voff),
+        one_chip, _sds((N_PAD, W), jnp.uint8), _sds((), jnp.int32),
+        _sds((), jnp.int32), kernel="histogram_pallas_rows_factored")
+
+
+@pytest.mark.parametrize("f,num_bins,kw", [
+    (13, 32, dict(packed=True)),     # a nibble a code: shifted out a sublane
+    (11, 512, dict(bpc=2)),          # two bytes a code: weighted 1 and 256
+    (28, 256, dict(exact=True)),     # f32 operands, HIGHEST
+])
+def test_histogram_rows_table_kinds_compile(one_chip, f, num_bins, kw):
+    """The block step's one extraction dot serves every table kind."""
+    _compile(lambda r, s, c: H.histogram_pallas_rows(
+        r, num_bins, s, c, num_features=f, voff=64, **kw),
         one_chip, _sds((N_PAD, W), jnp.uint8), _sds((), jnp.int32),
         _sds((), jnp.int32), kernel="histogram_pallas_rows_factored")
 

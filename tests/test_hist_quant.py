@@ -125,6 +125,10 @@ def test_operand_and_accumulator_geometry():
         shp_e = _factored_out_shape(F, B, False)
         shp_q = _factored_out_shape(F, B, True)
         assert shp_e[0] * shp_e[1] == shp_q[0] * shp_q[1]
+        # the weighted-hi operand's p * nch * nhi rows are the accumulator's
+        # LANES, a whole 128 of them either way: what VMEM holds is what
+        # the element count says (PR 37)
+        assert shp_e[1] == shp_q[1] == 128
 
 
 # ---- the quantizer itself ----

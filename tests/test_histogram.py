@@ -6,7 +6,10 @@ from lightgbm_tpu.core.histogram import (histogram_pallas,
                                          histogram_pallas_rows,
                                          histogram_xla, histogram_xla_masked,
                                          pack_nibbles, rows_split_xla,
-                                         _use_factored)
+                                         _factored_geometry,
+                                         _factored_out_shape, _fold_factored,
+                                         _group_block, _hilo_factors,
+                                         _hist_channels, _use_factored)
 
 
 def make(n=1024, f=6, b=32, seed=0):
@@ -101,6 +104,90 @@ def test_histogram_rows_interpret_matches_xla(b, bpc, packed, f):
         bins, values, b, jnp.int32(start), jnp.int32(count)))
     assert _use_factored(f, b)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+def _quantize_values(rows, voff, seed):
+    """Integer-valued grad / hess in place (|v| <= 255: exact in bf16), as
+    ``core/quant.py`` leaves them in the store."""
+    rng = np.random.RandomState(seed)
+    n = rows.shape[0]
+    for off, lo in ((voff, -255), (voff + 4, 0)):
+        v = rng.randint(lo, 256, size=n).astype(np.float32)
+        rows[:, off:off + 4] = v.view(np.uint8).reshape(n, 4)
+
+
+_STEP_CASES = [(f, b, mode, 1, False, 0)
+               for f in (9, 28, 67) for b in (64, 256)
+               for mode in ("plain", "quantized", "exact")] + [
+    (13, 32, "plain", 1, True, 0),        # nibble-packed: p = 8, a block a group
+    (11, 512, "plain", 2, False, 0),      # two-byte codes: both bytes in one E
+    (28, 256, "plain", 1, False, 6),      # a feature window: 11 of 28 from 6
+]
+
+
+@pytest.mark.parametrize("f,b,mode,bpc,packed,f_begin", _STEP_CASES)
+def test_factored_step_matches_xla(f, b, mode, bpc, packed, f_begin):
+    """The factored block step (one extraction dot a block of groups, the
+    select-weighted hi operand, the lane-dense contraction and its fold)
+    against the backend-agnostic reference, in interpret mode: G not a
+    multiple of the block wherever a block holds several groups (the last
+    block's skipped groups), a window that starts and ends inside a tile."""
+    quantized, exact = mode == "quantized", mode == "exact"
+    fc = f if not f_begin else 11
+    k, blocks = _group_block(fc, b, quantized)
+    _, G = _factored_geometry(fc, b, quantized)
+    assert _use_factored(fc, b, quantized)
+    assert k == 1 or G % k, "the case should leave the last block short"
+    n = 2048
+    rows, voff = make_rows_store(n, f, b, seed=b + f, bpc=bpc, packed=packed,
+                                 W=128 if bpc == 1 else 256)
+    if quantized:
+        _quantize_values(rows, voff, seed=f)
+    start, count = 700, 900              # tiles of 1024: both cut
+    got = np.asarray(histogram_pallas_rows(
+        jnp.asarray(rows), b, jnp.int32(start), jnp.int32(count),
+        num_features=fc, voff=voff, bpc=bpc, packed=packed, row_tile=1024,
+        interpret=True, exact=exact, quantized=quantized,
+        f_begin=jnp.int32(f_begin) if f_begin else 0))
+    bins, values = rows_split_xla(jnp.asarray(rows), f, voff, bpc, packed)
+    want = np.asarray(histogram_xla_masked(
+        bins, values, b, jnp.int32(start), jnp.int32(count))
+    )[f_begin:f_begin + fc]
+    assert got.shape == (fc, 2, b)
+    if quantized:
+        np.testing.assert_array_equal(got, want)      # integer sums: exact
+    elif exact:
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-5)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("f,b,quantized", [
+    (28, 256, False), (9, 256, False), (67, 64, False), (28, 256, True)])
+def test_fold_factored_reads_the_feature_diagonal(f, b, quantized):
+    """A hand-made accumulator of ``_factored_out_shape``: row (group,
+    feature q', lo), lane (feature q, channel, hi).  The fold keeps q' = q,
+    puts (hi, lo) back in bin order and adds the lo value channels to the
+    hi ones; the cross blocks (q' != q) and the features past F are junk it
+    must not read."""
+    nhi, nlo = _hilo_factors(b)
+    p, G = _factored_geometry(f, b, quantized)
+    nch = _hist_channels(quantized)
+    shape = _factored_out_shape(f, b, quantized)
+    assert shape == (G * p * nlo, p * nch * nhi) and shape[1] % 128 == 0
+    raw = np.full((G, p, nlo, p, nch, nhi), 1e9, np.float32)
+    want = np.zeros((f, 2, b), np.float32)
+    for feat in range(f):
+        g, q = divmod(feat, p)
+        for c in range(nch):
+            for hi in range(nhi):
+                for lo in range(nlo):
+                    v = feat * 1000 + c * 100 + hi * nlo + lo + 0.5
+                    raw[g, q, lo, q, c, hi] = v
+                    want[feat, c % 2, hi * nlo + lo] += v
+    got = np.asarray(_fold_factored(jnp.asarray(raw.reshape(shape)), f, b,
+                                    quantized))
+    np.testing.assert_array_equal(got, want)
 
 
 def test_histogram_rows_classic_fallback(monkeypatch):

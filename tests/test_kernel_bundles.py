@@ -75,6 +75,22 @@ def test_the_copy_back_has_an_outer_and_an_inner_body_by_name():
         "own bundles (0 more in nested loops)" in text
 
 
+def test_the_group_block_is_named_and_shared_out_a_group():
+    """The hist pass's inner body is a BLOCK of feature groups since PR 37
+    (one extraction dot, k group steps): named so, and with k given its
+    bundles are also printed a group."""
+    loops = KB.loop_bodies(_nest(PIPELINED_DEPTHS))
+    names = KB.loop_names(loops)
+    assert [n for n in names if "group" in n] == [KB.GROUP_BLOCK] * 2
+    assert names[names.index(KB.GROUP_BLOCK) - 1].startswith("hist_pass")
+    text = KB.report(_nest(PIPELINED_DEPTHS), nest=KB.PIPELINED_LOOPS,
+                     groups_a_block=2)
+    assert text.count("[a block of feature groups]: 2 own bundles (0 more "
+                      "in nested loops) = 1 a feature group (2 a block)") == 2
+    assert "a feature group (" not in KB.report(
+        _nest(PIPELINED_DEPTHS), nest=KB.PIPELINED_LOOPS)
+
+
 @pytest.mark.parametrize("depths", [
     PIPELINED_DEPTHS[:-1],                    # the parent's: one copy-back body
     [1],                                      # the small kernel has no loop nest

@@ -99,6 +99,11 @@ def loop_bodies(bundles):
     return loops
 
 
+# The hist pass's inner body: one extraction dot and the k group steps it
+# feeds, the last block's missing groups skipped
+# (``core/histogram.py::_accum_factored_block``).
+GROUP_BLOCK = "a block of feature groups"
+
 # The pipelined kernel's loop bodies in program order, (name, nested bodies):
 # what ``_make_partition_kernel`` rolls (its unrolled Python loops show as
 # segments).  The copy-back has two since PR 33: a chunk read of the scratch,
@@ -112,8 +117,8 @@ PIPELINED_LOOPS = [
     ("chunk_c: phase C of the trailing chunks", _FLUSH_LOOPS),
     ("drain: await_left, a tile", []),
     ("drain: await_right, a tile", []),
-    ("hist_pass of the left block: a chunk", [("a feature group", [])]),
-    ("hist_pass of the right block: a chunk", [("a feature group", [])]),
+    ("hist_pass of the left block: a chunk", [(GROUP_BLOCK, [])]),
+    ("hist_pass of the right block: a chunk", [(GROUP_BLOCK, [])]),
     ("copy-back cb_chunk: a chunk read of the scratch",
      [("copy-back cb_tile: a 128-row tile", [])]),
 ]
@@ -147,9 +152,11 @@ def _runs(values):
     return ", ".join("%d x %d" % (n, v) if n > 1 else str(v) for n, v in out)
 
 
-def report(bundles, top=8, nest=None):
+def report(bundles, top=8, nest=None, groups_a_block=None):
     """The text: per loop body its bundles, segments and ``top`` opcodes,
-    and its name where the loops are ``nest``'s (``loop_names``)."""
+    and its name where the loops are ``nest``'s (``loop_names``).  With
+    ``groups_a_block`` (k), the block body's bundles are also shared out:
+    a k-th of them a feature group, the extraction with them."""
     lines = ["%d bundles, %d outside every loop"
              % (len(bundles), sum(1 for b in bundles if b.depth == 0))]
     loops = loop_bodies(bundles)
@@ -162,6 +169,9 @@ def report(bundles, top=8, nest=None):
             "%sloop at bundle %d%s: %d own bundles (%d more in nested loops)"
             % ("  " * lp["depth"], lp["at"],
                " [%s]" % names[i] if names else "", lp["own"], lp["nested"]))
+        if names and names[i] == GROUP_BLOCK and groups_a_block:
+            lines[-1] += " = %d a feature group (%d a block)" % (
+                round(lp["own"] / groups_a_block), groups_a_block)
         pad = "  " * lp["depth"] + "  "
         if len(lp["segments"]) > 1:
             lines.append(pad + "segments: " + _runs(lp["segments"]))
@@ -178,7 +188,9 @@ from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
 jax.config.update("jax_enable_compilation_cache", False)
 from lightgbm_tpu.core import partition as P
+from lightgbm_tpu.core.histogram import _group_block
 small, chunk, f, bins = (int(a) for a in sys.argv[1:5])
+print("GROUPS_A_BLOCK %d" % _group_block(f, bins)[0], flush=True)
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 one = SingleDeviceSharding(topo.devices[0])
 voff = -(-f // 4) * 4                   # as build_tree_partitioned lays a
@@ -193,8 +205,9 @@ jax.jit(lambda r, s: P.partition_hist_pallas(
 
 
 def dump(bucket, features, bins, out_dir):
-    """Compile the variant in a child with the dump flags; the path of its
-    final-bundles file.  The child may abort after writing it."""
+    """Compile the variant in a child with the dump flags: (the path of its
+    final-bundles file, the feature groups a block of the factored
+    histogram step).  The child may abort after writing the file."""
     small, chunk = {"small": (1, 1024), "c1024": (0, 1024),
                     "c4096": (0, 4096)}[bucket]
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
@@ -212,7 +225,8 @@ def dump(bucket, features, bins, out_dir):
         raise SystemExit("no final_bundles file for %s under %s (child exit "
                          "%d):\n%s" % (name, out_dir, child.returncode,
                                        child.stdout[-3000:]))
-    return max(found, key=os.path.getmtime)
+    said = re.search(r"^GROUPS_A_BLOCK (\d+)$", child.stdout, re.M)
+    return max(found, key=os.path.getmtime), said and int(said.group(1))
 
 
 def main():
@@ -224,11 +238,12 @@ def main():
     args = ap.parse_args()
     out_dir = tempfile.mkdtemp()
     try:
-        with open(dump(args.bucket, args.features, args.bins, out_dir)) as fh:
+        path, k = dump(args.bucket, args.features, args.bins, out_dir)
+        with open(path) as fh:
             bundles = parse_bundles(fh)
     finally:
         shutil.rmtree(out_dir, ignore_errors=True)
-    print(report(bundles,
+    print(report(bundles, groups_a_block=k,
                  nest=None if args.bucket == "small" else PIPELINED_LOOPS))
 
 

@@ -19,7 +19,13 @@ Two measurements:
    the right block and its copy-back are empty), ``right`` (every row
    right: every row is also copied back) or ``half``.  Whole-kernel
    ``right`` minus ``left`` is the copy-back's cost a right row, which no
-   knockout can isolate.  Knockout deltas fold constants; trust whole-kernel
+   knockout can isolate.  With ``--hist`` the whole kernel runs once more
+   with the histogram ON, its ``hist_left`` scalar naming the block that
+   holds the rows (the left block, or the right one at ``--route right``),
+   and the difference is the smaller child's histogram, ns a histogrammed
+   row; the root pass (``histogram_pallas_rows`` over the same rows) is
+   timed beside it.  ``--features`` sets the column count (28; 67 and 9 are
+   the other cells').  Knockout deltas fold constants; trust whole-kernel
    A/B between two commits.  The static reading of the same phases is
    ``tools/kernel_bundles.py``.
 """
@@ -156,49 +162,82 @@ ROUTES = {"left": lambda num_bins: num_bins,
           "half": lambda num_bins: num_bins // 2 - 1}
 
 
-def bench_in_kernel(route="left", n_rows=2_097_152, num_bins=256, reps=3):
-    """Whole-kernel timing of the real pipelined kernel on one window of
-    ``n_rows`` rows at Higgs' shape (F = 28, W = 128, values at byte 28 as
-    ``build_tree_partitioned`` lays them out): phase A alone by knockout,
-    then everything but the histogram.  Prints ns a window row."""
-    from lightgbm_tpu.core.partition import partition_hist_pallas
+def _device_ms(fn, reps):
+    """Device time of one call of ``fn`` (the trace's largest op, which is
+    the kernel), after a warm-up call: (ms, the last call's result)."""
+    out = fn()
+    jax.block_until_ready(out)
+    with _trace_dir() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(reps):
+                out = fn()
+                jax.block_until_ready(out)
+            jax.device_get(jax.tree_util.tree_leaves(out)[-1])
+        best = max(aggregate_xplane(trace_dir, top=40),
+                   key=lambda q: q[1])[1] / reps
+    return best, out
 
-    f, WK, voff = 28, 128, 28
+
+def bench_in_kernel(route="left", n_rows=2_097_152, num_bins=256, reps=3,
+                    features=28, hist=False):
+    """Whole-kernel timing of the real pipelined kernel on one window of
+    ``n_rows`` rows at ``features`` columns (W = 128 up to 108 of them,
+    values behind the bin bytes as ``build_tree_partitioned`` lays them
+    out): phase A alone by knockout, then everything but the histogram and,
+    with ``hist``, everything.  Prints ns a window row."""
+    from lightgbm_tpu.core.histogram import histogram_pallas_rows
+    from lightgbm_tpu.core.partition import fold_hist, partition_hist_pallas
+
+    f = features
+    voff = -(-f // 4) * 4
+    WK = -(-(voff + 20) // 128) * 128
     n_pad = ((n_rows // CHUNK) + 2) * CHUNK     # the builder's spare chunk
     rng = np.random.RandomState(0)
     rows = np.zeros((n_pad, WK), np.uint8)
     rows[:, :f] = rng.randint(0, num_bins, size=(n_pad, f))
-    rows[:, voff:voff + 8] = rng.randint(0, 255, size=(n_pad, 8))
+    # finite gradients: the histogram's sums are checked below
+    rows[:, voff:voff + 8] = rng.uniform(
+        -1.0, 1.0, size=(n_pad, 2)).astype(np.float32).view(np.uint8)
     scal = np.zeros(12 + num_bins // 32, np.int32)
+    # hist_left (scal[9]) names the block that holds the rows
     scal[:12] = [0, n_rows, 2, ROUTES[route](num_bins), 1, 0, num_bins, 0, 0,
-                 1, 0, 1]
+                 0 if route == "right" else 1, 0, 1]
     r = jnp.asarray(rows)
     s = jnp.asarray(scal)
 
     def run(skip):
-        out = partition_hist_pallas(r, s, num_features=f, num_bins=num_bins,
-                                    voff=voff, dbg_skip=skip)
-        jax.block_until_ready(out[0])
-        with _trace_dir() as trace_dir:
-            with jax.profiler.trace(trace_dir):
-                for _ in range(reps):
-                    out = partition_hist_pallas(
-                        r, s, num_features=f, num_bins=num_bins, voff=voff,
-                        dbg_skip=skip)
-                    jax.block_until_ready(out[0])
-                float(jax.device_get(out[2][0, 0]))
-            best = max(aggregate_xplane(trace_dir, top=40),
-                       key=lambda q: q[1])[1] / reps
-        return best, int(out[2][0, 0])
+        ms, out = _device_ms(lambda: partition_hist_pallas(
+            r, s, num_features=f, num_bins=num_bins, voff=voff,
+            dbg_skip=skip), reps)
+        return ms, int(out[2][0, 0]), out[1]
 
-    ms_a, _ = run("phaseB,phaseC,flush,hist")
-    print("route=%s in-kernel phase A (pipelined, %.1fM-row window, %d "
-          "bins): %.3f ms = %.3f ns/row"
-          % (route, n_rows / 1e6, num_bins, ms_a, ms_a * 1e6 / n_rows))
-    ms_full, nl = run("hist")
+    ms_a, _, _ = run("phaseB,phaseC,flush,hist")
+    print("route=%s in-kernel phase A (pipelined, %.1fM-row window, F=%d, "
+          "%d bins): %.3f ms = %.3f ns/row"
+          % (route, n_rows / 1e6, f, num_bins, ms_a, ms_a * 1e6 / n_rows))
+    ms_full, nl, _ = run("hist")
     print("route=%s whole kernel less the histogram, %d rows left of %d: "
           "%.3f ms = %.3f ns/row"
           % (route, nl, n_rows, ms_full, ms_full * 1e6 / n_rows))
+    if hist:
+        ms_h, nl, raw = run("")
+        n_hist = n_rows - nl if route == "right" else nl
+        print("route=%s whole kernel, %d rows histogrammed: %.3f ms = %.3f "
+              "ns/row; the histogram: %.3f ns a histogrammed row"
+              % (route, n_hist, ms_h, ms_h * 1e6 / n_rows,
+                 (ms_h - ms_full) * 1e6 / n_hist))
+        ms_r, root = _device_ms(lambda: histogram_pallas_rows(
+            r, num_bins, jnp.int32(0), jnp.int32(n_rows), num_features=f,
+            voff=voff), reps)
+        print("root pass histogram_pallas_rows, %d rows: %.3f ms = %.3f "
+              "ns/row" % (n_rows, ms_r, ms_r * 1e6 / n_rows))
+        if n_hist == n_rows:
+            # the same rows either way (the kernel only moves them)
+            got = np.asarray(fold_hist(raw, f, num_bins))
+            err = float(np.max(np.abs(got - np.asarray(root))))
+            print("split kernel's histogram against the root pass's: max "
+                  "|diff| %.3g of max |sum| %.3g"
+                  % (err, float(np.max(np.abs(np.asarray(root))))))
     return ms_full * 1e6 / n_rows
 
 
@@ -214,9 +253,18 @@ def main():
                     default=["left"],
                     help="where the window's rows go; several routes run "
                          "in turn, and right minus left is the copy-back")
+    ap.add_argument("--hist", action="store_true",
+                    help="with --in-kernel: also the whole kernel with the "
+                         "histogram on (ns a histogrammed row by "
+                         "difference) and the root pass over the same rows")
+    ap.add_argument("--features", type=int, default=28,
+                    help="bin columns of the table (28; 67 and 9 are the "
+                         "other cells')")
     args = ap.parse_args()
     if args.in_kernel:
-        ns = {route: bench_in_kernel(route) for route in args.route}
+        ns = {route: bench_in_kernel(route, features=args.features,
+                                     hist=args.hist)
+              for route in args.route}
         if "left" in ns and "right" in ns:
             print("copy-back (whole kernel, right minus left): %.3f ns a "
                   "right row" % (ns["right"] - ns["left"]))
