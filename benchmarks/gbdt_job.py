@@ -24,6 +24,34 @@ def quiet():
     Log.reset_level(Log.level_from_verbosity(-1))
 
 
+def trace_first_tree(wl, warmup, unit):
+    """The tree index at which a traced run of the traffic mix ``wl`` starts
+    its traced units, or None for a mix that states none (it cannot be
+    traced).  It has to be the ``warmup`` trees plus a whole number of units
+    of ``unit`` trees, at least one: anything else is an error, not a
+    rounding, since the point is that every commit traces the same trees."""
+    if wl.get("trace_first_tree") is None:
+        return None
+    first = int(wl["trace_first_tree"])
+    if first <= warmup or (first - warmup) % unit:
+        raise ValueError(
+            "trace_first_tree %d is not the %d warm-up trees plus a whole "
+            "number (1 or more) of units of %d trees" % (first, warmup, unit))
+    return first
+
+
+def untraced_goes_on(job, tracer, seconds, trees):
+    """Whether the stretch of a run after the warm-up goes on once the booster
+    holds ``trees`` trees: by the clock in a run that is not traced, up to
+    the traffic file's ``trace_first_tree`` in one that is."""
+    if tracer is None:
+        return clock() - job.t_start < seconds
+    if job.trace_first_tree is None:
+        raise ValueError("a traced run needs trace_first_tree in the traffic "
+                         "file")
+    return trees < job.trace_first_tree
+
+
 def make_data(cfg, seed, rehearse_rows=None):
     """Training and held-out rows of ``cfg`` from ``seed``: (X, y, Xh, yh).
     ``rehearse_rows`` shrinks both (CPU rehearsal only)."""
@@ -111,24 +139,28 @@ def end_to_end(job):
     return {"train_row_trees_per_s": rate, "heldout_auc": auc}
 
 
-def checks(job, must_stay_fused):
+def checks(job, must_stay_fused, skip=()):
     """[(guarantee, holds, what was found)] — the configuration's guarantees
     that a run can show.  ``must_stay_fused``: the kind drives the fused
-    ``train_chunk`` path, and leaving it is a degraded path."""
+    ``train_chunk`` path, and leaving it is a degraded path.  ``skip`` names
+    the checks a kind replaces with its own: they are not computed."""
     counts = fallbacks()
     left = bool(must_stay_fused and job.gbdt._fuse_failed)
     n = job.counters["recompiles_in_window"]
     before = logloss(job.y, train_scores(job.gbdt, job.score_after_warmup))
     after = logloss(job.y, train_scores(job.gbdt))
-    return [
-        ("no_degraded_path", not any(counts.values()) and not left,
-         "fallbacks %r, left the fused path: %r" % (counts, left)),
-        ("no_recompile_in_window", n == 0,
-         "%d recompiles in the window" % n),
-        ("plain_root_split",) + check_root_split(job.gbdt, job.dataset, job.y,
-                                                 job.cfg["params"]),
-        ("plain_walk",) + check_walk(job.gbdt, job.Xh, job.auc_trees),
-        ("training_loss_falls", after < before,
-         "training logloss %.5f after the warm-up, %.5f after %d trees"
-         % (before, after, job.gbdt.iter_)),
+    found = [
+        ("no_degraded_path", lambda: (
+            not any(counts.values()) and not left,
+            "fallbacks %r, left the fused path: %r" % (counts, left))),
+        ("no_recompile_in_window", lambda: (
+            n == 0, "%d recompiles in the window" % n)),
+        ("plain_root_split", lambda: check_root_split(
+            job.gbdt, job.dataset, job.y, job.cfg["params"])),
+        ("plain_walk", lambda: check_walk(job.gbdt, job.Xh, job.auc_trees)),
+        ("training_loss_falls", lambda: (
+            after < before,
+            "training logloss %.5f after the warm-up, %.5f after %d trees"
+            % (before, after, job.gbdt.iter_))),
     ]
+    return [(name,) + check() for name, check in found if name not in skip]

@@ -4,14 +4,20 @@ ONE call of ``lightgbm_tpu.train(params, Dataset, num_boost_round=<huge>)``;
 one unit of work is one boosting iteration (``Booster.update``).  The window is
 cut out of that call by two callbacks: after ``warmup_iters`` iterations the
 after-iteration callback waits for the device and starts the clock; once
-``--seconds`` have passed it waits again, stops the clock and (after the traced
-iterations of a ``--trace 1`` run) ends training with ``EarlyStopException``.
-Inside the window the host never waits for the device, as a user's call does
-not.
+``--seconds`` have passed it waits again, stops the clock and ends training
+with ``EarlyStopException``.  Inside the window the host never waits for the
+device, as a user's call does not.
+
+A ``--trace 1`` run does not go by the clock: its untraced stretch ends when
+the booster holds ``trace_first_tree`` trees, and the next ``trace_units``
+iterations run under the profiler, so that every commit traces the same trees
+however fast it is.  ``unit_wall_ms_per_tree`` and ``recompiles_in_window``
+of such a run are over the trees between the warm-up and ``trace_first_tree``.
 
 Traffic parameters: ``warmup_iters``, ``auc_trees``, ``trace_units``
 (iterations traced, under one ``bench.unit`` span that ends when the device has
-finished them).
+finished them), ``trace_first_tree`` (``warmup_iters`` plus a whole number of
+``trace_units``, at least one; a mix that is never traced may leave it out).
 """
 from __future__ import annotations
 
@@ -28,6 +34,8 @@ class Job:
         self.rehearse_rows = rehearse_rows
         self.warmup = int(wl["warmup_iters"])
         self.auc_trees = int(wl["auc_trees"])
+        self.trace_first_tree = gbdt_job.trace_first_tree(
+            wl, warmup=self.warmup, unit=int(wl["trace_units"]))
         self.host_timers = {}
         self.counters = {}
         self.attempted = self.failed = 0
@@ -73,7 +81,7 @@ class Job:
                 state["phase"] = "window"
                 job.t_start = clock()
             elif state["phase"] == "window":
-                if clock() - job.t_start < seconds:
+                if gbdt_job.untraced_goes_on(job, tracer, seconds, done):
                     return
                 wait()
                 job.t_end = clock()
@@ -106,9 +114,13 @@ class Job:
         self.attempted = self.gbdt.iter_ - self.warmup
         self.host_timers["unit_wall_ms_per_tree"] = (
             1e3 * (self.t_end - self.t_start) / self.window_trees)
-        print("window %.3f s: %d iterations of one tree on %d rows"
+        traced = ""
+        if self.traced_trees:
+            traced = "; traced trees %d-%d" % (
+                state["first"], state["first"] + len(self.traced_trees) - 1)
+        print("window %.3f s: %d iterations of one tree on %d rows%s"
               % (self.t_end - self.t_start, self.window_trees,
-                 self.gbdt.num_data), flush=True)
+                 self.gbdt.num_data, traced), flush=True)
 
     def end_to_end(self):
         return gbdt_job.end_to_end(self)

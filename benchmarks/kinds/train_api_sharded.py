@@ -144,10 +144,9 @@ class Job(train_api.Job):
         return True, "; ".join(said)
 
     def check(self):
-        checks = [c for c in gbdt_job.checks(self, must_stay_fused=False)
-                  if c[0] != "plain_root_split"]
         n = self.counters.get("row_collectives")
-        return checks + [
+        return gbdt_job.checks(self, must_stay_fused=False,
+                               skip=("plain_root_split",)) + [
             ("sharded_4",) + self.check_sharded(),
             ("plain_first_splits",) + self.check_plain_splits(),
             ("no_row_collective", n == 0,

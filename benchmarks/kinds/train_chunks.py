@@ -1,11 +1,20 @@
-"""Job kind ``train_chunks``: what the CLI's ``GBDT.train`` and ``bench.py`` do.
+"""Job kind ``train_chunks``: what the CLI's ``GBDT.train`` does.
 
 Make data, bin it, build a booster, then call the fused ``train_chunk(K)`` —
 K trees in one XLA program — until the clock runs out.  One unit of work is one
 chunk, timed to ``block_until_ready`` of the training scores.
 
+A ``--trace 1`` run does not go by the clock: after the warm-up chunk it runs
+chunks until the booster holds ``trace_first_tree`` trees, then
+``trace_units`` chunks under the profiler, so that every commit traces the
+same trees however fast it is (later trees cost more: their windows hold more
+rows).  ``unit_wall_ms_per_tree`` and ``recompiles_in_window`` of such a run
+are over the trees between the warm-up and ``trace_first_tree``.
+
 Traffic parameters (the cell's file): ``trees_per_chunk``, ``auc_trees``,
-``trace_units``.
+``trace_units``, ``trace_first_tree`` (the warm-up chunk plus a whole number
+of chunks, at least one; a kind's subclass or a test that never traces may
+leave it out).
 """
 from __future__ import annotations
 
@@ -25,6 +34,8 @@ class Job:
         self.rehearse_rows = rehearse_rows
         self.k = int(wl["trees_per_chunk"])
         self.auc_trees = int(wl["auc_trees"])
+        self.trace_first_tree = gbdt_job.trace_first_tree(
+            wl, warmup=self.k, unit=self.k)
         self.host_timers = {}
         self.counters = {}
         self.attempted = self.failed = 0
@@ -37,20 +48,11 @@ class Job:
         from lightgbm_tpu import obs
         from lightgbm_tpu.boosting.gbdt import GBDT
         from lightgbm_tpu.config import Config
-        from lightgbm_tpu.io.dataset import BinnedDataset
         from lightgbm_tpu.objective import create_objective
 
         gbdt_job.quiet()
-        t0 = clock()
-        X, self.y, self.Xh, self.yh = gbdt_job.make_data(
-            self.cfg, self.seed, self.rehearse_rows)
-        self.host_timers["datagen_s"] = clock() - t0
         params = dict(self.cfg["params"])
-        t0 = clock()
-        self.dataset = BinnedDataset.from_matrix(
-            X, label=self.y, max_bin=int(params["max_bin"]))
-        self.host_timers["bin_s"] = clock() - t0
-        del X
+        self.make_dataset(params)
         t0 = clock()
         config = Config(verbosity=-1, **params)
         self.gbdt = GBDT(config, self.dataset,
@@ -64,6 +66,21 @@ class Job:
         self.unit_walls = []
         obs.recompile.reset()
         obs.launches.reset()
+
+    def make_dataset(self, params):
+        """Rows from the seed and the program's own binning of them:
+        ``self.y``, ``self.Xh``, ``self.yh``, ``self.dataset`` and the host
+        timers ``datagen_s`` and ``bin_s``.  A kind on other input replaces
+        this."""
+        from lightgbm_tpu.io.dataset import BinnedDataset
+        t0 = clock()
+        X, self.y, self.Xh, self.yh = gbdt_job.make_data(
+            self.cfg, self.seed, self.rehearse_rows)
+        self.host_timers["datagen_s"] = clock() - t0
+        t0 = clock()
+        self.dataset = BinnedDataset.from_matrix(
+            X, label=self.y, max_bin=int(params["max_bin"]))
+        self.host_timers["bin_s"] = clock() - t0
 
     def _unit(self):
         """One chunk of K trees, to the end of the device's work.  Failed when
@@ -91,18 +108,16 @@ class Job:
     def run(self, seconds, tracer):
         self.t_start = clock()
         first_tree = self.gbdt.iter_
-        while clock() - self.t_start < seconds and self._unit():
+        while gbdt_job.untraced_goes_on(self, tracer, seconds,
+                                        self.gbdt.iter_) and self._unit():
             pass
         self.t_end = clock()
         self.window_trees = self.gbdt.iter_ - first_tree
         gbdt_job.read_counters(self)
         self.host_timers["unit_wall_ms_per_tree"] = (
             1e3 * statistics.median(self.unit_walls) / self.k)
-        print("window %.3f s: %d chunks of %d trees on %d rows, %d failed; "
-              "seconds per chunk %s"
-              % (self.t_end - self.t_start, self.attempted, self.k,
-                 self.gbdt.num_data, self.failed,
-                 " ".join("%.3f" % w for w in self.unit_walls)), flush=True)
+        chunks = self.attempted
+        walls = " ".join("%.3f" % w for w in self.unit_walls)
         if tracer is not None and not self.failed:
             first = self.gbdt.iter_
             with tracer:
@@ -110,6 +125,13 @@ class Job:
                     if not self._unit():
                         break
             self.traced_trees = self.gbdt.models[first:self.gbdt.iter_]
+            walls += "; traced trees %d-%d in %s" % (
+                first, self.gbdt.iter_ - 1,
+                " ".join("%.3f" % w for w in self.unit_walls[chunks:]))
+        print("window %.3f s: %d chunks of %d trees on %d rows, %d failed; "
+              "seconds per chunk %s"
+              % (self.t_end - self.t_start, chunks, self.k,
+                 self.gbdt.num_data, self.failed, walls), flush=True)
 
     def program_temp_bytes(self):
         """Temporaries of the fused chunk program by the compiler's own

@@ -41,13 +41,18 @@ MAX_DEVICE_COLUMNS = 32
 class Job(train_chunks.Job):
     def setup(self):
         from lightgbm_tpu.obs import efb           # before any data is made
-        from lightgbm_tpu import obs
-        from lightgbm_tpu.boosting.gbdt import GBDT
-        from lightgbm_tpu.config import Config
-        from lightgbm_tpu.io.dataset import BinnedDataset
-        from lightgbm_tpu.objective import create_objective
+        super().setup()
+        # the split kernel reads efb_groups columns of a row, this many bins each
+        self.counters["kernel_bins"] = float(self.gbdt.learner.num_bins)
+        print("set-up: data %.1f s, from_csr %.1f s (%s), booster and the "
+              "warm-up chunk %.1f s"
+              % (self.host_timers["datagen_s"], self.host_timers["bin_s"],
+                 ", ".join("%s %d" % kv for kv in sorted(efb.counts().items())),
+                 self.host_timers["first_unit_s"]), flush=True)
 
-        gbdt_job.quiet()
+    def make_dataset(self, params):
+        from lightgbm_tpu.io.dataset import BinnedDataset
+        from lightgbm_tpu.obs import efb
         gen = self.cfg["generator"]
         rows, held = int(self.cfg["rows"]), int(self.cfg["heldout_rows"])
         if self.rehearse_rows:
@@ -63,7 +68,6 @@ class Job(train_chunks.Job):
         self.y, self.yh = y[:rows], y[rows:]
         del levels, numeric
         self.host_timers["datagen_s"] = clock() - t0
-        params = dict(self.cfg["params"])
         t0 = clock()
         # the arguments lightgbm_tpu.Dataset(csr, params=...) hands on
         self.dataset = BinnedDataset.from_csr(
@@ -72,26 +76,6 @@ class Job(train_chunks.Job):
         self.host_timers["bin_s"] = clock() - t0
         self.counters.update({name.replace(".", "_"): float(count)
                               for name, count in efb.counts().items()})
-        t0 = clock()
-        config = Config(verbosity=-1, **params)
-        self.gbdt = GBDT(config, self.dataset,
-                         create_objective(params["objective"], config))
-        # the split kernel reads efb_groups columns of a row, this many bins each
-        self.counters["kernel_bins"] = float(self.gbdt.learner.num_bins)
-        self._unit()                                    # compile or cache load
-        self.host_timers["first_unit_s"] = clock() - t0
-        if self.failed:
-            raise RuntimeError("the warm-up unit failed")
-        self.score_after_warmup = self.gbdt.train_score  # a device reference
-        self.attempted = 0
-        self.unit_walls = []
-        obs.recompile.reset()
-        obs.launches.reset()
-        print("set-up: data %.1f s, from_csr %.1f s (%s), booster and the "
-              "warm-up chunk %.1f s"
-              % (self.host_timers["datagen_s"], self.host_timers["bin_s"],
-                 ", ".join("%s %d" % kv for kv in sorted(efb.counts().items())),
-                 self.host_timers["first_unit_s"]), flush=True)
 
     def run(self, seconds, tracer):
         super().run(seconds, tracer)
@@ -148,9 +132,8 @@ class Job(train_chunks.Job):
         return ok, "tree 0 (%.1f s): %s" % (clock() - t0, found)
 
     def check(self):
-        checks = [c for c in gbdt_job.checks(self, must_stay_fused=True)
-                  if c[0] != "plain_root_split"]
-        return checks + [
+        return gbdt_job.checks(self, must_stay_fused=True,
+                               skip=("plain_root_split",)) + [
             ("sparse_ingest",) + self.check_sparse_ingest(),
             ("plain_first_splits",) + self.check_plain_splits(),
         ]

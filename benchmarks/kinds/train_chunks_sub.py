@@ -166,9 +166,8 @@ class Job(train_chunks.Job):
         return True, "; ".join(said)
 
     def check(self):
-        checks = [c for c in gbdt_job.checks(self, must_stay_fused=True)
-                  if c[0] != "plain_root_split"]
-        return checks + [
+        return gbdt_job.checks(self, must_stay_fused=True,
+                               skip=("plain_root_split",)) + [
             ("fused_every_tree",) + self.check_fused(),
             ("sampled_as_configured",) + self.check_sampling(),
             ("mask_honoured",) + self.check_masks(),

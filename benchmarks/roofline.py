@@ -24,25 +24,37 @@ def peaks(device_kind):
     return table[device_kind]
 
 
-def split_work(trees, *, features, bins, code_bytes=1):
-    """(bytes, operations) of partitioning every window of ``trees`` and
-    histogramming the smaller child of each split.
-
-    Bytes: each window row is read once and written once, its bin codes plus
-    the f32 gradient pair: 2 * (F * code_bytes + 8).
-    Operations: the one-hot histogram of the smaller child,
-    rows * F * bins * 2 values * 2 (multiply, add) * BF16_PASSES."""
-    window_rows = 0
-    small_rows = 0
+def tree_rows(trees):
+    """(window rows, smaller-child rows, right-child rows) of every split of
+    ``trees``: a split's window is its node's ``internal_count`` (the
+    program's own count, which it estimates from hessians as LightGBM does),
+    a child's rows its ``internal_count`` when it split again, else its
+    ``leaf_count``.  The kernel places left rows in the window and streams
+    right rows to a scratch, then copies that block back behind the left one,
+    so a right row is read and written twice."""
+    window_rows = small_rows = right_rows = 0
     for t in trees:
-        m = int(t.num_leaves) - 1
-        for node in range(m):
+        for node in range(int(t.num_leaves) - 1):
             window_rows += int(t.internal_count[node])
             kids = []
             for c in (int(t.left_child[node]), int(t.right_child[node])):
                 kids.append(int(t.internal_count[c]) if c >= 0
                             else int(t.leaf_count[~c]))
             small_rows += min(kids)
+            right_rows += kids[1]
+    return window_rows, small_rows, right_rows
+
+
+def split_work(trees, *, features, bins, code_bytes=1):
+    """(bytes, operations, window rows, smaller-child rows) of partitioning
+    every window of ``trees`` and histogramming the smaller child of each
+    split.
+
+    Bytes: each window row is read once and written once, its bin codes plus
+    the f32 gradient pair: 2 * (F * code_bytes + 8).
+    Operations: the one-hot histogram of the smaller child,
+    rows * F * bins * 2 values * 2 (multiply, add) * BF16_PASSES."""
+    window_rows, small_rows, _ = tree_rows(trees)
     nbytes = window_rows * 2 * (features * code_bytes + 8)
     ops = small_rows * features * bins * 2 * 2 * BF16_PASSES
     return nbytes, ops, window_rows, small_rows

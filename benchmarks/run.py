@@ -8,9 +8,11 @@ cell's file gives (``README.md`` in this directory).  This file holds the
 order of a run and the contract's last line, nothing else.
 
 A run: set-up (data from the seed, the program's own ingest, warm-up of the
-cell's shapes; ``setup_s`` ends at the first timed dispatch), the measured
-window of ``--seconds``, with ``--trace 1`` a few traced units after it, then
-the checks that decide ``correct``.  The last line of standard output is the
+cell's shapes; ``setup_s`` ends at the first timed dispatch), then with
+``--trace 0`` the measured window of ``--seconds``, with ``--trace 1`` the
+units up to the traffic file's ``trace_first_tree`` and a few traced units
+from that tree on (the same trees on every commit, however fast), then the
+checks that decide ``correct``.  The last line of standard output is the
 result; everything before it says what was found.
 """
 import time
@@ -60,36 +62,55 @@ def find_cell(bench, name, cells_dir):
 
 
 class Tracer:
-    """``with tracer:`` profiles what runs inside it into ``self.dir``."""
+    """``with tracer:`` profiles what runs inside it into ``self.dir``; with
+    ``profile`` false (a rehearsal) it only marks where the traced units
+    are."""
 
-    def __init__(self, keep_dir):
+    def __init__(self, keep_dir, profile=True):
         self.keep = keep_dir is not None
+        self.profile = profile
         if self.keep:
             os.makedirs(keep_dir, exist_ok=True)
-        self.dir = keep_dir or tempfile.mkdtemp(prefix="bench_trace_")
+        self.dir = keep_dir or (tempfile.mkdtemp(prefix="bench_trace_")
+                                if profile else None)
         self.ran = False
 
     def __enter__(self):
-        import jax
-        jax.profiler.start_trace(self.dir)
-        self.ran = True
+        if self.profile:
+            import jax
+            jax.profiler.start_trace(self.dir)
+            self.ran = True
 
     def __exit__(self, *exc):
-        import jax
-        jax.profiler.stop_trace()
+        if self.profile:
+            import jax
+            jax.profiler.stop_trace()
 
-    def reduced(self):
-        """What ``trace_reduce.reduce`` makes of the trace, or None when the
-        kind traced nothing; the trace itself goes unless it is to be kept."""
+    def reduced(self, program_spans=()):
+        """What ``trace_reduce.reduce`` makes of the trace, or None when
+        nothing was profiled; the trace itself goes unless it is to be
+        kept."""
         import trace_reduce
         try:
             if not self.ran:
                 return None
             return trace_reduce.reduce(trace_reduce.find_xplane(self.dir),
-                                       trace_reduce.UNIT_ANNOTATION)
+                                       trace_reduce.UNIT_ANNOTATION,
+                                       program_spans)
         finally:
-            if not self.keep:
+            if self.dir and not self.keep:
                 shutil.rmtree(self.dir, ignore_errors=True)
+
+
+def program_span_names():
+    """The names of the spans the program has opened so far (its own span
+    API, which also annotates the profiler's host line): what an idle gap is
+    labelled with.  Empty for a program without the API."""
+    try:
+        from lightgbm_tpu.obs import spans
+        return set(spans.totals())
+    except (ImportError, AttributeError):
+        return set()
 
 
 def layer_metrics(bench, cell, kind, ctx):
@@ -157,12 +178,12 @@ def main():
     kind = importlib.import_module("kinds." + wl["kind"])
     job = kind.Job(cfg, wl, args.seed, rehearse_rows=args.rehearse_rows)
     job.setup()
-    tracer = Tracer(args.trace_dir) if args.trace and not rehearsal else None
+    tracer = Tracer(args.trace_dir, not rehearsal) if args.trace else None
     job.run(args.seconds, tracer)
     setup_s = job.t_start - T0
 
     if args.trace:
-        trace = tracer.reduced() if tracer else None
+        trace = tracer.reduced(program_span_names())
         ctx = {"job": job, "trace": trace, "cfg": cfg, "wl": wl,
                "device_kind": devices[0].device_kind}
         metrics = layer_metrics(bench, args.workload, wl["kind"], ctx)

@@ -55,3 +55,73 @@ def test_every_name_has_its_file():
             == (m["layer"], m["unit"], m["moves"], m["better"])
         assert hasattr(importlib.import_module("readers." + spec["reader"]),
                        "read")
+
+
+def spec_of(metric):
+    return json.load(open(os.path.join(BENCH, "layer_metrics",
+                                       metric["name"] + ".json")))
+
+
+def kind_of(cell):
+    return json.load(open(os.path.join(
+        BENCH, "traffic", cell["traffic"] + ".json")))["kind"]
+
+
+def test_the_per_layer_list_has_room_and_every_entry_its_cells():
+    """At most the contract's 128 entries; every entry lists the cells that
+    report it, all of them listed cells; a metric file that no entry names
+    is a leftover."""
+    cells = {w["name"] for w in B["workloads"]}
+    assert len(B["per_layer"]) <= 128
+    for m in B["per_layer"]:
+        assert m.get("workloads"), m["name"]
+        assert set(m["workloads"]) <= cells, m["name"]
+        assert len(set(m["workloads"])) == len(m["workloads"]), m["name"]
+    assert sorted(os.listdir(os.path.join(BENCH, "layer_metrics"))) == sorted(
+        m["name"] + ".json" for m in B["per_layer"])
+
+
+def test_no_cell_reads_one_thing_under_two_names():
+    """Two entries with the same reader and arguments are copies: fine for
+    cells apart (a cell's PR may add files and edit none), never for one
+    cell."""
+    seen = {}
+    for m in B["per_layer"]:
+        spec = spec_of(m)
+        key = (spec["reader"], json.dumps(spec["args"], sort_keys=True))
+        for other, cells in seen.get(key, ()):
+            assert not set(cells) & set(m["workloads"]), (m["name"], other)
+        seen.setdefault(key, []).append((m["name"], m["workloads"]))
+
+
+def test_a_metrics_kinds_are_those_of_its_cells():
+    """Every kind a metric file lists is the kind of a cell on the entry's
+    list, or a kind no listed cell has (``train_api``: the probes under
+    ``tests/cells``); every cell on the list has a kind the file lists, or
+    the entry could never be reported there."""
+    kinds = {w["name"]: kind_of(w) for w in B["workloads"]}
+    for m in B["per_layer"]:
+        spec = spec_of(m)
+        of_cells = {kinds[c] for c in m["workloads"]}
+        assert of_cells <= set(spec["kinds"]), m["name"]
+        assert not (set(spec["kinds"]) - of_cells) & set(kinds.values()), \
+            m["name"]
+
+
+def test_every_traffic_mix_states_the_tree_it_is_traced_at():
+    """``trace_first_tree`` is the warm-up plus whole units: the kind's own
+    rule, asked of every traffic file here and under ``tests/``."""
+    import glob
+
+    import gbdt_job
+    files = (glob.glob(os.path.join(BENCH, "traffic", "*.json"))
+             + glob.glob(os.path.join(BENCH, "tests", "traffic", "*.json")))
+    assert len(files) >= 8
+    for path in files:
+        wl = json.load(open(path))
+        if "trees_per_chunk" in wl:
+            warmup = unit = int(wl["trees_per_chunk"])
+        else:
+            warmup, unit = int(wl["warmup_iters"]), int(wl["trace_units"])
+        first = gbdt_job.trace_first_tree(wl, warmup, unit)
+        assert first is not None and first > warmup, path

@@ -120,7 +120,8 @@ def test_the_glue_metrics_add_up_to_busy_less_the_kernels():
     ctx = ctx_of([tree([9], 2)], own=OWN, text=TEXT)
     from readers import trace_ops
     glue = [m["name"] for m in B["per_layer"]
-            if m["name"].startswith("glue_")]
+            if m["name"].startswith("glue_")
+            and "higgs_train" in m["workloads"]]
     assert len(glue) == 10
     total = sum((trace_scope if spec(m)["reader"] == "trace_scope"
                  else trace_ops).read(spec(m)["args"], ctx) for m in glue)
@@ -143,9 +144,9 @@ def test_trace_scope_nothing_to_read():
 
 def test_the_scopes_asked_for_are_those_with_a_metric():
     assert trace_scope.all_scopes() == sorted(
-        ["gbdt.gradients", "tree.store", "tree.root", "tree.pick_leaf",
-         "tree.split", "tree.find_split", "tree.state_update", "tree.finish",
-         "unscoped"])
+        ["gbdt.gradients", "gbdt.sample", "tree.store", "tree.root",
+         "tree.pick_leaf", "tree.split", "tree.find_split", "tree.unpack",
+         "tree.state_update", "tree.finish", "unscoped"])
 
 
 # ---- program_span ----------------------------------------------------------
@@ -185,7 +186,8 @@ def test_rehearsal_would_report_the_span_metrics(tmp_path):
     line = next(ln for ln in done.stdout.splitlines()
                 if ln.startswith("rehearsal on cpu: would report"))
     spans_metrics = [m["name"] for m in B["per_layer"]
-                     if m["source"] == "program_span"]
+                     if m["source"] == "program_span"
+                     and "higgs_train" in m["workloads"]]
     assert len(spans_metrics) == 9
     for name in spans_metrics:
         assert repr(name) in line, name

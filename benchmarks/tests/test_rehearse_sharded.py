@@ -33,5 +33,5 @@ def test_four_chip_cell_rehearses_to_the_contracts_line(tmp_path):
     would = next(ln for ln in lines if ln.startswith("rehearsal on cpu"))
     for name in ("collectives_per_tree.dp", "comm_bytes_per_tree.dp",
                  "row_collectives.dp", "shard_upload_s.dp",
-                 "launches_per_tree.dp"):
+                 "launches_per_tree"):
         assert name in would

@@ -38,10 +38,14 @@ def test_clip_and_gaps():
     assert tr.clip(events, [(5, 25)]) == [("a", 5, 5), ("b", 20, 5)]
     assert tr.gaps(events, [(5, 55)]) == [(10, 20), (30, 50)]
     host = [("outer", 0, 100), ("inner", 25, 30)]
+    # a gap is cut where a span begins or ends: the long one starts under
+    # "inner" (25-55), goes on under "outer" (to 100) and outlasts both
     assert tr.attribute_gaps([(10, 20), (30, 30 + 2 * tr.SHORT_GAP_NS)],
                              host) == {
         "outer: gaps under 10 us": 10,
-        "inner: gaps of 10 us or more": 2 * tr.SHORT_GAP_NS}
+        "inner: gaps of 10 us or more": 25,
+        "outer: gaps of 10 us or more": 45,
+        "no host span: gaps of 10 us or more": 2 * tr.SHORT_GAP_NS - 70}
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +82,60 @@ def test_reduce_gives_what_the_chip_run_printed():
     assert sum(r["own"].values()) == pytest.approx(r["busy_ns"], rel=1e-9)
     assert sum(r["idle"].values()) == pytest.approx(
         r["window_ns"] - r["busy_ns"], rel=1e-9)
-    assert all(k.startswith(("bench.unit: ", "PjitFunction")) for k in r["idle"])
+    # no name of the program's handed over: the unit's span leads
+    assert all(k.startswith(("bench.unit: ", "bench.unit > "))
+               for k in r["idle"])
+    assert "bench.unit > PjitFunction(converted): gaps of 10 us or more" \
+        in r["idle"]
     with pytest.raises(ValueError):
         tr.reduce(FIXTURE, "no.such.span")
+
+
+# ---- the asynchronous line, and the program's names on the gaps ------------
+
+def test_gaps_lead_with_the_programs_span():
+    long = 2 * tr.SHORT_GAP_NS
+    host = [("bench.unit", 0, 10 * long), ("gbdt.poll_stop", 20, 5 * long),
+            ("np.asarray(jax.Array)", 25, 3 * long),
+            ("PjitFunction(f)", 8 * long, 10)]
+    idle = [(5, 8), (10, 10 + long), (8 * long + 2, 8 * long + 5)]
+    names = {"gbdt.poll_stop", "bench.unit", "jax.lower"}
+    assert tr.attribute_gaps(idle, host, names) == {
+        "bench.unit: gaps under 10 us": 3,
+        # the long gap: 10 ns before the program's span opens, 5 in it
+        # before jax's, the rest under both
+        "bench.unit: gaps of 10 us or more": 10,
+        "gbdt.poll_stop: gaps of 10 us or more": 5,
+        "gbdt.poll_stop > np.asarray(jax.Array): gaps of 10 us or more":
+            long - 15,
+        "bench.unit > PjitFunction(f): gaps under 10 us": 3}
+    # no names given: the innermost span alone
+    assert set(tr.attribute_gaps(idle, host)) == {
+        "bench.unit: gaps under 10 us", "bench.unit: gaps of 10 us or more",
+        "gbdt.poll_stop: gaps of 10 us or more",
+        "np.asarray(jax.Array): gaps of 10 us or more",
+        "PjitFunction(f): gaps under 10 us"}
+
+
+def test_recorded_trace_has_its_asynchronous_line(recorded):
+    (plane, later), = recorded["async"].items()
+    assert plane == "/device:TPU:0" and len(later) == 218
+    assert {tr.op_name(n).split(".")[0] for n, _, _ in later} == {
+        "%copy-start", "%slice-start"}
+    # every asynchronous op is also an event of the ops line, where it is
+    # issued; on its own line it lasts until it is done
+    issued = {tr.op_name(n) for n, _, _ in recorded["device"][plane]}
+    assert {tr.op_name(n) for n, _, _ in later} <= issued
+
+
+def test_reduce_labels_the_fixtures_gaps_with_the_programs_span():
+    r = tr.reduce(FIXTURE, tr.UNIT_ANNOTATION, {"fused_train_chunk"})
+    assert sum(r["idle"].values()) == pytest.approx(
+        r["window_ns"] - r["busy_ns"], rel=1e-9)
+    assert all(k.startswith(("bench.unit", "fused_train_chunk"))
+               for k in r["idle"]), sorted(r["idle"])
+    # the launch: the gap before the device starts lies under the chunk's
+    # dispatch, and under jax's call inside it
+    assert "fused_train_chunk > PjitFunction(converted): gaps of 10 us or " \
+        "more" in r["idle"]
+    assert "bench.unit: gaps of 10 us or more" in r["idle"]
