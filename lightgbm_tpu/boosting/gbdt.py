@@ -45,13 +45,13 @@ from ..obs import devmem as _devmem
 from ..obs import launches as _launches
 from ..obs import recompile as _recompile
 from ..obs import sampling as _sampling
+from ..obs import scopes as _scopes
 from ..obs import spans as _spans
 from ..resilience import PROGRAM_ERRORS as _PROGRAM_ERRORS
 from ..resilience import preemption_requested as _preemption_requested
 from ..resilience import watch as _watch
 from ..utils.file_io import atomic_write
 from ..utils.log import LightGBMError, Log
-from ..utils.timer import FunctionTimer
 
 K_EPSILON = 1e-15
 MODEL_VERSION = "v3"
@@ -881,8 +881,7 @@ class GBDT:
         if gradients is None or hessians is None:
             for k in range(K):
                 init_scores[k] = self._boost_from_average(k, True)
-            with FunctionTimer("GBDT::Boosting(dispatch)"), \
-                    _spans.span("gbdt.gradients"):
+            with _spans.span("gbdt.gradients"):
                 grad, hess = self._get_gradients()
         else:
             grad = np.asarray(gradients, dtype=np.float32).reshape(
@@ -913,7 +912,7 @@ class GBDT:
             if self.class_need_train[k] and self.train_data.num_features > 0:
                 any_trained = True
                 gk, hk = self._masked_gradients(grad[k], hess[k])
-                with FunctionTimer("TreeLearner::Train(dispatch)"):
+                with _spans.span("gbdt.train_tree"):
                     arrays = self.learner.train(gk, hk, self.bag_data_cnt,
                                                 feature_mask,
                                                 iteration=self.iter_)
@@ -921,8 +920,7 @@ class GBDT:
                 scaled = arrays._replace(
                     leaf_value=arrays.leaf_value * rate,
                     internal_value=arrays.internal_value * rate)
-                with FunctionTimer("GBDT::UpdateScore(dispatch)"), \
-                        _spans.span("gbdt.update_score"):
+                with _spans.span("gbdt.update_score"):
                     self._add_tree_output(scaled, k)
                     for vs in self.valid_sets:
                         self._route_arrays_valid(scaled, k, vs)
@@ -1142,9 +1140,11 @@ class GBDT:
             (rows_fin, _, vs_out), stacked = _scan_grouped(
                 one_iter_of(bins), (rows0, sums0, tuple(vscores)),
                 it0 + jnp.arange(k, dtype=jnp.int32), self._trees_per_chunk())
-            score_out = jnp.zeros((ntot,), jnp.float32).at[
-                i32_col(rows_fin, voff + 8)].set(
-                    f32_col(rows_fin, soff), mode="drop")
+            # the chunk's epilogue: the score out of the store, by row id
+            with jax.named_scope(_scopes.CHUNK_SCORE_OUT):
+                score_out = jnp.zeros((ntot,), jnp.float32).at[
+                    i32_col(rows_fin, voff + 8)].set(
+                        f32_col(rows_fin, soff), mode="drop")
             if bag is not None:
                 # a bagged chunk also hands out its k realised bag counts
                 stacked, bag_rows = stacked
@@ -1221,7 +1221,9 @@ class GBDT:
                     arr = arr._replace(
                         leaf_value=arr.leaf_value * rate,
                         internal_value=arr.internal_value * rate)
-                    score = score.at[kk].add(arr.leaf_value[arr.row_leaf])
+                    with jax.named_scope(_scopes.CHUNK_SCORE_OUT):
+                        score = score.at[kk].add(
+                            arr.leaf_value[arr.row_leaf])
                     vscores = _add_valid_outputs(
                         vscores, kk, arr, feat, vbins, L,
                         learner.has_categorical)
@@ -1335,8 +1337,7 @@ class GBDT:
         init_scores = [self._boost_from_average(kk, True)
                        for kk in range(self.num_tree_per_iteration)]
         t0 = time.perf_counter()
-        with FunctionTimer("GBDT::TrainChunk(dispatch)"), \
-                _spans.span("fused_train_chunk"), \
+        with _spans.span("fused_train_chunk"), \
                 _watch("fused_train_chunk", compile_key=int(num_iters),
                        first_iter=int(self.iter_), iters=int(num_iters)):
             new_score, new_vscores, stacked, *bag_rows = fn(
@@ -1466,7 +1467,7 @@ class GBDT:
         if gradients is None or hessians is None:
             for k in range(self.num_tree_per_iteration):
                 init_scores[k] = self._boost_from_average(k, True)
-            with FunctionTimer("GBDT::Boosting"):
+            with _spans.span("gbdt.gradients"):
                 grad, hess = self._get_gradients()
         else:
             grad = np.asarray(gradients, dtype=np.float32).reshape(
@@ -1479,7 +1480,7 @@ class GBDT:
         grad = jnp.asarray(grad)
         hess = jnp.asarray(hess)
 
-        with FunctionTimer("GBDT::Bagging"):
+        with _spans.span("gbdt.bagging"):
             self._bagging(self.iter_)
             grad, hess = self._adjust_gradients_for_bagging(grad, hess)
 
@@ -1491,7 +1492,7 @@ class GBDT:
             arrays = None
             if self.class_need_train[k] and self.train_data.num_features > 0:
                 gk, hk = self._masked_gradients(grad[k], hess[k])
-                with FunctionTimer("TreeLearner::Train"):
+                with _spans.span("gbdt.train_tree"):
                     arrays = self.learner.train(gk, hk, self.bag_data_cnt,
                                                 feature_mask,
                                                 iteration=self.iter_)
@@ -1505,7 +1506,7 @@ class GBDT:
                 new_tree.shrink(self.shrinkage_rate)
                 scaled = arrays._replace(
                     leaf_value=arrays.leaf_value * self.shrinkage_rate)
-                with FunctionTimer("GBDT::UpdateScore"):
+                with _spans.span("gbdt.update_score"):
                     self._add_tree_output(scaled, k)
                     for vs in self.valid_sets:
                         self._add_tree_score_valid(len(self.models), new_tree, k,

@@ -222,11 +222,11 @@ def save_checkpoint(booster, prefix: str, keep: Optional[int] = None) -> str:
     (``snapshot_keep`` param when None; <= 0 keeps everything)."""
     import time
 
-    from .utils.timer import FunctionTimer
+    from .obs.spans import span
     global _LAST_WRITE_TS
     t0 = time.perf_counter()
     ts0 = time.time()
-    with FunctionTimer("Checkpoint::Write"):
+    with span("checkpoint.write"):
         meta, arrays, model_str = booster.capture_train_state()
         path = checkpoint_path(prefix, int(meta["iteration"]))
         blob = serialize_state(meta, arrays, model_str)
@@ -344,10 +344,10 @@ def restore_state(booster, state) -> int:
     the checkpoint BEFORE attaching valid sets (cli.py task=train)."""
     import time
 
-    from .utils.timer import FunctionTimer
+    from .obs.spans import span
     meta, arrays, model_str, path = state
     t0 = time.perf_counter()
-    with FunctionTimer("Checkpoint::Restore"):
+    with span("checkpoint.restore"):
         booster.restore_train_state(meta, arrays, model_str)
     Log.info("Resumed training from checkpoint %s (iteration %d)",
              path, booster.iter_)

@@ -22,7 +22,7 @@ from .metric.metric import create_metrics
 from .objective import create_objective
 from .utils.compile_cache import enable_compilation_cache
 from .utils.log import Log
-from .utils.timer import global_timer
+from .obs import spans as _spans
 
 
 def parse_args(argv: List[str]) -> Dict[str, str]:
@@ -242,7 +242,7 @@ class Application:
                              iters=int(booster.iter_) - it_start)
                 obs.disable()
             if cfg.verbosity > 0:
-                global_timer.print()
+                Log.debug("%s", _spans.summary())
         finally:
             self._disarm_resilience(preempt, own_wd)
             self._close_telemetry(tele)

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import scopes as _scopes
 from ..plan import device_specs as _device_specs
 from ..plan import state as _plan_state
 
@@ -669,7 +670,6 @@ def _hist_kernel_rows(win_ref, rows_ref, out_ref, w_sc, v4_sc, *,
     base = i * row_tile
     active = (base < start + count) & (base + row_tile > start)
 
-    @pl.when(active & (t == 0))
     def _stage_tile():
         w = rows_ref[...].astype(jnp.int32)              # [Nt, W]
         # bf16 staging: byte values are exact in bf16 and the scratch is
@@ -686,7 +686,6 @@ def _hist_kernel_rows(win_ref, rows_ref, out_ref, w_sc, v4_sc, *,
         v4_sc[...] = _hilo_split(vals, axis=1, exact=exact,
                                  quantized=quantized)    # [Nt, 4|2]
 
-    @pl.when(active)
     def _accum():
         # the feature window (win_ref[2]) is only supported on the factored
         # path; the learner only shards histogram construction when the
@@ -696,6 +695,13 @@ def _hist_kernel_rows(win_ref, rows_ref, out_ref, w_sc, v4_sc, *,
         _accum_onehot_tile_dyn(colf, v4_sc[...], out_ref, t,
                                num_features=num_features,
                                num_bins=num_bins, contract_dim=0)
+
+    # regions a grid step (obs/scopes.py KERNEL_REGIONS): the grid is the
+    # kernel's only loop over rows, so a region is a row tile's
+    with jax.named_scope(_scopes.K_STAGE):
+        pl.when(active & (t == 0))(_stage_tile)
+    with jax.named_scope(_scopes.K_GROUPS):
+        pl.when(active)(_accum)
 
 
 def _hist_kernel_rows_fac(win_ref, rows_ref, out_ref, *,
@@ -721,15 +727,19 @@ def _hist_kernel_rows_fac(win_ref, rows_ref, out_ref, *,
 
     @pl.when((base < start + count) & (base + row_tile > start))
     def _accum():
-        tib = rows_ref[...].astype(jnp.int32).astype(jnp.bfloat16)
-        posT = base + jax.lax.broadcasted_iota(jnp.int32, (1, row_tile), 1)
-        inwT = ((posT >= start).astype(jnp.float32)
-                * (posT < start + count).astype(jnp.float32))
-        v4T = _extract_values_T(tib, voff=voff, exact=exact, inwT=inwT,
-                                quantized=quantized)
-        _accum_factored_all(tib, v4T, out_ref, num_features=num_features,
-                            num_bins=num_bins, bpc=bpc, packed=packed,
-                            f_base=win_ref[2], quantized=quantized)
+        with jax.named_scope(_scopes.K_STAGE):
+            tib = rows_ref[...].astype(jnp.int32).astype(jnp.bfloat16)
+            posT = base + jax.lax.broadcasted_iota(jnp.int32,
+                                                   (1, row_tile), 1)
+            inwT = ((posT >= start).astype(jnp.float32)
+                    * (posT < start + count).astype(jnp.float32))
+            v4T = _extract_values_T(tib, voff=voff, exact=exact, inwT=inwT,
+                                    quantized=quantized)
+        with jax.named_scope(_scopes.K_GROUPS):
+            _accum_factored_all(tib, v4T, out_ref,
+                                num_features=num_features,
+                                num_bins=num_bins, bpc=bpc, packed=packed,
+                                f_base=win_ref[2], quantized=quantized)
 
 
 @functools.partial(jax.jit, static_argnames=("num_features", "num_bins",

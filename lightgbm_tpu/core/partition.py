@@ -67,6 +67,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import scopes as _scopes
 from .histogram import (_accum_factored_all, _accum_onehot_all,
                         _colf_rows_dyn, _extract_values_T,
                         _factored_out_shape, _fold_factored, _hilo_split,
@@ -479,55 +480,56 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
         # never stalls on HBM writes (sync flushes were ~60% of the kernel
         # in round-4 profiles).
         del rows_in_ref
-        scal = (_ScalRow(scal_ref, pl.program_id(0)) if multiwin
-                else scal_ref)
-        wb = scal[0]
-        wc = scal[1]
-        gcol = scal[2]
-        hist_left = scal[9]
-        # every merge of placed rows into a staging tile works on the words
-        stage_w = _WordRef(stage, interpret)
-        comp_w = _WordRef(comp_buf, interpret)
-        tmp_w = _WordRef(tmp, interpret)
-        wbase = _word_base(W)
+        with jax.named_scope(_scopes.K_PROLOGUE):
+            scal = (_ScalRow(scal_ref, pl.program_id(0)) if multiwin
+                    else scal_ref)
+            wb = scal[0]
+            wc = scal[1]
+            gcol = scal[2]
+            hist_left = scal[9]
+            # every merge of placed rows into a staging tile works on the words
+            stage_w = _WordRef(stage, interpret)
+            comp_w = _WordRef(comp_buf, interpret)
+            tmp_w = _WordRef(tmp, interpret)
+            wbase = _word_base(W)
 
-        wb_al = pl.multiple_of((wb // _ALIGN) * _ALIGN, _ALIGN)
-        headL = wb - wb_al
-        nchunks = (headL + wc + chunk - 1) // chunk
+            wb_al = pl.multiple_of((wb // _ALIGN) * _ALIGN, _ALIGN)
+            headL = wb - wb_al
+            nchunks = (headL + wc + chunk - 1) // chunk
 
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-        # upper-triangular ones U[j, t] = (j <= t): subtiles are STACKED
-        # ALONG M so one [2*nsub, T] @ U dot computes every subtile's local
-        # inclusive prefix lane-major — a skinny N=2 prefix matmul is MXU
-        # weight-load bound (~2.3us each), and sublane-major prefixes would
-        # put every per-row intermediate in 128x-padded [CHUNK, 1] vregs
-        ltri[...] = (jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
-                     <= jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-                     ).astype(jnp.int8)
+            hist_ref[...] = jnp.zeros_like(hist_ref)
+            # upper-triangular ones U[j, t] = (j <= t): subtiles are STACKED
+            # ALONG M so one [2*nsub, T] @ U dot computes every subtile's local
+            # inclusive prefix lane-major — a skinny N=2 prefix matmul is MXU
+            # weight-load bound (~2.3us each), and sublane-major prefixes would
+            # put every per-row intermediate in 128x-padded [CHUNK, 1] vregs
+            ltri[...] = (jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+                         <= jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+                         ).astype(jnp.int8)
 
-        def left_dst(nf):
-            return pl.multiple_of(wb_al + nf * TS, _ALIGN)
+            def left_dst(nf):
+                return pl.multiple_of(wb_al + nf * TS, _ALIGN)
 
-        # prefill the left stage's head with the old rows [wb_al, wb) so the
-        # first aligned flush preserves the neighbour leaf's rows
-        cp = pltpu.make_async_copy(
-            rows_ref.at[pl.ds(wb_al, _ALIGN)],
-            stage.at[0, pl.ds(0, _ALIGN)], sem_pre)
-        cp.start()
-        cp.wait()
+            # prefill the left stage's head with the old rows [wb_al, wb) so the
+            # first aligned flush preserves the neighbour leaf's rows
+            cp = pltpu.make_async_copy(
+                rows_ref.at[pl.ds(wb_al, _ALIGN)],
+                stage.at[0, pl.ds(0, _ALIGN)], sem_pre)
+            cp.start()
+            cp.wait()
 
-        # deepened input ring: NIN - 1 reads in flight, so the chunk-read
-        # semaphore wait overlaps the previous chunk's phase A/B matmuls and
-        # the trailing phase C (software pipeline below)
-        for j in range(NIN - 1):
-            @pl.when(j < nchunks)
-            def _prologue(j=j):
-                pltpu.make_async_copy(
-                    rows_ref.at[pl.ds(
-                        pl.multiple_of(wb_al + j * chunk, _ALIGN), chunk)],
-                    inbuf.at[j], sem_in.at[j]).start()
+            # deepened input ring: NIN - 1 reads in flight, so the chunk-read
+            # semaphore wait overlaps the previous chunk's phase A/B matmuls and
+            # the trailing phase C (software pipeline below)
+            for j in range(NIN - 1):
+                @pl.when(j < nchunks)
+                def _prologue(j=j):
+                    pltpu.make_async_copy(
+                        rows_ref.at[pl.ds(
+                            pl.multiple_of(wb_al + j * chunk, _ALIGN), chunk)],
+                        inbuf.at[j], sem_in.at[j]).start()
 
-        iota2ts1 = jax.lax.broadcasted_iota(jnp.int32, (2 * TS, 1), 0)
+            iota2ts1 = jax.lax.broadcasted_iota(jnp.int32, (2 * TS, 1), 0)
         totals_on = "totals" not in dbg_skip and "prefix" not in dbg_skip
         nsub = chunk // T
         npk = chunk // _LANE                   # lane-packed rows (row r ->
@@ -773,51 +775,53 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                                    (fillL, fillR, nfL, nfR, wdL, wdR))
             return (cumLv, cumRv) + cc
 
-        carry = jax.lax.fori_loop(
-            0, nchunks, pipe_body,
-            (zv, zv, zero, zero, zero, zero, zero, zero))
-        # pipeline epilogue: the trailing ``totk`` chunks' phase C
-        fillL, fillR, nfL, nfR, wdL, wdR = jax.lax.fori_loop(
-            jnp.maximum(nchunks - totk, 0), nchunks, chunk_c, carry[2:])
-        nl = fillL
-        nr = fillR
-        stats_ref[...] = jnp.full(stats_ref.shape, nl, jnp.int32)
+        with jax.named_scope(_scopes.K_PLACE):
+            carry = jax.lax.fori_loop(
+                0, nchunks, pipe_body,
+                (zv, zv, zero, zero, zero, zero, zero, zero))
+        with jax.named_scope(_scopes.K_DRAIN):
+            # pipeline epilogue: the trailing ``totk`` chunks' phase C
+            fillL, fillR, nfL, nfR, wdL, wdR = jax.lax.fori_loop(
+                jnp.maximum(nchunks - totk, 0), nchunks, chunk_c, carry[2:])
+            nl = fillL
+            nr = fillR
+            stats_ref[...] = jnp.full(stats_ref.shape, nl, jnp.int32)
 
-        # drain the outstanding async flushes
-        if "flush" not in dbg_skip:
-            await_left(wdL, nfL)
-            await_right(wdR, nfR)
+            # drain the outstanding async flushes
+            if "flush" not in dbg_skip:
+                await_left(wdL, nfL)
+                await_right(wdR, nfR)
 
-        # ---- final right partial flush (scratch is all ours: no RMW,
-        # garbage tail rows are masked by nr during copy-back) ----
-        pend_r = fillR - nfR * TS
+            # ---- final right partial flush (scratch is all ours: no RMW,
+            # garbage tail rows are masked by nr during copy-back) ----
+            pend_r = fillR - nfR * TS
 
-        @pl.when(pend_r > 0)
-        def _final_right():
-            cpf = pltpu.make_async_copy(
-                stage.at[nb_ring + jax.lax.rem(nfR, nb_ring)],
-                scratch_ref.at[pl.ds(pl.multiple_of(nfR * TS, _ALIGN), TS)],
-                sem_pre)
-            cpf.start()
-            cpf.wait()
+            @pl.when(pend_r > 0)
+            def _final_right():
+                cpf = pltpu.make_async_copy(
+                    stage.at[nb_ring + jax.lax.rem(nfR, nb_ring)],
+                    scratch_ref.at[pl.ds(pl.multiple_of(nfR * TS, _ALIGN), TS)],
+                    sem_pre)
+                cpf.start()
+                cpf.wait()
 
-        # ---- final left partial flush (read-modify-write) ----
-        pend_l = headL + fillL - nfL * TS
+            # ---- final left partial flush (read-modify-write) ----
+            pend_l = headL + fillL - nfL * TS
 
-        @pl.when(pend_l > 0)
-        def _final_left():
-            src = left_dst(nfL)
-            cpa = pltpu.make_async_copy(rows_ref.at[pl.ds(src, TS)],
-                                        tmp.at[0], sem_pre)
-            cpa.start()
-            cpa.wait()
-            tmp_w.store(0, _merge_rows(
-                tmp_w.load(0), stage_w.load(jax.lax.rem(nfL, nb_ring)),
-                _rows_from(wbase, pend_l)))
-            cpb = pltpu.make_async_copy(tmp.at[0], rows_ref.at[pl.ds(src, TS)],
-                                        sem_pre)
-            cpb.start()
-            cpb.wait()
+            @pl.when(pend_l > 0)
+            def _final_left():
+                src = left_dst(nfL)
+                cpa = pltpu.make_async_copy(rows_ref.at[pl.ds(src, TS)],
+                                            tmp.at[0], sem_pre)
+                cpa.start()
+                cpa.wait()
+                tmp_w.store(0, _merge_rows(
+                    tmp_w.load(0), stage_w.load(jax.lax.rem(nfL, nb_ring)),
+                    _rows_from(wbase, pend_l)))
+                cpb = pltpu.make_async_copy(tmp.at[0], rows_ref.at[pl.ds(src, TS)],
+                                            sem_pre)
+                cpb.start()
+                cpb.wait()
 
         # ---- smaller child's histogram from its CONTIGUOUS block ----
         # Post-partition the smaller child is contiguous (left block in
@@ -870,13 +874,14 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
 
                 jax.lax.fori_loop(0, nh, hbody, 0)
 
-            @pl.when(hist_left == 1)
-            def _hist_left_block():
-                hist_pass(rows_ref, wb_al, headL, nl)
+            with jax.named_scope(_scopes.K_HIST):
+                @pl.when(hist_left == 1)
+                def _hist_left_block():
+                    hist_pass(rows_ref, wb_al, headL, nl)
 
-            @pl.when(hist_left != 1)
-            def _hist_right_block():
-                hist_pass(scratch_ref, 0, 0, nr)
+                @pl.when(hist_left != 1)
+                def _hist_right_block():
+                    hist_pass(scratch_ref, 0, 0, nr)
 
         # ---- copy right block back: scratch[0:nr] -> rows[wb+nl ...) ----
         # Same streamed-append machinery (chunk reads through the input ring,
@@ -886,7 +891,6 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
         # PR 33): 1.031 ns a right row, whole kernel every row right less
         # every row left; 2.944 with ONE 16 KB read of the scratch in flight
         # a 128-row tile, which the loop waited for about 0.38 us a tile.
-        @pl.when(nr > 0)
         def _copy_back():
             d0 = wb + nl
             d_al = pl.multiple_of((d0 // _ALIGN) * _ALIGN, _ALIGN)
@@ -1023,6 +1027,9 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 cpb.start()
                 cpb.wait()
 
+        with jax.named_scope(_scopes.K_COPY_BACK):
+            pl.when(nr > 0)(_copy_back)
+
     return kernel
 
 
@@ -1074,91 +1081,94 @@ def _make_small_partition_kernel(*, n_pad, W, num_features, num_bins, voff,
         # what lets a level launch carry every frontier slot in every class
         @pl.when(wc > 0)
         def _run_window():
-            ltri[...] = (jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
-                         <= jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-                         ).astype(jnp.int8)
+            with jax.named_scope(_scopes.K_PLACE):
+                ltri[...] = (jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+                             <= jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+                             ).astype(jnp.int8)
 
-            # one read covers the whole window (+ head slack); rows past the
-            # window are carried through the identity permutation and written
-            # back byte-identical, so the RMW is safe for the neighbour leaf
-            cp = pltpu.make_async_copy(rows_ref.at[pl.ds(wb_al, sc)],
-                                       inbuf, sem)
-            cp.start()
-            cp.wait()
-            ti_i8 = jax.lax.bitcast_convert_type(inbuf[...], jnp.int8)
+                # one read covers the whole window (+ head slack); rows past the
+                # window are carried through the identity permutation and written
+                # back byte-identical, so the RMW is safe for the neighbour leaf
+                cp = pltpu.make_async_copy(rows_ref.at[pl.ds(wb_al, sc)],
+                                           inbuf, sem)
+                cp.start()
+                cp.wait()
+                ti_i8 = jax.lax.bitcast_convert_type(inbuf[...], jnp.int8)
 
-            # ---- phase A: shared extract/route/prefix, lane-resident ----
-            col_p = _extract_col_lanes(ti_i8, gcol, W=W, bpc=bpc,
-                                       packed=packed, npk=npk)
-            gl_p = _route_tile(col_p, scal, num_bins)        # [npk, 128]
-            pos_p = (wb_al
-                     + jax.lax.broadcasted_iota(jnp.int32, (npk, 1), 0)
-                     * _LANE
-                     + jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1))
-            inw_p = ((pos_p >= wb).astype(jnp.int32)
-                     * (pos_p < wb + wc).astype(jnp.int32))
-            selL_p = gl_p * inw_p
-            selR_p = (1 - gl_p) * inw_p
-            if T == _LANE:
-                S_L, S_R = selL_p, selR_p
-            else:
-                S_L = selL_p.reshape(nsub, T)
-                S_R = selR_p.reshape(nsub, T)
-            pfxU, _tot, incl_col, excl_col = _subtile_prefixes(S_L, S_R,
-                                                               ltri,
-                                                               nsub=nsub)
-            nlv = incl_col[nsub - 1:nsub, 0:1].astype(jnp.int32)  # [1, 1]
+                # ---- phase A: shared extract/route/prefix, lane-resident ----
+                col_p = _extract_col_lanes(ti_i8, gcol, W=W, bpc=bpc,
+                                           packed=packed, npk=npk)
+                gl_p = _route_tile(col_p, scal, num_bins)        # [npk, 128]
+                pos_p = (wb_al
+                         + jax.lax.broadcasted_iota(jnp.int32, (npk, 1), 0)
+                         * _LANE
+                         + jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1))
+                inw_p = ((pos_p >= wb).astype(jnp.int32)
+                         * (pos_p < wb + wc).astype(jnp.int32))
+                selL_p = gl_p * inw_p
+                selR_p = (1 - gl_p) * inw_p
+                if T == _LANE:
+                    S_L, S_R = selL_p, selR_p
+                else:
+                    S_L = selL_p.reshape(nsub, T)
+                    S_R = selR_p.reshape(nsub, T)
+                pfxU, _tot, incl_col, excl_col = _subtile_prefixes(S_L, S_R,
+                                                                   ltri,
+                                                                   nsub=nsub)
+                nlv = incl_col[nsub - 1:nsub, 0:1].astype(jnp.int32)  # [1, 1]
 
-            # ---- placement: window-global destinations, no staging ring --
-            # dest is a permutation of [0, sc): left rows compact to
-            # [headL, headL + nl), right rows to [headL + nl, headL + wc),
-            # out-of-window rows keep their own position — one [sc, T]
-            # one-hot dot per subtile accumulates the permuted tile (each
-            # output row receives exactly one contribution)
-            iota_sc = jax.lax.broadcasted_iota(jnp.int32, (sc, 1), 0)
-            iota_lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-            comp_i = jnp.zeros((sc, W), jnp.int32)
-            for s in range(nsub):
-                selLs = S_L[s:s + 1, :]
-                selRs = S_R[s:s + 1, :]
-                pfxLs = pfxU[s:s + 1, :]
-                pfxRs = pfxU[nsub + s:nsub + s + 1, :]
-                bL = excl_col[s:s + 1, 0:1].astype(jnp.int32)
-                bR = excl_col[nsub + s:nsub + s + 1, 0:1].astype(jnp.int32)
-                destL = headL + bL + pfxLs - 1
-                destR = headL + nlv + bR + pfxRs - 1
-                own = s * T + iota_lane
-                dest = jnp.where(selLs == 1, destL,
-                                 jnp.where(selRs == 1, destR, own))
-                Pt = (dest == iota_sc).astype(jnp.int8)          # [sc, T]
-                comp_i = comp_i + jax.lax.dot_general(
-                    Pt, ti_i8[s * T:(s + 1) * T, :],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)            # [sc, W]
-            outbuf[...] = (comp_i & 255).astype(jnp.uint8)
+                # ---- placement: window-global destinations, no staging ring --
+                # dest is a permutation of [0, sc): left rows compact to
+                # [headL, headL + nl), right rows to [headL + nl, headL + wc),
+                # out-of-window rows keep their own position — one [sc, T]
+                # one-hot dot per subtile accumulates the permuted tile (each
+                # output row receives exactly one contribution)
+                iota_sc = jax.lax.broadcasted_iota(jnp.int32, (sc, 1), 0)
+                iota_lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+                comp_i = jnp.zeros((sc, W), jnp.int32)
+                for s in range(nsub):
+                    selLs = S_L[s:s + 1, :]
+                    selRs = S_R[s:s + 1, :]
+                    pfxLs = pfxU[s:s + 1, :]
+                    pfxRs = pfxU[nsub + s:nsub + s + 1, :]
+                    bL = excl_col[s:s + 1, 0:1].astype(jnp.int32)
+                    bR = excl_col[nsub + s:nsub + s + 1, 0:1].astype(jnp.int32)
+                    destL = headL + bL + pfxLs - 1
+                    destR = headL + nlv + bR + pfxRs - 1
+                    own = s * T + iota_lane
+                    dest = jnp.where(selLs == 1, destL,
+                                     jnp.where(selRs == 1, destR, own))
+                    Pt = (dest == iota_sc).astype(jnp.int8)          # [sc, T]
+                    comp_i = comp_i + jax.lax.dot_general(
+                        Pt, ti_i8[s * T:(s + 1) * T, :],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.int32)            # [sc, W]
+                outbuf[...] = (comp_i & 255).astype(jnp.uint8)
 
-            # left count out via a plain VMEM write — no SMEM totals DMA
-            # and no vector->scalar extraction anywhere in this variant
-            nl_ref[0, 0:1, 0:1] = nlv
+                # left count out via a plain VMEM write — no SMEM totals DMA
+                # and no vector->scalar extraction anywhere in this variant
+                nl_ref[0, 0:1, 0:1] = nlv
 
             # ---- smaller child's histogram from the SAME resident tile --
             if "hist" not in dbg_skip:
-                ti_c = outbuf[...].astype(jnp.int32)
-                start = jnp.where(hist_left == 1,
-                                  jnp.full((1, 1), 1, jnp.int32) * headL,
-                                  headL + nlv)
-                cnt = jnp.where(hist_left == 1, nlv, wc - nlv)
-                _hist_tile(ti_c, hist_ref, scal, start, cnt,
-                           num_features=num_features, num_bins=num_bins,
-                           bpc=bpc, packed=packed, exact=exact, voff=voff,
-                           f_shard=f_shard, quantized=quantized)
+                with jax.named_scope(_scopes.K_HIST):
+                    ti_c = outbuf[...].astype(jnp.int32)
+                    start = jnp.where(hist_left == 1,
+                                      jnp.full((1, 1), 1, jnp.int32) * headL,
+                                      headL + nlv)
+                    cnt = jnp.where(hist_left == 1, nlv, wc - nlv)
+                    _hist_tile(ti_c, hist_ref, scal, start, cnt,
+                               num_features=num_features, num_bins=num_bins,
+                               bpc=bpc, packed=packed, exact=exact, voff=voff,
+                               f_shard=f_shard, quantized=quantized)
 
-            # ---- single write-back DMA ----
-            cpo = pltpu.make_async_copy(outbuf,
-                                        rows_ref.at[pl.ds(wb_al, sc)],
-                                        sem)
-            cpo.start()
-            cpo.wait()
+            with jax.named_scope(_scopes.K_PLACE):
+                # ---- single write-back DMA ----
+                cpo = pltpu.make_async_copy(outbuf,
+                                            rows_ref.at[pl.ds(wb_al, sc)],
+                                            sem)
+                cpo.start()
+                cpo.wait()
 
     return kernel
 

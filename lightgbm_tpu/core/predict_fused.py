@@ -52,7 +52,6 @@ from ..obs import recompile as _recompile
 from ..plan import device_specs as _device_specs
 from ..plan import state as _plan_state
 from ..resilience import PROGRAM_ERRORS
-from ..utils.timer import FunctionTimer
 from .predict import (EnsembleArrays, _path_matrix, decide_raw,
                       stack_ensemble_host)
 from .tree import K_CATEGORICAL_MASK, K_DEFAULT_LEFT_MASK, Tree
@@ -474,8 +473,7 @@ class FusedPredictor:
             t0 = time.perf_counter()
             misses = 0
             try:
-                with FunctionTimer("Predict::Fused(dispatch)"), \
-                        _span("tree_block_predict"):
+                with _span("tree_block_predict"):
                     out = predict_blocked(
                         self.ens, jnp.asarray(chunk),
                         early_stop_margin=float(early_stop_margin),
@@ -592,8 +590,7 @@ class FusedPredictor:
             try:
                 from .predict_contrib import (contrib_compile_count,
                                               predict_contrib_blocked)
-                with FunctionTimer("Predict::Contrib(dispatch)"), \
-                        _span("contrib_fused"), \
+                with _span("contrib_fused"), \
                         jax.enable_x64(True):
                     # materialize INSIDE the x64 scope: slicing the f64
                     # result outside it would re-canonicalize avals to f32

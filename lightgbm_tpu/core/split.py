@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..io.binning import MissingType
+from ..obs import scopes as _scopes
 
 K_EPSILON = 1e-15  # meta.h:51
 K_MIN_SCORE = -jnp.inf
@@ -245,15 +246,16 @@ def per_feature_best(hist: jax.Array, feat: FeatureInfo, feature_mask: jax.Array
     is_def = t == feat.default_bin[:, None]
     is_nan_bin = t == nb - 1
 
-    pre_g = jnp.cumsum(g, axis=1)
-    pre_h = jnp.cumsum(h, axis=1)
-    pre_c = jnp.cumsum(c, axis=1)
-    g_nz = jnp.where(is_def, 0.0, g)
-    h_nz = jnp.where(is_def, 0.0, h)
-    c_nz = jnp.where(is_def, 0.0, c)
-    pre_g_nz = jnp.cumsum(g_nz, axis=1)
-    pre_h_nz = jnp.cumsum(h_nz, axis=1)
-    pre_c_nz = jnp.cumsum(c_nz, axis=1)
+    with jax.named_scope(_scopes.FIND_SCAN):
+        pre_g = jnp.cumsum(g, axis=1)
+        pre_h = jnp.cumsum(h, axis=1)
+        pre_c = jnp.cumsum(c, axis=1)
+        g_nz = jnp.where(is_def, 0.0, g)
+        h_nz = jnp.where(is_def, 0.0, h)
+        c_nz = jnp.where(is_def, 0.0, c)
+        pre_g_nz = jnp.cumsum(g_nz, axis=1)
+        pre_h_nz = jnp.cumsum(h_nz, axis=1)
+        pre_c_nz = jnp.cumsum(c_nz, axis=1)
     # totals over data bins only (padded bins hold zeros)
     tot = lambda a: a[:, -1:]
     # totals excluding the NaN bin (last data bin is nb-2)
@@ -265,100 +267,102 @@ def per_feature_best(hist: jax.Array, feat: FeatureInfo, feature_mask: jax.Array
     is_nan_mode = mt == int(MissingType.NAN)
     is_zero_mode = mt == int(MissingType.ZERO)
 
-    # ---------- direction 0: missing/default LEFT (reference dir=-1 scan) ----------
-    right_g0 = jnp.where(has_missing & is_nan_mode, tot_nonan(pre_g) - pre_g,
-                jnp.where(has_missing & is_zero_mode, tot(pre_g_nz) - pre_g_nz,
-                          tot(pre_g) - pre_g))
-    right_h0 = jnp.where(has_missing & is_nan_mode, tot_nonan(pre_h) - pre_h,
-                jnp.where(has_missing & is_zero_mode, tot(pre_h_nz) - pre_h_nz,
-                          tot(pre_h) - pre_h)) + K_EPSILON
-    right_c0 = jnp.where(has_missing & is_nan_mode, tot_nonan(pre_c) - pre_c,
-                jnp.where(has_missing & is_zero_mode, tot(pre_c_nz) - pre_c_nz,
-                          tot(pre_c) - pre_c))
-    left_g0 = total_g - right_g0
-    left_h0 = total_h - right_h0
-    left_c0 = num_data_f - right_c0
-    # valid threshold range: t <= nb-2 always; t <= nb-3 when NaN two-dir;
-    # zero-mode cannot place a threshold at default_bin - 1 (:548 skip -> t-1)
-    valid0 = t <= nb - 2
-    valid0 &= jnp.where(has_missing & is_nan_mode, t <= nb - 3, True)
-    valid0 &= jnp.where(has_missing & is_zero_mode,
-                        t != feat.default_bin[:, None] - 1, True)
+    with jax.named_scope(_scopes.FIND_GAIN):
+        # ---------- direction 0: missing/default LEFT (reference dir=-1 scan) ----------
+        right_g0 = jnp.where(has_missing & is_nan_mode, tot_nonan(pre_g) - pre_g,
+                    jnp.where(has_missing & is_zero_mode, tot(pre_g_nz) - pre_g_nz,
+                              tot(pre_g) - pre_g))
+        right_h0 = jnp.where(has_missing & is_nan_mode, tot_nonan(pre_h) - pre_h,
+                    jnp.where(has_missing & is_zero_mode, tot(pre_h_nz) - pre_h_nz,
+                              tot(pre_h) - pre_h)) + K_EPSILON
+        right_c0 = jnp.where(has_missing & is_nan_mode, tot_nonan(pre_c) - pre_c,
+                    jnp.where(has_missing & is_zero_mode, tot(pre_c_nz) - pre_c_nz,
+                              tot(pre_c) - pre_c))
+        left_g0 = total_g - right_g0
+        left_h0 = total_h - right_h0
+        left_c0 = num_data_f - right_c0
+        # valid threshold range: t <= nb-2 always; t <= nb-3 when NaN two-dir;
+        # zero-mode cannot place a threshold at default_bin - 1 (:548 skip -> t-1)
+        valid0 = t <= nb - 2
+        valid0 &= jnp.where(has_missing & is_nan_mode, t <= nb - 3, True)
+        valid0 &= jnp.where(has_missing & is_zero_mode,
+                            t != feat.default_bin[:, None] - 1, True)
 
-    # ---------- direction 1: missing/default RIGHT (reference dir=+1 scan) --------
-    left_g1 = jnp.where(is_zero_mode, pre_g_nz, pre_g)
-    left_h1 = jnp.where(is_zero_mode, pre_h_nz, pre_h) + K_EPSILON
-    left_c1 = jnp.where(is_zero_mode, pre_c_nz, pre_c)
-    right_g1 = total_g - left_g1
-    right_h1 = total_h - left_h1
-    right_c1 = num_data_f - left_c1
-    valid1 = has_missing & (t <= nb - 2)
-    valid1 &= jnp.where(is_zero_mode, ~is_def, True)
+        # ---------- direction 1: missing/default RIGHT (reference dir=+1 scan) --------
+        left_g1 = jnp.where(is_zero_mode, pre_g_nz, pre_g)
+        left_h1 = jnp.where(is_zero_mode, pre_h_nz, pre_h) + K_EPSILON
+        left_c1 = jnp.where(is_zero_mode, pre_c_nz, pre_c)
+        right_g1 = total_g - left_g1
+        right_h1 = total_h - left_h1
+        right_c1 = num_data_f - left_c1
+        valid1 = has_missing & (t <= nb - 2)
+        valid1 &= jnp.where(is_zero_mode, ~is_def, True)
 
-    gain_shift = leaf_split_gain(total_g, total_h, params.lambda_l1,
-                                 params.lambda_l2, params.max_delta_step)
-    min_gain_shift = gain_shift + params.min_gain_to_split
+        gain_shift = leaf_split_gain(total_g, total_h, params.lambda_l1,
+                                     params.lambda_l2, params.max_delta_step)
+        min_gain_shift = gain_shift + params.min_gain_to_split
 
-    mono = (feat.monotone[:, None]
-            if cmin is not None and feat.monotone is not None else None)
+        mono = (feat.monotone[:, None]
+                if cmin is not None and feat.monotone is not None else None)
 
-    def evaluate(gl, hl, cl, gr, hr, cr, valid):
-        return _evaluate_candidates(gl, hl, cl, gr, hr, cr, valid, params,
-                                    min_gain_shift, cmin, cmax, mono)
+        def evaluate(gl, hl, cl, gr, hr, cr, valid):
+            return _evaluate_candidates(gl, hl, cl, gr, hr, cr, valid, params,
+                                        min_gain_shift, cmin, cmax, mono)
 
-    if threshold_mask is not None:
-        valid0 = valid0 & threshold_mask[None, :]
-        valid1 = valid1 & threshold_mask[None, :]
-    elif params.extra_trees:
-        # forced splits (threshold_mask) bypass the randomization, matching
-        # the reference's GatherInfoForThreshold
-        et_mask = t == _extra_trees_draw(feat.num_bin, sum_grad, sum_hess,
-                                         params)[:, None]
-        valid0 = valid0 & et_mask
-        valid1 = valid1 & et_mask
-    gain0, lo0, ro0 = evaluate(left_g0, left_h0, left_c0,
-                               right_g0, right_h0, right_c0, valid0)
-    gain1, lo1, ro1 = evaluate(left_g1, left_h1, left_c1,
-                               right_g1, right_h1, right_c1, valid1)
+        if threshold_mask is not None:
+            valid0 = valid0 & threshold_mask[None, :]
+            valid1 = valid1 & threshold_mask[None, :]
+        elif params.extra_trees:
+            # forced splits (threshold_mask) bypass the randomization, matching
+            # the reference's GatherInfoForThreshold
+            et_mask = t == _extra_trees_draw(feat.num_bin, sum_grad, sum_hess,
+                                             params)[:, None]
+            valid0 = valid0 & et_mask
+            valid1 = valid1 & et_mask
+        gain0, lo0, ro0 = evaluate(left_g0, left_h0, left_c0,
+                                   right_g0, right_h0, right_c0, valid0)
+        gain1, lo1, ro1 = evaluate(left_g1, left_h1, left_c1,
+                                   right_g1, right_h1, right_c1, valid1)
 
-    fm = feature_mask & ~feat.is_categorical
-    gain0 = jnp.where(fm[:, None], gain0, K_MIN_SCORE)
-    gain1 = jnp.where(fm[:, None], gain1, K_MIN_SCORE)
+        fm = feature_mask & ~feat.is_categorical
+        gain0 = jnp.where(fm[:, None], gain0, K_MIN_SCORE)
+        gain1 = jnp.where(fm[:, None], gain1, K_MIN_SCORE)
 
-    # per-feature argmax with reference tie-breaking
-    idx0 = (B - 1) - jnp.argmax(gain0[:, ::-1], axis=1)   # largest t wins ties
-    best0 = jnp.take_along_axis(gain0, idx0[:, None], axis=1)[:, 0]
-    idx1 = jnp.argmax(gain1, axis=1)                      # smallest t wins ties
-    best1 = jnp.take_along_axis(gain1, idx1[:, None], axis=1)[:, 0]
-    use1 = best1 > best0                                  # dir0 wins ties
-    feat_gain = jnp.where(use1, best1, best0)
-    feat_thr = jnp.where(use1, idx1, idx0).astype(jnp.int32)
+    with jax.named_scope(_scopes.FIND_PICK):
+        # per-feature argmax with reference tie-breaking
+        idx0 = (B - 1) - jnp.argmax(gain0[:, ::-1], axis=1)   # largest t wins ties
+        best0 = jnp.take_along_axis(gain0, idx0[:, None], axis=1)[:, 0]
+        idx1 = jnp.argmax(gain1, axis=1)                      # smallest t wins ties
+        best1 = jnp.take_along_axis(gain1, idx1[:, None], axis=1)[:, 0]
+        use1 = best1 > best0                                  # dir0 wins ties
+        feat_gain = jnp.where(use1, best1, best0)
+        feat_thr = jnp.where(use1, idx1, idx0).astype(jnp.int32)
 
-    # with <=2 bins and NaN missing, the single scan reports default_left = false
-    # (feature_histogram.hpp:128-130)
-    two_bin_nan = (mt[:, 0] == int(MissingType.NAN)) & (feat.num_bin <= 2)
-    feat_default_left = ~use1 & ~two_bin_nan
+        # with <=2 bins and NaN missing, the single scan reports default_left = false
+        # (feature_histogram.hpp:128-130)
+        two_bin_nan = (mt[:, 0] == int(MissingType.NAN)) & (feat.num_bin <= 2)
+        feat_default_left = ~use1 & ~two_bin_nan
 
-    fidx = jnp.arange(F)
+        fidx = jnp.arange(F)
 
-    def pick(arr0, arr1):
-        return jnp.where(use1, arr1[fidx, feat_thr], arr0[fidx, feat_thr])
+        def pick(arr0, arr1):
+            return jnp.where(use1, arr1[fidx, feat_thr], arr0[fidx, feat_thr])
 
-    found = feat_gain > K_MIN_SCORE
-    return FeatureBest(
-        gain=jnp.where(found, feat_gain - min_gain_shift, K_MIN_SCORE),
-        threshold=feat_thr,
-        default_left=feat_default_left,
-        left_sum_grad=pick(left_g0, left_g1),
-        left_sum_hess=pick(left_h0, left_h1) - K_EPSILON,
-        left_count=pick(left_c0, left_c1),
-        right_sum_grad=pick(right_g0, right_g1),
-        right_sum_hess=pick(right_h0, right_h1) - K_EPSILON,
-        right_count=pick(right_c0, right_c1),
-        left_output=jnp.where(use1, lo1[fidx, feat_thr], lo0[fidx, feat_thr]),
-        right_output=jnp.where(use1, ro1[fidx, feat_thr], ro0[fidx, feat_thr]),
-        cat_bitset=jnp.zeros((F, B // 32), dtype=jnp.uint32),
-    )
+        found = feat_gain > K_MIN_SCORE
+        return FeatureBest(
+            gain=jnp.where(found, feat_gain - min_gain_shift, K_MIN_SCORE),
+            threshold=feat_thr,
+            default_left=feat_default_left,
+            left_sum_grad=pick(left_g0, left_g1),
+            left_sum_hess=pick(left_h0, left_h1) - K_EPSILON,
+            left_count=pick(left_c0, left_c1),
+            right_sum_grad=pick(right_g0, right_g1),
+            right_sum_hess=pick(right_h0, right_h1) - K_EPSILON,
+            right_count=pick(right_c0, right_c1),
+            left_output=jnp.where(use1, lo1[fidx, feat_thr], lo0[fidx, feat_thr]),
+            right_output=jnp.where(use1, ro1[fidx, feat_thr], ro0[fidx, feat_thr]),
+            cat_bitset=jnp.zeros((F, B // 32), dtype=jnp.uint32),
+        )
 
 
 def _bits_to_words(bits: jax.Array) -> jax.Array:
@@ -587,22 +591,23 @@ def reduce_feature_best(fb: FeatureBest, feature_ids: jax.Array) -> BestSplit:
     """Argmax-by-gain across features; ties go to the smaller feature id
     (split_info.hpp:185 comparators).  ``feature_ids`` maps positions in ``fb`` to
     global inner-feature indices (they must be ascending for the tie-break)."""
-    best_f = jnp.argmax(fb.gain).astype(jnp.int32)   # first max = smallest id
-    return BestSplit(
-        gain=fb.gain[best_f],
-        feature=feature_ids[best_f].astype(jnp.int32),
-        threshold=fb.threshold[best_f],
-        default_left=fb.default_left[best_f],
-        left_sum_grad=fb.left_sum_grad[best_f],
-        left_sum_hess=fb.left_sum_hess[best_f],
-        left_count=fb.left_count[best_f],
-        right_sum_grad=fb.right_sum_grad[best_f],
-        right_sum_hess=fb.right_sum_hess[best_f],
-        right_count=fb.right_count[best_f],
-        left_output=fb.left_output[best_f],
-        right_output=fb.right_output[best_f],
-        cat_bitset=fb.cat_bitset[best_f],
-    )
+    with jax.named_scope(_scopes.FIND_PICK):
+        best_f = jnp.argmax(fb.gain).astype(jnp.int32)   # first max = smallest id
+        return BestSplit(
+            gain=fb.gain[best_f],
+            feature=feature_ids[best_f].astype(jnp.int32),
+            threshold=fb.threshold[best_f],
+            default_left=fb.default_left[best_f],
+            left_sum_grad=fb.left_sum_grad[best_f],
+            left_sum_hess=fb.left_sum_hess[best_f],
+            left_count=fb.left_count[best_f],
+            right_sum_grad=fb.right_sum_grad[best_f],
+            right_sum_hess=fb.right_sum_hess[best_f],
+            right_count=fb.right_count[best_f],
+            left_output=fb.left_output[best_f],
+            right_output=fb.right_output[best_f],
+            cat_bitset=fb.cat_bitset[best_f],
+        )
 
 
 class GroupLanes(NamedTuple):
@@ -718,28 +723,29 @@ def group_scans(hist: jax.Array, lanes: GroupLanes, sum_grad: jax.Array,
     reference's serial scan.  f32 in, exactly, at any matmul precision: each
     value goes in as three parts of bf16's eight bits, which the unit
     multiplies by 0 or 1 and adds up in f32."""
-    f32 = jnp.float32
-    Bg = hist.shape[-1]
-    cnt_factor = num_data.astype(f32) / (sum_hess + 2 * K_EPSILON)
-    x = jnp.stack([hist[:, 0, :], hist[:, 1, :],
-                   jnp.round(hist[:, 1, :] * cnt_factor)])        # [3, G, Bg]
-    # the reference's right-to-left scan accumulates the RIGHT side, its
-    # left-to-right scan the left (feature_histogram.hpp:535-650); the two
-    # plain rows give the segment's own sum, for bin 0
-    rows = jnp.concatenate([jnp.where(lanes.drop[0], 0.0, x),
-                            jnp.where(lanes.drop[1], 0.0, x), x[:2]])
-    # reduce_precision, not a round trip through bf16: the compiler may drop
-    # a pair of converts as excess precision, never this
-    hi = jax.lax.reduce_precision(rows, exponent_bits=8, mantissa_bits=7)
-    mid = jax.lax.reduce_precision(rows - hi, exponent_bits=8, mantissa_bits=7)
-    lo = (rows - hi) - mid
-    lo, mid, hi = jnp.einsum("prgk,gkj->prgj", jnp.stack([lo, mid, hi]),
-                             lanes.scan.astype(f32))
-    sums = (lo + mid) + hi                                    # [8, G, 2 Bg]
-    own = sums[6:, :, :Bg] + sums[6:, :, Bg:]
-    totals = jnp.stack([sum_grad, sum_hess]).astype(f32)[:, None, None]
-    return GroupScans(after=sums[:3, :, :Bg], before=sums[3:6, :, Bg:],
-                      bin0=totals - own)
+    with jax.named_scope(_scopes.FIND_SCAN):
+        f32 = jnp.float32
+        Bg = hist.shape[-1]
+        cnt_factor = num_data.astype(f32) / (sum_hess + 2 * K_EPSILON)
+        x = jnp.stack([hist[:, 0, :], hist[:, 1, :],
+                       jnp.round(hist[:, 1, :] * cnt_factor)])        # [3, G, Bg]
+        # the reference's right-to-left scan accumulates the RIGHT side, its
+        # left-to-right scan the left (feature_histogram.hpp:535-650); the two
+        # plain rows give the segment's own sum, for bin 0
+        rows = jnp.concatenate([jnp.where(lanes.drop[0], 0.0, x),
+                                jnp.where(lanes.drop[1], 0.0, x), x[:2]])
+        # reduce_precision, not a round trip through bf16: the compiler may drop
+        # a pair of converts as excess precision, never this
+        hi = jax.lax.reduce_precision(rows, exponent_bits=8, mantissa_bits=7)
+        mid = jax.lax.reduce_precision(rows - hi, exponent_bits=8, mantissa_bits=7)
+        lo = (rows - hi) - mid
+        lo, mid, hi = jnp.einsum("prgk,gkj->prgj", jnp.stack([lo, mid, hi]),
+                                 lanes.scan.astype(f32))
+        sums = (lo + mid) + hi                                    # [8, G, 2 Bg]
+        own = sums[6:, :, :Bg] + sums[6:, :, Bg:]
+        totals = jnp.stack([sum_grad, sum_hess]).astype(f32)[:, None, None]
+        return GroupScans(after=sums[:3, :, :Bg], before=sums[3:6, :, Bg:],
+                          bin0=totals - own)
 
 
 def _two_sides(scans: GroupScans, zero_is_default, total_g, total_h,
@@ -775,64 +781,66 @@ def group_best(scans: GroupScans, lanes: GroupLanes, valid: jax.Array,
     ``lanes.feature2``: the candidate's ``feature_contri``, or None.
     """
     G, Bg = lanes.feature.shape
-    total_h = sum_hess + 2 * K_EPSILON  # feature_histogram.hpp:88
-    total_g = sum_grad
-    num_data_f = num_data.astype(jnp.float32)
-    sides0, sides1 = _two_sides(scans, lanes.zero_is_default, total_g,
-                                total_h, num_data_f)
-    if params.extra_trees:
-        valid = valid & (lanes.threshold == _extra_trees_draw(
-            lanes.num_bin, sum_grad, sum_hess, params,
-            fid=lanes.feature.astype(jnp.uint32)))
-    gain_shift = leaf_split_gain(total_g, total_h, params.lambda_l1,
-                                 params.lambda_l2, params.max_delta_step)
-    min_gain_shift = gain_shift + params.min_gain_to_split
-    mono = lanes.monotone if cmin is not None else None
-    gain0, _, _ = _evaluate_candidates(*sides0, valid[0], params,
-                                       min_gain_shift, cmin, cmax, mono)
-    gain1, _, _ = _evaluate_candidates(*sides1, valid[1], params,
-                                       min_gain_shift, cmin, cmax, mono)
+    with jax.named_scope(_scopes.FIND_GAIN):
+        total_h = sum_hess + 2 * K_EPSILON  # feature_histogram.hpp:88
+        total_g = sum_grad
+        num_data_f = num_data.astype(jnp.float32)
+        sides0, sides1 = _two_sides(scans, lanes.zero_is_default, total_g,
+                                    total_h, num_data_f)
+        if params.extra_trees:
+            valid = valid & (lanes.threshold == _extra_trees_draw(
+                lanes.num_bin, sum_grad, sum_hess, params,
+                fid=lanes.feature.astype(jnp.uint32)))
+        gain_shift = leaf_split_gain(total_g, total_h, params.lambda_l1,
+                                     params.lambda_l2, params.max_delta_step)
+        min_gain_shift = gain_shift + params.min_gain_to_split
+        mono = lanes.monotone if cmin is not None else None
+        gain0, _, _ = _evaluate_candidates(*sides0, valid[0], params,
+                                           min_gain_shift, cmin, cmax, mono)
+        gain1, _, _ = _evaluate_candidates(*sides1, valid[1], params,
+                                           min_gain_shift, cmin, cmax, mono)
 
-    # the winner over (direction, lane): the largest reported gain; among
-    # equals the smaller feature id; inside that feature the largest scanned
-    # gain, then lanes.order (per_feature_best's argmaxes and
-    # reduce_feature_best's, as maxima and one ranked minimum)
-    raw = jnp.stack([gain0, gain1]).reshape(-1)
-    shown = raw - min_gain_shift
-    if lane_contri is not None:
-        shown = shown * lane_contri
-    shown = jnp.where(raw > K_MIN_SCORE, shown, K_MIN_SCORE)
-    best_f = jnp.min(jnp.where(shown == jnp.max(shown), lanes.feature2,
-                               jnp.int32(2**31 - 1)))
-    in_f = lanes.feature2 == best_f
-    best_raw = jnp.max(jnp.where(in_f, raw, K_MIN_SCORE))
-    at = jnp.argmin(jnp.where(in_f & (raw == best_raw), lanes.order,
-                              jnp.int32(2**31 - 1))).astype(jnp.int32)
-    lane = at % (G * Bg)
-    use1 = at >= G * Bg
+    with jax.named_scope(_scopes.FIND_PICK):
+        # the winner over (direction, lane): the largest reported gain; among
+        # equals the smaller feature id; inside that feature the largest scanned
+        # gain, then lanes.order (per_feature_best's argmaxes and
+        # reduce_feature_best's, as maxima and one ranked minimum)
+        raw = jnp.stack([gain0, gain1]).reshape(-1)
+        shown = raw - min_gain_shift
+        if lane_contri is not None:
+            shown = shown * lane_contri
+        shown = jnp.where(raw > K_MIN_SCORE, shown, K_MIN_SCORE)
+        best_f = jnp.min(jnp.where(shown == jnp.max(shown), lanes.feature2,
+                                   jnp.int32(2**31 - 1)))
+        in_f = lanes.feature2 == best_f
+        best_raw = jnp.max(jnp.where(in_f, raw, K_MIN_SCORE))
+        at = jnp.argmin(jnp.where(in_f & (raw == best_raw), lanes.order,
+                                  jnp.int32(2**31 - 1))).astype(jnp.int32)
+        lane = at % (G * Bg)
+        use1 = at >= G * Bg
 
-    # the winner's own record from its lane's scans (one lane, not stacked
-    # arrays of them): the same formulas over again
-    def one(x):
-        return jax.lax.dynamic_index_in_dim(
-            x.reshape(x.shape[:-2] + (-1,)), lane, axis=-1, keepdims=False)
-    feature, thr, zero_is_default, two_bin_nan = \
-        jax.lax.dynamic_index_in_dim(lanes.record, lane, axis=1,
-                                     keepdims=False)
-    gl, hl, cl, gr, hr, cr = (
-        jnp.where(use1, a1, a0) for a0, a1 in zip(*_two_sides(
-            GroupScans(*[one(x) for x in scans]), zero_is_default != 0,
-            total_g, total_h, num_data_f)))
-    _, lo, ro = _split_gains_clamped(gl, hl, gr, hr, params, params.lambda_l2,
-                                     cmin, cmax)
-    return BestSplit(
-        gain=jax.lax.dynamic_index_in_dim(shown, at, keepdims=False),
-        feature=feature, threshold=thr,
-        default_left=~use1 & (two_bin_nan == 0),
-        left_sum_grad=gl, left_sum_hess=hl - K_EPSILON, left_count=cl,
-        right_sum_grad=gr, right_sum_hess=hr - K_EPSILON, right_count=cr,
-        left_output=lo, right_output=ro,
-        cat_bitset=jnp.zeros((feat_num_bins // 32,), dtype=jnp.uint32))
+        # the winner's own record from its lane's scans (one lane, not stacked
+        # arrays of them): the same formulas over again
+        def one(x):
+            return jax.lax.dynamic_index_in_dim(
+                x.reshape(x.shape[:-2] + (-1,)), lane, axis=-1, keepdims=False)
+        feature, thr, zero_is_default, two_bin_nan = \
+            jax.lax.dynamic_index_in_dim(lanes.record, lane, axis=1,
+                                         keepdims=False)
+        gl, hl, cl, gr, hr, cr = (
+            jnp.where(use1, a1, a0) for a0, a1 in zip(*_two_sides(
+                GroupScans(*[one(x) for x in scans]), zero_is_default != 0,
+                total_g, total_h, num_data_f)))
+        _, lo, ro = _split_gains_clamped(gl, hl, gr, hr, params, params.lambda_l2,
+                                         cmin, cmax)
+        return BestSplit(
+            gain=jax.lax.dynamic_index_in_dim(shown, at, keepdims=False),
+            feature=feature, threshold=thr,
+            default_left=~use1 & (two_bin_nan == 0),
+            left_sum_grad=gl, left_sum_hess=hl - K_EPSILON, left_count=cl,
+            right_sum_grad=gr, right_sum_hess=hr - K_EPSILON, right_count=cr,
+            left_output=lo, right_output=ro,
+            cat_bitset=jnp.zeros((feat_num_bins // 32,), dtype=jnp.uint32))
 
 
 def sync_best(best: BestSplit, axis_name: str) -> BestSplit:

@@ -48,8 +48,8 @@ from .tree import Tree
 from ..io.binning import BinType, MissingType
 from ..io.dataset import BinnedDataset
 from ..obs import efb as _efb_counters
+from ..obs import scopes as _scopes
 from ..obs.spans import span as _span
-from ..utils.timer import FunctionTimer
 
 
 class Comm(NamedTuple):
@@ -703,13 +703,14 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # split penalty + coupled (until first use) + lazy on-demand
             # cost for rows that have not paid the feature yet
             split_pen, coupled, _, lazy = cegb
-            penalty = (split_pen * cnt.astype(jnp.float32)
-                       + jnp.where(used, 0.0, coupled))
-            if lazy_on:
-                penalty = penalty + lazy * jnp.maximum(
-                    cnt.astype(jnp.float32) - ucnt, 0.0)
-            fb = fb._replace(gain=jnp.where(fb.gain > K_MIN_SCORE,
-                                            fb.gain - penalty, fb.gain))
+            with jax.named_scope(_scopes.FIND_GAIN):
+                penalty = (split_pen * cnt.astype(jnp.float32)
+                           + jnp.where(used, 0.0, coupled))
+                if lazy_on:
+                    penalty = penalty + lazy * jnp.maximum(
+                        cnt.astype(jnp.float32) - ucnt, 0.0)
+                fb = fb._replace(gain=jnp.where(fb.gain > K_MIN_SCORE,
+                                                fb.gain - penalty, fb.gain))
             return reduce_feature_best(fb, jnp.arange(f, dtype=jnp.int32)), fb
         return reduce_feature_best(fb, jnp.arange(f, dtype=jnp.int32))
 
@@ -1014,7 +1015,8 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             """Masked state write: keep ``old`` on dead iterations."""
             return jnp.where(ok, new, old)
 
-        with jax.named_scope("tree.find_split"):
+        with jax.named_scope("tree.find_split"), \
+                jax.named_scope(_scopes.FIND_HIST_CACHE):
             if pooled:
                 # parent histogram from its LRU slot, or rebuilt by streaming the
                 # window (post-partition it still holds exactly the parent rows —
@@ -1088,39 +1090,41 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 # reference adds the refund to the PRE-penalty cached gain — a
                 # quirk that inflates promoted gains; here the cache holds
                 # penalized gains so the refund yields the intended value.)
-                coupled_arr = cegb[1]
-                fnew = b.feature
-                newly = ok & ~st.feat_used[fnew]
-                refund = jnp.where(newly, coupled_arr[fnew], 0.0)
-                fbc = st.fbc._replace(gain=st.fbc.gain.at[:, fnew].add(refund))
-                cand_gain = jnp.take(fbc.gain, fnew, axis=1)          # [L]
-                promote = (newly & (st.bests.gain > K_MIN_SCORE)
-                           & (cand_gain > st.bests.gain))
+                with jax.named_scope(_scopes.FIND_BESTS):
+                    coupled_arr = cegb[1]
+                    fnew = b.feature
+                    newly = ok & ~st.feat_used[fnew]
+                    refund = jnp.where(newly, coupled_arr[fnew], 0.0)
+                    fbc = st.fbc._replace(
+                        gain=st.fbc.gain.at[:, fnew].add(refund))
+                    cand_gain = jnp.take(fbc.gain, fnew, axis=1)      # [L]
+                    promote = (newly & (st.bests.gain > K_MIN_SCORE)
+                               & (cand_gain > st.bests.gain))
 
-                def pick(cand_field, old_field):
-                    cand_col = jnp.take(cand_field, fnew, axis=1)
-                    shape_tail = (1,) * (old_field.ndim - 1)
-                    return jnp.where(promote.reshape((-1,) + shape_tail),
-                                     cand_col, old_field)
+                    def pick(cand_field, old_field):
+                        cand_col = jnp.take(cand_field, fnew, axis=1)
+                        shape_tail = (1,) * (old_field.ndim - 1)
+                        return jnp.where(promote.reshape((-1,) + shape_tail),
+                                         cand_col, old_field)
 
-                promoted = BestSplit(
-                    gain=jnp.where(promote, cand_gain, st.bests.gain),
-                    feature=jnp.where(promote, fnew, st.bests.feature),
-                    threshold=pick(fbc.threshold, st.bests.threshold),
-                    default_left=pick(fbc.default_left, st.bests.default_left),
-                    left_sum_grad=pick(fbc.left_sum_grad,
-                                       st.bests.left_sum_grad),
-                    left_sum_hess=pick(fbc.left_sum_hess,
-                                       st.bests.left_sum_hess),
-                    left_count=pick(fbc.left_count, st.bests.left_count),
-                    right_sum_grad=pick(fbc.right_sum_grad,
-                                        st.bests.right_sum_grad),
-                    right_sum_hess=pick(fbc.right_sum_hess,
-                                        st.bests.right_sum_hess),
-                    right_count=pick(fbc.right_count, st.bests.right_count),
-                    left_output=pick(fbc.left_output, st.bests.left_output),
-                    right_output=pick(fbc.right_output, st.bests.right_output),
-                    cat_bitset=pick(fbc.cat_bitset, st.bests.cat_bitset))
+                    promoted = BestSplit(
+                        gain=jnp.where(promote, cand_gain, st.bests.gain),
+                        feature=jnp.where(promote, fnew, st.bests.feature),
+                        threshold=pick(fbc.threshold, st.bests.threshold),
+                        default_left=pick(fbc.default_left, st.bests.default_left),
+                        left_sum_grad=pick(fbc.left_sum_grad,
+                                           st.bests.left_sum_grad),
+                        left_sum_hess=pick(fbc.left_sum_hess,
+                                           st.bests.left_sum_hess),
+                        left_count=pick(fbc.left_count, st.bests.left_count),
+                        right_sum_grad=pick(fbc.right_sum_grad,
+                                            st.bests.right_sum_grad),
+                        right_sum_hess=pick(fbc.right_sum_hess,
+                                            st.bests.right_sum_hess),
+                        right_count=pick(fbc.right_count, st.bests.right_count),
+                        left_output=pick(fbc.left_output, st.bests.left_output),
+                        right_output=pick(fbc.right_output, st.bests.right_output),
+                        cat_bitset=pick(fbc.cat_bitset, st.bests.cat_bitset))
                 child_best, child_fb = vmapped_best(
                     jnp.stack([hist_left, hist_right]),
                     jnp.stack([b.left_sum_grad, b.right_sum_grad]),
@@ -1130,8 +1134,10 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     feat_used, jnp.stack([used_l, used_r]))
                 fbc = type(fbc)(*[x.at[leaf].set(c[0]).at[k].set(c[1])
                                   for x, c in zip(fbc, child_fb)])
-                bests = _bests_update(promoted, leaf,
-                                      BestSplit(*[x[0] for x in child_best]))
+                with jax.named_scope(_scopes.FIND_BESTS):
+                    bests = _bests_update(
+                        promoted, leaf,
+                        BestSplit(*[x[0] for x in child_best]))
             else:
                 fbc = st.fbc
                 child_best = vmapped_best(
@@ -1141,9 +1147,13 @@ def build_tree_partitioned(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     jnp.stack([b.left_count, b.right_count]),
                     jnp.stack([lmin, rmin]), jnp.stack([lmax, rmax]),
                     feat_used)
-                bests = _bests_update(st.bests, leaf,
-                                      BestSplit(*[x[0] for x in child_best]))
-            bests = _bests_update(bests, k, BestSplit(*[x[1] for x in child_best]))
+                with jax.named_scope(_scopes.FIND_BESTS):
+                    bests = _bests_update(
+                        st.bests, leaf,
+                        BestSplit(*[x[0] for x in child_best]))
+            with jax.named_scope(_scopes.FIND_BESTS):
+                bests = _bests_update(
+                    bests, k, BestSplit(*[x[1] for x in child_best]))
 
         with jax.named_scope("tree.state_update"):
             # parent child-pointer fixup (tree.h:338-346)
@@ -2001,8 +2011,7 @@ class SerialTreeLearner:
             _plan_state.stamp(tele, "tree_build", prov,
                               key="n%d_b%d" % (self.num_data, self.num_bins),
                               mode=grow_mode)
-        with span_ctx, FunctionTimer("Partition::BuildTree(dispatch)"), \
-                _span("partition_build_tree"):
+        with span_ctx, _span("partition_build_tree"):
             out = build_tree_partitioned(
                 self.bins, grad, hess,
                 jnp.asarray(num_data_in_bag, dtype=jnp.int32),

@@ -19,7 +19,7 @@ from .basic import Booster, Dataset, LightGBMError
 from .config import alias_transform
 from .utils.compile_cache import enable_compilation_cache
 from .utils.log import Log
-from .utils.timer import global_timer
+from .obs import spans as _spans
 
 __all__ = ["train", "cv", "serve", "serve_and_train", "CVBooster"]
 
@@ -316,7 +316,7 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
                 obs.disable()
         # reference exit-time dump at the end of the training driver too
         # (Log.debug-gated on verbosity)
-        global_timer.print()
+        Log.debug("%s", _spans.summary())
         return booster
     finally:
         resilience.disarm_supervision(owned_handler, own_wd)

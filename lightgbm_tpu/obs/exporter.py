@@ -455,16 +455,23 @@ class MetricsExporter:
             alerts=eng.snapshot() if eng is not None else None)
 
     def _debug_profile(self, query: str):
-        """GET /debug/profile?seconds=N: one bounded jax.profiler capture
-        into the run's artifact dir; 409 when one is already running."""
+        """GET /debug/profile?seconds=N[&detail=kernel]: one bounded
+        jax.profiler capture into the run's artifact dir; 409 when one is
+        already running."""
         from urllib.parse import parse_qs
         from . import profiling
+        asked = parse_qs(query)
         try:
-            seconds = float(parse_qs(query).get(
+            seconds = float(asked.get(
                 "seconds", [profiling.DEFAULT_SECONDS])[0])
         except (TypeError, ValueError):
             return 400, {"error": "seconds must be a number"}
-        meta = profiling.capture(self.tele, seconds=seconds, reason="http")
+        detail = asked.get("detail", [None])[0]
+        if detail not in profiling.DETAILS:
+            return 400, {"error": "detail must be one of %r" % sorted(
+                d for d in profiling.DETAILS if d)}
+        meta = profiling.capture(self.tele, seconds=seconds, reason="http",
+                                 detail=detail)
         if meta.get("busy"):
             return 409, meta
         return (200 if "error" not in meta else 501), meta

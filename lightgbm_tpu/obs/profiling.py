@@ -102,13 +102,39 @@ def open_capture(root: str, n: int, reason: str) -> str:
     return outdir
 
 
-def trace_block(outdir: str):
+# ``detail`` of a capture -> the profiler's ``tpu_trace_mode``.  "kernel":
+# the device plane also gets the ``Tensor Core Sync Flag`` line (every DMA
+# and semaphore wait with its duration), beside what the process's own
+# start-up flags already put there under any mode: with
+# ``obs.scopes.KERNEL_TRACE_FLAGS`` in LIBTPU_INIT_ARGS the ``XLA TraceMe``
+# line holds an event for every region a Pallas kernel opens
+# (``obs.scopes.KERNEL_REGIONS``).  Without the flags the regions compile to
+# nothing and the line is absent: the mode alone does not bring them.
+DETAILS = {None: None, "kernel": "TRACE_COMPUTE_AND_SYNC"}
+
+
+def profile_options(detail: Optional[str]):
+    """``jax.profiler.ProfileOptions`` for a capture of ``detail`` (None:
+    the profiler's defaults, passed as None)."""
+    if detail not in DETAILS:
+        raise ValueError("detail must be one of %r, got %r"
+                         % (sorted(d for d in DETAILS if d), detail))
+    if DETAILS[detail] is None:
+        return None
+    from jax import profiler
+    options = profiler.ProfileOptions()
+    options.advanced_configuration = {"tpu_trace_mode": DETAILS[detail]}
+    return options
+
+
+def trace_block(outdir: str, detail: Optional[str] = None):
     """Context manager running ``jax.profiler.trace`` into ``outdir``; a
     null context (still yielding) when the profiler is unavailable, so
     callers never need their own import guard."""
     try:
         from jax import profiler
-        return profiler.trace(outdir)
+        return profiler.trace(outdir,
+                              profiler_options=profile_options(detail))
     except Exception:
         return contextlib.nullcontext()
 
@@ -128,13 +154,16 @@ def write_meta(outdir: str, **meta: Any) -> Dict[str, Any]:
 
 
 def capture(tele, seconds: float = DEFAULT_SECONDS,
-            reason: str = "manual") -> Dict[str, Any]:
+            reason: str = "manual",
+            detail: Optional[str] = None) -> Dict[str, Any]:
     """Run one bounded profiler capture on ``tele``'s run; returns the
     capture metadata (or ``{"error": ...}`` when a capture is already in
     flight — never recursive, never concurrent).  Blocks for ``seconds``;
     the /debug/profile handler calls this from its own request thread so
-    scrapes stay live meanwhile.  Callers gate on ``tele is not None``."""
+    scrapes stay live meanwhile.  Callers gate on ``tele is not None``.
+    ``detail="kernel"``: see :data:`DETAILS`."""
     seconds = min(max(float(seconds), 0.05), MAX_SECONDS)
+    options = profile_options(detail)      # a wrong detail raises here
     st = state(tele, create=True)
     with st.lock:
         if st.active:
@@ -147,6 +176,8 @@ def capture(tele, seconds: float = DEFAULT_SECONDS,
     err = None
     outdir = None
     meta = {"n": n, "reason": str(reason), "seconds": seconds, "t0": t0}
+    if detail is not None:
+        meta["detail"] = detail
     try:
         try:
             root = artifact_root(tele)
@@ -157,7 +188,7 @@ def capture(tele, seconds: float = DEFAULT_SECONDS,
                 err = "jax.profiler unavailable: %s" % exc
             else:
                 try:
-                    with profiler.trace(outdir):
+                    with profiler.trace(outdir, profiler_options=options):
                         time.sleep(seconds)
                 except Exception as exc:  # a broken backend must not
                     err = "%s: %s" % (type(exc).__name__, exc)  # kill the run

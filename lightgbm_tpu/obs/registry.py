@@ -269,7 +269,7 @@ class Telemetry:
         import collections
         import socket
 
-        from ..utils.timer import global_timer
+        from . import spans as _span_totals
         self.registry = MetricsRegistry()
         self.out_path = out
         self.summary_base = summary_base if summary_base is not None else out
@@ -307,10 +307,10 @@ class Telemetry:
         # tools/obs_report.py's died-run recovery path
         self._fh = open(out, "w", buffering=1) if out else None
         self.started_at = time.time()
-        # global_timer and the recompile counters accumulate for the whole
-        # process; snapshotting both here lets report.summarize attribute
-        # only THIS run's scope time and cache misses
-        self.timer_baseline = global_timer.totals()
+        # the spans' totals and the recompile counters accumulate for the
+        # whole process; snapshotting both here lets report.summarize
+        # attribute only THIS run's span time and cache misses
+        self.timer_baseline = _span_totals.seconds()
         from . import launches as _launches
         from . import recompile as _recompile
         self.recompile_baseline = _recompile.counts()

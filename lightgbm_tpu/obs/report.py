@@ -14,7 +14,7 @@ Layout::
       "iterations": N, "rows": N, "wall_s": ...,
       "rows_per_s": {histogram summary},        # per-chunk training rate
       "ns_per_row": {histogram summary},
-      "host_phases": {"scope": seconds, ...},   # global_timer snapshot
+      "host_phases": {"span": seconds, ...},    # obs.spans totals
       "counters": {...}, "gauges": {...}, "histograms": {...},
       "recompiles": {"fn|bucket": n}, "recompile_total": n,
       "resilience": {"preemptions": n, "io_retries": n,
@@ -209,7 +209,7 @@ def quant_block(counters: Dict[str, Any], gauges: Dict[str, Any],
 def summarize(tele: Telemetry, extra: Optional[Dict[str, Any]] = None
               ) -> Dict[str, Any]:
     """Fold a run's registry + recompile counters into the summary dict."""
-    from ..utils.timer import global_timer
+    from . import spans
     snap = tele.registry.snapshot()
     hists = snap["histograms"]
     gauges = snap["gauges"]
@@ -221,13 +221,15 @@ def summarize(tele: Telemetry, extra: Optional[Dict[str, Any]] = None
     value = None
     if rows and iters and wall:
         value = rows * iters / wall
-    # host phases scoped to THIS run: global_timer totals minus the
-    # snapshot taken when the Telemetry was constructed (a second run in
-    # the same process must not inherit the first run's scope time)
+    # host phases scoped to THIS run: the spans' totals minus the snapshot
+    # taken when the Telemetry was constructed (a second run in the same
+    # process must not inherit the first run's span time; after a
+    # spans.reset() inside the run the totals are the run's own)
     base = getattr(tele, "timer_baseline", {})
     phases = {}
-    for name, tot in global_timer.totals().items():
-        delta = tot - base.get(name, 0.0)
+    for name, tot in spans.seconds().items():
+        delta = tot - base.get(name, 0.0) if tot >= base.get(name, 0.0) \
+            else tot
         if delta > 1e-9:
             phases[name] = delta
     # recompiles likewise scoped to THIS run (an obs.recompile.reset()

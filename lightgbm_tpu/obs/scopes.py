@@ -17,9 +17,80 @@ from __future__ import annotations
 
 import re
 from collections import Counter
-from typing import Dict, Iterable, Optional
+from typing import Dict, Iterable, NamedTuple, Optional, Tuple
 
 UNSCOPED = "unscoped"
+
+
+class Region(NamedTuple):
+    """A ``jax.named_scope`` opened INSIDE a Pallas kernel body, around a
+    whole stage and never inside a loop that runs a chunk, a tile or a
+    subtile.  Pallas lowers it to ``tpu.trace_start(message=name)`` /
+    ``tpu.trace_stop``: a few scalar instructions a launch, and an event a
+    launch on the device plane of a profile taken with
+    ``obs.profiling.capture(detail="kernel")`` in a process started with
+    :data:`KERNEL_TRACE_FLAGS` (``tools/kernel_regions.py`` reads them)."""
+    name: str
+    kernel: str      # prefix of the kernel's ``name`` (the trace's event)
+    holds: str       # the stage, and the item of ROADMAP A1 it times
+
+
+K_PROLOGUE = "k.prologue"
+K_PLACE = "k.place"
+K_DRAIN = "k.drain"
+K_HIST = "k.hist"
+K_COPY_BACK = "k.copy_back"
+K_STAGE = "k.stage"
+K_GROUPS = "k.groups"
+
+KERNEL_REGIONS: Tuple[Region, ...] = (
+    Region(K_PROLOGUE, "partition_hist_pallas_c",
+           "scalars, the constants, the first reads of the input ring"),
+    Region(K_PLACE, "partition_hist_pallas_c",
+           "the pipelined chunk loop: phases A and B, the trailing phase C "
+           "and its flushes (A1: phases B + C and the flushes, phase A)"),
+    Region(K_DRAIN, "partition_hist_pallas_c",
+           "phase C of the last totk chunks, the pending flushes, the two "
+           "partial tiles"),
+    Region(K_HIST, "partition_hist_pallas_c",
+           "hist_pass over the smaller child's block, either source "
+           "(A1: the smaller child's histogram)"),
+    Region(K_COPY_BACK, "partition_hist_pallas_c",
+           "the right block from the scratch back into the store "
+           "(A1: the copy-back)"),
+    Region(K_PLACE, "partition_hist_pallas_small",
+           "one read, phase A, the permutation dots, the write-back"),
+    Region(K_HIST, "partition_hist_pallas_small",
+           "the histogram of the resident tile"),
+    Region(K_STAGE, "histogram_pallas_rows",
+           "a grid step's row tile made ready: the bf16 copy and the value "
+           "operand (a region a row tile: the grid is the only row loop)"),
+    Region(K_GROUPS, "histogram_pallas_rows",
+           "the tile's feature groups accumulated (the factored step's "
+           "rolled loop over blocks; the classic kernel's lane tile)"),
+)
+
+# what ``tree.find_split`` and the chunk epilogue are made of: scopes nested
+# in today's, read by ``benchmarks/readers/trace_scope_among.py``
+(FIND_HIST_CACHE, FIND_SCAN, FIND_GAIN, FIND_PICK,
+ FIND_BESTS) = FIND_PARTS = ("find.hist_cache", "find.scan", "find.gain",
+                             "find.pick", "find.bests")
+CHUNK_SCORE_OUT, = CHUNK_PARTS = ("chunk.score_out",)
+
+# What no scope can name: jax lowers ``cumsum`` through a function of its own
+# (``inline=False``), so the running sums' instructions carry the bare name
+# ``reduce_window_sum`` and no path of the program, and the chip's compiler
+# rewrites a 256-bin scan into pieces with no name at all.
+BARE_OPS = {"reduce_window_sum": FIND_SCAN}
+
+# The process whose kernels' regions are to show in a profile starts with
+# this in LIBTPU_INIT_ARGS (the TPU's library reads it once, at load; on the
+# chip ``--xla_xprof_register_llo_debug_info`` adds and changes nothing:
+# PERF.md §6, PR 39).  It also makes the library write the line ``Tensor
+# Core``, an event for every instrumented bundle of every custom call:
+# 12.3M a chunk of 8 trees on 10.5M rows, more than the profiler's buffer
+# takes, so capture a fraction of a second, not a chunk.
+KERNEL_TRACE_FLAGS = ("--xla_enable_custom_call_region_trace=true",)
 
 # "  %name = ..." or "  ROOT %name = ..."; the name ends at the first space
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?(%[^\s=]+)\s*=\s")
@@ -101,3 +172,51 @@ def op_scopes(hlo_text: str, scopes: Iterable[str]) -> Dict[str, str]:
         if name in orphans:
             out[name] = most(out[user] for user in users[name])
     return {name: scope or UNSCOPED for name, scope in out.items()}
+
+
+_TO_APPLY = re.compile(r"\bto_apply=(%[^\s,)}]+)")
+
+
+def bare_op_scopes(hlo_text: str, bare: Dict[str, str]) -> Dict[str, str]:
+    """``{"%instruction": scope}`` for the instructions of a compiled program
+    that a cached lowering made (:data:`BARE_OPS`): an instruction whose own
+    ``op_name`` is one of the bare names ``bare``; one with no ``op_name``
+    that applies (``to_apply=``) a computation naming nothing else; and, in
+    the text's order, one with no ``op_name`` at all whose operands are all
+    of these (the pieces the compiler cuts a long scan into).  Everything
+    else is left to :func:`op_scopes`."""
+    inside: Dict[str, set] = {}
+    lines = []
+    computation = None
+    for line in hlo_text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header is not None:
+            computation = header.group(1)
+            continue
+        found = _INSTRUCTION.match(line)
+        if found is None:
+            continue
+        op_name = _OP_NAME.search(line)
+        if op_name is not None:
+            inside.setdefault(computation, set()).add(op_name.group(1))
+        lines.append((found.group(1), line[found.end():],
+                      op_name.group(1) if op_name else None))
+    out: Dict[str, str] = {}
+    for name, rest, path in lines:
+        if path is not None:
+            if path in bare:
+                out[name] = bare[path]
+            continue
+        applied = _TO_APPLY.search(rest)
+        names = inside.get(applied.group(1), ()) if applied else ()
+        if len(names) == 1 and next(iter(names)) in bare:
+            out[name] = bare[next(iter(names))]
+            continue
+        # operands only: what follows the opcode's bracket, less attributes
+        operands = [o for o in _NAME.findall(rest.split("), ")[0])
+                    if o != name]
+        scopes_ = {out.get(o) for o in operands if not o.startswith(
+            ("%constant", "%param"))}
+        if len(scopes_) == 1 and None not in scopes_:
+            out[name] = scopes_.pop()
+    return out

@@ -185,6 +185,24 @@ def totals() -> Dict[str, Dict[str, float]]:
                 for n, (c, t, m) in sorted(_totals.items())}
 
 
+def seconds() -> Dict[str, float]:
+    """``{name: total_s}``: where the host's time went, span by span (the
+    run summary's ``host_phases``, the watchdog's diagnostics)."""
+    with _lock:
+        return {n: t for n, (_, t, _) in _totals.items()}
+
+
+def summary() -> str:
+    """The end-of-run dump (``verbosity>=2``): every span's total, the
+    longest first, with its count."""
+    lines = ["LightGBM-TPU host timing summary:"]
+    for name, tot in sorted(totals().items(),
+                            key=lambda kv: -kv[1]["total_s"]):
+        lines.append("  %s: %.6f s in %d" % (name, tot["total_s"],
+                                             tot["count"]))
+    return "\n".join(lines)
+
+
 def reset() -> None:
     """Forget every record and total (spans still open are kept when they
     close)."""

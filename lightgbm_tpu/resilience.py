@@ -334,8 +334,7 @@ class Watchdog:
 
     def _diagnostics(self, name: str, elapsed: float,
                      info: Dict[str, Any]) -> Dict[str, Any]:
-        from .obs import recompile
-        from .utils.timer import global_timer
+        from .obs import recompile, spans
         diag: Dict[str, Any] = {
             "v": 1, "kind": "watchdog_stall", "ts": time.time(),
             "section": name, "stall_s": round(elapsed, 3),
@@ -343,7 +342,7 @@ class Watchdog:
             "info": {k: v for k, v in info.items()},
             "recompiles": recompile.as_flat_dict(),
             "host_phases": {k: round(v, 6)
-                            for k, v in global_timer.totals().items()},
+                            for k, v in spans.seconds().items()},
         }
         try:  # the live device set: which peers the runtime still sees
             import jax

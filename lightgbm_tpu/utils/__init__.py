@@ -1,4 +1,3 @@
 from .log import Log
-from .timer import Timer, FunctionTimer, global_timer
 
-__all__ = ["Log", "Timer", "FunctionTimer", "global_timer"]
+__all__ = ["Log"]

@@ -33,8 +33,16 @@ from lightgbm_tpu.objective import create_objective  # noqa: E402
 
 ROWS = 24576           # six chunks of the fused path's 4096 rows
 B = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+CELL = "expo_onehot_train"
+# the cell's metrics: every entry that lists it, its own (suffix ``.efb``)
+# and those it shares with other cells
 EFB_METRICS = sorted(m["name"] for m in B["per_layer"]
-                     if m.get("workloads") == ["expo_onehot_train"])
+                     if CELL in m.get("workloads", ()))
+
+
+def named(values, name, suffix=".efb"):
+    """A metric's value by its name, with the cell's suffix or without."""
+    return values[name + suffix] if name + suffix in values else values[name]
 
 
 @pytest.fixture(scope="module")
@@ -218,7 +226,9 @@ def test_every_efb_metric_has_something_to_read(job):
         ops = [op for op, s in scope_of.items() if s == scope]
         if scope == "tree.unpack":
             assert ops, "no instruction of the chunk is under tree.unpack"
-        own.update({op: 1000.0 for op in ops[:3]})
+        # every op of the search's two parents, three of any other scope
+        own.update({op: 1000.0 for op in (
+            ops if scope in ("tree.find_split", "tree.unpack") else ops[:3])})
     own.update({"%partition_hist_pallas_c4096.1": 5e6,
                 "%partition_hist_pallas_c1024.2": 1e6,
                 "%histogram_pallas_rows_factored.3": 2e6,
@@ -228,15 +238,28 @@ def test_every_efb_metric_has_something_to_read(job):
     job.traced_trees = job.gbdt.models[:2]
     ctx = {"job": job, "trace": trace, "cfg": job.cfg, "wl": job.wl,
            "device_kind": "TPU v5 lite"}
-    got = run.layer_metrics(B, "expo_onehot_train", "train_chunks_csr", ctx)
+    got = run.layer_metrics(B, CELL, "train_chunks_csr", ctx)
     assert sorted(got) == EFB_METRICS
     value = {name: m["value"] for name, m in got.items()}
     parts = [n for n in EFB_METRICS if n.startswith("glue_")]
     assert len(parts) == 11
     assert sum(value[n] for n in parts) == pytest.approx(
-        value["xla_glue_ms_per_tree.efb"], rel=1e-9)
-    assert value["glue_unpack_ms_per_tree.efb"] > 0
-    assert value["efb_groups.efb"] == job.dataset.binned.shape[1]
+        named(value, "xla_glue_ms_per_tree"), rel=1e-9)
+    assert named(value, "glue_unpack_ms_per_tree") > 0
+    assert named(value, "efb_groups") == job.dataset.binned.shape[1]
+    # one level down (PR 39): the search's parts and what no part claims add
+    # up to its two parents, and the scans run under tree.unpack here
+    find = [n for n in EFB_METRICS if n.startswith("find_")]
+    assert len(find) == 6
+    assert sum(value[n] for n in find) == pytest.approx(
+        named(value, "glue_find_split_ms_per_tree")
+        + named(value, "glue_unpack_ms_per_tree"), rel=1e-9)
+    assert all(value[n] > 0 for n in find if n != "find_rest_ms_per_tree")
+    assert value["find_scan_ms_per_tree"] == pytest.approx(
+        named(value, "glue_unpack_ms_per_tree"), rel=1e-9)
+    assert value["chunk_score_out_ms_per_tree"] \
+        + value["chunk_rest_ms_per_tree"] == pytest.approx(
+            named(value, "glue_unscoped_ms_per_tree"), rel=1e-9)
     # the roofline counts the bytes of the device columns, not of the
     # configuration's features: the same reading off the plain reader's count
     import roofline
@@ -244,7 +267,7 @@ def test_every_efb_metric_has_something_to_read(job):
         job.traced_trees, features=job.dataset.binned.shape[1], bins=256)
     least, _ = roofline.least_seconds(nbytes, ops,
                                       roofline.peaks("TPU v5 lite"))
-    assert value["split_kernel_roofline.efb"] == pytest.approx(
+    assert named(value, "split_kernel_roofline") == pytest.approx(
         100.0 * least / 6e-3)
 
 

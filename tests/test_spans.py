@@ -168,8 +168,11 @@ def test_spans_one_train_one_iter_opens():
     g.train_one_iter()
     counts = {n: t["count"] for n, t in spans.totals().items()
               if not n.startswith("jax.")}
+    # gbdt.train_tree (PR 39: where FunctionTimer stood alone) is the call
+    # into the learner, whichever learner; partition_build_tree its dispatch
     assert counts == {"gbdt.gradients": 1, "gbdt.bagging": 1,
-                      "partition_build_tree": 1, "gbdt.update_score": 1}
+                      "gbdt.train_tree": 1, "partition_build_tree": 1,
+                      "gbdt.update_score": 1}
 
 
 def test_cache_load_is_taken_out_of_backend_compile():

@@ -23,8 +23,26 @@ from lightgbm_tpu.boosting import gbdt as G  # noqa: E402
 
 ROWS = 24576           # six chunks of the fused path's 4096 rows
 B = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+CELL = "higgs_prod_train"
+# the cell's metrics: every entry that lists it, its own (suffix ``.sub``)
+# and those it shares with other cells
 SUB_METRICS = sorted(m["name"] for m in B["per_layer"]
-                     if m.get("workloads") == ["higgs_prod_train"])
+                     if CELL in m.get("workloads", ()))
+# the 16 PR 36 gave the cell, by name: a later fold may drop the suffix
+PR36_METRICS = [
+    "first_unit_s", "unit_wall_ms_per_tree", "recompiles_in_window",
+    "device_idle_share", "split_kernel_ms_per_tree",
+    "split_ns_per_window_row", "split_kernel_roofline",
+    "hist_kernel_ms_per_tree", "row_pass_ms_per_tree", "xla_glue_ms_per_tree",
+    "glue_find_split_ms_per_tree", "features_used_per_tree", "bag_rows_share",
+    "per_iteration_trees", "glue_sample_ms_per_tree",
+    "dead_window_rows_share"]
+
+
+def name_in(values, name, suffix=".sub"):
+    """A metric's name as ``values`` has it: with the cell's suffix, or
+    without; None when it has neither."""
+    return next((n for n in (name + suffix, name) if n in values), None)
 
 
 # ---- the plain draws are the program's, bit for bit ------------------------
@@ -162,7 +180,8 @@ def test_every_sub_metric_has_something_to_read(job):
     assert drawn, "no instruction of the chunk is under gbdt.sample"
     own = {op: 1000.0 for op in drawn[:3]}
     own.update({op: 1000.0 for op, s in scope_of.items()
-                if s == "tree.find_split" and len(own) < 6})
+                if s == "tree.find_split"})
+    searched = len(own) - 3
     own.update({"%partition_hist_pallas_c4096.1": 5e6,
                 "%histogram_pallas_rows_factored.3": 2e6,
                 "%row_state_pass.4": 1e6, "%while.5": 3000.0})
@@ -171,20 +190,31 @@ def test_every_sub_metric_has_something_to_read(job):
     job.traced_trees = job.gbdt.models[8:16]
     ctx = {"job": job, "trace": trace, "cfg": job.cfg, "wl": job.wl,
            "device_kind": "TPU v5 lite"}
-    got = run.layer_metrics(B, "higgs_prod_train", "train_chunks_sub", ctx)
-    assert sorted(got) == SUB_METRICS and len(SUB_METRICS) == 16
-    value = {name: m["value"] for name, m in got.items()}
-    assert value["features_used_per_tree.sub"] == 22
-    assert value["per_iteration_trees.sub"] == 0
-    assert value["glue_sample_ms_per_tree.sub"] == pytest.approx(3e-3 / 8)
-    assert 78 < value["bag_rows_share.sub"] < 82
+    got = run.layer_metrics(B, CELL, "train_chunks_sub", ctx)
+    assert sorted(got) == SUB_METRICS
+    mine = [name_in(got, name) for name in PR36_METRICS]
+    assert None not in mine and len(set(mine)) == 16
+    value = {name: got[name_in(got, name)]["value"] for name in PR36_METRICS}
+    assert value["features_used_per_tree"] == 22
+    assert value["per_iteration_trees"] == 0
+    assert value["glue_sample_ms_per_tree"] == pytest.approx(3e-3 / 8)
+    assert value["glue_find_split_ms_per_tree"] == pytest.approx(
+        searched * 1e-3 / 8)
+    assert 78 < value["bag_rows_share"] < 82
     # dead rows: 1 - the bag share, weighted by each tree's window rows
-    assert 18 < value["dead_window_rows_share.sub"] < 22
+    assert 18 < value["dead_window_rows_share"] < 22
     shares = [t.internal_count[0] / ROWS for t in job.traced_trees]
-    assert 100 * (1 - max(shares)) <= value["dead_window_rows_share.sub"] \
+    assert 100 * (1 - max(shares)) <= value["dead_window_rows_share"] \
         <= 100 * (1 - min(shares))
-    # no other cell reads them, and the flagship's kind reads none of them
-    assert not run.layer_metrics(B, "higgs_prod_train", "train_chunks", ctx)
+    # one level down (PR 39): the search's parts add up to their parent on
+    # the subsampled program too
+    find = [n for n in SUB_METRICS if n.startswith("find_")]
+    assert len(find) == 6
+    assert sum(got[n]["value"] for n in find) == pytest.approx(
+        value["glue_find_split_ms_per_tree"], rel=1e-9)
+    # the flagship's kind reads none of those 16
+    flagship = run.layer_metrics(B, CELL, "train_chunks", ctx)
+    assert not set(mine) & set(flagship)
 
 
 def test_the_window_rows_really_hold_the_out_of_bag_rows(job):

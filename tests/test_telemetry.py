@@ -1,6 +1,7 @@
 """Telemetry subsystem (lightgbm_tpu/obs): registry semantics, JSONL
 schema round-trip, per-iteration cadence, recompile accounting pinned at
-zero in steady state, zero-overhead-when-off, and the stacked Timer fix.
+zero in steady state, zero-overhead-when-off, and the one host timer: the
+spans' totals (nested, re-entrant, per thread, across a reset).
 """
 import json
 import threading
@@ -13,7 +14,7 @@ from lightgbm_tpu import obs
 from lightgbm_tpu.obs.registry import (EVENT_SCHEMA_VERSION, Histogram,
                                        MetricsRegistry, Telemetry,
                                        read_events, validate_event)
-from lightgbm_tpu.utils.timer import FunctionTimer, Timer
+from lightgbm_tpu.obs import spans
 
 
 @pytest.fixture(autouse=True)
@@ -180,9 +181,8 @@ def test_summary_artifact_contents(tmp_path):
     # per-iteration rows/s (chunk granularity on the fused driver)
     assert summary["rows_per_s"]["count"] >= 1
     # per-phase host dispatch times
-    assert any("TrainChunk" in k or "Train" in k
-               for k in summary["host_phases"])
-    assert "Checkpoint::Write" in summary["host_phases"]
+    assert "fused_train_chunk" in summary["host_phases"]
+    assert "checkpoint.write" in summary["host_phases"]
     # checkpoint latencies
     assert summary["histograms"]["checkpoint_write_s"]["count"] >= 1
     # per-shape-bucket predict latency
@@ -295,18 +295,21 @@ def test_recompiles_scoped_per_run():
 
 def test_host_phases_scoped_per_run():
     from lightgbm_tpu.obs.report import summarize
-    from lightgbm_tpu.utils.timer import global_timer
-    global_timer.start("phase_scoped")
-    time.sleep(0.02)
-    global_timer.stop("phase_scoped")
+    with spans.span("phase_scoped"):
+        time.sleep(0.06)
     tele = obs.configure(freq=1)
     s = summarize(tele)
     assert "phase_scoped" not in s["host_phases"]
-    global_timer.start("phase_scoped")
-    time.sleep(0.02)
-    global_timer.stop("phase_scoped")
+    with spans.span("phase_scoped"):
+        time.sleep(0.02)
     s2 = summarize(tele)
     assert 0.01 < s2["host_phases"]["phase_scoped"] < 1.0
+    # a reset inside the run: totals under the baseline are the run's own
+    spans.reset()
+    with spans.span("phase_scoped"):
+        time.sleep(0.02)
+    s3 = summarize(tele)
+    assert 0.01 < s3["host_phases"]["phase_scoped"] < 0.06
 
 
 def test_resumed_run_iterations_not_inflated(tmp_path):
@@ -488,81 +491,89 @@ def test_c_api_telemetry_impls(tmp_path):
     assert _impl_telemetry_summary() == ""
 
 
-# ---- Timer stacking / re-entrancy (satellite fix) ----
+# ---- the one host timer: the spans' totals (PR 39 folded the second) ----
 
-def test_timer_nested_same_name_scopes_stack():
-    t = Timer()
-    t.start("a")
-    time.sleep(0.02)
-    t.start("a")          # nested scope on the SAME key
-    time.sleep(0.02)
-    t.stop("a")           # closes the inner scope (~0.02)
-    inner = t.total("a")
-    assert inner >= 0.015
-    t.stop("a")           # closes the OUTER scope (~0.04) — was dropped
-    assert t.total("a") >= inner + 0.03
+def test_spans_nested_same_name_both_count():
+    spans.reset()
+    with spans.span("a"):
+        time.sleep(0.02)
+        with spans.span("a"):      # nested span of the SAME name
+            time.sleep(0.02)
+        inner = spans.seconds()["a"]
+        assert inner >= 0.015
+    # the OUTER one (~0.04) counts too
+    assert spans.seconds()["a"] >= inner + 0.03
+    assert spans.totals()["a"]["count"] == 2
+    outer, nested = sorted(spans.records("a"), key=lambda r: r["start"])
+    assert nested["parent"] == outer["id"]
 
 
-def test_timer_function_timer_reentrant():
-    t = Timer()
+def test_spans_reentrant_function():
+    spans.reset()
 
-    @FunctionTimer("f", timer=t)
     def rec(n):
-        if n:
-            time.sleep(0.01)
-            rec(n - 1)
+        with spans.span("f"):
+            if n:
+                time.sleep(0.01)
+                rec(n - 1)
 
     rec(3)
-    # 4 nested scopes of ~30/20/10/0 ms: total ~60ms, NOT just the leaf
-    assert t.total("f") >= 0.05
+    # 4 nested spans of ~30/20/10/0 ms: total ~60ms, NOT just the leaf
+    assert spans.seconds()["f"] >= 0.05
+    assert spans.totals()["f"]["count"] == 4
 
 
-def test_timer_threads_do_not_cross():
-    t = Timer()
+def test_spans_threads_do_not_cross():
+    spans.reset()
 
     def work(ms):
-        t.start("w")
-        time.sleep(ms / 1000.0)
-        t.stop("w")
+        with spans.span("w"):
+            time.sleep(ms / 1000.0)
 
-    threads = [threading.Thread(target=work, args=(20,)) for _ in range(4)]
-    for th in threads:
-        th.start()
-    for th in threads:
-        th.join()
-    # each thread closed its OWN scope: ~4 * 20ms accumulated
-    assert t.total("w") >= 0.06
-    assert t.totals() == {"w": t.total("w")}
-
-
-def test_timer_stop_without_start_is_noop():
-    t = Timer()
-    t.stop("nope")
-    assert t.total("nope") == 0.0
-    assert "nope" not in t.totals()
+    with spans.span("main"):
+        threads = [threading.Thread(target=work, args=(20,))
+                   for _ in range(4)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+    # each thread closed its OWN span: ~4 * 20ms accumulated, and none of
+    # them is a child of the span another thread holds open
+    assert spans.seconds()["w"] >= 0.06
+    assert [r["parent"] for r in spans.records("w")] == [0] * 4
+    assert sorted(spans.seconds()) == ["main", "w"]
 
 
-def test_timer_reset_discards_other_threads_inflight_scopes():
-    """A scope opened before reset() (possibly on another thread, which
-    reset's thread-local clear cannot reach) must not pollute the fresh
-    totals when it closes after the reset."""
-    t = Timer()
+def test_spans_of_nothing():
+    spans.reset()
+    assert spans.seconds() == {} and "nope" not in spans.totals()
+    assert spans.summary().splitlines() == [
+        "LightGBM-TPU host timing summary:"]
+    with spans.span("one"):
+        pass
+    assert spans.summary().splitlines()[1].startswith("  one: ")
+
+
+def test_spans_reset_keeps_a_span_another_thread_holds_open():
+    """A span opened before reset() on another thread is not lost: it is
+    recorded when it closes (the totals never hold half of one)."""
+    spans.reset()
     opened = threading.Event()
     go = threading.Event()
 
     def work():
-        t.start("x")
-        opened.set()
-        go.wait(timeout=5)
-        t.stop("x")   # closes AFTER the main thread's reset
+        with spans.span("x"):
+            opened.set()
+            go.wait(timeout=5)     # closes AFTER the main thread's reset
 
     th = threading.Thread(target=work)
     th.start()
     opened.wait(timeout=5)
-    t.reset()
+    spans.reset()
+    assert "x" not in spans.seconds()
     go.set()
     th.join()
-    assert t.total("x") == 0.0, "pre-reset scope leaked into fresh totals"
+    assert spans.totals()["x"]["count"] == 1
 
 
 # ---- round 22: quantized-training telemetry + died-run recovery ----
