@@ -226,6 +226,7 @@ def phase_kernels(ctx):
                        dev))
 
     _check_right_block_phases()
+    _check_placement_windows()
     _check_row_state_pass(n_pad)
 
     # the two variants that are off by default (hist_precision=quantized,
@@ -293,6 +294,31 @@ def _check_right_block_phases():
         say("  chunk=%d: right blocks of %s rows copied back at phases %s, "
             "both histogram sides: rows, nl and histogram equal"
             % (chunk, RIGHT_ROWS, PHASES))
+
+
+def _check_placement_windows():
+    """The placement stage's block flushes and register-carried open tiles,
+    compiled: windows whose chunks finish chosen numbers of one stream's
+    tiles, long enough that both flush rings wrap, and lopsided windows (3%
+    right, 3% left) with an unaligned head, at both chunk sizes
+    (tests/test_partition_blend.py's cases since PR 40), every byte of the
+    store against ``partition_hist_xla``."""
+    from tests.test_partition_blend import (CHUNKS, WINDOW_CASES,
+                                            check_window, lopsided,
+                                            tile_patterns)
+    for chunk, name, wb in WINDOW_CASES:
+        for hist_left in (1, 0):
+            check_window(chunk, wb, tile_patterns(chunk)[name],
+                         interpret=False, hist_left=hist_left, seed=wb)
+    for chunk in CHUNKS:
+        for share_left in (0.03, 0.97):
+            check_window(chunk, 2 * chunk + 19, lopsided(chunk, share_left),
+                         interpret=False,
+                         hist_left=1 if share_left < 0.5 else 0, seed=11)
+    say("  %d windows a chunk at a time (%s) and lopsided ones at chunks "
+        "%s, both histogram sides: rows, nl and histogram equal"
+        % (len(WINDOW_CASES), ", ".join(sorted(tile_patterns(CHUNKS[0]))),
+           CHUNKS))
 
 
 def _check_row_state_pass(n_pad):

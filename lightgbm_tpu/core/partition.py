@@ -19,9 +19,10 @@ learner's copy/kernel overlap (src/treelearner/gpu_tree_learner.cpp:952-1055)
   and are copied back after the left block settles.  Every HBM touch is a
   contiguous DMA at a 32-row-aligned offset: the window, the smaller child's
   block and the scratch for the copy-back are READ a ``chunk`` at a time
-  (512 KB at chunk=4096, 128 KB at 1024), the flush rings and the copy-back
-  WRITE 16 KB tiles of TS rows; zero per-row descriptors, no switch, cost
-  proportional to the window.
+  (512 KB at chunk=4096, 128 KB at 1024), the flush rings WRITE blocks of
+  TS-row tiles (128 KB at chunk=4096, 32 KB at 1024) and the copy-back 16 KB
+  tiles; zero per-row descriptors, no switch, cost proportional to the
+  window.
 - The smaller child's histogram (serial_tree_learner.cpp:347-356 subtraction
   trick feeds on it) accumulates in the same pass from the same VMEM tiles —
   the routing/scatter/histogram fusion PERF.md round 3 listed as the next
@@ -51,6 +52,28 @@ learner's copy/kernel overlap (src/treelearner/gpu_tree_learner.cpp:952-1055)
   All variants share the same phase-A/histogram building blocks, so
   interpret-mode numerics are bit-exact across buckets (pinned by
   tests/test_partition_buckets.py).
+- PR 40 (the placement stage's fixed costs: phases B and C and the flush
+  loops were bound by ISSUE, not by memory, 5,281 scheduled bundles a
+  4,096-row chunk): (a) phase A works out, for all ``2 * nsub`` subtiles at
+  once, every scalar phase C needs of a subtile (the first tile row's two
+  mask scalars, whether the subtile completes its tile, the word-row offset
+  of its ring slot) and ships them in further rows of the same VMEM -> SMEM
+  bank DMA (:func:`_subtile_scalars_lanes`): phase C reads four scalars a
+  stream and derives none; (b) phase B's placement one-hot emits its rows
+  byte-major, so the placed rows are made into 32-bit words with shifts and
+  ors of whole vregs (:func:`_words_of`, the copy-back's way) and
+  ``comp_buf`` HOLDS WORDS; (c) a stream's open tile rides in registers from
+  subtile to subtile and from chunk to chunk: no load from the ring, the
+  merged tile stored to its slot every time, the next open tile a select:
+  no branch, so a chunk's subtiles are ONE basic block (the parent's 54
+  bundles a subtile were one serial chain a stream, cut by two branches, the
+  right stream's load waiting on the left stream's stores to the same
+  buffer; now 18.5), and the two streams' rings are separate buffers; (d) a
+  stream's finished tiles leave in ALIGNED blocks of :func:`_flush_run` ring
+  slots, one descriptor and one semaphore a block, started when the block's
+  last tile is complete and awaited as they were started; the drain sends
+  what a window's end leaves short of a block a tile at a time
+  (:func:`_ring_depth` has the invariants).
 
 Mosaic constraints honored (probed on v5e): no u8 vector arithmetic (u8 used
 only for DMA/select; math in i32/bf16/f32), no dynamic sublane rotate on u8
@@ -105,12 +128,40 @@ assert CHUNK % SMALL_CHUNK == 0 and SMALL_CHUNK % T == 0
 assert 2 * CHUNK // T <= _LANE       # a chunk's subtile totals fit one lane row
 
 
+def _flush_run(chunk: int) -> int:
+    """Tiles a flush descriptor (PR 40): a stream's finished tiles leave in
+    ALIGNED blocks of this many ring slots, one DMA and one semaphore a
+    block, started when the block's last tile is complete; a quarter of a
+    chunk's subtiles, so a chunk starts about four descriptors where it
+    started ``chunk // TS``.  What a window's end leaves short of a block
+    goes a tile at a time in the drain."""
+    run = max(1, chunk // TS // 4)
+    assert run & (run - 1) == 0
+    return run
+
+
 def _ring_depth(chunk: int) -> int:
-    """Flush-ring depth per stream: >= chunk/TS + 2 so a whole chunk can
-    blend before its flushes start (single-flush circular staging depends on
-    nls <= TS per subtile — at most one stage wrap per append — and the
-    subtile loop covering the chunk exactly; retuning one constant without
-    the other silently corrupts the partition)."""
+    """Flush-ring depth per stream, in TS-row tiles: a multiple of
+    :func:`_flush_run` (a block never crosses the ring's wrap), and
+    ``chunk // TS + 3 * run``.  Before a chunk blends, phase C awaits the
+    blocks whose slots the chunk may store into: its subtiles open at most
+    ``chunk // TS`` tiles past the open one (single-flush circular staging
+    depends on nls <= TS per subtile: at most one tile completed per append)
+    so tiles up to ``k1`` must be free, ``k1`` the stream's complete tiles
+    after the chunk, and every tile <= ``k1 - depth`` gone.  Up to ``run -
+    1`` complete tiles wait in the ring for their block to fill; with ``3 *
+    run`` slots of slack every block a chunk has to await was started by
+    the chunk before it or earlier, and the two newest started blocks are
+    never among them (they stay in flight behind the chunk's blend).
+    Retuning one constant without the others silently corrupts the
+    partition."""
+    return chunk // TS + 3 * _flush_run(chunk)
+
+
+def _cb_depth(chunk: int) -> int:
+    """The copy-back's ring (``stage``), in TS-row tiles: its own since PR
+    40, a tile a descriptor and a semaphore, a chunk's tiles and four of
+    slack as the flush rings had before."""
     return chunk // TS + 4
 
 
@@ -299,6 +350,56 @@ def _subtile_totals_lanes(S_L, S_R, *, nsub):
     return jnp.concatenate([tot_row, incl_row.astype(jnp.int32)], axis=0)
 
 
+# rows of a chunk's bank entry (one [8, 128] i32 vreg a chunk): the subtile
+# totals, then what phase C needs of each subtile ready-made (PR 40)
+_BANK_ROWS = 8       # six in use
+_BK_TOT, _BK_INCL, _BK_FIRST, _BK_CUT, _BK_ADV, _BK_CUR = range(6)
+_TS_SHIFT = TS.bit_length() - 1
+assert 1 << _TS_SHIFT == TS
+
+
+def _subtile_scalars_lanes(totals, headL, cumLv, cumRv, slotLv, slotRv, *,
+                           nsub, nb_ring):
+    """Phase C's per-subtile scalars for a whole chunk, as lane vectors
+    (PR 40): from ``totals`` (:func:`_subtile_totals_lanes`, [2, 2*nsub]),
+    the streams' fills before the chunk (``headL`` a scalar, ``cumLv`` /
+    ``cumRv`` [1, 1]) and the ring slots of their open tiles (``slotLv`` /
+    ``slotRv`` [1, 1]: ``(stream position // TS) % nb_ring``, carried and
+    never divided), the [6, 2*nsub] i32 bank entry, lane s the left stream
+    of subtile s and lane nsub + s the right:
+
+    - ``_BK_TOT``, ``_BK_INCL``: the totals as they came;
+    - ``_BK_FIRST``, ``_BK_CUT``: :func:`_rows_mask`'s two scalars of the
+      subtile's first tile row ``start`` (``(headL + fill + base) & (TS -
+      1)``, ``base`` the side's rows in the chunk's earlier subtiles);
+    - ``_BK_ADV``: ``start + n >= TS``, the subtile completes its tile;
+    - ``_BK_CUR``: the WORD-ROW offset of the subtile's open tile in its
+      stream's ring (``slot * _WPT``).
+
+    Also returns the two streams' open slots after the chunk.  What the
+    parent's phase C derived from SMEM with about 46 scalar operations a
+    subtile is here a handful of vector operations a chunk
+    (tests/test_partition_blend.py holds the two equal)."""
+    tot = totals[0:1, :]
+    incl = totals[1:2, :]
+    left = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * nsub), 1) < nsub
+    pos0 = jnp.where(left, headL + cumLv, cumRv)         # before the chunk
+    pos = pos0 + incl - tot                              # before subtile s
+    start = pos & (TS - 1)
+    tile0 = pos0 >> _TS_SHIFT
+
+    def slot_of(p):
+        # p's tile is at most nsub tiles past tile0 and nsub < nb_ring
+        sl = jnp.where(left, slotLv, slotRv) + (p >> _TS_SHIFT) - tile0
+        return jnp.where(sl >= nb_ring, sl - nb_ring, sl)
+
+    bank = jnp.concatenate([
+        tot, incl, start - (start & 3), jnp.int32(-1) << (8 * (start & 3)),
+        (start + tot >= TS).astype(jnp.int32), slot_of(pos) * _WPT], axis=0)
+    after = slot_of(pos + tot)                           # [1, 2*nsub]
+    return (bank, after[:, nsub - 1:nsub], after[:, 2 * nsub - 1:2 * nsub])
+
+
 def _hist_tile(ti_c, hist_ref, scal_ref, start, cnt, *, num_features,
                num_bins, bpc, packed, exact, voff, f_shard,
                quantized=False):
@@ -369,48 +470,79 @@ _WPT = TS // 4       # word rows per staging tile
 
 
 class _WordRef:
-    """A u8 [.., R, W] VMEM scratch, loaded and stored as its [.., R // 4, W]
-    i32 words.  On the chip that is a bitcast of the ref (no data moves);
-    interpret mode refuses a store through a bitcast ref, so there the same
-    bytes go through the value-level ``pltpu.bitcast`` of the u8 tile."""
+    """A u8 [.., R, W] VMEM scratch, loaded and stored a tile at a time as
+    its [.., R // 4, W] i32 words.  On the chip that is a bitcast of the ref
+    (no data moves); interpret mode refuses a store through a bitcast ref,
+    so there the same bytes go through the value-level ``pltpu.bitcast`` of
+    the u8 tile."""
 
     def __init__(self, ref, interpret):
         self._interpret = interpret
         self._ref = ref if interpret else ref.bitcast(jnp.int32)
 
-    def load(self, lead, w0=0):
-        """The tile of ``_WPT`` word rows that starts at word row ``w0``."""
+    def _rows(self, w0):
+        """The ``_WPT`` word rows from word row ``w0`` (a multiple of
+        ``_WPT``), as a slice of the ref's second dimension."""
         if self._interpret:
-            return pltpu.bitcast(self._ref[lead, 4 * w0:4 * (w0 + _WPT), :],
-                                 jnp.int32)
-        return self._ref[lead, w0:w0 + _WPT, :]
+            return pl.ds(4 * w0, TS)
+        return pl.ds(w0, _WPT)
 
-    def store(self, lead, words):
+    def load(self, lead):
         if self._interpret:
-            self._ref[lead, :, :] = pltpu.bitcast(words, jnp.uint8)
+            return pltpu.bitcast(self._ref[lead, self._rows(0), :],
+                                 jnp.int32)
+        return self._ref[lead, self._rows(0), :]
+
+    def store(self, lead, words, w0=0):
+        if self._interpret:
+            self._ref[lead, self._rows(w0), :] = pltpu.bitcast(words,
+                                                               jnp.uint8)
         else:
-            self._ref[lead, :, :] = words
+            self._ref[lead, self._rows(w0), :] = words
+
+
+def _words_of(tr):
+    """[TS, W] i32 dot result whose row ``k * _WPT + i`` holds byte k of
+    word row i (the one-hot's output rows ordered ``q = (p % 4) * _WPT + p
+    // 4`` for tile row p) -> the [TS // 4, W] i32 words: three shifts and
+    ors of whole vregs, not a 32 -> 16 -> 8 bit pack; ``& 255`` undoes the
+    signed-byte wrap of the i8 x i8 dot."""
+    return ((tr[0:_WPT] & 255)
+            | ((tr[_WPT:2 * _WPT] & 255) << 8)
+            | ((tr[2 * _WPT:3 * _WPT] & 255) << 16)
+            | (tr[3 * _WPT:] << 24))
+
+
+def _byte_major(q):
+    """Tile row ``p`` that output row ``q`` of a [TS, .] placement one-hot
+    holds in :func:`_words_of`'s order: ``4 * (q % _WPT) + q // _WPT``."""
+    return 4 * (q & (_WPT - 1)) + (q >> (_WPT.bit_length() - 1))
 
 
 def _word_base(W):
-    """Loop-invariant base of :func:`_rows_from`: the first tile row of each
-    word, ``4 * word row``, over the lanes."""
+    """Loop-invariant base of :func:`_rows_mask`: the first tile row of
+    each word, ``4 * word row``, over the lanes."""
     return 4 * jax.lax.broadcasted_iota(jnp.int32, (_WPT, W), 0)
 
 
-def _rows_from(base, start):
+def _rows_mask(base, first, cut):
     """[TS // 4, W] i32 word mask of a staging tile: all ones in the bytes of
-    tile rows >= ``start`` (a scalar in [0, TS]), zero in the bytes below.
-    Only the word that holds row ``start`` is cut; what it keeps is one
-    scalar shift, and the vector work is two compares and two selects."""
-    cut = jnp.int32(-1) << (8 * (start & 3))
-    first = start - (start & 3)
+    tile rows >= ``start`` (in [0, TS]), zero in the bytes below, from
+    ``first = start - (start & 3)`` and ``cut = -1 << (8 * (start & 3))``:
+    only the word that holds row ``start`` is cut; the vector work is two
+    compares and two selects."""
     return jnp.where(base > first, -1, jnp.where(base == first, cut, 0))
+
+
+def _rows_from(base, start):
+    """:func:`_rows_mask` of a scalar ``start``."""
+    return _rows_mask(base, start - (start & 3),
+                      jnp.int32(-1) << (8 * (start & 3)))
 
 
 def _merge_rows(upper, lower, m):
     """Rows >= start from ``upper``, rows below from ``lower`` (words under
-    the mask ``m`` of :func:`_rows_from`)."""
+    the mask ``m`` of :func:`_rows_mask`)."""
     return lower ^ ((lower ^ upper) & m)
 
 
@@ -420,9 +552,9 @@ def _next_slot(slot, nb_ring):
 
 
 def _walk_ring(lo, hi, fn, nb_ring):
-    """``fn(m, slot)`` for the tiles ``m`` of [lo, hi) in turn, with ``slot
-    = m % nb_ring`` carried on from one divide a walk, not divided a tile:
-    the flush loops are scalar-only, so a tile's divide is latency nothing
+    """``fn(m, slot)`` for the items ``m`` of [lo, hi) in turn, with ``slot
+    = m % nb_ring`` carried on from one divide a walk, not divided an item:
+    the flush loops are scalar-only, so an item's divide is latency nothing
     hides (0.125 ns a window row on the chip, PERF.md §6 PR 31)."""
     def body(m, slot):
         fn(m, slot)
@@ -431,27 +563,34 @@ def _walk_ring(lo, hi, fn, nb_ring):
     jax.lax.fori_loop(lo, hi, body, jax.lax.rem(lo, nb_ring))
 
 
-def _append_placed(ring, streams, base):
-    """Merge one subtile's placed rows into the flush rings.  Each stream is
-    ``(cur, nxt, comp, start, n)``: ``comp`` (words) holds ``n`` rows at the
+def _append_placed(streams, base):
+    """Merge one subtile's placed rows into the two streams' open tiles.
+    Each stream is ``(ring, tile, cur, comp, first, cut, adv)``: ``tile``
+    the words of the stream's open tile, carried in registers from subtile
+    to subtile, ``cur`` the word-row offset of its slot in ``ring``
+    (:class:`_WordRef`), ``comp`` (words) the subtile's ``n`` rows at the
     circular positions [start, start + n) mod TS and ZEROS everywhere else
-    (the one-hot placement), so the open tile ``cur`` takes every row >=
-    ``start`` from it, with no upper bound: a tile's rows past its fill
-    point are never read before they are filled (the finals mask by their
-    pending count, the copy-back by ``nr``).  Rows below ``start`` stay:
-    earlier subtiles', or the prefilled head's.  A range that wraps opens
-    ``nxt`` with a plain store of ``comp``: the wrapped rows lie below the
-    new tile's fill point ``start + n - TS``, and everything above it is
-    past the fill point again.  The streams' merges come first, in one
-    basic block where their chains overlap; the wraps are branches (``nxt``
-    may still be in flight when nothing wraps)."""
-    for cur, _, comp, start, _ in streams:
-        ring.store(cur, _merge_rows(comp, ring.load(cur),
-                                    _rows_from(base, start)))
-    for _, nxt, comp, start, n in streams:
-        @pl.when(start + n > TS)
-        def _wrap(nxt=nxt, comp=comp):
-            ring.store(nxt, comp)
+    (the one-hot placement), ``first`` / ``cut`` :func:`_rows_mask`'s
+    scalars of ``start``, ``adv`` nonzero when ``start + n >= TS``.  The
+    open tile takes every row >= ``start`` from ``comp``, with no upper
+    bound: a tile's rows past its fill point are never read before they are
+    filled (the finals mask by their pending count, the copy-back by
+    ``nr``).  Rows below ``start`` stay: earlier subtiles', or the prefilled
+    head's.  The merged tile goes to its slot every time: when the subtile
+    completes it that is the copy the flush reads, and before that a store
+    nobody reads, which is cheaper than a branch around it.  A subtile that
+    completes its tile leaves ``comp`` as the next open tile: the wrapped
+    rows lie below the new tile's fill point ``start + n - TS``, and
+    everything above it is past the fill point again.  No load from the
+    ring, no branch: a chunk's subtiles are one basic block, the two
+    streams' chains in it side by side.  Returns the streams' open tiles
+    after the subtile."""
+    out = []
+    for ring, tile, cur, comp, first, cut, adv in streams:
+        merged = _merge_rows(comp, tile, _rows_mask(base, first, cut))
+        ring.store(0, merged, w0=cur)
+        out.append(jnp.where(adv != 0, comp, merged))
+    return tuple(out)
 
 
 def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
@@ -463,22 +602,29 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
     # on the full row store); num_features is then the WINDOW's width
     del n_pad  # shapes come from the refs; kept for cache-key clarity
     nb_ring = _ring_depth(chunk)
+    run = _flush_run(chunk)  # tiles a flush descriptor
+    run_shift = run.bit_length() - 1
+    nbk = nb_ring // run     # flush blocks (and semaphores) a stream's ring
+    nb_cb = _cb_depth(chunk)
+    assert nb_ring % run == 0
     totk = _totk(chunk)
     ncb = totk + 1           # comp_buf banks: totk chunks awaiting phase C
                              # plus the chunk being placed
 
     def kernel(scal_ref, rows_in_ref, rows_ref, scratch_ref, hist_ref,
-               stats_ref, inbuf, stage, ltri, rot, tmp, comp_buf,
-               totals_vm, totals_sm,
+               stats_ref, inbuf, ring_l, ring_r, stage, ltri, rot, tmp,
+               comp_buf, totals_vm, totals_sm,
                sem_in, sem_pre, sem_fl, sem_fr, sem_cb, sem_tot):
         # rows_in_ref is the pre-alias view of rows_ref (same buffer); all
         # reads and writes go through rows_ref so ordering is explicit.
-        # stage is a [2*nb_ring, TS, W] ring: slots [0, nb_ring) buffer the
-        # left stream, [nb_ring, 2*nb_ring) the right stream.  Flush DMAs
-        # are ASYNC — a slot's previous flush is awaited only when the ring
-        # wraps back to it (nb_ring-1 flushes of slack), so the VPU/MXU
-        # never stalls on HBM writes (sync flushes were ~60% of the kernel
-        # in round-4 profiles).
+        # ring_l / ring_r are the two streams' flush rings, [1, nb_ring*TS,
+        # W] each: a stream's finished TS-row tiles wait there for their
+        # block's flush (separate buffers, so the compiler knows a store
+        # into one never aliases the other); stage is the copy-back's ring
+        # of nb_cb tiles.  Flush DMAs are ASYNC — a block of slots'
+        # previous flush is awaited only when the ring wraps back to it, so
+        # the VPU/MXU never stalls on HBM writes (sync flushes were ~60% of
+        # the kernel in round-4 profiles).
         del rows_in_ref
         with jax.named_scope(_scopes.K_PROLOGUE):
             scal = (_ScalRow(scal_ref, pl.program_id(0)) if multiwin
@@ -489,7 +635,7 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             hist_left = scal[9]
             # every merge of placed rows into a staging tile works on the words
             stage_w = _WordRef(stage, interpret)
-            comp_w = _WordRef(comp_buf, interpret)
+            rings_w = (_WordRef(ring_l, interpret), _WordRef(ring_r, interpret))
             tmp_w = _WordRef(tmp, interpret)
             wbase = _word_base(W)
 
@@ -510,11 +656,16 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             def left_dst(nf):
                 return pl.multiple_of(wb_al + nf * TS, _ALIGN)
 
-            # prefill the left stage's head with the old rows [wb_al, wb) so the
-            # first aligned flush preserves the neighbour leaf's rows
+            def ring_tiles(ring, slot, n=1):
+                """``n`` tiles of a stream's ring from slot ``slot``."""
+                return ring.at[0, pl.ds(pl.multiple_of(slot * TS, TS), n * TS)]
+
+            # the left stream's first open tile starts with the old rows
+            # [wb_al, wb), so the first aligned flush preserves the
+            # neighbour leaf's rows
             cp = pltpu.make_async_copy(
                 rows_ref.at[pl.ds(wb_al, _ALIGN)],
-                stage.at[0, pl.ds(0, _ALIGN)], sem_pre)
+                tmp.at[0, pl.ds(0, _ALIGN)], sem_pre)
             cp.start()
             cp.wait()
 
@@ -529,31 +680,45 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                             pl.multiple_of(wb_al + j * chunk, _ALIGN), chunk)],
                         inbuf.at[j], sem_in.at[j]).start()
 
-            iota2ts1 = jax.lax.broadcasted_iota(jnp.int32, (2 * TS, 1), 0)
+            # stage row of each output row of the placement one-hot, the
+            # left stream's TS rows then the right's: byte-major a side
+            # (:func:`_words_of`), so phase B puts its rows out as words
+            q2 = jax.lax.broadcasted_iota(jnp.int32, (2 * TS, 1), 0)
+            q_in = q2 & (TS - 1)
+            place_row = q2 - q_in + _byte_major(q_in)
         totals_on = "totals" not in dbg_skip and "prefix" not in dbg_skip
         nsub = chunk // T
         npk = chunk // _LANE                   # lane-packed rows (row r ->
                                                # [r // 128, r % 128])
 
-        # the flush of stream tile m from its ring slot sl = m % nb_ring
-        def flush_left(m, sl):
+        # A stream (0 left, 1 right) flushes its ring to its destination: the
+        # window's front in place, or the scratch.  A block b (tiles [b * run,
+        # (b + 1) * run)) leaves its ring slots, bs = b % nbk, with ONE
+        # descriptor on one semaphore of its own; a wait consumes exactly
+        # what its start signalled (DMAs may land out of order: a shared
+        # counting semaphore could not say that the OLDEST block is done)
+        rings, sems = (ring_l, ring_r), (sem_fl, sem_fr)
+
+        def stream_dst(side, m, n=1):
+            """``n`` tiles of a stream's destination from its tile ``m``."""
+            if side:
+                return scratch_ref.at[pl.ds(pl.multiple_of(m * TS, _ALIGN),
+                                            n * TS)]
+            return rows_ref.at[pl.ds(left_dst(m), n * TS)]
+
+        def flush(side, b, bs):
             return pltpu.make_async_copy(
-                stage.at[sl], rows_ref.at[pl.ds(left_dst(m), TS)],
-                sem_fl.at[sl])
+                ring_tiles(rings[side], bs * run, run),
+                stream_dst(side, b * run, run), sems[side].at[bs])
 
-        def flush_right(m, sl):
-            return pltpu.make_async_copy(
-                stage.at[nb_ring + sl],
-                scratch_ref.at[pl.ds(pl.multiple_of(m * TS, _ALIGN), TS)],
-                sem_fr.at[sl])
+        def each_block(side, lo, hi, do):
+            _walk_ring(lo, hi, lambda b, bs: do(flush(side, b, bs)), nbk)
 
-        def await_left(lo, hi):
-            _walk_ring(lo, hi, lambda m, sl: flush_left(m, sl).wait(),
-                       nb_ring)
+        def start(copy):
+            copy.start()
 
-        def await_right(lo, hi):
-            _walk_ring(lo, hi, lambda m, sl: flush_right(m, sl).wait(),
-                       nb_ring)
+        def wait(copy):
+            copy.wait()
 
         # ---- software pipeline (rounds 6-7) ----
         # The round-5 kernel ran A -> B -> totals-DMA-wait -> C per chunk:
@@ -568,10 +733,12 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
         # that has had a full group of matmuls to land.  Phase B never
         # needs the scalar fill counters — the cumulative placed-row counts
         # ride the A/B stage as lane-resident [1, 1] vectors (cumLv/cumRv),
-        # bit-equal to the SMEM-derived scalars phase C still uses for DMA
-        # offsets.
+        # bit-equal to the SMEM-derived scalars phase C uses for DMA
+        # offsets; since PR 40 phase A also works out, from the same
+        # vectors, every scalar phase C needs of a subtile and ships it in
+        # the bank (:func:`_subtile_scalars_lanes`).
         def chunk_ab(c, cum):
-            cumLv, cumRv = cum
+            cumLv, cumRv, slotLv, slotRv = cum
             slot = jax.lax.rem(c, NIN)
             pltpu.make_async_copy(
                 rows_ref.at[pl.ds(pl.multiple_of(wb_al + c * chunk, _ALIGN),
@@ -625,7 +792,7 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             else:
                 S_L = selL_p.reshape(nsub, T)
                 S_R = selR_p.reshape(nsub, T)
-            # round-7 group banking: chunk c's totals live at bank row
+            # round-7 group banking: chunk c's bank entry lives at bank row
             # gpar*totk + kk, reused by chunk c + 2*totk — whose phase A
             # runs only after this group's DMA was awaited by phase
             # C(c - totk) (C trails totk chunks, so the reuse never races
@@ -642,12 +809,15 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 pfxU, _tot, incl_col, excl_col = _subtile_prefixes(
                     S_L, S_R, ltri, nsub=nsub)
                 if totals_on:
-                    totals_vm[bankt, :, 0:2 * nsub] = _subtile_totals_lanes(
-                        S_L, S_R, nsub=nsub)
+                    bank, slotLv, slotRv = _subtile_scalars_lanes(
+                        _subtile_totals_lanes(S_L, S_R, nsub=nsub), headL,
+                        cumLv, cumRv, slotLv, slotRv, nsub=nsub,
+                        nb_ring=nb_ring)
+                    totals_vm[bankt, 0:bank.shape[0], 0:2 * nsub] = bank
 
                     @pl.when((kk == totk - 1) | (c == nchunks - 1))
                     def _start_totals():
-                        # ONE DMA ships the whole group's totals (partial
+                        # ONE DMA ships the whole group's entries (partial
                         # final groups ship stale tail rows phase C never
                         # reads); awaited by phase C of the group's FIRST
                         # chunk, a full ``totk`` chunks of matmuls later,
@@ -664,8 +834,11 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             # one-hot is built TRANSPOSED — dest as a [1, T] lane vector
             # against a [2TS, 1] iota — so the dest math is lane-packed
             # too; the [2TS, T] @ [T, W] dot then lands rows directly in
-            # staging order.  The cross-chunk fill counters enter as the
-            # lane-resident cumLv/cumRv (phase B no longer reads SMEM).
+            # staging order, byte-major a stream, and the words phase C
+            # merges are three shifts and ors of whole vregs
+            # (:func:`_words_of`, as the copy-back makes its own).  The
+            # cross-chunk fill counters enter as the lane-resident
+            # cumLv/cumRv (phase B never reads SMEM).
             for s in range(nsub) if "phaseB" not in dbg_skip else []:
                 selLs = S_L[s:s + 1, :]                      # [1, T] i32
                 selRs = S_R[s:s + 1, :]
@@ -677,27 +850,30 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 destR = TS + jax.lax.rem(cumRv + bR + pfxRs - 1, TS)
                 dest = jnp.where(selLs == 1, destL,
                                  jnp.where(selRs == 1, destR, 2 * TS))
-                Pt = (dest == iota2ts1).astype(jnp.int8)         # [2TS, T]
+                Pt = (dest == place_row).astype(jnp.int8)        # [2TS, T]
                 comp_i = jax.lax.dot_general(
                     Pt, ti_i8[s * T:(s + 1) * T, :],
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.int32)            # [2TS, W]
-                comp_buf[bankb, s * 2 * TS:(s + 1) * 2 * TS, :] = (
-                    comp_i & 255).astype(jnp.uint8)
+                for side in range(2):
+                    comp_buf[bankb, pl.ds((2 * s + side) * _WPT, _WPT), :] = (
+                        _words_of(comp_i[side * TS:(side + 1) * TS]))
 
             # per-side chunk totals ride the carry as [1, 1] vectors (exact:
             # counts <= chunk << 2^24, and the bf16 operands of the incl dot
             # are exact 0/1 and <= 128 values)
             totL = incl_col[nsub - 1:nsub, 0:1].astype(jnp.int32)
             totR = incl_col[2 * nsub - 1:2 * nsub, 0:1].astype(jnp.int32)
-            return cumLv + totL, cumRv + totR
+            return cumLv + totL, cumRv + totR, slotLv, slotRv
 
         def chunk_c(c, cc):
             # phase C for chunk c (scalar blends + flushes), running ``totk``
             # CHUNKS behind phase A/B: the group's banked totals DMA has had
             # a full group of matmuls to land, so the once-per-group wait
-            # below is free in steady state.
-            fillL, fillR, nfL, nfR, wdL, wdR = cc
+            # below is free in steady state.  Every part of the carry is a
+            # pair, left stream then right: the rows placed so far, the flush
+            # blocks started, the blocks awaited, the open tiles' words.
+            fill, started, awaited, opens = cc
             kk = jax.lax.rem(c, totk)
             gpar = jax.lax.rem(c // totk, 2)
             bankt = gpar * totk + kk
@@ -710,55 +886,51 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                         totals_vm.at[pl.ds(base, totk)],
                         totals_sm.at[pl.ds(base, totk)],
                         sem_tot.at[gpar]).wait()
-                accL = fillL + totals_sm[bankt, 1, nsub - 1]
-                accR = fillR + totals_sm[bankt, 1, 2 * nsub - 1]
-            else:                              # "prefix"/"totals" knockouts
-                accL, accR = fillL, fillR
-            k1L = (headL + accL) // TS       # stream tiles complete after c
-            k1R = accR // TS
+                fill = tuple(
+                    fill[side] + totals_sm[bankt, _BK_INCL,
+                                           (side + 1) * nsub - 1]
+                    for side in range(2))
+            # the streams' tiles complete after chunk c
+            k1 = ((headL + fill[0]) >> _TS_SHIFT, fill[1] >> _TS_SHIFT)
 
-            # await ring slots this chunk will reuse (flushes older than the
-            # ring depth)
+            # await the blocks whose ring slots this chunk may store into:
+            # tiles up to k1, so every tile <= k1 - nb_ring has to be gone
             if "flush" not in dbg_skip:
-                doneL = jnp.maximum(wdL, k1L - nb_ring + 1)
-                doneR = jnp.maximum(wdR, k1R - nb_ring + 1)
-                await_left(wdL, doneL)
-                await_right(wdR, doneR)
-                wdL, wdR = doneL, doneR
-
-            # ring slots of the two streams' open tiles: one rem a chunk,
-            # then a subtile moves on by at most one slot (nls, nrs <= TS)
-            curL = jax.lax.rem((headL + fillL) // TS, nb_ring)
-            curR = jax.lax.rem(fillR // TS, nb_ring)
+                done = tuple(
+                    jnp.maximum(awaited[side], jnp.maximum(
+                        k1[side] - nb_ring + run, 0) >> run_shift)
+                    for side in range(2))
+                for side in range(2):
+                    each_block(side, awaited[side], done[side], wait)
+                awaited = done
 
             for s in range(nsub) if "phaseC" not in dbg_skip else []:
-                # subtile s's rows in the two streams' open tiles: count and
-                # first tile row a side (the stream positions are >= 0)
-                nls = totals_sm[bankt, 0, s]
-                nrs = totals_sm[bankt, 0, nsub + s]
-                baseL = fillL + totals_sm[bankt, 1, s] - nls
-                baseR = fillR + totals_sm[bankt, 1, nsub + s] - nrs
-                startL = (headL + baseL) & (TS - 1)
-                startR = baseR & (TS - 1)
-                nxtL = _next_slot(curL, nb_ring)
-                nxtR = _next_slot(curR, nb_ring)
-                _append_placed(stage_w, [
-                    (curL, nxtL, comp_w.load(bankb, s * 2 * _WPT),
-                     startL, nls),
-                    (nb_ring + curR, nb_ring + nxtR,
-                     comp_w.load(bankb, s * 2 * _WPT + _WPT), startR, nrs)],
-                    wbase)
-                curL = jnp.where(startL + nls >= TS, nxtL, curL)
-                curR = jnp.where(startR + nrs >= TS, nxtR, curR)
+                # subtile s's rows into the two streams' open tiles, every
+                # scalar of it ready-made in the bank
+                streams = []
+                for side, j in ((0, s), (1, nsub + s)):
+                    if totals_on:
+                        cur, first, cut, adv = (
+                            totals_sm[bankt, r, j]
+                            for r in (_BK_CUR, _BK_FIRST, _BK_CUT, _BK_ADV))
+                        cur = pl.multiple_of(cur, _WPT)
+                    else:                      # "prefix"/"totals" knockouts:
+                        cur, first, cut, adv = 0, 0, -1, 0   # an empty bank
+                    streams.append((
+                        rings_w[side], opens[side], cur,
+                        comp_buf[bankb, pl.ds((2 * s + side) * _WPT, _WPT), :],
+                        first, cut, adv))
+                opens = _append_placed(streams, wbase)
 
-            # start this chunk's completed-tile flushes (scalar-only loops)
+            # start the flushes of the blocks this chunk completed
+            # (scalar-only loops, a descriptor a block)
             if "flush" not in dbg_skip:
-                _walk_ring(nfL, k1L,
-                           lambda m, sl: flush_left(m, sl).start(), nb_ring)
-                _walk_ring(nfR, k1R,
-                           lambda m, sl: flush_right(m, sl).start(), nb_ring)
+                whole = tuple(k >> run_shift for k in k1)
+                for side in range(2):
+                    each_block(side, started[side], whole[side], start)
+                started = whole
 
-            return accL, accR, k1L, k1R, wdL, wdR
+            return fill, started, awaited, opens
 
         zero = jnp.int32(0)
         zv = jnp.zeros((1, 1), jnp.int32)
@@ -768,60 +940,72 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             # of the previous group, whose phase C trails ``totk`` chunks
             # behind (the inner fori_loop has exactly one trip for
             # c >= totk and zero before)
-            cumLv, cumRv, fillL, fillR, nfL, nfR, wdL, wdR = carry
-            cumLv, cumRv = chunk_ab(c, (cumLv, cumRv))
+            cum, cc = carry
+            cum = chunk_ab(c, cum)
             cc = jax.lax.fori_loop(jnp.maximum(c - totk, 0),
-                                   jnp.maximum(c - totk + 1, 0), chunk_c,
-                                   (fillL, fillR, nfL, nfR, wdL, wdR))
-            return (cumLv, cumRv) + cc
+                                   jnp.maximum(c - totk + 1, 0), chunk_c, cc)
+            return cum, cc
 
         with jax.named_scope(_scopes.K_PLACE):
-            carry = jax.lax.fori_loop(
+            # the streams' open tiles as words: the left one starts with the
+            # prefilled head (rows past it are past the fill point)
+            opens = (tmp_w.load(0), jnp.zeros((_WPT, W), jnp.int32))
+            _, cc = jax.lax.fori_loop(
                 0, nchunks, pipe_body,
-                (zv, zv, zero, zero, zero, zero, zero, zero))
+                ((zv, zv, zv, zv), ((zero, zero),) * 3 + (opens,)))
         with jax.named_scope(_scopes.K_DRAIN):
             # pipeline epilogue: the trailing ``totk`` chunks' phase C
-            fillL, fillR, nfL, nfR, wdL, wdR = jax.lax.fori_loop(
-                jnp.maximum(nchunks - totk, 0), nchunks, chunk_c, carry[2:])
-            nl = fillL
-            nr = fillR
+            (nl, nr), started, awaited, opens = jax.lax.fori_loop(
+                jnp.maximum(nchunks - totk, 0), nchunks, chunk_c, cc)
             stats_ref[...] = jnp.full(stats_ref.shape, nl, jnp.int32)
+            # the streams' complete tiles, and the rows of their open ones
+            nf = ((headL + nl) >> _TS_SHIFT, nr >> _TS_SHIFT)
+            pend_l = headL + nl - nf[0] * TS
+            pend_r = nr - nf[1] * TS
 
-            # drain the outstanding async flushes
+            # the complete tiles past a stream's last whole block (under
+            # ``run`` of them) go a tile at a time, all on one semaphore of
+            # the stream's: equal sizes, and every one of them is awaited
+            def each_tail(side, do):
+                _walk_ring(
+                    started[side] * run, nf[side],
+                    lambda m, sl: do(pltpu.make_async_copy(
+                        ring_tiles(rings[side], sl), stream_dst(side, m),
+                        sems[side].at[0])), nb_ring)
+
             if "flush" not in dbg_skip:
-                await_left(wdL, nfL)
-                await_right(wdR, nfR)
+                for side in range(2):          # the outstanding blocks
+                    each_block(side, awaited[side], started[side], wait)
+                for side in range(2):
+                    each_tail(side, start)
 
             # ---- final right partial flush (scratch is all ours: no RMW,
             # garbage tail rows are masked by nr during copy-back) ----
-            pend_r = fillR - nfR * TS
-
             @pl.when(pend_r > 0)
             def _final_right():
-                cpf = pltpu.make_async_copy(
-                    stage.at[nb_ring + jax.lax.rem(nfR, nb_ring)],
-                    scratch_ref.at[pl.ds(pl.multiple_of(nfR * TS, _ALIGN), TS)],
-                    sem_pre)
+                tmp_w.store(0, opens[1])
+                cpf = pltpu.make_async_copy(tmp.at[0], stream_dst(1, nf[1]),
+                                            sem_pre)
                 cpf.start()
                 cpf.wait()
 
             # ---- final left partial flush (read-modify-write) ----
-            pend_l = headL + fillL - nfL * TS
-
             @pl.when(pend_l > 0)
             def _final_left():
-                src = left_dst(nfL)
-                cpa = pltpu.make_async_copy(rows_ref.at[pl.ds(src, TS)],
-                                            tmp.at[0], sem_pre)
+                cpa = pltpu.make_async_copy(stream_dst(0, nf[0]), tmp.at[0],
+                                            sem_pre)
                 cpa.start()
                 cpa.wait()
-                tmp_w.store(0, _merge_rows(
-                    tmp_w.load(0), stage_w.load(jax.lax.rem(nfL, nb_ring)),
-                    _rows_from(wbase, pend_l)))
-                cpb = pltpu.make_async_copy(tmp.at[0], rows_ref.at[pl.ds(src, TS)],
+                tmp_w.store(0, _merge_rows(tmp_w.load(0), opens[0],
+                                           _rows_from(wbase, pend_l)))
+                cpb = pltpu.make_async_copy(tmp.at[0], stream_dst(0, nf[0]),
                                             sem_pre)
                 cpb.start()
                 cpb.wait()
+
+            if "flush" not in dbg_skip:
+                for side in range(2):
+                    each_tail(side, wait)
 
         # ---- smaller child's histogram from its CONTIGUOUS block ----
         # Post-partition the smaller child is contiguous (left block in
@@ -885,7 +1069,7 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
 
         # ---- copy right block back: scratch[0:nr] -> rows[wb+nl ...) ----
         # Same streamed-append machinery (chunk reads through the input ring,
-        # nb_ring-deep async flush ring on the left slots), with a constant
+        # the nb_cb-deep async flush ring ``stage``), with a constant
         # row rotation by the destination's 32-row phase.  One window on the
         # chip (2,097,152 rows, F = 28, histogram knocked out, PERF.md §6
         # PR 33): 1.031 ns a right row, whole kernel every row right less
@@ -896,16 +1080,16 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
             d_al = pl.multiple_of((d0 // _ALIGN) * _ALIGN, _ALIGN)
             ph = d0 - d_al
             # constant row-rotation one-hot: source row j -> stage row
-            # p = (j + ph) % TS, which the dot puts out at q = (p % 4) *
-            # TS // 4 + p // 4: byte k of every word as one contiguous
+            # p = (j + ph) % TS, which the dot puts out byte-major
+            # (:func:`_words_of`): byte k of every word as one contiguous
             # [TS // 4, W] block, so the words are three shifts and ors of
             # whole vregs and not a 32 -> 16 -> 8 bit pack
             # (rot is [q, j]: a plain [TS, TS] @ [TS, W] dot, no transpose
             # of the constant in every trip)
-            q = jax.lax.broadcasted_iota(jnp.int32, (TS, 1), 0)
             rot[...] = (jax.lax.rem(
                 jax.lax.broadcasted_iota(jnp.int32, (1, TS), 1) + ph, TS)
-                == 4 * jax.lax.rem(q, _WPT) + q // _WPT
+                == _byte_major(
+                    jax.lax.broadcasted_iota(jnp.int32, (TS, 1), 0))
             ).astype(jnp.int8)
             # ph is the loop's constant: rows >= ph of a tile come from the
             # source tile that fills it, rows below from the one before
@@ -937,34 +1121,33 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                     read_cb(j, j).start()
             cph.wait()
 
-            def cb_tile(slot, k0, k, carry):
+            def cb_tile(slot, left, kk, carry):
+                # kk: the tile within its chunk read; left: the scratch's
+                # rows from that read's first tile on
                 fill, nf, cur = carry          # cur: tile nf's ring slot
                 tr = jax.lax.dot_general(
                     rot[...],
                     jax.lax.bitcast_convert_type(
-                        inbuf[slot, pl.ds(pl.multiple_of((k - k0) * TS, TS),
-                                          TS), :], jnp.int8),
+                        inbuf[slot, pl.ds(pl.multiple_of(kk * TS, TS), TS), :],
+                        jnp.int8),
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.int32)
-                comp = ((tr[0:_WPT] & 255)
-                        | ((tr[_WPT:2 * _WPT] & 255) << 8)
-                        | ((tr[2 * _WPT:3 * _WPT] & 255) << 16)
-                        | (tr[3 * _WPT:] << 24))                 # [TS//4, W]
+                comp = _words_of(tr)                             # [TS//4, W]
                 # the last tile's rows past nvs are the scratch's garbage
                 # tail: they land past the fill point, which _final_cb masks
-                nvs = jnp.minimum(nr - k * TS, TS)
-                nxt = _next_slot(cur, nb_ring)
+                nvs = jnp.minimum(left - kk * TS, TS)
+                nxt = _next_slot(cur, nb_cb)
                 stage_w.store(cur, _merge_rows(comp, stage_w.load(cur), m_ph))
                 cross = ph + nvs >= TS
 
                 @pl.when(cross)
                 def _flush_cb():
-                    @pl.when(nf >= nb_ring - 1)
+                    @pl.when(nf >= nb_cb - 1)
                     def _await_prev():
                         pltpu.make_async_copy(
                             stage.at[nxt],
                             rows_ref.at[pl.ds(pl.multiple_of(
-                                d_al + (nf - (nb_ring - 1)) * TS, _ALIGN),
+                                d_al + (nf - (nb_cb - 1)) * TS, _ALIGN),
                                 TS)],
                             sem_cb.at[nxt]).wait()
                     pltpu.make_async_copy(
@@ -993,17 +1176,18 @@ def _make_partition_kernel(*, n_pad, W, num_features, num_bins, voff, bpc,
                 # a tile of it merged in would take the last tile's rows)
                 k0 = c * (chunk // TS)
                 fill, nf, cur = jax.lax.fori_loop(
-                    k0, jnp.minimum(k0 + chunk // TS, ncbk),
-                    functools.partial(cb_tile, slot, k0), (fill, nf, cur))
+                    0, jnp.minimum(chunk // TS, ncbk - k0),
+                    functools.partial(cb_tile, slot, nr - k0 * TS),
+                    (fill, nf, cur))
                 return fill, nf, cur, _next_slot(slot, NIN)
 
             fill, nf, cur, _ = jax.lax.fori_loop(
                 0, ncc, cb_chunk, (zero, zero, zero, zero))
-            for j in range(1, nb_ring):
+            for j in range(1, nb_cb):
                 @pl.when(nf - j >= 0)
                 def _drain_cb(j=j):
                     idx = nf - j
-                    sl = jax.lax.rem(idx, nb_ring)
+                    sl = jax.lax.rem(idx, nb_cb)
                     pltpu.make_async_copy(
                         stage.at[sl],
                         rows_ref.at[pl.ds(pl.multiple_of(
@@ -1190,7 +1374,11 @@ def partition_hist_pallas(rows: jax.Array, scal: jax.Array,
     "totals", "statslot") — outputs are WRONG when set ("prefix"/"totals"
     additionally zero the chunk fill counters, so even row counts lie).
     Knockout timings are scheduling-sensitive (zeroed inputs constant-fold
-    downstream phases); trust whole-kernel A/B timings over deltas.
+    downstream phases); trust whole-kernel A/B timings over deltas.  Since
+    PR 40: "phaseB" leaves ``comp_buf``'s words unwritten, "phaseC" hands the
+    streams' open tiles through unmerged, "flush" drops the block flushes,
+    their waits and the drain's tail, "totals" / "prefix" leave the bank
+    unshipped and phase C reads an empty one (slot 0, nothing completes).
 
     ``chunk``/``small`` (round 7): size-bucketed kernel variants.  ``chunk``
     sets the streamed tile height of the pipelined kernel (1024 or 4096 —
@@ -1309,6 +1497,7 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
         return rows_new, hist, nl[:, 0, 0:1]
 
     nb_ring = _ring_depth(chunk)
+    nbk = nb_ring // _flush_run(chunk)
     totk = _totk(chunk)
     nsub = chunk // T
     kernel = _make_partition_kernel(
@@ -1316,8 +1505,17 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
         voff=voff, bpc=bpc, packed=packed, exact=exact, f_shard=f_shard,
         dbg_skip=dbg_skip, chunk=chunk, multiwin=multiwin,
         quantized=quantized, interpret=interpret)
+    # VMEM the kernel declares: the chunk ring, the placed words' banks, the
+    # three tile rings and the histogram block (twice: it is pipelined out).
+    # Twice that leaves the compiler its temporaries; at W = 128 it is under
+    # the 16 MiB a kernel gets anyway, a table of more than 108 byte columns
+    # (W = 256) needs the room asked for
+    vmem = ((NIN * chunk + (totk + 1) * 2 * TS * nsub
+             + (2 * nb_ring + _cb_depth(chunk)) * TS) * W + 2 * 4 * h0 * h1)
     rows_new, _scratch, hist, nl = pl.pallas_call(
         kernel,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 << 20, 2 * vmem)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nwin,),
@@ -1333,19 +1531,21 @@ def _partition_call(rows, scal, *, num_features, num_bins, voff, bpc,
             scratch_shapes=[
                 pltpu.VMEM((NIN, chunk, W), jnp.uint8),  # streamed chunk ring
                                                          # (window, hist, cb)
-                pltpu.VMEM((2 * nb_ring, TS, W), jnp.uint8),  # L/R flush rings
+                pltpu.VMEM((1, nb_ring * TS, W), jnp.uint8),  # left flush ring
+                pltpu.VMEM((1, nb_ring * TS, W), jnp.uint8),  # right flush ring
+                pltpu.VMEM((_cb_depth(chunk), TS, W), jnp.uint8),  # copy-back's
                 pltpu.VMEM((T, T), jnp.int8),            # upper-tri prefix ones
                 pltpu.VMEM((TS, TS), jnp.int8),          # copy-back rotation
                 pltpu.VMEM((1, TS, W), jnp.uint8),       # finals' RMW bounce
-                pltpu.VMEM((totk + 1, 2 * TS * nsub, W),
-                           jnp.uint8),                   # placed, group banks
-                pltpu.VMEM((2 * totk, 2, _LANE), jnp.int32),   # totals banks
-                pltpu.SMEM((2 * totk, 2, _LANE), jnp.int32),   # totals land
+                pltpu.VMEM((totk + 1, 2 * _WPT * nsub, W),
+                           jnp.int32),                   # placed words, banks
+                pltpu.VMEM((2 * totk, _BANK_ROWS, _LANE), jnp.int32),  # bank
+                pltpu.SMEM((2 * totk, _BANK_ROWS, _LANE), jnp.int32),  # lands
                 pltpu.SemaphoreType.DMA((NIN,)),         # chunk ring reads
                 pltpu.SemaphoreType.DMA,                 # prefills + finals
-                pltpu.SemaphoreType.DMA((nb_ring,)),     # left flush ring
-                pltpu.SemaphoreType.DMA((nb_ring,)),     # right flush ring
-                pltpu.SemaphoreType.DMA((nb_ring,)),     # copy-back ring
+                pltpu.SemaphoreType.DMA((nbk,)),         # left flush blocks
+                pltpu.SemaphoreType.DMA((nbk,)),         # right flush blocks
+                pltpu.SemaphoreType.DMA((_cb_depth(chunk),)),  # copy-back ring
                 pltpu.SemaphoreType.DMA((2,)),           # totals group banks
             ],
         ),

@@ -127,6 +127,22 @@ def test_partition_hist_compiles_at_the_other_cells_columns(
         kernel="partition_hist_pallas_" + P.bucket_name(small, chunk))
 
 
+@pytest.mark.parametrize("f", [120, 236])
+@pytest.mark.parametrize("chunk", [P.CHUNK, P.SMALL_CHUNK])
+def test_partition_hist_compiles_at_a_row_of_256_bytes(one_chip, chunk, f):
+    """More than 108 byte columns make the row store 256 bytes wide and every
+    VMEM buffer of the pipelined kernel twice as large: past the 16 MiB a
+    kernel gets unasked (PR 39's kernel compiled up to about 199 columns
+    there, PR 40's deeper flush rings would not have at 109), so the call
+    asks for twice what it declares (``vmem_limit_bytes``)."""
+    voff = -(-f // 4) * 4
+    assert -(-(voff + 20) // 128) * 128 == 256
+    _compile(lambda r, s: P.partition_hist_pallas(
+        r, s, num_features=f, num_bins=256, voff=voff, chunk=chunk),
+        one_chip, _sds((N_PAD, 256), jnp.uint8), _sds((12 + 8,), jnp.int32),
+        kernel="partition_hist_pallas_" + P.bucket_name(False, chunk))
+
+
 @pytest.mark.parametrize("f,voff", OTHER_CELLS)
 def test_histogram_rows_compiles_at_the_other_cells_columns(one_chip, f,
                                                              voff):

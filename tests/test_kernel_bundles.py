@@ -58,20 +58,29 @@ def _nest(depths):
     return out
 
 
-PIPELINED_DEPTHS = [1, 2, 3, 3, 3, 3, 1, 2, 2, 2, 2, 1, 1, 1, 2, 1, 2, 1, 2]
+# as the compiler dumps the c4096 kernel since PR 40 (the drain's four tail
+# loops are new)
+PIPELINED_DEPTHS = [1, 2, 3, 3, 3, 3, 1, 2, 2, 2, 2, 1, 1, 1, 1, 1, 1,
+                    1, 2, 1, 2, 1, 2]
 
 
 def test_the_copy_back_has_an_outer_and_an_inner_body_by_name():
     loops = KB.loop_bodies(_nest(PIPELINED_DEPTHS))
     names = KB.loop_names(loops)
-    assert len(names) == len(loops) == 19
+    assert len(names) == len(loops) == 23
+    assert [n for n in names if "tail" in n] == [
+        "drain: tail_left start, a tile", "drain: tail_right start, a tile",
+        "drain: tail_left wait, a tile", "drain: tail_right wait, a tile"]
+    assert names[2:6] == ["await_left, a block", "await_right, a block",
+                          "flush_left start, a block",
+                          "flush_right start, a block"]
     assert names[0].startswith("pipe_body") and names[1].startswith("chunk_c")
     assert names[-2:] == ["copy-back cb_chunk: a chunk read of the scratch",
                           "copy-back cb_tile: a 128-row tile"]
     text = KB.report(_nest(PIPELINED_DEPTHS), nest=KB.PIPELINED_LOOPS)
-    assert "  loop at bundle 34 [copy-back cb_chunk: a chunk read of the " \
+    assert "  loop at bundle 42 [copy-back cb_chunk: a chunk read of the " \
         "scratch]: 2 own bundles (2 more in nested loops)" in text
-    assert "    loop at bundle 36 [copy-back cb_tile: a 128-row tile]: 2 " \
+    assert "    loop at bundle 44 [copy-back cb_tile: a 128-row tile]: 2 " \
         "own bundles (0 more in nested loops)" in text
 
 
@@ -92,7 +101,8 @@ def test_the_group_block_is_named_and_shared_out_a_group():
 
 
 @pytest.mark.parametrize("depths", [
-    PIPELINED_DEPTHS[:-1],                    # the parent's: one copy-back body
+    PIPELINED_DEPTHS[:-1],                    # PR 32's: one copy-back body
+    PIPELINED_DEPTHS[:13] + PIPELINED_DEPTHS[17:],   # PR 39's: no tail loops
     [1],                                      # the small kernel has no loop nest
     PIPELINED_DEPTHS + [1],
 ])

@@ -107,16 +107,22 @@ GROUP_BLOCK = "a block of feature groups"
 # The pipelined kernel's loop bodies in program order, (name, nested bodies):
 # what ``_make_partition_kernel`` rolls (its unrolled Python loops show as
 # segments).  The copy-back has two since PR 33: a chunk read of the scratch,
-# and the 128-row tiles taken out of it.
-_FLUSH_LOOPS = [("await_left, a tile", []), ("await_right, a tile", []),
-                ("flush_left start, a tile", []),
-                ("flush_right start, a tile", [])]
+# and the 128-row tiles taken out of it.  The flush loops walk BLOCKS of
+# ``partition._flush_run`` tiles since PR 40, a descriptor a block, and the
+# drain sends what a window's end leaves short of a block a tile at a time.
+_FLUSH_LOOPS = [("await_left, a block", []), ("await_right, a block", []),
+                ("flush_left start, a block", []),
+                ("flush_right start, a block", [])]
 PIPELINED_LOOPS = [
     ("pipe_body: chunk_ab, phases A + B of a chunk",
      [("chunk_c: phase C of the chunk totk behind", _FLUSH_LOOPS)]),
     ("chunk_c: phase C of the trailing chunks", _FLUSH_LOOPS),
-    ("drain: await_left, a tile", []),
-    ("drain: await_right, a tile", []),
+    ("drain: await_left, a block", []),
+    ("drain: await_right, a block", []),
+    ("drain: tail_left start, a tile", []),
+    ("drain: tail_right start, a tile", []),
+    ("drain: tail_left wait, a tile", []),
+    ("drain: tail_right wait, a tile", []),
     ("hist_pass of the left block: a chunk", [(GROUP_BLOCK, [])]),
     ("hist_pass of the right block: a chunk", [(GROUP_BLOCK, [])]),
     ("copy-back cb_chunk: a chunk read of the scratch",
