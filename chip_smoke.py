@@ -442,6 +442,69 @@ def phase_save_load_score(ctx):
     ctx["loaded"] = loaded
 
 
+def categorical_table(n, n_test, seed):
+    """The benchmark's table with its first two columns handed over as
+    categories: column 0 cut into 40 levels at its quantiles and column 1 into
+    3, each level's code drawn from a fixed shuffle, so that a code's order
+    says nothing and only a set of codes can follow the label."""
+    X, y, Xt, yt = benchmark_table(n, n_test, seed)
+    rng = np.random.default_rng(seed)
+    for column, levels in ((0, 40), (1, 3)):
+        edges = np.quantile(X[:, column], np.linspace(0, 1, levels + 1)[1:-1])
+        codes = rng.permutation(levels).astype(np.float32)
+        for part in (X, Xt):
+            part[:, column] = codes[np.searchsorted(edges, part[:, column])]
+    return X, y, Xt, yt
+
+
+def phase_categorical(ctx):
+    """A categorical decision on the chip: two categorical columns (one
+    searched many-vs-many, one a category against the rest) through the fused
+    path, saved, loaded and scored, against the benchmark's plain walk."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    from lightgbm_tpu.obs import categorical
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    try:
+        import plain_categorical
+    finally:
+        sys.path.pop(0)
+    n = ROWS_SMALL
+    X, y, Xt, yt = categorical_table(n, n // 8, SEED + 2)
+    ds = BinnedDataset.from_matrix(X, label=y, max_bin=MAX_BIN,
+                                   categorical_feature=[0, 1])
+    categorical.reset()
+    booster = make_gbdt(ds, binary_config(num_iterations=ITERS_SMALL,
+                                          categorical_feature=[0, 1]))
+    assert_compiled_pallas(booster.learner)
+    assert booster.learner.has_categorical
+    booster.train_chunk(ITERS_SMALL)
+    booster.train_score.block_until_ready()
+    assert not booster._fuse_failed, "training left the fused path"
+    check_trees(booster.models, LEAVES)
+    counts = categorical.counts()
+    many = counts["cat.splits"] - counts["cat.onehot_splits"]
+    assert many > 0 and counts["cat.onehot_splits"] > 0, counts
+    assert counts["cat.scan_steps"] == 32, counts
+    path = os.path.join(OUT, "categorical_model.txt")
+    booster.save_model(path)
+    in_memory = booster.predict(Xt, raw_score=True)
+    loaded = lgb.Booster(model_file=path)
+    scores = loaded.predict(Xt, raw_score=True)
+    np.testing.assert_array_equal(scores, in_memory)
+    walked = plain_categorical.walk(loaded._booster.models, Xt)
+    gap = float(np.max(np.abs(np.asarray(scores, np.float64) - walked)))
+    assert gap <= 1e-5, "scores are %.3g from the plain walk" % gap
+    say("  trained %d rows, %d trees on the fused path with 2 categorical "
+        "columns: %d many-vs-many splits and %d of a category against the "
+        "rest, scans of %d steps; saved, loaded, scored %d held-out rows: "
+        "equal to the in-memory booster, %.3g from the plain walk of the "
+        "loaded trees, AUC %.5f"
+        % (n, ITERS_SMALL, many, counts["cat.onehot_splits"],
+           counts["cat.scan_steps"], len(Xt), gap,
+           auc(yt, scores)))
+
+
 def phase_entry_points(ctx):
     """lightgbm_tpu.train (per-iteration path) and the CLI, in-process: the
     chip has one owner, so no ``python -m lightgbm_tpu`` child."""
@@ -645,6 +708,8 @@ def phase_data_parallel(ctx):
 ONE_CHIP = (("device", phase_device), ("kernels vs reference", phase_kernels),
             ("train", phase_train),
             ("save / load / score", phase_save_load_score),
+            ("categorical columns: train, save, load, score",
+             phase_categorical),
             ("train, the two public entry points", phase_entry_points),
             ("agree with the plain learner", phase_agree_with_xla_learner),
             ("contrib", phase_contrib), ("serve", phase_serve))

@@ -373,11 +373,18 @@ def _bits_to_words(bits: jax.Array) -> jax.Array:
     return (w << jnp.arange(32, dtype=jnp.uint32)).sum(axis=-1, dtype=jnp.uint32)
 
 
+def cat_scan_steps(num_bins: int, params: SplitParams) -> int:
+    """Steps a direction that the sorted many-vs-many scan of
+    :func:`per_feature_best_categorical` runs over ``num_bins`` bins."""
+    return max(min(int(num_bins), int(params.max_cat_threshold)), 0)
+
+
 def per_feature_best_categorical(hist: jax.Array, feat: FeatureInfo,
                                  feature_mask: jax.Array, sum_grad: jax.Array,
                                  sum_hess: jax.Array, num_data: jax.Array,
                                  params: SplitParams,
-                                 cmin=None, cmax=None) -> FeatureBest:
+                                 cmin=None, cmax=None,
+                                 scan_steps=None) -> FeatureBest:
     """Best categorical split of each feature
     (feature_histogram.hpp:136-304 FindBestThresholdCategorical).
 
@@ -385,11 +392,27 @@ def per_feature_best_categorical(hist: jax.Array, feat: FeatureInfo,
     sorted many-vs-many scan: bins with count >= cat_smooth sorted by
     grad/(hess+cat_smooth), prefix-scanned from both ends up to
     max_cat_threshold with the min_data_per_group batching.  The serial
-    two-direction scan becomes a vmapped lax.scan over the (small) bin axis.
-    Resulting left-bin sets are returned as bitsets."""
+    two-direction scan becomes a vmapped lax.scan over the sorted positions.
+    Resulting left-bin sets are returned as bitsets.
+
+    Each direction's scan runs :func:`cat_scan_steps` steps, ``min(B,
+    max_cat_threshold)`` (32 of 256 under the defaults), and that is exact:
+    a step is ``active`` only while ``i < min(max_cat_threshold, (used + 1)
+    // 2)``, and a step that is not active adds nothing to a sum, reaches no
+    group, offers no candidate and sets no stop, so every step from
+    ``max_cat_threshold`` on leaves the carried state as it found it.
+    ``scan_steps`` is for the test that holds the bounded scan to the whole
+    one bit for bit.
+
+    Its own named scopes, inside the caller's ``tree.find_split`` /
+    ``tree.root``: ``find.cat_onehot`` (one category against the rest),
+    ``find.cat_sort`` (the key, the sort, the gathers through the order, and
+    the winning prefix scattered back to bin order and packed into words)
+    and ``find.cat_scan`` (the two directional scans and the left sums at
+    the winner)."""
     F, _, B = hist.shape
-    W = B // 32
     p = params
+    steps = cat_scan_steps(B, p) if scan_steps is None else int(scan_steps)
     g = hist[:, 0, :]
     h = hist[:, 1, :]
     total_h = sum_hess + 2 * K_EPSILON
@@ -409,32 +432,35 @@ def per_feature_best_categorical(hist: jax.Array, feat: FeatureInfo,
     use_onehot = feat.num_bin <= p.max_cat_to_onehot                # [F]
 
     # ---------- one-hot: category t vs rest (:157-189) ----------
-    other_g = total_g - g
-    other_h = total_h - h - K_EPSILON
-    other_cnt = num_data_f - cnt
-    ok1 = (in_range & (cnt >= p.min_data_in_leaf)
-           & (h >= p.min_sum_hessian_in_leaf)
-           & (other_cnt >= p.min_data_in_leaf)
-           & (other_h >= p.min_sum_hessian_in_leaf))
-    oh_gain, oh_lo, oh_ro = _split_gains_clamped(
-        g, h + K_EPSILON, other_g, other_h, p, p.lambda_l2, cmin, cmax)
-    oh_gain = jnp.where(ok1 & (oh_gain > min_gain_shift), oh_gain, K_MIN_SCORE)
-    oh_t = jnp.argmax(oh_gain, axis=1).astype(jnp.int32)            # first max
     fidx = jnp.arange(F)
-    oh_best = oh_gain[fidx, oh_t]
+    with jax.named_scope(_scopes.FIND_CAT_ONEHOT):
+        other_g = total_g - g
+        other_h = total_h - h - K_EPSILON
+        other_cnt = num_data_f - cnt
+        ok1 = (in_range & (cnt >= p.min_data_in_leaf)
+               & (h >= p.min_sum_hessian_in_leaf)
+               & (other_cnt >= p.min_data_in_leaf)
+               & (other_h >= p.min_sum_hessian_in_leaf))
+        oh_gain, oh_lo, oh_ro = _split_gains_clamped(
+            g, h + K_EPSILON, other_g, other_h, p, p.lambda_l2, cmin, cmax)
+        oh_gain = jnp.where(ok1 & (oh_gain > min_gain_shift), oh_gain,
+                            K_MIN_SCORE)
+        oh_t = jnp.argmax(oh_gain, axis=1).astype(jnp.int32)        # first max
+        oh_best = oh_gain[fidx, oh_t]
 
     # ---------- sorted many-vs-many (:191-268) ----------
     l2c = p.lambda_l2 + p.cat_l2
-    valid_sort = in_range & (cnt >= p.cat_smooth)
-    ctr = g / (h + p.cat_smooth)
-    sort_key = jnp.where(valid_sort, ctr, jnp.inf)
-    order = jnp.argsort(sort_key, axis=1, stable=True).astype(jnp.int32)
-    used = valid_sort.sum(axis=1).astype(jnp.int32)                 # [F]
-    max_num_cat = jnp.minimum(p.max_cat_threshold, (used + 1) // 2)
+    with jax.named_scope(_scopes.FIND_CAT_SORT):
+        valid_sort = in_range & (cnt >= p.cat_smooth)
+        ctr = g / (h + p.cat_smooth)
+        sort_key = jnp.where(valid_sort, ctr, jnp.inf)
+        order = jnp.argsort(sort_key, axis=1, stable=True).astype(jnp.int32)
+        used = valid_sort.sum(axis=1).astype(jnp.int32)             # [F]
+        max_num_cat = jnp.minimum(p.max_cat_threshold, (used + 1) // 2)
 
-    gs = jnp.take_along_axis(g, order, axis=1)
-    hs = jnp.take_along_axis(h, order, axis=1)
-    cs = jnp.take_along_axis(cnt, order, axis=1)
+        gs = jnp.take_along_axis(g, order, axis=1)
+        hs = jnp.take_along_axis(h, order, axis=1)
+        cs = jnp.take_along_axis(cnt, order, axis=1)
 
     def scan_dir(gs_f, hs_f, cs_f, used_f, maxcat_f, backward):
         def idx(i):
@@ -472,26 +498,27 @@ def per_feature_best_categorical(hist: jax.Array, feat: FeatureInfo,
                 jnp.float32(0), jnp.bool_(False), jnp.float32(K_MIN_SCORE),
                 jnp.int32(-1))
         (slg, slh, lc, cg, st, bgain, bi), _ = jax.lax.scan(
-            step, init, jnp.arange(B, dtype=jnp.int32))
+            step, init, jnp.arange(steps, dtype=jnp.int32))
         return bgain, bi
 
-    vscan = jax.vmap(scan_dir, in_axes=(0, 0, 0, 0, 0, None))
-    fwd_gain, fwd_i = vscan(gs, hs, cs, used, max_num_cat, False)
-    bwd_gain, bwd_i = vscan(gs, hs, cs, used, max_num_cat, True)
-    use_bwd = bwd_gain > fwd_gain                                    # fwd ties
-    so_gain = jnp.where(use_bwd, bwd_gain, fwd_gain)
-    so_i = jnp.where(use_bwd, bwd_i, fwd_i)
+    with jax.named_scope(_scopes.FIND_CAT_SCAN):
+        vscan = jax.vmap(scan_dir, in_axes=(0, 0, 0, 0, 0, None))
+        fwd_gain, fwd_i = vscan(gs, hs, cs, used, max_num_cat, False)
+        bwd_gain, bwd_i = vscan(gs, hs, cs, used, max_num_cat, True)
+        use_bwd = bwd_gain > fwd_gain                                # fwd ties
+        so_gain = jnp.where(use_bwd, bwd_gain, fwd_gain)
+        so_i = jnp.where(use_bwd, bwd_i, fwd_i)
 
-    # recompute left sums at the winning prefix (inclusive of position so_i)
-    pos = jnp.arange(B, dtype=jnp.int32)[None, :]
-    in_prefix = jnp.where(use_bwd[:, None],
-                          (pos >= jnp.maximum(used - 1 - so_i, 0)[:, None])
-                          & (pos < used[:, None]),
-                          pos <= so_i[:, None])
-    in_prefix &= so_i[:, None] >= 0
-    so_lg = jnp.sum(jnp.where(in_prefix, gs, 0.0), axis=1)
-    so_lh = jnp.sum(jnp.where(in_prefix, hs, 0.0), axis=1) + K_EPSILON
-    so_lc = jnp.sum(jnp.where(in_prefix, cs, 0.0), axis=1)
+        # recompute left sums at the winning prefix (inclusive of so_i)
+        pos = jnp.arange(B, dtype=jnp.int32)[None, :]
+        in_prefix = jnp.where(use_bwd[:, None],
+                              (pos >= jnp.maximum(used - 1 - so_i, 0)[:, None])
+                              & (pos < used[:, None]),
+                              pos <= so_i[:, None])
+        in_prefix &= so_i[:, None] >= 0
+        so_lg = jnp.sum(jnp.where(in_prefix, gs, 0.0), axis=1)
+        so_lh = jnp.sum(jnp.where(in_prefix, hs, 0.0), axis=1) + K_EPSILON
+        so_lc = jnp.sum(jnp.where(in_prefix, cs, 0.0), axis=1)
 
     # ---------- combine one-hot / sorted per feature ----------
     oh = use_onehot
@@ -510,12 +537,14 @@ def per_feature_best_categorical(hist: jax.Array, feat: FeatureInfo,
         r_out = jnp.clip(r_out, cmin, cmax)
 
     # left-bin bitsets: one-hot -> {oh_t}; sorted -> prefix through order
-    bits_oh = t == oh_t[:, None]
-    bits_sorted = jnp.zeros((F, B), dtype=bool)
-    scatter_f = jnp.broadcast_to(fidx[:, None], (F, B)).reshape(-1)
-    bits_sorted = bits_sorted.at[scatter_f, order.reshape(-1)].set(
-        in_prefix.reshape(-1))
-    bits = jnp.where(oh[:, None], bits_oh, bits_sorted)
+    with jax.named_scope(_scopes.FIND_CAT_SORT):
+        bits_oh = t == oh_t[:, None]
+        bits_sorted = jnp.zeros((F, B), dtype=bool)
+        scatter_f = jnp.broadcast_to(fidx[:, None], (F, B)).reshape(-1)
+        bits_sorted = bits_sorted.at[scatter_f, order.reshape(-1)].set(
+            in_prefix.reshape(-1))
+        bits = jnp.where(oh[:, None], bits_oh, bits_sorted)
+        words = _bits_to_words(bits)
 
     found = (cat_gain > K_MIN_SCORE) & feature_mask & feat.is_categorical
     zero = jnp.zeros((F,), jnp.float32)
@@ -531,8 +560,7 @@ def per_feature_best_categorical(hist: jax.Array, feat: FeatureInfo,
         right_count=jnp.where(found, r_c, zero),
         left_output=l_out,
         right_output=r_out,
-        cat_bitset=jnp.where(found[:, None], _bits_to_words(bits), 0).astype(
-            jnp.uint32),
+        cat_bitset=jnp.where(found[:, None], words, 0).astype(jnp.uint32),
     )
 
 

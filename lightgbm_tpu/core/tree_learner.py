@@ -41,12 +41,13 @@ from .partition import (CHUNK as _PCHUNK, fold_hist, fused_bucket_plan,
 from .quant import quantize_gradients
 from .row_state import advance_row_state, f32_col, i32_col, leaf_windows
 from .split import (BestSplit, FeatureInfo, SplitParams, best_split_numerical,
-                    dequantize_hist, group_best, group_lanes, group_scans,
+                    cat_scan_steps, dequantize_hist, group_best, group_lanes, group_scans,
                     per_feature_best, per_feature_best_combined,
                     reduce_feature_best, sync_best, K_MIN_SCORE)
 from .tree import Tree
 from ..io.binning import BinType, MissingType
 from ..io.dataset import BinnedDataset
+from ..obs import categorical as _cat_counters
 from ..obs import efb as _efb_counters
 from ..obs import scopes as _scopes
 from ..obs.spans import span as _span
@@ -1629,7 +1630,8 @@ class SerialTreeLearner:
             extra_trees=bool(config.extra_trees),
             extra_seed=int(config.extra_seed),
             feature_contri=self._map_feature_contri(config, dataset))
-        self.has_categorical = bool(dataset.feature_is_categorical().any())
+        is_cat = dataset.feature_is_categorical()
+        self.has_categorical = bool(is_cat.any())
         mono_cfg = list(getattr(config, "monotone_constraints", []) or [])
         mono = np.zeros(dataset.num_features, dtype=np.int32)
         for j, orig in enumerate(dataset.used_feature_idx):
@@ -1711,6 +1713,12 @@ class SerialTreeLearner:
         self._upload_bins(matrix)
         self.forced = self._load_forced_splits(config, dataset)
         self.cegb = self._init_cegb(config, dataset)
+        _cat_counters.record_learner(
+            int(is_cat.sum()),
+            sum(int(dataset.bin_mappers[i].num_bin) for i, c in zip(
+                dataset.used_feature_idx, is_cat) if c),
+            cat_scan_steps(self.feat_bins, self.params)
+            if self.has_categorical else 0, self.params.max_cat_to_onehot)
         if self.grouped:
             in_groups = group_search_applies(True, self.has_categorical,
                                              self.cegb is not None)
@@ -2118,6 +2126,7 @@ def tree_from_arrays(arrays: TreeArrays, dataset: BinnedDataset,
             t.cat_boundaries.append(t.cat_boundaries[-1] + nw)
             t.cat_threshold.extend(cwords)
             t.num_cat += 1
+            _cat_counters.record_split(int(m.num_bin))
         else:
             t.threshold_in_bin[node] = int(a.threshold_bin[node])
             t.threshold[node] = m.bin_to_value(int(a.threshold_bin[node]))

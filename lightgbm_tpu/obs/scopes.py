@@ -76,6 +76,12 @@ KERNEL_REGIONS: Tuple[Region, ...] = (
  FIND_BESTS) = FIND_PARTS = ("find.hist_cache", "find.scan", "find.gain",
                              "find.pick", "find.bests")
 CHUNK_SCORE_OUT, = CHUNK_PARTS = ("chunk.score_out",)
+# the categorical search's parts (core/split.py
+# per_feature_best_categorical), opened only by a program with a categorical
+# feature: a list of their own, so FIND_PARTS' readers see what they saw
+(FIND_CAT_SORT, FIND_CAT_SCAN,
+ FIND_CAT_ONEHOT) = FIND_CAT_PARTS = ("find.cat_sort", "find.cat_scan",
+                                      "find.cat_onehot")
 
 # What no scope can name: jax lowers ``cumsum`` through a function of its own
 # (``inline=False``), so the running sums' instructions carry the bare name

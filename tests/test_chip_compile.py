@@ -404,6 +404,43 @@ def test_grouped_chunk_program_compiles_with_the_search_on_group_lanes(
                 assert "700" not in dims.split(","), line[:300]
 
 
+def test_categorical_chunk_program_compiles_with_the_search_under_its_scopes(
+        one_chip, monkeypatch_module):
+    """The ``expo-cat`` chunk: 8 columns, six of them categorical (12 to 255
+    bins), so ``has_categorical`` puts the sorted many-vs-many search (a sort
+    of [8, 256] keys, two scans of ``max_cat_threshold`` steps, a scatter
+    back to bin order) inside the 254-step split loop.  The chip's compiler
+    takes it, the three parts stay under their scopes inside the loop's
+    ``tree.find_split``, and the scans run 32 steps, not 256."""
+    import re
+
+    import numpy as np
+    from lightgbm_tpu.io.dataset import BinnedDataset
+    from lightgbm_tpu.obs import categorical
+    from lightgbm_tpu.obs.scopes import FIND_CAT_PARTS
+    rng = np.random.default_rng(0)
+    n, levels = 1 << 16, (12, 31, 7, 29, 307, 312)
+    X = np.stack([rng.integers(0, lv, size=n) for lv in levels]
+                 + [rng.uniform(0, 24, size=n), rng.lognormal(6.4, 0.7, n)],
+                 axis=1).astype(np.float32)
+    ds = BinnedDataset.from_matrix(
+        X, label=(rng.random(n) < 0.4).astype(np.float32), max_bin=255,
+        min_data_in_leaf=0, categorical_feature=range(6))
+    assert ds.binned.shape == (n, 8) and ds.feature_is_categorical().sum() == 6
+    text = _chunk_program_text(one_chip, monkeypatch_module, ds)
+    assert categorical.counts()["cat.scan_steps"] == 32
+    for part in FIND_CAT_PARTS:
+        assert any("while/body" in ln and "tree.find_split" in ln
+                   for ln in text.splitlines() if part + ")/" in ln
+                   or part + "/" in ln), part
+    # where the compiler kept a direction's scan a loop, it runs 32 trips
+    trips = {int(n) for ln in text.splitlines() if "find.cat_scan" in ln
+             for n in re.findall(r"known_trip_count\D*(\d+)", ln)}
+    assert trips <= {32}, trips
+    assert " sort(" in "".join(ln for ln in text.splitlines()
+                               if "find.cat_sort" in ln)
+
+
 def test_predict_blocked_compiles(one_chip):
     """The blocked predict contraction at a 255-leaf block shape."""
     from lightgbm_tpu.core.predict import EnsembleArrays
