@@ -1254,6 +1254,12 @@ class GBDT:
         (``obs.scopes.op_scopes`` reads them), and a profiler trace names its
         device events by these instructions.  Lowers again and asks the
         compiler, which the persistent cache answers."""
+        lowered = self._lowered_chunk(num_iters)
+        return None if lowered is None else lowered.compile().as_text()
+
+    def _lowered_chunk(self, num_iters: int):
+        """That chunk program lowered and not yet compiled (its StableHLO is
+        the program as written, before any compiler's rewriting), or None."""
         fn = self._fused_cache.get(
             (num_iters, self.shrinkage_rate, self.num_tree_per_iteration,
              len(self.valid_sets)))
@@ -1261,7 +1267,7 @@ class GBDT:
             return None
         return fn.lower(self.train_score,
                         tuple(vs["score"] for vs in self.valid_sets),
-                        jnp.int32(0)).compile().as_text()
+                        jnp.int32(0))
 
     def _objective_traceable(self) -> bool:
         """Whether the objective's gradients trace under jit (an objective

@@ -374,45 +374,64 @@ def _bits_to_words(bits: jax.Array) -> jax.Array:
 
 
 def cat_scan_steps(num_bins: int, params: SplitParams) -> int:
-    """Steps a direction that the sorted many-vs-many scan of
-    :func:`per_feature_best_categorical` runs over ``num_bins`` bins."""
+    """Sorted positions a direction that the many-vs-many search of
+    :func:`per_feature_best_categorical` walks over ``num_bins`` bins."""
     return max(min(int(num_bins), int(params.max_cat_threshold)), 0)
+
+
+def _in_scan_order(x: jax.Array, start: float) -> jax.Array:
+    """Running sums of ``x`` ``[2, steps, F]`` along its second axis, from
+    ``start``: one add a position in the walk's own order (a ``cumsum`` is
+    free to associate otherwise and would round differently)."""
+    total = jnp.full(x.shape[:1] + x.shape[2:], start, x.dtype)
+    sums = []
+    for i in range(x.shape[1]):
+        total = total + x[:, i]
+        sums.append(total)
+    return jnp.stack(sums, axis=1)
 
 
 def per_feature_best_categorical(hist: jax.Array, feat: FeatureInfo,
                                  feature_mask: jax.Array, sum_grad: jax.Array,
                                  sum_hess: jax.Array, num_data: jax.Array,
                                  params: SplitParams,
-                                 cmin=None, cmax=None,
-                                 scan_steps=None) -> FeatureBest:
+                                 cmin=None, cmax=None) -> FeatureBest:
     """Best categorical split of each feature
     (feature_histogram.hpp:136-304 FindBestThresholdCategorical).
 
     One-hot mode for features with <= max_cat_to_onehot bins; otherwise the
-    sorted many-vs-many scan: bins with count >= cat_smooth sorted by
-    grad/(hess+cat_smooth), prefix-scanned from both ends up to
-    max_cat_threshold with the min_data_per_group batching.  The serial
-    two-direction scan becomes a vmapped lax.scan over the sorted positions.
-    Resulting left-bin sets are returned as bitsets.
+    sorted many-vs-many search: bins with count >= cat_smooth sorted by
+    grad/(hess+cat_smooth), walked from both ends up to max_cat_threshold
+    with the min_data_per_group batching.  Resulting left-bin sets are
+    returned as bitsets.
 
-    Each direction's scan runs :func:`cat_scan_steps` steps, ``min(B,
-    max_cat_threshold)`` (32 of 256 under the defaults), and that is exact:
-    a step is ``active`` only while ``i < min(max_cat_threshold, (used + 1)
-    // 2)``, and a step that is not active adds nothing to a sum, reaches no
-    group, offers no candidate and sets no stop, so every step from
-    ``max_cat_threshold`` on leaves the carried state as it found it.
-    ``scan_steps`` is for the test that holds the bounded scan to the whole
-    one bit for bit.
+    The sorted search is straight-line array code: no loop and no indexing
+    by data.  ONE stable sort carries ``g``, ``h``, the counts and the bin
+    index into sorted order.  A direction walks at most
+    :func:`cat_scan_steps` positions, ``min(B, max_cat_threshold)`` (32 of
+    256 under the defaults; a position is ``active`` only while ``i <
+    min(max_cat_threshold, (used + 1) // 2)``, so later ones change
+    nothing), and both directions' positions are taken once as a
+    ``[2, steps, F]`` window: the forward one a static slice, the backward
+    one, which starts at the data's ``used - 1``, selected by comparison.
+    On the window the reference's serial walk unrolls: the left sums are
+    running sums in the walk's order; the walk stops after the first active
+    position whose right side is too small; the ``min_data_per_group``
+    batching is the one true recurrence, a compare-select a position; every
+    position's candidate is priced at once and the first maximum wins (the
+    serial walk's strict ``>``).  The winner's bins are marked by comparing
+    its window's bin indices with every bin.  ``tests/
+    test_categorical_table.py`` holds every field to the serial walk bit
+    for bit.
 
     Its own named scopes, inside the caller's ``tree.find_split`` /
     ``tree.root``: ``find.cat_onehot`` (one category against the rest),
-    ``find.cat_sort`` (the key, the sort, the gathers through the order, and
-    the winning prefix scattered back to bin order and packed into words)
-    and ``find.cat_scan`` (the two directional scans and the left sums at
-    the winner)."""
+    ``find.cat_sort`` (the key, the sort, the two windows, and the winning
+    prefix marked in bin order and packed into words) and ``find.cat_scan``
+    (the walk of the two windows and the left sums at the winner)."""
     F, _, B = hist.shape
     p = params
-    steps = cat_scan_steps(B, p) if scan_steps is None else int(scan_steps)
+    steps = cat_scan_steps(B, p)
     g = hist[:, 0, :]
     h = hist[:, 1, :]
     total_h = sum_hess + 2 * K_EPSILON
@@ -450,71 +469,74 @@ def per_feature_best_categorical(hist: jax.Array, feat: FeatureInfo,
 
     # ---------- sorted many-vs-many (:191-268) ----------
     l2c = p.lambda_l2 + p.cat_l2
+    step = jnp.arange(steps, dtype=jnp.int32)[:, None]              # [steps, 1]
     with jax.named_scope(_scopes.FIND_CAT_SORT):
         valid_sort = in_range & (cnt >= p.cat_smooth)
         ctr = g / (h + p.cat_smooth)
         sort_key = jnp.where(valid_sort, ctr, jnp.inf)
-        order = jnp.argsort(sort_key, axis=1, stable=True).astype(jnp.int32)
+        # the order of argsort(sort_key, stable=True), its values carried
+        _, gs, hs, cs, order = jax.lax.sort(
+            (sort_key, g, h, cnt, jnp.broadcast_to(t, (F, B))),
+            dimension=1, is_stable=True, num_keys=1)
         used = valid_sort.sum(axis=1).astype(jnp.int32)             # [F]
         max_num_cat = jnp.minimum(p.max_cat_threshold, (used + 1) // 2)
 
-        gs = jnp.take_along_axis(g, order, axis=1)
-        hs = jnp.take_along_axis(h, order, axis=1)
-        cs = jnp.take_along_axis(cnt, order, axis=1)
+        # walk position i reads sorted position i forward and used - 1 - i
+        # backward: one term of each sum below is not zero, so it is exact
+        at_bwd = t[None] == jnp.maximum(used[None, :] - 1 - step,
+                                        0)[:, :, None]          # [steps, F, B]
 
-    def scan_dir(gs_f, hs_f, cs_f, used_f, maxcat_f, backward):
-        def idx(i):
-            return jnp.where(backward, jnp.maximum(used_f - 1 - i, 0), i)
-
-        def step(state, i):
-            sum_lg, sum_lh, left_c, cnt_grp, stop, bgain, bi = state
-            j = idx(i)
-            active = (i < used_f) & (i < maxcat_f) & ~stop
-            af = active.astype(jnp.float32)
-            sum_lg = sum_lg + gs_f[j] * af
-            sum_lh = sum_lh + hs_f[j] * af
-            left_c = left_c + cs_f[j] * af
-            cnt_grp = cnt_grp + cs_f[j] * af
-            cont1 = ((left_c < p.min_data_in_leaf)
-                     | (sum_lh < p.min_sum_hessian_in_leaf))
-            right_c = num_data_f - left_c
-            sum_rh = total_h - sum_lh
-            brk = ((right_c < p.min_data_in_leaf)
-                   | (right_c < p.min_data_per_group)
-                   | (sum_rh < p.min_sum_hessian_in_leaf))
-            reached_group = active & ~cont1 & ~brk & \
-                (cnt_grp >= p.min_data_per_group)
-            sum_rg = total_g - sum_lg
-            gain, _, _ = _split_gains_clamped(sum_lg, sum_lh, sum_rg, sum_rh,
-                                              p, l2c, cmin, cmax)
-            cand = reached_group & (gain > min_gain_shift) & (gain > bgain)
-            bgain = jnp.where(cand, gain, bgain)
-            bi = jnp.where(cand, i, bi)
-            cnt_grp = jnp.where(reached_group, 0.0, cnt_grp)
-            stop = stop | (active & brk)
-            return (sum_lg, sum_lh, left_c, cnt_grp, stop, bgain, bi), None
-
-        init = (jnp.float32(0), jnp.float32(K_EPSILON), jnp.float32(0),
-                jnp.float32(0), jnp.bool_(False), jnp.float32(K_MIN_SCORE),
-                jnp.int32(-1))
-        (slg, slh, lc, cg, st, bgain, bi), _ = jax.lax.scan(
-            step, init, jnp.arange(steps, dtype=jnp.int32))
-        return bgain, bi
+        def window(xs):
+            """[F, B] in sorted order -> [2 directions, steps, F]."""
+            bwd = jnp.sum(jnp.where(at_bwd, xs[None], 0), axis=2,
+                          dtype=xs.dtype)
+            return jnp.stack([xs[:, :steps].T, bwd])
+        wg, wh, wc, wbin = window(gs), window(hs), window(cs), window(order)
 
     with jax.named_scope(_scopes.FIND_CAT_SCAN):
-        vscan = jax.vmap(scan_dir, in_axes=(0, 0, 0, 0, 0, None))
-        fwd_gain, fwd_i = vscan(gs, hs, cs, used, max_num_cat, False)
-        bwd_gain, bwd_i = vscan(gs, hs, cs, used, max_num_cat, True)
-        use_bwd = bwd_gain > fwd_gain                                # fwd ties
-        so_gain = jnp.where(use_bwd, bwd_gain, fwd_gain)
-        so_i = jnp.where(use_bwd, bwd_i, fwd_i)
+        live = (step < used[None, :]) & (step < max_num_cat[None, :])
+        af = live.astype(jnp.float32)                               # [steps, F]
+        sum_lg = _in_scan_order(wg * af, 0.0)                   # [2, steps, F]
+        sum_lh = _in_scan_order(wh * af, K_EPSILON)
+        left_c = _in_scan_order(wc * af, 0.0)
+        cont1 = ((left_c < p.min_data_in_leaf)
+                 | (sum_lh < p.min_sum_hessian_in_leaf))
+        right_c = num_data_f - left_c
+        sum_rh = total_h - sum_lh
+        brk = ((right_c < p.min_data_in_leaf)
+               | (right_c < p.min_data_per_group)
+               | (sum_rh < p.min_sum_hessian_in_leaf))
+        # the walk stops after its first live position that breaks: up to
+        # there every live position was active and the sums above are the
+        # walk's own, past it nothing is a candidate
+        first_brk = jnp.min(jnp.where(live & brk, step, steps), axis=1,
+                            keepdims=True)
+        active = live & (step <= first_brk)
+        in_group = active & ~cont1 & ~brk
+        grp_c = wc * active.astype(jnp.float32)
+        cnt_grp = jnp.zeros((2, F), jnp.float32)
+        reached_group = []
+        for i in range(steps):
+            cnt_grp = cnt_grp + grp_c[:, i]
+            reached = in_group[:, i] & (cnt_grp >= p.min_data_per_group)
+            reached_group.append(reached)
+            cnt_grp = jnp.where(reached, 0.0, cnt_grp)
+        gain, _, _ = _split_gains_clamped(sum_lg, sum_lh, total_g - sum_lg,
+                                          sum_rh, p, l2c, cmin, cmax)
+        gain = jnp.where(jnp.stack(reached_group, axis=1)
+                         & (gain > min_gain_shift), gain, K_MIN_SCORE)
+        dir_gain = jnp.max(gain, axis=1)                            # [2, F]
+        dir_i = jnp.where(dir_gain > K_MIN_SCORE,
+                          jnp.argmax(gain, axis=1).astype(jnp.int32), -1)
+        use_bwd = dir_gain[1] > dir_gain[0]                          # fwd ties
+        so_gain = jnp.where(use_bwd, dir_gain[1], dir_gain[0])
+        so_i = jnp.where(use_bwd, dir_i[1], dir_i[0])
 
         # recompute left sums at the winning prefix (inclusive of so_i)
-        pos = jnp.arange(B, dtype=jnp.int32)[None, :]
         in_prefix = jnp.where(use_bwd[:, None],
-                              (pos >= jnp.maximum(used - 1 - so_i, 0)[:, None])
-                              & (pos < used[:, None]),
-                              pos <= so_i[:, None])
+                              (t >= jnp.maximum(used - 1 - so_i, 0)[:, None])
+                              & (t < used[:, None]),
+                              t <= so_i[:, None])
         in_prefix &= so_i[:, None] >= 0
         so_lg = jnp.sum(jnp.where(in_prefix, gs, 0.0), axis=1)
         so_lh = jnp.sum(jnp.where(in_prefix, hs, 0.0), axis=1) + K_EPSILON
@@ -536,13 +558,13 @@ def per_feature_best_categorical(hist: jax.Array, feat: FeatureInfo,
         l_out = jnp.clip(l_out, cmin, cmax)
         r_out = jnp.clip(r_out, cmin, cmax)
 
-    # left-bin bitsets: one-hot -> {oh_t}; sorted -> prefix through order
+    # left-bin bitsets: one-hot -> {oh_t}; sorted -> the bins of the
+    # winner's window up to so_i (so_i = -1: none)
     with jax.named_scope(_scopes.FIND_CAT_SORT):
         bits_oh = t == oh_t[:, None]
-        bits_sorted = jnp.zeros((F, B), dtype=bool)
-        scatter_f = jnp.broadcast_to(fidx[:, None], (F, B)).reshape(-1)
-        bits_sorted = bits_sorted.at[scatter_f, order.reshape(-1)].set(
-            in_prefix.reshape(-1))
+        win_bin = jnp.where(use_bwd[None, :], wbin[1], wbin[0])     # [steps, F]
+        bits_sorted = jnp.any((step <= so_i[None, :])[:, :, None]
+                              & (win_bin[:, :, None] == t[None]), axis=0)
         bits = jnp.where(oh[:, None], bits_oh, bits_sorted)
         words = _bits_to_words(bits)
 

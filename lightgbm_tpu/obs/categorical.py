@@ -12,10 +12,10 @@ telemetry run::
 
 ``cat.features`` are the used features binned as categories and ``cat.bins``
 the sum of their bins: what one leaf's search sorts.  ``cat.scan_steps`` are
-the steps a direction that the compiled many-vs-many scan runs
+the sorted positions a direction that the many-vs-many search walks
 (``core/split.py::cat_scan_steps``: ``min(feature bins,
-max_cat_threshold)``); 0 for a learner with no categorical feature, whose
-programs trace none of the search.  ``cat.splits`` are the categorical splits
+max_cat_threshold)``, the lanes of one window and no loop's trips); 0 for a
+learner with no categorical feature, whose programs trace none of the search.  ``cat.splits`` are the categorical splits
 of the trees finished since :func:`reset` and ``cat.onehot_splits`` those of
 them on a feature searched one category against the rest (``num_bin <=
 max_cat_to_onehot``); the rest are many-vs-many.

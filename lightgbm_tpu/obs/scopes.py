@@ -78,7 +78,11 @@ KERNEL_REGIONS: Tuple[Region, ...] = (
 CHUNK_SCORE_OUT, = CHUNK_PARTS = ("chunk.score_out",)
 # the categorical search's parts (core/split.py
 # per_feature_best_categorical), opened only by a program with a categorical
-# feature: a list of their own, so FIND_PARTS' readers see what they saw
+# feature: a list of their own, so FIND_PARTS' readers see what they saw.
+# find.cat_sort: the key, the one sort that carries its values, the two
+# windows of sorted positions, the winner's bins marked and packed into words;
+# find.cat_scan: the walk of the windows (no loop) and the left sums at the
+# winner; find.cat_onehot: one category against the rest
 (FIND_CAT_SORT, FIND_CAT_SCAN,
  FIND_CAT_ONEHOT) = FIND_CAT_PARTS = ("find.cat_sort", "find.cat_scan",
                                       "find.cat_onehot")

@@ -5,9 +5,11 @@ interpret mode, held to the plain grower and walk of
 the bounded many-vs-many scan against the whole one; the cell
 ``expo_cat_train``'s layout, kind, counters, scopes and controls.
 """
+import collections
 import copy
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -25,10 +27,14 @@ import plain_categorical as pc  # noqa: E402
 from lightgbm_tpu.boosting.gbdt import GBDT  # noqa: E402
 from lightgbm_tpu.config import Config  # noqa: E402
 from lightgbm_tpu.core import split as S  # noqa: E402
+from lightgbm_tpu.core.split import (  # noqa: E402  (the oracle's names)
+    K_EPSILON, K_MIN_SCORE, FeatureBest, _bits_to_words, _leaf_output_l2,
+    _split_gains_clamped, cat_scan_steps, leaf_split_gain)
 from lightgbm_tpu.io.binning import MissingType  # noqa: E402
+from lightgbm_tpu.obs import scopes as _scopes  # noqa: E402
 from lightgbm_tpu.io.dataset import BinnedDataset  # noqa: E402
 from lightgbm_tpu.obs import categorical  # noqa: E402
-from lightgbm_tpu.obs.scopes import FIND_CAT_PARTS  # noqa: E402
+from lightgbm_tpu.obs.scopes import _TRANSFORMED, FIND_CAT_PARTS  # noqa: E402
 from lightgbm_tpu.objective import create_objective  # noqa: E402
 
 ROWS = 4096
@@ -174,9 +180,173 @@ def test_leaf_values_carry_cat_l2_where_a_many_vs_many_split_made_them(case):
     assert np.max(np.abs(got - want)) <= 1e-5
 
 
-# ---- the bounded scan ------------------------------------------------------
+# ---- the oracle: the serial walk as the program ran it until PR 42 ---------
 
-def _search_inputs(seed, F=5, bins=256, used=(200, 90, 40, 3, 33)):
+def loop_search(hist, feat, feature_mask, sum_grad, sum_hess, num_data,
+                params, cmin=None, cmax=None, scan_steps=None):
+    """``per_feature_best_categorical`` as it stood at PR 41, body verbatim:
+    ``argsort`` and three gathers through the order, a vmapped ``lax.scan`` a
+    direction over ``scan_steps`` sorted positions (all of them at 256), the
+    winning prefix scattered back to bin order.  The reference the straight-
+    line search is held to, bit for bit."""
+    F, _, B = hist.shape
+    p = params
+    steps = cat_scan_steps(B, p) if scan_steps is None else int(scan_steps)
+    g = hist[:, 0, :]
+    h = hist[:, 1, :]
+    total_h = sum_hess + 2 * K_EPSILON
+    total_g = sum_grad
+    num_data_f = num_data.astype(jnp.float32)
+    cnt_factor = num_data_f / total_h
+    cnt = jnp.round(h * cnt_factor)
+
+    is_full = feat.missing_type == int(MissingType.NONE)
+    used_bin = feat.num_bin - 1 + is_full.astype(jnp.int32)     # [F]
+    t = jnp.arange(B, dtype=jnp.int32)[None, :]
+    in_range = t < used_bin[:, None]
+
+    gain_shift = leaf_split_gain(total_g, total_h, p.lambda_l1, p.lambda_l2,
+                                 p.max_delta_step)
+    min_gain_shift = gain_shift + p.min_gain_to_split
+    use_onehot = feat.num_bin <= p.max_cat_to_onehot                # [F]
+
+    # ---------- one-hot: category t vs rest (:157-189) ----------
+    fidx = jnp.arange(F)
+    with jax.named_scope(_scopes.FIND_CAT_ONEHOT):
+        other_g = total_g - g
+        other_h = total_h - h - K_EPSILON
+        other_cnt = num_data_f - cnt
+        ok1 = (in_range & (cnt >= p.min_data_in_leaf)
+               & (h >= p.min_sum_hessian_in_leaf)
+               & (other_cnt >= p.min_data_in_leaf)
+               & (other_h >= p.min_sum_hessian_in_leaf))
+        oh_gain, oh_lo, oh_ro = _split_gains_clamped(
+            g, h + K_EPSILON, other_g, other_h, p, p.lambda_l2, cmin, cmax)
+        oh_gain = jnp.where(ok1 & (oh_gain > min_gain_shift), oh_gain,
+                            K_MIN_SCORE)
+        oh_t = jnp.argmax(oh_gain, axis=1).astype(jnp.int32)        # first max
+        oh_best = oh_gain[fidx, oh_t]
+
+    # ---------- sorted many-vs-many (:191-268) ----------
+    l2c = p.lambda_l2 + p.cat_l2
+    with jax.named_scope(_scopes.FIND_CAT_SORT):
+        valid_sort = in_range & (cnt >= p.cat_smooth)
+        ctr = g / (h + p.cat_smooth)
+        sort_key = jnp.where(valid_sort, ctr, jnp.inf)
+        order = jnp.argsort(sort_key, axis=1, stable=True).astype(jnp.int32)
+        used = valid_sort.sum(axis=1).astype(jnp.int32)             # [F]
+        max_num_cat = jnp.minimum(p.max_cat_threshold, (used + 1) // 2)
+
+        gs = jnp.take_along_axis(g, order, axis=1)
+        hs = jnp.take_along_axis(h, order, axis=1)
+        cs = jnp.take_along_axis(cnt, order, axis=1)
+
+    def scan_dir(gs_f, hs_f, cs_f, used_f, maxcat_f, backward):
+        def idx(i):
+            return jnp.where(backward, jnp.maximum(used_f - 1 - i, 0), i)
+
+        def step(state, i):
+            sum_lg, sum_lh, left_c, cnt_grp, stop, bgain, bi = state
+            j = idx(i)
+            active = (i < used_f) & (i < maxcat_f) & ~stop
+            af = active.astype(jnp.float32)
+            sum_lg = sum_lg + gs_f[j] * af
+            sum_lh = sum_lh + hs_f[j] * af
+            left_c = left_c + cs_f[j] * af
+            cnt_grp = cnt_grp + cs_f[j] * af
+            cont1 = ((left_c < p.min_data_in_leaf)
+                     | (sum_lh < p.min_sum_hessian_in_leaf))
+            right_c = num_data_f - left_c
+            sum_rh = total_h - sum_lh
+            brk = ((right_c < p.min_data_in_leaf)
+                   | (right_c < p.min_data_per_group)
+                   | (sum_rh < p.min_sum_hessian_in_leaf))
+            reached_group = active & ~cont1 & ~brk & \
+                (cnt_grp >= p.min_data_per_group)
+            sum_rg = total_g - sum_lg
+            gain, _, _ = _split_gains_clamped(sum_lg, sum_lh, sum_rg, sum_rh,
+                                              p, l2c, cmin, cmax)
+            cand = reached_group & (gain > min_gain_shift) & (gain > bgain)
+            bgain = jnp.where(cand, gain, bgain)
+            bi = jnp.where(cand, i, bi)
+            cnt_grp = jnp.where(reached_group, 0.0, cnt_grp)
+            stop = stop | (active & brk)
+            return (sum_lg, sum_lh, left_c, cnt_grp, stop, bgain, bi), None
+
+        init = (jnp.float32(0), jnp.float32(K_EPSILON), jnp.float32(0),
+                jnp.float32(0), jnp.bool_(False), jnp.float32(K_MIN_SCORE),
+                jnp.int32(-1))
+        (slg, slh, lc, cg, st, bgain, bi), _ = jax.lax.scan(
+            step, init, jnp.arange(steps, dtype=jnp.int32))
+        return bgain, bi
+
+    with jax.named_scope(_scopes.FIND_CAT_SCAN):
+        vscan = jax.vmap(scan_dir, in_axes=(0, 0, 0, 0, 0, None))
+        fwd_gain, fwd_i = vscan(gs, hs, cs, used, max_num_cat, False)
+        bwd_gain, bwd_i = vscan(gs, hs, cs, used, max_num_cat, True)
+        use_bwd = bwd_gain > fwd_gain                                # fwd ties
+        so_gain = jnp.where(use_bwd, bwd_gain, fwd_gain)
+        so_i = jnp.where(use_bwd, bwd_i, fwd_i)
+
+        # recompute left sums at the winning prefix (inclusive of so_i)
+        pos = jnp.arange(B, dtype=jnp.int32)[None, :]
+        in_prefix = jnp.where(use_bwd[:, None],
+                              (pos >= jnp.maximum(used - 1 - so_i, 0)[:, None])
+                              & (pos < used[:, None]),
+                              pos <= so_i[:, None])
+        in_prefix &= so_i[:, None] >= 0
+        so_lg = jnp.sum(jnp.where(in_prefix, gs, 0.0), axis=1)
+        so_lh = jnp.sum(jnp.where(in_prefix, hs, 0.0), axis=1) + K_EPSILON
+        so_lc = jnp.sum(jnp.where(in_prefix, cs, 0.0), axis=1)
+
+    # ---------- combine one-hot / sorted per feature ----------
+    oh = use_onehot
+    cat_gain = jnp.where(oh, oh_best, so_gain)
+    l_g = jnp.where(oh, g[fidx, oh_t], so_lg)
+    l_h = jnp.where(oh, h[fidx, oh_t] + K_EPSILON, so_lh)
+    l_c = jnp.where(oh, cnt[fidx, oh_t], so_lc)
+    eff_l2 = jnp.where(oh, p.lambda_l2, l2c)
+    r_g = total_g - l_g
+    r_h = total_h - l_h
+    r_c = num_data_f - l_c
+    l_out = _leaf_output_l2(l_g, l_h, p, eff_l2)
+    r_out = _leaf_output_l2(r_g, r_h, p, eff_l2)
+    if cmin is not None:
+        l_out = jnp.clip(l_out, cmin, cmax)
+        r_out = jnp.clip(r_out, cmin, cmax)
+
+    # left-bin bitsets: one-hot -> {oh_t}; sorted -> prefix through order
+    with jax.named_scope(_scopes.FIND_CAT_SORT):
+        bits_oh = t == oh_t[:, None]
+        bits_sorted = jnp.zeros((F, B), dtype=bool)
+        scatter_f = jnp.broadcast_to(fidx[:, None], (F, B)).reshape(-1)
+        bits_sorted = bits_sorted.at[scatter_f, order.reshape(-1)].set(
+            in_prefix.reshape(-1))
+        bits = jnp.where(oh[:, None], bits_oh, bits_sorted)
+        words = _bits_to_words(bits)
+
+    found = (cat_gain > K_MIN_SCORE) & feature_mask & feat.is_categorical
+    zero = jnp.zeros((F,), jnp.float32)
+    return FeatureBest(
+        gain=jnp.where(found, cat_gain - min_gain_shift, K_MIN_SCORE),
+        threshold=jnp.where(oh, oh_t, so_i + 1).astype(jnp.int32),
+        default_left=jnp.zeros((F,), bool),
+        left_sum_grad=jnp.where(found, l_g, zero),
+        left_sum_hess=jnp.where(found, l_h - K_EPSILON, zero),
+        left_count=jnp.where(found, l_c, zero),
+        right_sum_grad=jnp.where(found, r_g, zero),
+        right_sum_hess=jnp.where(found, r_h - K_EPSILON, zero),
+        right_count=jnp.where(found, r_c, zero),
+        left_output=l_out,
+        right_output=r_out,
+        cat_bitset=jnp.where(found[:, None], words, 0).astype(jnp.uint32),
+    )
+
+
+# ---- the straight-line search against the oracle ---------------------------
+
+def _search_inputs(seed, F=5, bins=256, used=(200, 90, 40, 3, 33),
+                   missing=None, ties=False):
     rng = np.random.default_rng(seed)
     num_bin = np.asarray(used, np.int32)
     live = np.arange(bins)[None, :] < num_bin[:, None]
@@ -184,39 +354,237 @@ def _search_inputs(seed, F=5, bins=256, used=(200, 90, 40, 3, 33)):
     h = (0.25 * rows).astype(np.float32)
     g = np.where(live, rng.normal(size=(F, bins)) * np.sqrt(rows + 1.0),
                  0.0).astype(np.float32)
+    if ties:
+        # a few values of g over one of h: most sort keys are shared
+        h = np.where(live & (rows >= 40), 50.0, 0.0).astype(np.float32)
+        g = np.where(h > 0, np.round(g / 8.0) * 8.0, 0.0).astype(np.float32)
     hist = jnp.asarray(np.stack([g, h], axis=1))
+    if missing is None:
+        missing = [int(MissingType.NONE), int(MissingType.NAN),
+                   int(MissingType.NONE), int(MissingType.NONE),
+                   int(MissingType.NAN)]
     feat = S.FeatureInfo(
         num_bin=jnp.asarray(num_bin),
-        missing_type=jnp.asarray([int(MissingType.NONE), int(MissingType.NAN),
-                                  int(MissingType.NONE), int(MissingType.NONE),
-                                  int(MissingType.NAN)], jnp.int32),
+        missing_type=jnp.asarray(missing, jnp.int32),
         default_bin=jnp.zeros(F, jnp.int32),
         is_categorical=jnp.ones(F, bool))
-    return hist, feat, jnp.ones(F, bool), jnp.float32(g.sum(axis=1)[0]), \
-        jnp.float32(h.sum(axis=1)[0]), jnp.int32(rows.sum(axis=1)[0])
-
-
-@pytest.mark.parametrize("seed,how", [
-    (0, {}), (1, {"min_data_per_group": 1000}), (2, {"cat_smooth": 150.0}),
-    (3, {"max_cat_threshold": 7}), (4, {"min_sum_hessian_in_leaf": 900.0})])
-def test_the_bounded_scan_is_the_whole_scan_bit_for_bit(seed, how):
-    hist, feat, mask, sg, sh, n = _search_inputs(seed)
-    # one total for every feature: make each feature's bins add up to it
+    # one total for every feature: each feature's bins add up to feature 0's
+    # (the rest goes to bin 255, past every feature's range)
     hist = hist.at[1:, :, 255].add(hist[0].sum(axis=1)[None, :]
                                    - hist[1:].sum(axis=2))
-    p = S.SplitParams(**dict(dict(min_data_in_leaf=0,
-                                  min_sum_hessian_in_leaf=100.0), **how))
-    assert S.cat_scan_steps(256, p) == min(32, p.max_cat_threshold)
-    run = jax.jit(S.per_feature_best_categorical, static_argnums=(6, 9))
-    bounded = run(hist, feat, mask, sg, sh, n, p, None, None, None)
-    whole = run(hist, feat, mask, sg, sh, n, p, None, None, 256)
-    sortable = (np.asarray(hist[:, 1, :]) * float(n) / float(sh) >= p.cat_smooth
-                ).sum(axis=1)
-    assert sortable.max() > 2 * 32, sortable    # the bound cuts real steps
-    assert np.isfinite(np.asarray(bounded.gain)).sum() >= 2
-    for name, a, b in zip(bounded._fields, bounded, whole):
+    n = 4.0 * float(hist[0, 1].sum())             # 4 rows a unit of hessian
+    return hist, feat, jnp.ones(F, bool), jnp.float32(hist[0, 0].sum()), \
+        jnp.float32(hist[0, 1].sum()), jnp.int32(round(n))
+
+
+def _sorted_keys(hist, feat, n, sh, p):
+    """(sort key of every bin with inf where it is not sortable) as the
+    search makes them, in NumPy."""
+    g, h = np.asarray(hist[:, 0, :]), np.asarray(hist[:, 1, :])
+    cnt = np.round(h * (np.float32(n) / (np.float32(sh) + 2e-15)))
+    full = np.asarray(feat.missing_type) == int(MissingType.NONE)
+    used_bin = np.asarray(feat.num_bin) - 1 + full
+    ok = (np.arange(h.shape[1])[None, :] < used_bin[:, None]) \
+        & (cnt >= p.cat_smooth)
+    return np.where(ok, g / (h + np.float32(p.cat_smooth)), np.inf)
+
+
+def _left_bins(fb):
+    """[F, B] bool out of the bitset words."""
+    words = np.asarray(fb.cat_bitset)
+    return ((words[:, :, None] >> np.arange(32, dtype=np.uint32)) & 1
+            ).astype(bool).reshape(words.shape[0], -1)
+
+
+BASE = dict(min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0)
+# (seed, SplitParams over BASE; keys that are none of its fields shape the
+# input): the first five are PR 41's, which held 32 steps to 256
+SEARCHES = [
+    (0, {}), (1, {"min_data_per_group": 1000}), (2, {"cat_smooth": 150.0}),
+    (3, {"max_cat_threshold": 7}), (4, {"min_sum_hessian_in_leaf": 900.0}),
+    pytest.param(5, {"bounds": (-0.02, 0.03)}, id="monotone_bounds"),
+    pytest.param(6, {"max_cat_threshold": 64}, id="max_cat_threshold_64"),
+    pytest.param(7, {"used": (200, 1, 40, 5, 33),
+                     "min_sum_hessian_in_leaf": 20.0},
+                 id="no_sortable_bin_and_five"),
+    pytest.param(8, {"backward": True}, id="the_backward_walk_wins"),
+    pytest.param(9, {"ties": True, "min_sum_hessian_in_leaf": 200.0},
+                 id="tied_sort_keys"),
+    pytest.param(10, {"min_data_per_group": 450,
+                      "min_sum_hessian_in_leaf": 10.0},
+                 id="the_batching_resets_several_times"),
+    pytest.param(11, {"missing": [int(MissingType.NAN)] * 5},
+                 id="nan_bin_features"),
+    pytest.param(12, {"children": True}, id="vmapped_over_two_children"),
+]
+SHAPES = ("bounds", "used", "backward", "ties", "missing", "children")
+
+
+@pytest.mark.parametrize("seed,how", SEARCHES)
+def test_the_bounded_scan_is_the_whole_scan_bit_for_bit(seed, how):
+    """Every field of the straight-line search equals the serial walk's at
+    256 steps, bit for bit."""
+    p = S.SplitParams(**dict(BASE, **{k: v for k, v in how.items()
+                                      if k not in SHAPES}))
+    steps = S.cat_scan_steps(256, p)
+    assert steps == min(256, p.max_cat_threshold)
+    inputs = {k: how[k] for k in ("used", "missing", "ties") if k in how}
+    hist, feat, mask, sg, sh, n = _search_inputs(seed, **inputs)
+    cmin, cmax = how.get("bounds", (None, None))
+
+    def search(fn, **kw):
+        def one(hist, sg, sh, n):
+            return fn(hist, feat, mask, sg, sh, n, p,
+                      None if cmin is None else jnp.float32(cmin),
+                      None if cmax is None else jnp.float32(cmax), **kw)
+        return jax.jit(jax.vmap(one) if how.get("children") else one)
+
+    if how.get("children"):
+        # the two children of a split, as tree_learner's best_of is vmapped
+        other = _search_inputs(seed + 100)
+        hist, sg, sh, n = (jnp.stack([a, b]) for a, b in zip(
+            (hist, sg, sh, n), (other[0],) + other[3:]))
+    new = search(S.per_feature_best_categorical)(hist, sg, sh, n)
+    whole = search(loop_search, scan_steps=256)(hist, sg, sh, n)
+    for name, a, b in zip(new._fields, new, whole):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
-    assert np.asarray(bounded.cat_bitset).any()
+    assert np.asarray(new.cat_bitset).any()
+    if how.get("children"):
+        assert not np.array_equal(np.asarray(new.gain[0]),
+                                  np.asarray(new.gain[1]))
+        return
+    keys = _sorted_keys(hist, feat, n, sh, p)
+    sortable = np.isfinite(keys).sum(axis=1)
+    found = np.isfinite(np.asarray(new.gain))
+    if "used" in how:
+        assert sortable[1] == 0 and not found[1]
+        assert sortable[3] == 5 and found[3]     # 3 steps a side of 5: overlap
+    else:
+        assert sortable.max() > 2 * steps, sortable  # positions never walked
+        assert found.sum() >= 2
+    left = _left_bins(new)
+    many = found & (np.asarray(feat.num_bin) > p.max_cat_to_onehot)
+    # a winner of the backward walk holds the bin of the largest key and
+    # not that of the smallest
+    top = np.where(np.isfinite(keys), keys, -np.inf).argmax(axis=1)
+    backward = many & left[np.arange(len(top)), top] \
+        & ~left[np.arange(len(top)), keys.argmin(axis=1)]
+    if how.get("backward"):
+        assert backward.any()
+    if how.get("ties"):
+        shared = [len(np.unique(k[np.isfinite(k)])) < np.isfinite(k).sum() / 2
+                  for k in keys[many]]
+        assert many.any() and all(shared)
+    if "min_data_per_group" in how and how["min_data_per_group"] < 1000:
+        # a winner several batches in: its left side holds three groups
+        assert (np.asarray(new.left_count)[many]
+                >= 3 * p.min_data_per_group).any()
+
+
+# ---- the structure: what the lowered search holds --------------------------
+
+_LOC_DEF = re.compile(r'^(#loc\d+) = loc\("([^"]*)"')
+_LOC_REF = re.compile(r'loc\((#loc\d+)\)\s*$')
+_OP = re.compile(r'(?:^|[\s"])(?:(?:stablehlo|chlo)\.([a-z_]+)|(call) @([\w.$-]+))')
+_FUNC = re.compile(r'^\s*func\.func\s+(?:public |private )?@([\w.$-]+)')
+SEARCH = (_scopes.FIND_CAT_SORT, _scopes.FIND_CAT_SCAN)
+INDEXED_OR_LOOPED = ("while", "gather", "scatter", "dynamic_slice",
+                     "dynamic_gather", "dynamic_update_slice")
+
+
+def ops_under(text, scopes, within=()):
+    """Counter of the StableHLO op names of ``text`` (a lowered program's
+    ``as_text(debug_info=True)``) at a location whose path holds one of
+    ``scopes`` and every one of ``within``, with the ops of the functions
+    called from there."""
+    lines = text.splitlines()
+    paths = dict(m.groups() for m in map(_LOC_DEF.match, lines) if m)
+
+    def wanted(ref):
+        parts = _TRANSFORMED.sub(r"\1", paths.get(ref, "")).split("/")
+        return any(s in parts for s in scopes) and all(
+            w in parts for w in within)
+    ops, func = collections.defaultdict(list), None  # a function's (op, loc, callee)
+    for k, line in enumerate(lines):
+        opens = _FUNC.match(line)
+        if opens:
+            func = opens.group(1)
+        op = None if opens or line.lstrip().startswith(("#loc", "}")) \
+            else _OP.search(line)
+        if op is None:
+            continue
+        ref = _LOC_REF.search(line)
+        if ref is None:          # an op with regions: its loc closes the last
+            close = " " * (len(line) - len(line.lstrip())) + "}"
+            ref = next(filter(None, (_LOC_REF.search(later)
+                                     for later in lines[k + 1:]
+                                     if later.startswith(close))), None)
+        ops[func].append((op.group(1) or op.group(2), ref and ref.group(1),
+                          op.group(3)))
+    found = collections.Counter()
+
+    def take(func, everything):
+        for name, ref, callee in ops[func]:
+            if everything or wanted(ref):
+                found[name] += 1
+                if callee:
+                    take(callee, True)
+    for func in list(ops):
+        take(func, False)
+    return found
+
+
+def _lowered_search(fn):
+    F = 8
+    feat = S.FeatureInfo(
+        num_bin=jnp.full(F, 200, jnp.int32),
+        missing_type=jnp.zeros(F, jnp.int32),
+        default_bin=jnp.zeros(F, jnp.int32), is_categorical=jnp.ones(F, bool))
+    return jax.jit(fn, static_argnums=(6,)).lower(
+        jnp.zeros((F, 2, 256)), feat, jnp.ones(F, bool), jnp.float32(1),
+        jnp.float32(1), jnp.int32(1), S.SplitParams()
+    ).as_text(debug_info=True)
+
+
+def test_the_lowered_search_is_one_sort_and_no_loop_gather_or_scatter():
+    text = _lowered_search(S.per_feature_best_categorical)
+    found = ops_under(text, SEARCH)
+    assert found["sort"] == 1 and found["reduce"] and found["compare"], found
+    assert not [op for op in INDEXED_OR_LOOPED if found[op]], found
+    for scope in SEARCH:        # both still hold what a trace can time
+        assert sum(ops_under(text, (scope,)).values()) > 20, scope
+    # ... and the reading sees them where they are: the serial walk's program
+    was = ops_under(_lowered_search(loop_search), SEARCH)
+    assert was["sort"] == 1 and was["while"] == 2, was
+    assert was["gather"] and was["scatter"] and was["dynamic_slice"], was
+
+
+def test_the_chunk_programs_search_is_one_sort_a_site_and_no_loop():
+    ds, g, *_ = trained("many_vs_many")
+    text = g._lowered_chunk(TREES).as_text(debug_info=True)
+    for site in ("tree.root", "tree.find_split"):
+        found = ops_under(text, SEARCH, within=(site,))
+        assert found["sort"] == 1 and found["reduce"], (site, found)
+        assert not [op for op in INDEXED_OR_LOOPED if found[op]], (site, found)
+    # the split loop itself is a while outside the search, so they are seen
+    assert ops_under(text, ("tree.find_split",))["compare"]
+    assert sum(ops_under(text, ("tree.finish", "tree.store")).values())
+
+
+def test_a_numerical_tables_chunk_program_holds_no_search_scope():
+    rng = np.random.default_rng(1)
+    X = rng.normal(size=(ROWS, 3)).astype(np.float32)
+    nds = BinnedDataset.from_matrix(X, label=(X[:, 0] + X[:, 1] > 0).astype(
+        np.float32), max_bin=63)
+    cfg = Config(verbosity=-1, objective="binary", num_leaves=7,
+                 min_data_in_leaf=5)
+    n = GBDT(cfg, nds, create_objective("binary", cfg))
+    n.learner.use_pallas = n.learner.pallas_interpret = True
+    n.train_chunk(2)
+    text = n._lowered_chunk(2).as_text(debug_info=True)
+    assert not sum(ops_under(text, FIND_CAT_PARTS).values())
+    assert "find.cat_" not in text
+    assert ops_under(text, ("tree.find_split",))["compare"]
 
 
 # ---- the counters ----------------------------------------------------------

@@ -407,11 +407,13 @@ def test_grouped_chunk_program_compiles_with_the_search_on_group_lanes(
 def test_categorical_chunk_program_compiles_with_the_search_under_its_scopes(
         one_chip, monkeypatch_module):
     """The ``expo-cat`` chunk: 8 columns, six of them categorical (12 to 255
-    bins), so ``has_categorical`` puts the sorted many-vs-many search (a sort
-    of [8, 256] keys, two scans of ``max_cat_threshold`` steps, a scatter
-    back to bin order) inside the 254-step split loop.  The chip's compiler
-    takes it, the three parts stay under their scopes inside the loop's
-    ``tree.find_split``, and the scans run 32 steps, not 256."""
+    bins), so ``has_categorical`` puts the sorted many-vs-many search (one
+    sort of [8, 256] keys that carries its values, the walk of two windows of
+    ``max_cat_threshold`` sorted positions, the winner's bins marked by
+    comparison) inside the 254-step split loop.  The chip's compiler takes
+    it, the three parts stay under their scopes inside the loop's
+    ``tree.find_split``, and what it makes of the sorted search holds one
+    sort a site and no loop, gather or scatter (PR 42)."""
     import re
 
     import numpy as np
@@ -433,12 +435,15 @@ def test_categorical_chunk_program_compiles_with_the_search_under_its_scopes(
         assert any("while/body" in ln and "tree.find_split" in ln
                    for ln in text.splitlines() if part + ")/" in ln
                    or part + "/" in ln), part
-    # where the compiler kept a direction's scan a loop, it runs 32 trips
-    trips = {int(n) for ln in text.splitlines() if "find.cat_scan" in ln
-             for n in re.findall(r"known_trip_count\D*(\d+)", ln)}
-    assert trips <= {32}, trips
-    assert " sort(" in "".join(ln for ln in text.splitlines()
-                               if "find.cat_sort" in ln)
+    searched = [ln for ln in text.splitlines()
+                if "find.cat_sort" in ln or "find.cat_scan" in ln]
+    assert not [ln for ln in searched if re.search(
+        r" (while|gather|scatter|dynamic-slice)\(|"
+        r"/(while|gather|scatter|dynamic_slice)\"|known_trip_count", ln)]
+    sorts = [ln for ln in searched if " sort(" in ln]
+    assert len(sorts) == 2, sorts           # the root's and the split loop's
+    assert sum("tree.find_split" in ln for ln in sorts) == 1
+    assert sum("tree.root" in ln for ln in sorts) == 1
 
 
 def test_predict_blocked_compiles(one_chip):
