@@ -24,28 +24,63 @@ def quiet():
     Log.reset_level(Log.level_from_verbosity(-1))
 
 
+def tree_index(wl, key, warmup, unit):
+    """The traffic file's tree count under ``key``, or None where it states
+    none.  It has to be the ``warmup`` trees plus a whole number of units of
+    ``unit`` trees, at least one: anything else is an error, not a rounding,
+    since the point is that every commit runs the same trees."""
+    if wl.get(key) is None:
+        return None
+    index = int(wl[key])
+    if index <= warmup or (index - warmup) % unit:
+        raise ValueError(
+            "%s %d is not the %d warm-up trees plus a whole number (1 or "
+            "more) of units of %d trees" % (key, index, warmup, unit))
+    return index
+
+
 def trace_first_tree(wl, warmup, unit):
     """The tree index at which a traced run of the traffic mix ``wl`` starts
     its traced units, or None for a mix that states none (it cannot be
-    traced).  It has to be the ``warmup`` trees plus a whole number of units
-    of ``unit`` trees, at least one: anything else is an error, not a
-    rounding, since the point is that every commit traces the same trees."""
-    if wl.get("trace_first_tree") is None:
-        return None
-    first = int(wl["trace_first_tree"])
-    if first <= warmup or (first - warmup) % unit:
-        raise ValueError(
-            "trace_first_tree %d is not the %d warm-up trees plus a whole "
-            "number (1 or more) of units of %d trees" % (first, warmup, unit))
-    return first
+    traced)."""
+    return tree_index(wl, "trace_first_tree", warmup, unit)
+
+
+def window_end_tree(wl, warmup, unit):
+    """The tree count at which an untraced run of the traffic mix ``wl`` ends
+    its window, or None for a mix that states none (its window goes by the
+    clock)."""
+    return tree_index(wl, "window_end_tree", warmup, unit)
+
+
+# a window that ends at a tree count still ends once this many times
+# ``--seconds`` have passed, at the last finished unit
+CEILING = 3
 
 
 def untraced_goes_on(job, tracer, seconds, trees):
     """Whether the stretch of a run after the warm-up goes on once the booster
-    holds ``trees`` trees: by the clock in a run that is not traced, up to
-    the traffic file's ``trace_first_tree`` in one that is."""
+    holds ``trees`` trees.  In a run that is not traced: up to the traffic
+    file's ``window_end_tree``, or until ``CEILING`` x ``seconds`` have passed
+    if that comes first (said on standard output); by the clock alone where
+    the job has no such tree.  The tree at which such a window ends, its own
+    or the ceiling's, is kept as ``job.window_ended_at`` for
+    :func:`window_check`.
+    In a traced run: up to the traffic file's ``trace_first_tree``."""
     if tracer is None:
-        return clock() - job.t_start < seconds
+        elapsed = clock() - job.t_start
+        end = getattr(job, "window_end_tree", None)
+        if end is None:
+            return elapsed < seconds
+        goes_on = trees < end
+        if goes_on and elapsed >= CEILING * seconds:
+            print("ceiling: window cut at tree %d, %.3f s after it opened "
+                  "(%g x %g s); the traffic file's window ends at tree %d"
+                  % (trees, elapsed, CEILING, seconds, end), flush=True)
+            goes_on = False
+        if not goes_on:
+            job.window_ended_at = trees
+        return goes_on
     if job.trace_first_tree is None:
         raise ValueError("a traced run needs trace_first_tree in the traffic "
                          "file")
@@ -164,3 +199,17 @@ def checks(job, must_stay_fused, skip=()):
             % (before, after, job.gbdt.iter_))),
     ]
     return [(name,) + check() for name, check in found if name not in skip]
+
+
+def window_check(job):
+    """[(guarantee, holds, what was found)] of an untraced window that ends at
+    the traffic file's ``window_end_tree``, else [].  A window that the
+    ceiling cut holds fewer, earlier and cheaper trees: its rate is not over
+    the cell's trees, so the run is not correct."""
+    ended = getattr(job, "window_ended_at", None)
+    if ended is None:
+        return []
+    end = job.window_end_tree
+    return [("window_reached_its_end", ended >= end,
+             "the window ended at tree %d; the traffic file's window ends at "
+             "tree %d" % (ended, end))]

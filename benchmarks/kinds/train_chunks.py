@@ -1,20 +1,28 @@
 """Job kind ``train_chunks``: what the CLI's ``GBDT.train`` does.
 
-Make data, bin it, build a booster, then call the fused ``train_chunk(K)`` —
-K trees in one XLA program — until the clock runs out.  One unit of work is one
-chunk, timed to ``block_until_ready`` of the training scores.
+Make data, bin it, build a booster, run one warm-up chunk, then call the fused
+``train_chunk(K)`` — K trees in one XLA program — back to back until the
+booster holds the traffic file's ``window_end_tree`` trees.  One unit of work
+is one chunk, timed to ``block_until_ready`` of the training scores.  The
+window is the same trees on every seed and every commit (later trees cost
+more: their windows hold more rows), and its true length divides the rate.
+``--seconds`` is only a ceiling there: once ``gbdt_job.CEILING`` times it
+have passed, the window ends at the last finished chunk and the run says so.
+A traffic file that states no ``window_end_tree`` runs chunks until
+``--seconds`` have passed.
 
-A ``--trace 1`` run does not go by the clock: after the warm-up chunk it runs
+A ``--trace 1`` run has no such window: after the warm-up chunk it runs
 chunks until the booster holds ``trace_first_tree`` trees, then
 ``trace_units`` chunks under the profiler, so that every commit traces the
-same trees however fast it is (later trees cost more: their windows hold more
-rows).  ``unit_wall_ms_per_tree`` and ``recompiles_in_window`` of such a run
-are over the trees between the warm-up and ``trace_first_tree``.
+same trees however fast it is.  ``unit_wall_ms_per_tree`` and
+``recompiles_in_window`` of such a run are over the trees between the warm-up
+and ``trace_first_tree``.
 
 Traffic parameters (the cell's file): ``trees_per_chunk``, ``auc_trees``,
-``trace_units``, ``trace_first_tree`` (the warm-up chunk plus a whole number
-of chunks, at least one; a kind's subclass or a test that never traces may
-leave it out).
+``trace_units``, ``trace_first_tree`` and ``window_end_tree`` (each the
+warm-up chunk plus a whole number of chunks, at least one; a kind's subclass
+or a test that never traces may leave the first out, one that goes by the
+clock the second).
 """
 from __future__ import annotations
 
@@ -35,6 +43,8 @@ class Job:
         self.k = int(wl["trees_per_chunk"])
         self.auc_trees = int(wl["auc_trees"])
         self.trace_first_tree = gbdt_job.trace_first_tree(
+            wl, warmup=self.k, unit=self.k)
+        self.window_end_tree = gbdt_job.window_end_tree(
             wl, warmup=self.k, unit=self.k)
         self.host_timers = {}
         self.counters = {}

@@ -9,11 +9,12 @@ order of a run and the contract's last line, nothing else.
 
 A run: set-up (data from the seed, the program's own ingest, warm-up of the
 cell's shapes; ``setup_s`` ends at the first timed dispatch), then with
-``--trace 0`` the measured window of ``--seconds``, with ``--trace 1`` the
-units up to the traffic file's ``trace_first_tree`` and a few traced units
-from that tree on (the same trees on every commit, however fast), then the
-checks that decide ``correct``.  The last line of standard output is the
-result; everything before it says what was found.
+``--trace 0`` the measured window (up to the traffic file's
+``window_end_tree``, or of ``--seconds`` where it states none), with
+``--trace 1`` the units up to the traffic file's ``trace_first_tree`` and a
+few traced units from that tree on (the same trees on every commit, however
+fast), then the checks that decide ``correct``.  The last line of standard
+output is the result; everything before it says what was found.
 """
 import time
 T0 = time.perf_counter()     # process start, before every other import
@@ -194,8 +195,9 @@ def main():
                                "unit": m["unit"]}
                    for m in bench["end_to_end"] if m["name"] in values}
 
+    import gbdt_job
     correct = not job.failed
-    for name, holds, found in job.check():
+    for name, holds, found in job.check() + gbdt_job.window_check(job):
         print("%s %s: %s" % ("ok " if holds else "NOT", name, found),
               flush=True)
         correct = correct and bool(holds)

@@ -121,7 +121,13 @@ def test_every_traffic_mix_states_the_tree_it_is_traced_at():
         wl = json.load(open(path))
         if "trees_per_chunk" in wl:
             warmup = unit = int(wl["trees_per_chunk"])
+            # where a chunk mix ends its window at a tree, that tree obeys
+            # the same rule
+            end = gbdt_job.window_end_tree(wl, warmup, unit)
+            assert end is None or end > warmup, path
         else:
             warmup, unit = int(wl["warmup_iters"]), int(wl["trace_units"])
+            # the iteration kinds' windows go by the clock
+            assert "window_end_tree" not in wl, path
         first = gbdt_job.trace_first_tree(wl, warmup, unit)
         assert first is not None and first > warmup, path
